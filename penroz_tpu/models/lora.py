@@ -311,7 +311,7 @@ def train_adapter(model, adapter_id: str, config: dict, dataset_id: str,
                         buffer_size=buffer_size, idx_offset=buffer_size)
         optimizer = dsl.build_optimizer(model.optimizer_config)
         opt_state = optimizer.init(lora_params)
-        platform = model._platform
+        platform = model._placement
         s = jnp.asarray(scale(config), jnp.float32)
         scale_keys = {k[:-len("lora_A")] + "lora_scale"
                       for k in lora_params if k.endswith(".lora_A")}

@@ -114,8 +114,11 @@ def _fce_fwd(logits, targets, chunk_rows: int, platform):
     x2d = logits.reshape(-1, v)
     t1d = targets.reshape(-1).astype(jnp.int32)
     if _use_pallas(x2d, platform):
+        from penroz_tpu.ops.attention import _on_shards
         from penroz_tpu.ops.pallas import cross_entropy as ce
-        lse, ll = ce.ce_forward(x2d, t1d)
+        # rows split over the data axis, the vocabulary never
+        lse, ll = _on_shards(ce.ce_forward, platform, ("b.", "b"),
+                             ("b.", "b."), x2d, t1d)
     else:
         lse, ll = _jnp_forward(x2d, t1d, chunk_rows)
     loss = jnp.sum(lse - ll) / n
@@ -130,8 +133,10 @@ def _fce_bwd(chunk_rows: int, platform, residuals, gbar):
     t1d = targets.reshape(-1).astype(jnp.int32)
     scale = gbar.astype(jnp.float32) / n
     if _use_pallas(x2d, platform):
+        from penroz_tpu.ops.attention import _on_shards
         from penroz_tpu.ops.pallas import cross_entropy as ce
-        grad = ce.ce_backward(x2d, t1d, lse, scale)
+        grad = _on_shards(ce.ce_backward, platform, ("b.", "b", "b.", ""),
+                          "b.", x2d, t1d, lse, scale)
     else:
         grad = _jnp_backward(x2d, t1d, lse, scale, chunk_rows)
     t_tangent = np.zeros(targets.shape, dtype=jax.dtypes.float0)

@@ -1252,7 +1252,8 @@ class CausalSelfAttention(Module):
                         q.shape[1], k.shape[1], n=n_seq)):
                 out = a2a.alltoall_attention_manual(
                     q, k, v, axis_name=ctx.sp_manual_axis,
-                    window=self.sliding_window, platform=ctx.platform,
+                    window=self.sliding_window,
+                    platform=attn_ops.platform_of(ctx.platform),
                     scale=self.attn_scale)
             else:
                 if ctx.sp_mode == "alltoall":
@@ -1281,11 +1282,12 @@ class CausalSelfAttention(Module):
                     and self.logit_softcap is None
                     and a2a.alltoall_supported(q.shape[1], k.shape[1],
                                                ctx.sp_mesh)):
-                out = a2a.alltoall_attention(q, k, v, ctx.sp_mesh,
-                                             causal=True,
-                                             window=self.sliding_window,
-                                             platform=ctx.platform,
-                                             scale=self.attn_scale)
+                # its body runs the kernel per shard, inside its own map
+                out = a2a.alltoall_attention(
+                    q, k, v, ctx.sp_mesh, causal=True,
+                    window=self.sliding_window,
+                    platform=attn_ops.platform_of(ctx.platform),
+                    scale=self.attn_scale)
             else:
                 if ctx.sp_mode == "alltoall":
                     # every fallback cause gets a trace-time signal, like

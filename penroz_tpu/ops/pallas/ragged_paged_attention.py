@@ -36,11 +36,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 from penroz_tpu.ops.pallas.flash_attention import _LANES
 
-# jax renamed TPUCompilerParams → CompilerParams across versions; take
-# whichever this install provides.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or getattr(pltpu, "TPUCompilerParams")
-
 _NEG_INF = -1e30
 
 #: Descriptor columns: (row, q_pos0, q_valid, kv_len).  ``row = -1`` marks
@@ -273,7 +268,7 @@ def ragged_paged_attention(q, flat_k, flat_v, block_table, page_size: int,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((Hkv, group, Tp, D), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         cost_estimate=pl.CostEstimate(
             flops=int(4 * Hq * Tp * span * D),

@@ -33,25 +33,40 @@ from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_BLOCK_T = 128
 _LOG_EPS = 1e-6  # floor before log: sigmoid underflow -> exactly-0 gate
+_NEG_INF = -1e30
 
-# jax renamed TPUCompilerParams → CompilerParams across versions; take
-# whichever this jax ships (same shim as ragged_paged_attention.py).
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or getattr(pltpu, "TPUCompilerParams")
+
+def _dot(a, b, contract):
+    """fp32 matmul at true fp32 precision (the MXU default multiplies f32
+    operands in bf16 passes — too coarse for products of decay factors)."""
+    return jax.lax.dot_general(a, b, (contract, ((), ())),
+                               preferred_element_type=jnp.float32,
+                               precision=jax.lax.Precision.HIGHEST)
 
 
 def _chunk_body(q, k, v, lg, s0):
-    """One chunk in fp32: (y, s_end) from (block_t, ·) operands + carry."""
-    la = jnp.cumsum(lg)  # inclusive
-    y = (q * jnp.exp(la)[:, None]) @ s0
-    scores = q @ k.T
-    t = la.shape[0]
+    """One chunk in fp32: (y, s_end) from (block_t, ·) operands + carry;
+    ``lg`` is the (block_t, 1) column of log gates.
+
+    Written in what Mosaic lowers: no cumsum primitive and no transpose,
+    so the inclusive prefix sum ``La`` is built in both orientations by
+    masked reductions over a (t, t) broadcast — along sublanes for the
+    row form, then the diagonal of that for the column form.
+    """
+    t = lg.shape[0]
     row = jax.lax.broadcasted_iota(jnp.int32, (t, t), 0)
     col = jax.lax.broadcasted_iota(jnp.int32, (t, t), 1)
-    decay = jnp.where(row >= col, jnp.exp(la[:, None] - la[None, :]), 0.0)
-    y = y + (scores * decay) @ v
-    kd = k * jnp.exp(la[-1] - la)[:, None]
-    s_end = jnp.exp(la[-1]) * s0 + kd.T @ v
+    la_row = jnp.sum(jnp.where(row <= col, lg, 0.0), axis=0,
+                     keepdims=True)                        # (1, t)
+    la_col = jnp.sum(jnp.where(row == col, la_row, 0.0), axis=1,
+                     keepdims=True)                        # (t, 1)
+    la_end = jnp.sum(lg, axis=0, keepdims=True)            # (1, 1)
+    y = _dot(q * jnp.exp(la_col), s0, ((1,), (0,)))
+    scores = _dot(q, k, ((1,), (1,)))
+    decay = jnp.exp(jnp.where(row >= col, la_col - la_row, _NEG_INF))
+    y = y + _dot(scores * decay, v, ((1,), (0,)))
+    kd = k * jnp.exp(la_end - la_col)
+    s_end = jnp.exp(la_end) * s0 + _dot(kd, v, ((0,), (0,)))
     return y, s_end
 
 
@@ -95,7 +110,10 @@ def gla_chunked(q, k, v, g, block_t: int = DEFAULT_BLOCK_T,
         return x.transpose(0, 2, 1, 3).reshape(B * H, Tp, x.shape[-1])
 
     qf, kf, vf = flat(q), flat(k), flat(v)
-    lgf = lg.transpose(0, 2, 1).reshape(B * H, Tp)
+    # Trailing unit dim: a 2-D (B*H, Tp) gate array would need a
+    # (1, block_t) block, and Mosaic requires a block's last two dims to be
+    # (8, 128)-divisible or the array's own.
+    lgf = lg.transpose(0, 2, 1).reshape(B * H, Tp, 1)
     num_t = Tp // block_t
     kernel = functools.partial(_gla_kernel, block_t=block_t)
     out = pl.pallas_call(
@@ -108,14 +126,14 @@ def gla_chunked(q, k, v, g, block_t: int = DEFAULT_BLOCK_T,
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((1, block_t, dv), lambda i, j: (i, j, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block_t), lambda i, j: (i, j),
+            pl.BlockSpec((1, block_t, 1), lambda i, j: (i, j, 0),
                          memory_space=pltpu.VMEM),
         ],
         out_specs=pl.BlockSpec((1, block_t, dv), lambda i, j: (i, j, 0),
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((B * H, Tp, dv), jnp.float32),
         scratch_shapes=[pltpu.VMEM((dk, dv), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         cost_estimate=pl.CostEstimate(
             flops=int(4 * B * H * Tp * block_t * (dk + dv)),
@@ -147,7 +165,7 @@ def gla_chunked_reference(q, k, v, g, block_t: int = DEFAULT_BLOCK_T):
     def per_seq(qs, ks, vs, lgs):
         def step(s0, xt):
             qc, kc, vc, lgc = xt
-            y, s_end = _chunk_body(qc, kc, vc, lgc, s0)
+            y, s_end = _chunk_body(qc, kc, vc, lgc[:, None], s0)
             return s_end, y
         xs = (qs.reshape(-1, block_t, dk), ks.reshape(-1, block_t, dk),
               vs.reshape(-1, block_t, dv), lgs.reshape(-1, block_t))

@@ -311,5 +311,7 @@ def gla_full(q, k, v, g, platform=None, training: bool = False):
     from penroz_tpu.ops import attention as attn_ops
     if not training and attn_ops._tpu_platform(q, platform):
         from penroz_tpu.ops.pallas import ssm_scan
-        return ssm_scan.gla_chunked(q, k, v, g)
+        return attn_ops._on_shards(ssm_scan.gla_chunked, platform,
+                                   ("b.h.", "b.h.", "b.h.", "b.h"), "b.h.",
+                                   q, k, v, g)
     return gla_full_reference(q, k, v, g)

@@ -77,28 +77,47 @@ def make_mesh(devices=None, *, data: Optional[int] = None, model: int = 1,
                       PIPE_AXIS))
 
 
-def serve_mesh(model: int = 1, devices=None) -> Mesh:
+def _replica_devices(devices, width: int, replica: int) -> list:
+    """The ``width`` local devices replica ``replica`` of a router group
+    owns: the contiguous range ``[replica·width, (replica+1)·width)``, so
+    N replicas on an N·width-chip host each get their own chips.  A host
+    too small for that range collapses the replica onto the first
+    ``width`` devices — same programs and numerics, shared placement (the
+    CPU parity suite rides this) — and says so, since on real chips it
+    means replicas queue behind one another on the same device."""
+    lo = int(replica) * width
+    if lo and lo + width > len(devices):
+        log.warning("serving replica %d needs local devices [%d, %d) but "
+                    "the host has %d; sharing the first %d with replica 0",
+                    replica, lo, lo + width, len(devices), width)
+        lo = 0
+    return devices[lo:lo + width]
+
+
+def serve_mesh(model: int = 1, devices=None, replica: int = 0) -> Mesh:
     """Serving mesh for ONE decode engine: ``model`` tensor-parallel
     devices, every other axis trivial.  Data parallelism across engines is
     the router's job (serve/router.py) — replicas own disjoint meshes
-    rather than sharing a ``data`` axis, so one replica's crash recovery
-    never invalidates another's compiled programs.  Built over the FIRST
-    ``model`` local devices so a 1-wide mesh on a multi-device host stays
-    on device 0 exactly like the unmeshed engine (the token-parity
-    guarantee the CPU suite proves rides on this)."""
+    (:func:`_replica_devices`) rather than sharing a ``data`` axis, so one
+    replica's crash recovery never invalidates another's compiled
+    programs.  Replica 0 is built over the FIRST ``model`` local devices,
+    so a 1-wide mesh on a multi-device host stays on device 0 exactly like
+    the unmeshed engine (the token-parity guarantee the CPU suite proves
+    rides on this)."""
     devices = list(devices if devices is not None else jax.local_devices())
     if model < 1 or model > len(devices):
         raise ValueError(f"serve mesh needs 1 <= model <= {len(devices)} "
                          f"local devices (got model={model})")
-    return make_mesh(devices[:model], model=model)
+    return make_mesh(_replica_devices(devices, model, replica), model=model)
 
 
 def serve_stage_meshes(stages: int, model: int = 1,
-                       devices=None) -> list[Mesh]:
+                       devices=None, replica: int = 0) -> list[Mesh]:
     """Per-stage serving meshes for ONE pipeline group
     (PENROZ_SERVE_PIPE_STAGES × PENROZ_SERVE_MESH_MODEL): stage ``s``
-    owns the contiguous local device range ``[s·model, (s+1)·model)``
-    as its own ``model``-wide TP mesh.  Disjoint meshes rather than one
+    owns the contiguous device range ``[s·model, (s+1)·model)`` of its
+    replica's ``stages × model`` devices as its own ``model``-wide TP
+    mesh.  Disjoint meshes rather than one
     ``pipe``-axis mesh because serving stages are MPMD — each stage
     compiles and dispatches its own program and the scheduler hands
     activations across (PAPERS.md #3), so a stage recompile or crash
@@ -114,6 +133,7 @@ def serve_stage_meshes(stages: int, model: int = 1,
                          f"(got {stages}, {model})")
     if len(devices) < stages * model:
         return [serve_mesh(model=model, devices=devices)] * stages
+    devices = _replica_devices(devices, stages * model, replica)
     return [make_mesh(devices[s * model:(s + 1) * model], model=model)
             for s in range(stages)]
 

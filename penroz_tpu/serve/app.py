@@ -1329,21 +1329,12 @@ def _configure_logging():  # pragma: no cover
         format="%(asctime)s %(levelname)s [%(processName)s] %(name)s: %(message)s")
 
 
-def _configure_compile_cache():  # pragma: no cover
-    """Persistent XLA compile cache so server restarts skip the 20-40s
-    first-compile of train/decode programs.  PENROZ_COMPILE_CACHE sets the
-    directory; empty string disables."""
-    path = os.environ.get("PENROZ_COMPILE_CACHE",
-                          os.path.expanduser("~/.cache/penroz_jax"))
-    if not path:
-        return
-    try:
-        import jax
-        os.makedirs(path, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", path)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-    except Exception:  # noqa: BLE001 — cache is an optimization only
-        log.exception("Persistent compile cache unavailable")
+def _configure_compile_cache():
+    """Persistent XLA compile cache so server restarts skip the first
+    compile of train/decode programs; placement rule in
+    utils/compile_cache.py (``JAX_COMPILATION_CACHE_DIR`` wins)."""
+    from penroz_tpu.utils import compile_cache
+    log.info("Persistent compile cache: %s", compile_cache.configure())
 
 
 def main(host: str = "127.0.0.1", port: int = 8000):  # pragma: no cover

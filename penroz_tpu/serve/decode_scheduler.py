@@ -778,7 +778,7 @@ class DecodeEngine:
         # pipeline group, placement is stage-partitioned instead: stage
         # params and KV-pool slices land on per-stage meshes.
         self._kv, self._mesh_devices = self._model.enter_serve_mesh(
-            self._kv, pipe=self._pipe)
+            self._kv, pipe=self._pipe, replica=self.replica)
         self._prefix_cache = None
         if self._extra_pages > 0 and isinstance(self._kv, KV.PagedKVState):
             base = self.capacity * self._kv.pages_per_seq

@@ -1,11 +1,9 @@
 #!/bin/bash
-# Persistent accelerator watcher: probe the backend in short-lived child
-# processes; on every success, run the full bench with per-phase partials
-# written into the repo (BENCH_PARTIAL.json), snapshot the result to a
-# round-stamped artifact, and COMMIT it.  Then re-arm: a pool that opens
-# twice yields two captures (rounds 2 and 3 both ended rc=3 with zero
-# driver-captured numbers; round 4's single-shot watcher fired once and
-# the final driver capture still missed).  Evidence must land in git the
+# Persistent accelerator watcher: probe the backend in a short-lived child
+# process (this shell never holds the chip, so bench.py can); on every
+# success, run the full bench with per-phase partials written into the
+# repo (BENCH_PARTIAL.json), snapshot the result to a round-stamped
+# artifact, and COMMIT it.  Then re-arm, so evidence lands in git the
 # moment it exists.
 set -u
 cd "$(dirname "$0")/.."
@@ -46,14 +44,14 @@ while true; do
       >> logs/bench_watch.log 2>&1; then
     attempt=$((attempt + 1))
     echo "$(date -u +%FT%TZ) backend up -> running bench (attempt $attempt)" >> logs/bench_watch.log
-    PENROZ_BENCH_PARTIAL=BENCH_PARTIAL.json PENROZ_BENCH_WAIT_S=300 \
+    PENROZ_BENCH_PARTIAL=BENCH_PARTIAL.json \
       timeout 3600 python bench.py > BENCH_MIDROUND.out 2>> logs/bench_watch.log
     rc=$?
     echo "$(date -u +%FT%TZ) bench rc=$rc" >> logs/bench_watch.log
     if [ "$rc" -ne 0 ]; then
       # Even a died/timed-out run leaves per-phase metrics in the
       # partial — commit the evidence rather than waiting for a clean
-      # pass that may never come (r02/r03 ended with zero numbers).
+      # pass that may never come.
       git add -- BENCH_PARTIAL.json >> logs/bench_watch.log 2>&1 \
         && git commit -m "bench watcher: partial capture (rc=$rc)" \
           -- BENCH_PARTIAL.json >> logs/bench_watch.log 2>&1 \

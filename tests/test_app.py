@@ -404,15 +404,18 @@ def test_docs_page(client):
     assert b"openapi.json" in body
 
 
-def test_train_bad_device_400s_before_202(client, toy_shards_appdir=None):
-    """A device typo must 400 synchronously, not 202 then silently no-op in
-    the background task."""
+@pytest.mark.parametrize("device", ["tpuu", "tpu", "cuda", "accelerator"])
+def test_train_bad_device_400s_before_202(client, device):
+    """A device typo — and an accelerator this (CPU-only) process does not
+    have — must 400 synchronously: not 202 then silently no-op in the
+    background task, and never a run on the CPU that reports Trained."""
     _create_model(client, "devcheck")
     status, body = client.json("PUT", "/train/", json={
         "model_id": "devcheck", "dataset_id": "nope", "shard": 0,
         "epochs": 1, "batch_size": 1, "block_size": 4, "step_size": 1,
-        "device": "tpuu"})
+        "device": device})
     assert status == 400
+    assert device in body["detail"]
 
 
 def test_orphaned_training_swept_at_startup(workdir):

@@ -1,0 +1,226 @@
+"""Every main-path Pallas kernel compiles for a TPU v5e at GPT-2 124M widths.
+
+Interpret mode (the other kernel tests) runs the kernel body as jnp and
+accepts layouts Mosaic refuses: a block whose last two dims are neither
+(8, 128)-divisible nor the array's own, more VMEM than a kernel may hold.
+The TPU compiler is installed here and compiles for a chip that is
+*described*, not attached (``jax.experimental.topologies``), so these
+cases ask it directly — no chip time, ~2 s each.  Nothing runs: a pass
+says the chip's compiler accepts the program, never that its output is
+right (``chip_smoke.py`` checks that on the chip against the jnp oracles).
+
+Shapes: 12 heads, D = 64, T = 1024, vocab 50304, 8 decode rows, and the
+page sizes the README launches use (8, 16) plus the 128-token default.
+"""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs to /tmp
+# Only the compiler is used, no chip is opened: let every xdist worker load
+# libtpu at once instead of failing on its one-process lockfile.
+os.environ.setdefault("ALLOW_MULTIPLE_LIBTPU_LOAD", "1")
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import (NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
+
+HEADS, HEAD_DIM, BLOCK, VOCAB, ROWS = 12, 64, 1024, 50304, 8
+
+
+@pytest.fixture(scope="module")
+def chips():
+    """The four described chips of a v5e 2x2 host; compile cache off around
+    the module (a described-topology entry can be written but not read back
+    without a chip, so a warm cache only adds a warning per compile)."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no libtpu / unknown topology
+        pytest.skip(f"TPU topology cannot be described here: {e}")
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield topo.devices
+    jax.config.update("jax_enable_compilation_cache", was_on)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def chip(chips):
+    return SingleDeviceSharding(chips[0])
+
+
+def _pool(page, dtype):
+    """(flat pool, per-token scale plane, block table) shapes of an
+    8-row × 1024-token paged cache."""
+    rows = ROWS * BLOCK
+    return ((HEADS, rows, HEAD_DIM), dtype), ((HEADS, rows, 1), jnp.float32), \
+        ((ROWS, BLOCK // page), jnp.int32)
+
+
+def _flash_fwd():
+    from penroz_tpu.ops.pallas import flash_attention as fa
+    qkv = ((ROWS, HEADS, BLOCK, HEAD_DIM), jnp.bfloat16)
+    return (lambda q, k, v: fa.flash_attention(q, k, v)), [qkv] * 3
+
+
+def _flash_bwd():
+    from penroz_tpu.ops.pallas import flash_attention as fa
+    qkv = ((ROWS, HEADS, BLOCK, HEAD_DIM), jnp.bfloat16)
+
+    def loss(q, k, v):
+        return fa.flash_attention(q, k, v).astype(jnp.float32).sum()
+
+    return jax.grad(loss, argnums=(0, 1, 2)), [qkv] * 3
+
+
+def _decode(quantized, dtype=jnp.bfloat16):
+    from penroz_tpu.ops.pallas import decode_attention as da
+    kv_dtype = jnp.int8 if quantized else dtype
+    q = ((ROWS, HEADS, 1, HEAD_DIM), dtype)
+    kv = ((ROWS, HEADS, BLOCK, HEAD_DIM), kv_dtype)
+    lengths = ((ROWS,), jnp.int32)
+    if not quantized:
+        return (lambda q, k, v, n: da.decode_attention(q, k, v, 0, n)), \
+            [q, kv, kv, lengths]
+    scale = ((ROWS, HEADS, BLOCK, 1), jnp.float32)
+    return (lambda q, k, v, n, ks, vs: da.decode_attention(
+        q, k, v, 0, n, k_scale=ks, v_scale=vs)), \
+        [q, kv, kv, lengths, scale, scale]
+
+
+def _paged(page, quantized):
+    from penroz_tpu.ops.pallas import paged_attention as pa
+    pool, scale, table = _pool(page, jnp.int8 if quantized else jnp.bfloat16)
+    q = ((ROWS, HEADS, 1, HEAD_DIM), jnp.bfloat16)
+    lengths = ((ROWS,), jnp.int32)
+    if not quantized:
+        return (lambda q, k, v, t, n: pa.paged_decode_attention(
+            q, k, v, t, page, 0, n)), [q, pool, pool, table, lengths]
+    return (lambda q, k, v, t, n, ks, vs: pa.paged_decode_attention(
+        q, k, v, t, page, 0, n, k_scale=ks, v_scale=vs)), \
+        [q, pool, pool, table, lengths, scale, scale]
+
+
+def _ragged(page, quantized, descs=40, block_q=8, dtype=jnp.bfloat16):
+    """A unified tick: 8 decode rows + a 256-token prefill chunk = 40
+    descriptors of block_q = 8 packed query slots."""
+    from penroz_tpu.ops.pallas import ragged_paged_attention as rpa
+    pool, scale, table = _pool(page, jnp.int8 if quantized else dtype)
+    q = ((1, HEADS, descs * block_q, HEAD_DIM), dtype)
+    d = ((descs, rpa.DESC_COLS), jnp.int32)
+    if not quantized:
+        return (lambda q, k, v, t, d: rpa.ragged_paged_attention(
+            q, k, v, t, page, d)), [q, pool, pool, table, d]
+    return (lambda q, k, v, t, d, ks, vs: rpa.ragged_paged_attention(
+        q, k, v, t, page, d, k_scale=ks, v_scale=vs)), \
+        [q, pool, pool, table, d, scale, scale]
+
+
+def _ce_fwd():
+    from penroz_tpu.ops.pallas import cross_entropy as ce
+    return ce.ce_forward, [((ROWS * BLOCK, VOCAB), jnp.bfloat16),
+                           ((ROWS * BLOCK,), jnp.int32)]
+
+
+def _ce_bwd():
+    from penroz_tpu.ops.pallas import cross_entropy as ce
+    return ce.ce_backward, [((ROWS * BLOCK, VOCAB), jnp.bfloat16),
+                            ((ROWS * BLOCK,), jnp.int32),
+                            ((ROWS * BLOCK, 1), jnp.float32),
+                            ((), jnp.float32)]
+
+
+def _ssm_scan():
+    """presets.hybrid_custom at GPT-2 124M widths: dk = dv = d / heads."""
+    from penroz_tpu.ops.pallas import ssm_scan
+    qkv = ((ROWS, BLOCK, HEADS, HEAD_DIM), jnp.bfloat16)
+    return ssm_scan.gla_chunked, [qkv] * 3 + [((ROWS, BLOCK, HEADS),
+                                               jnp.float32)]
+
+
+CASES = {
+    "flash_fwd": _flash_fwd,
+    "flash_bwd": _flash_bwd,
+    "decode_bf16": lambda: _decode(False),
+    "decode_int8": lambda: _decode(True),
+    "paged_bf16_page128": lambda: _paged(128, False),
+    "paged_int8_page128": lambda: _paged(128, True),
+    "paged_bf16_page16": lambda: _paged(16, False),
+    "paged_int8_page16": lambda: _paged(16, True),
+    "ragged_bf16_page128": lambda: _ragged(128, False),
+    "ragged_int8_page128": lambda: _ragged(128, True),
+    "ragged_bf16_page16": lambda: _ragged(16, False),
+    "ragged_int8_page16": lambda: _ragged(16, True),
+    "ragged_bf16_page8": lambda: _ragged(8, False),
+    "ragged_int8_page8": lambda: _ragged(8, True),
+    "ce_fwd": _ce_fwd,
+    "ce_bwd": _ce_bwd,
+    "ssm_scan": _ssm_scan,
+    # what a model created through POST /model/ serves in: fp32 params,
+    # fp32 cache (HF imports are bf16)
+    "decode_f32": lambda: _decode(False, jnp.float32),
+    "ragged_f32_page16": lambda: _ragged(16, False, dtype=jnp.float32),
+}
+
+
+@pytest.mark.parametrize("name", list(CASES))
+def test_kernel_compiles_for_v5e(chip, name):
+    fn, shapes = CASES[name]()
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+            for shape, dtype in shapes]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text(), \
+        f"{name}: no Mosaic custom call in the compiled program"
+
+
+# -- the same kernels inside a program GSPMD partitions over four chips -------
+#
+# Mosaic refuses a kernel call under jit over more than one device ("cannot
+# be automatically partitioned"), so the dispatchers in ops/attention.py map
+# the kernel over the mesh named by their ``platform`` hint.  These go
+# through those dispatchers, as the model does.
+
+def _mesh_train(hint):
+    """Flash fwd+bwd and fused CE fwd+bwd, batch split over ``data``."""
+    from penroz_tpu.ops import attention as A
+    from penroz_tpu.ops import losses
+    qkv = ((ROWS, HEADS, BLOCK, HEAD_DIM), jnp.bfloat16, P("data", "model"))
+    w = ((HEADS * HEAD_DIM, 2048), jnp.bfloat16, P())
+    y = ((ROWS, BLOCK), jnp.int32, P("data"))
+
+    def loss(q, k, v, w, y):
+        out = A.causal_attention(q, k, v, platform=hint)
+        logits = out.transpose(0, 2, 1, 3).reshape(ROWS, BLOCK, -1) @ w
+        return losses.fused_cross_entropy_mean(logits, y, 512, hint)
+
+    return jax.grad(loss, argnums=(0, 1, 2, 3)), [qkv, qkv, qkv, w, y]
+
+
+def _mesh_ragged(hint):
+    """The unified serving tick, heads split over ``model``."""
+    from penroz_tpu.ops import attention as A
+    _, shapes = _ragged(16, False, dtype=jnp.float32)
+    specs = [P(None, "model"), P("model"), P("model"), P(), P()]
+    return (lambda q, k, v, t, d: A.ragged_paged_cached_attention(
+        q, k, v, t, 16, d, platform=hint)), \
+        [(*shape, spec) for shape, spec in zip(shapes, specs)]
+
+
+@pytest.mark.parametrize("case,axes", [(_mesh_train, {"data": 4}),
+                                       (_mesh_train, {"model": 2}),
+                                       (_mesh_ragged, {"model": 4})])
+def test_kernels_compile_partitioned_for_v5e(chips, case, axes):
+    from penroz_tpu.ops.attention import Placement
+    from penroz_tpu.parallel import mesh as mesh_lib
+    mesh = mesh_lib.make_mesh(chips, model=axes.get("model", 1))
+    fn, shapes = case(Placement("tpu", mesh))
+    args = [jax.ShapeDtypeStruct(shape, dtype,
+                                 sharding=NamedSharding(mesh, spec))
+            for shape, dtype, spec in shapes]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()

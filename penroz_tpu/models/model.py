@@ -47,7 +47,8 @@ from penroz_tpu.ops import modules as M
 from penroz_tpu.parallel import dist
 from penroz_tpu.parallel import mesh as mesh_lib
 from penroz_tpu.parallel import sharding as sharding_lib
-from penroz_tpu.utils import checkpoint, profiling, stats as stats_lib
+from penroz_tpu.utils import (checkpoint, profiling, stats as stats_lib,
+                              tracing)
 
 log = logging.getLogger(__name__)
 
@@ -1274,7 +1275,7 @@ class NeuralNetworkModel:
     # -- training -----------------------------------------------------------
 
     def train_model(self, dataset_id, shard=0, epochs=1, batch_size=1,
-                    block_size=1024, step_size=1):
+                    block_size=1024, step_size=1, setup_span=None):
         """Grad-accumulated training with progress/stats bookkeeping and
         periodic checkpoints (reference: neural_net_model.py:552-722).
 
@@ -1290,8 +1291,15 @@ class NeuralNetworkModel:
         Per-epoch cost under a multi-host mesh is computed over the global
         batch inside the compiled program, which subsumes the reference's
         per-epoch ``ddp_all_reduce(cost)`` (:664-665).
+
+        ``setup_span``: the caller's open ``penroz/train_setup`` span
+        (:meth:`train_model_on_device` opens it before deserializing); it
+        is closed here, before the first epoch.  Without one, set-up is
+        this method's own part of it.
         """
         from penroz_tpu.data.loaders import Loader
+        if setup_span is None:
+            setup_span = tracing.span("penroz/train_setup").__enter__()
         master = dist.master_proc()
         saves_shards = False
         epoch = 0
@@ -1467,6 +1475,7 @@ class NeuralNetworkModel:
             stats_interval = float(
                 os.environ.get("PENROZ_STATS_INTERVAL", "60"))
             last_batch = None  # host-local numpy micro-batch for /stats/
+            setup_span.close()
             for epoch in range(epochs):
                 # Decode-priority window: queued /generate/ dispatches get
                 # the chip before the next epoch program is enqueued.
@@ -1480,7 +1489,8 @@ class NeuralNetworkModel:
                     # deterministic across the fleet.
                     long_training = dist.all_reduce_mean(
                         1.0 if long_training else 0.0) >= 0.5
-                with profiling.span("penroz/load_batch"):
+                with tracing.span("penroz/load_batch",
+                                  tokens=num_steps * buffer_size):
                     xs, ys = [], []
                     for _ in range(num_steps):
                         x, y = loader.next_batch()
@@ -1510,22 +1520,29 @@ class NeuralNetworkModel:
                              and num_steps > 1 and decode_pending() > 0
                              and float(os.environ.get(
                                  "PENROZ_DECODE_PRIORITY_MS", "1000")) > 0)
-                with profiling.span("penroz/train_epoch"):
-                    if use_micro:
-                        (self.params, self.opt_state, self.buffers, cost,
-                         ratios) = self._train_epoch_microstepped(
-                            xs, ys, jax.random.fold_in(rng, epoch),
-                            num_steps, remat=remat,
-                            compute_dtype=compute_dtype, sp_mesh=sp_mesh,
-                            out_shardings=epoch_out_shardings,
-                            sp_mode=sp_mode, ep_mesh=ep_mesh,
-                            with_ratios=sampled)
-                    else:
-                        (self.params, self.opt_state, self.buffers, cost,
-                         ratios) = fn(self.params, self.opt_state,
-                                      self.buffers, xs, ys,
-                                      jax.random.fold_in(rng, epoch))
-                cost = float(cost)
+                # The epoch ends when its cost is on the host: the call
+                # returns at dispatch, float(cost) waits for the device.
+                with tracing.span("penroz/train_epoch", epoch=epoch + 1,
+                                  tokens=num_steps * buffer_size,
+                                  sampled=sampled, microstepped=use_micro):
+                    with tracing.span("penroz/train_dispatch"):
+                        if use_micro:
+                            (self.params, self.opt_state, self.buffers,
+                             cost, ratios) = self._train_epoch_microstepped(
+                                xs, ys, jax.random.fold_in(rng, epoch),
+                                num_steps, remat=remat,
+                                compute_dtype=compute_dtype,
+                                sp_mesh=sp_mesh,
+                                out_shardings=epoch_out_shardings,
+                                sp_mode=sp_mode, ep_mesh=ep_mesh,
+                                with_ratios=sampled)
+                        else:
+                            (self.params, self.opt_state, self.buffers,
+                             cost, ratios) = fn(
+                                self.params, self.opt_state, self.buffers,
+                                xs, ys, jax.random.fold_in(rng, epoch))
+                    with tracing.span("penroz/train_wait"):
+                        cost = float(cost)
                 duration = time.monotonic() - t0
                 if master:
                     if epoch % sample_every == 0:
@@ -1544,8 +1561,10 @@ class NeuralNetworkModel:
                     if master:
                         refresh = (time.monotonic() - last_stats
                                    >= stats_interval)
-                        self._record_overall_progress(
-                            last_batch if refresh else None)
+                        with tracing.span("penroz/train_stats",
+                                          refreshed=refresh):
+                            self._record_overall_progress(
+                                last_batch if refresh else None)
                         if refresh:
                             last_stats = time.monotonic()
                     if master or saves_shards:
@@ -1555,7 +1574,8 @@ class NeuralNetworkModel:
             self.status = {"code": "Trained",
                            "message": f"Trained {epochs} epoch(s)"}
             if master:
-                self._record_overall_progress(last_batch)
+                with tracing.span("penroz/train_stats", refreshed=True):
+                    self._record_overall_progress(last_batch)
             if master or saves_shards:
                 self.serialize(tag=epochs)
             # Fence the run's end across processes: the master's post-train
@@ -1574,6 +1594,7 @@ class NeuralNetworkModel:
                 log.warning("train-end barrier failed; a peer may have "
                             "errored mid-run", exc_info=True)
         except Exception as e:  # noqa: BLE001
+            setup_span.close()
             try:
                 # Hosts reach this handler independently — never run the
                 # (collective) cross-host unstack one-sided.
@@ -2023,7 +2044,7 @@ class NeuralNetworkModel:
     @classmethod
     def train_model_on_device(cls, model_id, device, dataset_id, shard,
                               epochs, batch_size, block_size, step_size,
-                              adapter=None):
+                              adapter=None, trace=None):
         """Worker entry: deserialize → place → train (reference DDP worker:
         neural_net_model.py:516-550, minus the process tree — one process
         owns the TPU runtime and the mesh handles per-chip parallelism).
@@ -2042,41 +2063,73 @@ class NeuralNetworkModel:
         (here when the device cannot be resolved, in the parent's
         post-mortem when the runtime would not start at all).  It is
         opt-in for exactly that reason.
+
+        ``trace`` (``utils/tracing.py``, started by ``PUT /train/``) is
+        made current for this thread, so the job's spans land in it, and
+        is finished here with the job's end status.  A child process
+        records nothing into it, and the trace says so.
         """
+        try:
+            with tracing.use(trace):
+                model = cls._train_on_device(
+                    model_id, device, dataset_id, shard, epochs,
+                    batch_size, block_size, step_size, adapter, trace)
+        except BaseException as e:
+            if trace is not None:
+                trace.annotate(status="Error", error=str(e))
+                trace.finish("error")
+            raise
+        if trace is not None:
+            code = model.status.get("code")
+            trace.annotate(status=code)
+            trace.finish("error" if code == "Error" else "completed")
+        return model
+
+    @classmethod
+    def _train_on_device(cls, model_id, device, dataset_id, shard, epochs,
+                         batch_size, block_size, step_size, adapter, trace):
         if (os.environ.get("PENROZ_TRAIN_WORKER", "0") == "1"
                 and dist.process_count() == 1):
+            if trace is not None:
+                trace.annotate(recorded=False, note=(
+                    "PENROZ_TRAIN_WORKER=1: the job ran in a child process; "
+                    "spans are recorded in the training process only"))
             return cls._train_in_worker_process(
                 model_id, device, dataset_id, shard, epochs, batch_size,
                 block_size, step_size, adapter=adapter)
-        model = cls.deserialize(model_id)
-        try:
-            model.to_device(device)
-        except ValueError as e:
-            # /train/ validated the string in the serving process; failing
-            # here means THIS process (a worker child) cannot reach the
-            # device — record it where /progress/ polls will see it (an
-            # adapter run leaves the base status alone; the parent's
-            # post-mortem logs its death).
+        with tracing.span("penroz/train_setup") as setup_span:
+            model = cls.deserialize(model_id)
+            try:
+                model.to_device(device)
+            except ValueError as e:
+                # /train/ validated the string in the serving process;
+                # failing here means THIS process (a worker child) cannot
+                # reach the device — record it where /progress/ polls will
+                # see it (an adapter run leaves the base status alone; the
+                # parent's post-mortem logs its death).
+                if adapter is None:
+                    model.status = {"code": "Error", "message": str(e)}
+                    model.serialize(sync_flush=True)
+                raise
+            log.info("Training model %s on %s", model_id,
+                     model.device if model.device is not None
+                     else f"default placement ({jax.default_backend()})")
             if adapter is None:
-                model.status = {"code": "Error", "message": str(e)}
-                model.serialize(sync_flush=True)
-            raise
-        log.info("Training model %s on %s", model_id,
-                 model.device if model.device is not None
-                 else f"default placement ({jax.default_backend()})")
-        if adapter is not None:
-            # LoRA fine-tune: the base stays frozen, only the adapter tree
-            # trains, and the checkpoint written is adapter-only
-            # (models/lora.py) — registry-loadable the moment it lands.
-            from penroz_tpu.models import lora
-            lora.train_adapter(model, adapter["adapter_id"], adapter,
-                               dataset_id, shard=shard, epochs=epochs,
-                               batch_size=batch_size, block_size=block_size,
-                               step_size=step_size)
-            return model
-        model.train_model(dataset_id, shard=shard, epochs=epochs,
-                          batch_size=batch_size, block_size=block_size,
-                          step_size=step_size)
+                # closes setup_span itself, before the first epoch
+                model.train_model(dataset_id, shard=shard, epochs=epochs,
+                                  batch_size=batch_size,
+                                  block_size=block_size,
+                                  step_size=step_size,
+                                  setup_span=setup_span)
+                return model
+        # LoRA fine-tune: the base stays frozen, only the adapter tree
+        # trains, and the checkpoint written is adapter-only
+        # (models/lora.py) — registry-loadable the moment it lands.
+        from penroz_tpu.models import lora
+        lora.train_adapter(model, adapter["adapter_id"], adapter,
+                           dataset_id, shard=shard, epochs=epochs,
+                           batch_size=batch_size, block_size=block_size,
+                           step_size=step_size)
         return model
 
     @classmethod
@@ -3308,7 +3361,20 @@ class NeuralNetworkModel:
         The raw-layout check runs BEFORE the canonical conversion: with a
         pipeline-stacked layout still active, unstacking cross-host leaves
         is itself a collective, and an uncoordinated call must not launch
-        one one-sided."""
+        one one-sided.
+
+        One ``penroz/ckpt_save`` span (``periodic``: tagged, i.e. a
+        checkpoint of a training step — the 10 s cadence and the job's
+        last) with the d2h / encode / write / flush anatomy beneath it."""
+        with tracing.span("penroz/ckpt_save", tag=tag,
+                          periodic=tag is not None) as save_span:
+            nbytes = self._serialize(sync_flush, tag)
+            if nbytes is not None:
+                save_span.set(bytes=nbytes)
+
+    def _serialize(self, sync_flush: bool, tag) -> int | None:
+        """:meth:`serialize` proper; the main blob's size in bytes where
+        this process wrote one."""
         if tag is None:
             # Raw-layout check over params + buffers + optimizer leaves:
             # buffers are placed replicated at train start, but epoch
@@ -3346,8 +3412,11 @@ class NeuralNetworkModel:
         # Host-readable materialization only after the master check — every
         # non-master host doing full D2H copies of replicated state just to
         # discard them would waste seconds per checkpoint at scale.
-        host_arrays = {name: np.asarray(v) for name, v in items.items()
-                       if self._is_host_readable(v)}
+        with tracing.span("penroz/ckpt_d2h") as d2h_span:
+            host_arrays = {name: np.asarray(v) for name, v in items.items()
+                           if self._is_host_readable(v)}
+            d2h_span.set(arrays=len(host_arrays),
+                         bytes=sum(a.nbytes for a in host_arrays.values()))
         # Key/leaf sets come from the canonical layout (== items), not
         # self.params/opt_state, which may be pipeline-stacked mid-training.
         n_opt = sum(1 for name in items if name.startswith("__opt__"))
@@ -3372,7 +3441,7 @@ class NeuralNetworkModel:
             "stats": self.stats,
             "status": self.status,
         }
-        checkpoint.save(self.model_id, data, sync_flush=sync_flush)
+        return checkpoint.save(self.model_id, data, sync_flush=sync_flush)
 
     def _serialize_meta_only(self, sync_flush: bool = False):
         """Update progress/status in the existing blob without touching the

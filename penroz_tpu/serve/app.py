@@ -837,6 +837,12 @@ async def train_model(request: web.Request):
         return _json({"detail": f"Training already in progress for model {model_id}."},
                      status=409)
 
+    # The job's timeline (utils/tracing.py): its id is this request's, in
+    # the 202's X-Request-Id; GET /trace/{id} resolves it while the job
+    # runs and after.  Sampled by PENROZ_TRACE_SAMPLE like any request.
+    trace = tracing.maybe_trace(request["request_id"], job=True,
+                                route="/train/", model_id=model_id)
+
     async def _launch():
         async with lock:
             log.info("Waiting for training of model %s to complete...", model_id)
@@ -845,7 +851,7 @@ async def train_model(request: web.Request):
                     NeuralNetworkModel.train_model_on_device, model_id,
                     body.device, body.dataset_id, body.shard, body.epochs,
                     body.batch_size, body.block_size, body.step_size,
-                    adapter_cfg)
+                    adapter_cfg, trace)
             except Exception:  # noqa: BLE001
                 log.exception("Training failed for model %s", model_id)
             else:

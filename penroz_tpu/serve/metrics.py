@@ -48,7 +48,9 @@ histograms ttft_ms, itl_ms, queue_wait_ms, chunk_stall_ms, tick_ms
            queue_wait_ms_by_class{priority} (one series family per
            SLO class), plus the disagg pair disagg_handoff_ms /
            disagg_handoff_bytes (hand-off latency and payload size),
-           and session_resume_ttft_ms (hibernated-session wake latency)
+           and session_resume_ttft_ms (hibernated-session wake latency),
+           and train_span_ms{span} (every span of /train/ jobs by name,
+           observed by utils/tracing.py)
 
 The tier/session series (tier_pages{tier}, sessions_resident, and the
 tier_* counters) describe the hierarchical session store
@@ -58,6 +60,7 @@ tier_* counters) describe the hierarchical session store
 from __future__ import annotations
 
 from penroz_tpu.utils import metrics as m
+from penroz_tpu.utils import tracing
 
 REGISTRY = m.Registry()
 
@@ -250,6 +253,10 @@ SESSION_RESUME_TTFT_MS = REGISTRY.register(m.Histogram(
     "session (radix hit on still-resident pages, or a host/disk-tier "
     "promotion) — compare against penroz_ttft_ms for the cold-"
     "re-prefill baseline"))
+
+# Every span of every /train/ job by name (train_epoch, ckpt_save and its
+# children, ...): the object lives with the code that observes it.
+TRAIN_SPAN_MS = REGISTRY.register(tracing.TRAIN_SPAN_MS)
 
 # -- gauges (scrape-time reads of live state) -------------------------------
 

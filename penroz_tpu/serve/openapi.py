@@ -99,19 +99,27 @@ def _routes() -> list[dict]:
                      "dependency-free): request/token/shed/crash "
                      "counters, engine gauges, and fixed-bucket TTFT / "
                      "ITL / queue-wait / chunk-stall / tick-duration "
-                     "histograms",
+                     "histograms, and penroz_train_span_ms{span}: every "
+                     "span of /train/ jobs by name",
              responses=dict([_resp(200, "text/plain exposition")])),
         dict(method="get", path="/trace/",
              summary="Recent per-request trace summaries (completed ring "
                      "of PENROZ_TRACE_BUFFER + in-flight), sampled via "
-                     "PENROZ_TRACE_SAMPLE",
+                     "PENROZ_TRACE_SAMPLE; training jobs are listed too "
+                     "(route '/train/', model_id, end status)",
              responses=dict([_resp(200, "Trace summaries")])),
         dict(method="get", path="/trace/{request_id}",
              summary="One request's lifecycle span tree: queue wait, "
                      "prefix-cache match, prefill chunks, decode/verify "
                      "steps, crash-recovery events, retirement reason "
                      "(request ids come from the X-Request-Id response "
-                     "header); ?format=chrome returns the same tree as "
+                     "header).  For a PUT /train/ job, live and after: "
+                     "penroz/train_setup, load_batch, train_epoch "
+                     "(train_dispatch + train_wait), train_stats, "
+                     "ckpt_save (ckpt_d2h, ckpt_encode, ckpt_write, "
+                     "ckpt_flush), compile; the newest subtrees, "
+                     "dropped_spans and per-name totals.  "
+                     "?format=chrome returns the same tree as "
                      "Chrome trace-event JSON loadable in Perfetto / "
                      "chrome://tracing",
              params=[{"name": "format", "in": "query", "required": False,
@@ -214,9 +222,12 @@ def _routes() -> list[dict]:
         dict(method="put", path="/train/",
              summary="Train asynchronously (poll /progress/; with an "
                      "'adapter' config, fine-tune a LoRA adapter against "
-                     "the frozen base and poll GET /adapters/)",
+                     "the frozen base and poll GET /adapters/).  The "
+                     "202's X-Request-Id names the job's trace: GET "
+                     "/trace/{id} is its timeline while it runs and after",
              body=_body("TrainingRequest"),
-             responses=dict([_resp(202, "Training started"),
+             responses=dict([_resp(202, "Training started; X-Request-Id "
+                                        "is the job's trace id"),
                              _resp(404, "Unknown model"),
                              _resp(400, "Invalid device or adapter config"),
                              _resp(409, "Training already in progress")])),

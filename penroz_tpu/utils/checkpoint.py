@@ -39,6 +39,8 @@ import zlib
 
 import numpy as np
 
+from penroz_tpu.utils import tracing
+
 log = logging.getLogger(__name__)
 
 MODELS_FOLDER = "models"
@@ -95,9 +97,9 @@ def _encode_parts(data):
     return header, arrays, meta
 
 
-def _write_stream(f, data):
-    """Write the container to a binary file object."""
-    header, arrays, meta = _encode_parts(data)
+def _write_parts(f, header, arrays, meta) -> int:
+    """Write :func:`_encode_parts`' output to a binary file object as the
+    container; returns the bytes written."""
     f.write(MAGIC)
     f.write(struct.pack("<Q", len(header)))
     f.write(header)
@@ -109,13 +111,14 @@ def _write_stream(f, data):
         # memory at max(array) instead of sum(arrays).
         f.write(a.tobytes())
         written = m["offset"] + m["nbytes"]
+    return 16 + len(header) + written
 
 
 def _encode(data) -> bytes:
     """Container bytes in memory (tests / small blobs)."""
     import io
     buf = io.BytesIO()
-    _write_stream(buf, data)
+    _write_parts(buf, *_encode_parts(data))
     return buf.getvalue()
 
 
@@ -368,7 +371,8 @@ def save_shard(model_id: str, process_index: int, data: dict,
     os.makedirs(os.path.join(SHM_PATH, MODELS_FOLDER), exist_ok=True)
     rel = shard_file_path(model_id, process_index)
     shm_path = os.path.join(SHM_PATH, rel)
-    _atomic_write(shm_path, data)
+    with tracing.span("penroz/ckpt_shard_write") as sp:
+        sp.set(bytes=_atomic_write(shm_path, data))
     if sync_flush:
         _flush(shm_path, rel)
     else:
@@ -653,8 +657,9 @@ def sweep_tier_orphans(referenced_ids) -> dict:
     return {"temp_files_swept": temps, "blobs_swept": blobs}
 
 
-def save(model_id: str, data: dict, sync_flush: bool = False):
-    """Write checkpoint to shm and flush to disk in the background.
+def save(model_id: str, data: dict, sync_flush: bool = False) -> int:
+    """Write checkpoint to shm and flush to disk in the background;
+    returns the checkpoint's size in bytes.
 
     Both writes are atomic (temp file + rename) so concurrent readers —
     cross-process ``load()`` on shm, the background flush on durable — never
@@ -665,7 +670,7 @@ def save(model_id: str, data: dict, sync_flush: bool = False):
     shm_path = shm_model_path(model_id)
     durable_path = model_path(model_id)
     log.info("Caching model to %s...", shm_path)
-    _atomic_write(shm_path, data)
+    nbytes = _atomic_write(shm_path, data)
     log.info("Model cached successfully: %s", shm_path)
     if sync_flush:
         _flush(shm_path, durable_path)
@@ -674,6 +679,7 @@ def save(model_id: str, data: dict, sync_flush: bool = False):
         # JAX's thread pool, and the copy is pure file I/O anyway.
         log.info("Offload flushing model cache %s to %s...", shm_path, durable_path)
         _spawn_flush(shm_path, durable_path)
+    return nbytes
 
 
 def _mkstemp_for(path: str):
@@ -699,18 +705,25 @@ def _mkstemp_for(path: str):
             continue
 
 
-def _atomic_write(path: str, data: dict):
+def _atomic_write(path: str, data: dict) -> int:
+    """Encode ``data`` and write it to ``path`` through a temp sibling and a
+    rename; returns the file's size in bytes."""
     from penroz_tpu.utils import faults
     faults.check("ckpt.write")
-    fd, tmp_path = _mkstemp_for(path)
-    try:
-        with os.fdopen(fd, "wb") as f:
-            _write_stream(f, data)
-        os.replace(tmp_path, path)
-    except BaseException:
-        if os.path.exists(tmp_path):
-            os.remove(tmp_path)
-        raise
+    with tracing.span("penroz/ckpt_encode"):
+        parts = _encode_parts(data)
+    with tracing.span("penroz/ckpt_write") as sp:
+        fd, tmp_path = _mkstemp_for(path)
+        try:
+            with os.fdopen(fd, "wb") as f:
+                nbytes = _write_parts(f, *parts)
+            os.replace(tmp_path, path)
+        except BaseException:
+            if os.path.exists(tmp_path):
+                os.remove(tmp_path)
+            raise
+        sp.set(bytes=nbytes)
+    return nbytes
 
 
 _FLUSH_THREADS: list = []
@@ -721,8 +734,15 @@ def _spawn_flush(shm_path: str, durable_path: str):
     deleting the source (a delete racing an in-flight flush is harmless
     but logs a 'source vanished' warning)."""
     _FLUSH_THREADS[:] = [t for t in _FLUSH_THREADS if t.is_alive()]
-    t = threading.Thread(target=_flush, args=(shm_path, durable_path),
-                         daemon=True)
+    # A plain thread does not inherit the caller's context: hand it the
+    # trace binding, so the flush is a child of the save that spawned it.
+    binding = tracing.capture()
+
+    def flush():
+        with tracing.use(binding):
+            _flush(shm_path, durable_path)
+
+    t = threading.Thread(target=flush, daemon=True)
     _FLUSH_THREADS.append(t)
     t.start()
 
@@ -736,23 +756,26 @@ def join_flushes(timeout: float = 10.0):
 
 def _flush(shm_path: str, durable_path: str):
     tmp_path = None
-    try:
-        # Unique temp name: overlapping flushes of the same model must not
-        # interleave writes into one file.
-        fd, tmp_path = _mkstemp_for(durable_path)
-        os.close(fd)
-        shutil.copyfile(shm_path, tmp_path)
-        os.replace(tmp_path, durable_path)
-        if not os.path.exists(shm_path):
-            # delete() ran mid-flush: don't resurrect the durable copy
-            os.remove(durable_path)
-            log.warning("Flush rolled back, model deleted: %s", durable_path)
-    except FileNotFoundError:
-        # Model deleted (or workdir cleaned) between save and flush.
-        log.warning("Flush skipped, source vanished: %s", shm_path)
-    finally:
-        if tmp_path is not None and os.path.exists(tmp_path):
-            os.remove(tmp_path)
+    with tracing.span("penroz/ckpt_flush") as sp:
+        try:
+            # Unique temp name: overlapping flushes of the same model must
+            # not interleave writes into one file.
+            fd, tmp_path = _mkstemp_for(durable_path)
+            os.close(fd)
+            shutil.copyfile(shm_path, tmp_path)
+            sp.set(bytes=os.path.getsize(tmp_path))
+            os.replace(tmp_path, durable_path)
+            if not os.path.exists(shm_path):
+                # delete() ran mid-flush: don't resurrect the durable copy
+                os.remove(durable_path)
+                log.warning("Flush rolled back, model deleted: %s",
+                            durable_path)
+        except FileNotFoundError:
+            # Model deleted (or workdir cleaned) between save and flush.
+            log.warning("Flush skipped, source vanished: %s", shm_path)
+        finally:
+            if tmp_path is not None and os.path.exists(tmp_path):
+                os.remove(tmp_path)
 
 
 def load(model_id: str) -> dict:

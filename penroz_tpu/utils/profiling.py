@@ -8,7 +8,8 @@ a real profile hook on top: these helpers expose
 - ``start(log_dir)`` / ``stop()`` — capture an XLA/TPU trace viewable in
   TensorBoard or Perfetto (device kernels, HBM transfers, host callbacks);
 - ``span(name)`` — a trace annotation context for hot-path regions (train
-  epoch, decode dispatch) so captured traces carry framework-level names;
+  epoch, decode dispatch) so captured traces carry framework-level names
+  (``utils/tracing.py::span``, which also records into the current trace);
 - ``maybe_start_server()`` — a live-profiling gRPC endpoint
   (``PENROZ_PROFILER_PORT``) for `tensorboard --logdir` capture on a
   running service.
@@ -19,12 +20,13 @@ training or serving.
 
 from __future__ import annotations
 
-import contextlib
 import logging
 import os
 import threading
 
 import jax
+
+from penroz_tpu.utils import tracing
 
 log = logging.getLogger(__name__)
 
@@ -75,13 +77,10 @@ def stop() -> str | None:
         return log_dir
 
 
-def span(name: str):
-    """Named region annotation visible in captured traces (cheap no-op when
-    nothing is capturing)."""
-    try:
-        return jax.profiler.TraceAnnotation(name)
-    except Exception:  # noqa: BLE001 — profiling must never break the path
-        return contextlib.nullcontext()
+# Named region visible in captured traces (cheap when nothing is capturing)
+# and, when a trace is current, in that trace's span tree: one
+# implementation, in utils/tracing.py.
+span = tracing.span
 
 
 def maybe_start_server() -> bool:

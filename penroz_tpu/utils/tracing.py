@@ -31,6 +31,34 @@ admission — at 0 the scheduler's per-request overhead is a single
 Tracing is host-side bookkeeping only: it never touches device buffers,
 so greedy outputs are token-identical with tracing on, sampled, or off
 (pinned by tests/test_observability.py).
+
+**One span call, two sinks.**  :func:`span` is the one place the program
+names a region of its own time.  It always opens a
+``jax.profiler.TraceAnnotation`` of exactly that name, so the region has
+a twin on the device trace's clock whenever ``/profile/`` (or a benchmark)
+captures; and when a trace is *current* in the calling context
+(``with tracing.use(trace):``) it records the same interval into that
+trace's tree, under the enclosing open span.  A ``PUT /train/`` job is
+such a trace (``job=True``): hours long, so it keeps its first top-level
+spans (set-up, the compiling first epochs), a ring of the newest, and
+per-name totals that never forget:
+
+    request                      [meta: route=/train/, model_id, status]
+    ├─ penroz/train_setup        deserialize → placement → loader, programs
+    │  └─ penroz/ckpt_save       (status "Training")
+    ├─ penroz/load_batch         [tokens]
+    ├─ penroz/train_epoch        [epoch, tokens, sampled, microstepped]
+    │  ├─ penroz/train_dispatch  the call of the epoch program
+    │  │  └─ penroz/compile      [seconds]  (first epoch only)
+    │  └─ penroz/train_wait      float(cost): the device's work
+    ├─ penroz/train_stats        [refreshed]
+    ├─ penroz/ckpt_save          [tag, periodic, bytes]
+    │  ├─ penroz/ckpt_d2h        [bytes, arrays]
+    │  ├─ penroz/ckpt_encode     CRC32 pass
+    │  ├─ penroz/ckpt_write      [bytes]  header + arrays + rename, to shm
+    │  └─ penroz/ckpt_flush      [bytes]  background copy to models/; ends
+    │                            after its parent has closed
+    └─ ...
 """
 
 from __future__ import annotations
@@ -44,12 +72,35 @@ import threading
 import time
 import uuid
 
+import jax
+
+from penroz_tpu.utils import metrics
+
 TRACE_BUFFER_ENV = "PENROZ_TRACE_BUFFER"
 TRACE_SAMPLE_ENV = "PENROZ_TRACE_SAMPLE"
 
 # Hard per-trace span cap: a 100k-token generation must not grow an
 # unbounded span list — past the cap, spans are counted, not stored.
 MAX_SPANS = 1024
+
+# A job trace (``job=True``: one ``PUT /train/``) keeps its first JOB_HEAD
+# top-level spans for good (set-up and the first epochs, where programs
+# compile) and a ring of the JOB_RING newest; whole subtrees leave from
+# the ring's old end.  Two top-level spans an epoch: the ring is the last
+# ~2000 epochs, a few MB.
+JOB_HEAD = 8
+JOB_RING = 4096
+
+# Every job span's duration by name, over the life of the process:
+# ``penroz_train_span_ms{span=...}`` on GET /metrics (registered by
+# serve/metrics.py).  The ring forgets; this does not.
+TRAIN_SPAN_MS = metrics.Histogram(
+    "penroz_train_span_ms",
+    "Duration of each span of /train/ jobs by span name "
+    "(utils/tracing.py: train_epoch, ckpt_save and its children, ...), ms",
+    labelnames=("span",))
+
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 _request_id_var: contextvars.ContextVar = contextvars.ContextVar(
     "penroz_request_id", default=None)
@@ -115,7 +166,7 @@ class RequestIdFilter(logging.Filter):
 # -- spans ------------------------------------------------------------------
 
 class Span:
-    __slots__ = ("name", "t0", "t1", "meta", "children")
+    __slots__ = ("name", "t0", "t1", "meta", "children", "detached")
 
     def __init__(self, name: str, t0: float, meta: dict | None = None):
         self.name = name
@@ -123,6 +174,7 @@ class Span:
         self.t1: float | None = None
         self.meta = meta or {}
         self.children: list[Span] = []
+        self.detached = False   # its subtree has left a job trace's ring
 
     def to_dict(self, base: float) -> dict:
         out = {
@@ -140,12 +192,24 @@ class Span:
         return out
 
 
+def _detach(sp: Span) -> int:
+    """Mark a subtree as gone from its trace; returns its size."""
+    sp.detached = True
+    return 1 + sum(_detach(c) for c in sp.children)
+
+
 class Trace:
     """One request's span tree.  All mutation goes through methods that
     take the trace lock — spans arrive from the scheduler worker thread
-    while the HTTP layer may be serializing the in-flight tree."""
+    while the HTTP layer may be serializing the in-flight tree.
 
-    def __init__(self, request_id: str, **meta):
+    ``job=True`` is a long-running job's trace (a ``PUT /train/``): the
+    root keeps its first ``JOB_HEAD`` children and the ``JOB_RING`` newest
+    (a request keeps the *oldest* ``MAX_SPANS``, which for a job of
+    600 000 epochs would be its first minutes), counts what left in
+    ``dropped_spans``, and folds every closed span into ``totals``."""
+
+    def __init__(self, request_id: str, job: bool = False, **meta):
         self.request_id = request_id
         self.started_unix = time.time()
         self.t0 = time.monotonic()
@@ -155,6 +219,9 @@ class Trace:
         self._finished = False
         self._span_count = 1
         self.dropped_spans = 0
+        self.job = job
+        # span name -> metrics.Hist of durations in ms (job traces only)
+        self.totals: dict = {}
         # Set by the scheduler once the request is accepted into its
         # queue: from then on the ENGINE guarantees the finish (retire /
         # shed / crash recovery), and the HTTP layer must not finish the
@@ -171,12 +238,24 @@ class Trace:
         with self._lock:
             if self._finished:
                 return None
-            if self._span_count >= MAX_SPANS:
+            if not self.job and self._span_count >= MAX_SPANS:
                 self.dropped_spans += 1
                 return None
             sp = Span(name, t0 if t0 is not None else time.monotonic(), meta)
-            (parent or self.root).children.append(sp)
+            if parent is not None and parent.detached:
+                # a late child (a flush) of a subtree the ring has let go:
+                # timed for the totals, held nowhere
+                sp.detached = True
+                self.dropped_spans += 1
+                return sp
+            siblings = (parent or self.root).children
+            siblings.append(sp)
             self._span_count += 1
+            if (self.job and siblings is self.root.children
+                    and len(siblings) > JOB_HEAD + JOB_RING):
+                gone = _detach(siblings.pop(JOB_HEAD))
+                self._span_count -= gone
+                self.dropped_spans += gone
             return sp
 
     def end(self, sp: Span | None, t1: float | None = None, **meta) -> None:
@@ -186,6 +265,14 @@ class Trace:
             sp.t1 = t1 if t1 is not None else time.monotonic()
             if meta:
                 sp.meta.update(meta)
+            if not self.job:
+                return
+            hist = self.totals.get(sp.name)
+            if hist is None:
+                hist = self.totals[sp.name] = metrics.Hist()
+            ms = (sp.t1 - sp.t0) * 1000.0
+            hist.observe(ms)
+        TRAIN_SPAN_MS.observe(ms, span=sp.name)
 
     def event(self, name: str, parent: Span | None = None, **meta) -> None:
         """Point-in-time marker: a zero-length span."""
@@ -238,6 +325,15 @@ class Trace:
                 "meta": dict(self.meta),
                 "dropped_spans": self.dropped_spans,
                 "root": self.root.to_dict(self.t0),
+                # quantiles of a bucket histogram: the bucket's upper edge
+                **({"totals": {
+                    name: {"count": h.count, "sum_ms": round(h.sum, 3),
+                           "mean_ms": round(h.sum / h.count, 3),
+                           "p50_le_ms": round(h.quantile(0.5), 3),
+                           "p99_le_ms": round(h.quantile(0.99), 3),
+                           "max_ms": round(h.max, 3)}
+                    for name, h in sorted(self.totals.items())}}
+                   if self.job else {}),
             }
 
     def to_chrome(self) -> dict:
@@ -276,16 +372,147 @@ class Trace:
             return {"traceEvents": events, "displayTimeUnit": "ms"}
 
 
+# -- one span call, two sinks -----------------------------------------------
+
+# (trace, innermost open span or None) of the calling context.  A thread
+# started on the job's behalf does not inherit it: take it with
+# :func:`capture` at spawn and bind it inside with :func:`use`.
+_binding_var: contextvars.ContextVar = contextvars.ContextVar(
+    "penroz_trace_binding", default=None)
+
+_listener_lock = threading.Lock()
+_listener_registered = False
+
+
+class span:
+    """``with tracing.span("penroz/ckpt_write", bytes=n) as sp:`` — a named
+    region of the program's own time, to both sinks (module docstring).
+
+    The ``TraceAnnotation`` carries exactly ``name`` and no metadata (the
+    benchmark's trace reduction matches ``penroz/*`` names by equality);
+    ``counters`` go to the current trace's span ``meta`` only, as do those
+    given later through :meth:`set`.  With no current trace this is the
+    annotation and nothing more.  :meth:`close` ends the span before its
+    ``with`` block does (set-up that hands over to a loop) and is
+    idempotent.  Failures of the profiler never reach the caller."""
+
+    __slots__ = ("name", "_counters", "_ann", "_outer", "_span")
+
+    def __init__(self, name: str, **counters):
+        self.name = name
+        self._counters = counters
+        self._ann = None
+        self._outer = None      # the binding to restore at close
+        self._span = None
+
+    def __enter__(self):
+        try:
+            self._ann = jax.profiler.TraceAnnotation(self.name)
+            self._ann.__enter__()
+        except Exception:  # noqa: BLE001 — profiling must never break the path
+            self._ann = None
+        outer = _binding_var.get()
+        if outer is not None:
+            trace, parent = outer
+            self._outer = outer
+            self._span = trace.span(self.name, parent=parent,
+                                    **self._counters)
+            self._counters = {}     # from here on: what set() brings
+            if self._span is not None:
+                _binding_var.set((trace, self._span))
+        return self
+
+    def set(self, **counters) -> None:
+        """Counters known only once the work is done (bytes written)."""
+        if self._outer is not None:
+            self._counters.update(counters)
+
+    def close(self) -> None:
+        outer, self._outer = self._outer, None
+        if outer is not None:
+            outer[0].end(self._span, **self._counters)
+            _binding_var.set(outer)
+        ann, self._ann = self._ann, None
+        if ann is not None:
+            try:
+                ann.__exit__(None, None, None)
+            except Exception:  # noqa: BLE001
+                pass
+
+    def __exit__(self, *exc):
+        self.close()
+
+
+def capture():
+    """The calling context's binding, to hand to a thread (``None`` when no
+    trace is current): spans the thread opens under ``use(binding)`` become
+    children of the span that was open *here*, though it may have closed by
+    the time they end (``penroz/ckpt_flush`` under its ``ckpt_save``)."""
+    return _binding_var.get()
+
+
+class use:
+    """``with tracing.use(trace):`` makes ``trace`` (a :class:`Trace`, a
+    :func:`capture` result, or ``None``: no-op) current for the block, so
+    that :func:`span` and the compile listener record into it."""
+
+    __slots__ = ("_binding", "_token")
+
+    def __init__(self, trace):
+        self._binding = ((trace, None) if isinstance(trace, Trace)
+                         else trace)
+        self._token = None
+
+    def __enter__(self):
+        if self._binding is not None:
+            _register_compile_listener()
+            self._token = _binding_var.set(self._binding)
+        return self
+
+    def __exit__(self, *exc):
+        if self._token is not None:
+            _binding_var.reset(self._token)
+
+
+def _on_event_duration(event: str, duration: float, **_kwargs) -> None:
+    """``jax.monitoring`` listener: a backend compile (jax 0.9.0 reports it
+    from the compiling thread, so the binding is there) becomes a closed
+    ``penroz/compile`` span under the current span.  A persistent-cache hit
+    is reported too, with the time the retrieval took."""
+    if event != COMPILE_EVENT:
+        return
+    binding = _binding_var.get()
+    if binding is None:
+        return
+    trace, parent = binding
+    now = time.monotonic()
+    sp = trace.span("penroz/compile", t0=now - duration, parent=parent,
+                    seconds=round(duration, 6))
+    trace.end(sp, t1=now)
+
+
+def _register_compile_listener() -> None:
+    global _listener_registered
+    if _listener_registered:
+        return
+    with _listener_lock:
+        if not _listener_registered:
+            jax.monitoring.register_event_duration_secs_listener(
+                _on_event_duration)
+            _listener_registered = True
+
+
 # -- registry ---------------------------------------------------------------
 
-def maybe_trace(request_id: str, **meta) -> Trace | None:
+def maybe_trace(request_id: str, job: bool = False,
+                **meta) -> Trace | None:
     """Start a trace for ``request_id`` under the sampling rate (None when
     sampled out — every recording site is None-guarded, so the disabled
-    path costs one comparison)."""
+    path costs one comparison).  ``job=True``: see :class:`Trace`."""
     rate = _sample_rate()
     if rate <= 0.0 or (rate < 1.0 and random.random() >= rate):
         return None
-    trace = Trace(request_id, **meta)
+    trace = Trace(request_id, job=job, **meta)
     with _lock:
         _live[request_id] = trace
     return trace

@@ -457,100 +457,32 @@ def bench_paged_generate(arch, params, block=1024, tokens=64):
 
 def bench_long_context(depth=12, d_model=768, block=4096, batch=1,
                        steps_per_call=2, timed=4, heads=12):
-    """Long-context training throughput at T=4096 (flash fwd+bwd kernels
-    stream K/V through the grid, so the (T,S) score matrix never
-    materializes), without remat: at batch=1 the activations (~1.5 GB) fit
-    v5e HBM, and a whole-loss checkpoint would replay the forward.
-
-    Capture-time tuning sweep (skip with PENROZ_BENCH_LONGCTX_SWEEP=0):
-    probes flash block_q/block_k and batch variants with a short timed
-    window each — a fresh ``CompiledArch`` per config, since the env
-    knobs are read at trace time — then re-measures the winner with the
-    full window.  The chip picks the config; per-config results land in
-    the partial as ``long_ctx_sweep`` so a mid-run death still records
-    what was learned.  A candidate that fails is recorded there as
-    ``failed: …`` and fails the phase once the winner has been measured.
-    Emits its own metrics."""
+    """Long-context training throughput at T=4096 (the flash kernels never
+    materialize the (T,S) score matrix; their tiling comes from the shapes,
+    ``ops/pallas/flash_attention.py::plan_flash``), without remat: at
+    batch=1 the activations (~1.5 GB) fit v5e HBM, and a whole-loss
+    checkpoint would replay the forward.  Emits its own metrics."""
     from __graft_entry__ import OPTIMIZER
     from penroz_tpu.models.dsl import Mapper
     from penroz_tpu.models.model import CompiledArch
     from penroz_tpu.models import presets
 
-    def run_cfg(bq, bk, b, tsteps, twarm, ttimed):
-        os.environ["PENROZ_FLASH_BLOCK_Q"] = str(bq)
-        os.environ["PENROZ_FLASH_BLOCK_K"] = str(bk)
-        layers = presets.gpt2_custom(d=d_model, heads=heads, depth=depth,
-                                     vocab=50304, block=block)
-        mapper = Mapper(layers, OPTIMIZER)
-        arch = CompiledArch(mapper.layers)  # fresh jit caches per config
-        params, _ = mapper.init_params(arch.mods, seed=0)
-        n_params = sum(int(np.prod(p.shape)) for p in params.values())
-        n_matmul = n_params - sum(int(np.prod(p.shape))
-                                  for k, p in params.items()
-                                  if k.startswith("layers.0."))
-        tps, _ = bench_train(arch, mapper, params, batch=b, block=block,
-                             steps_per_call=tsteps, warmup=twarm,
-                             timed=ttimed, remat=False)
-        return tps, _mfu(tps, n_matmul, depth, d_model, block)
-
-    prev_q = os.environ.get("PENROZ_FLASH_BLOCK_Q")
-    prev_k = os.environ.get("PENROZ_FLASH_BLOCK_K")
-    try:
-        sweep_on = (os.environ.get("PENROZ_BENCH_LONGCTX_SWEEP", "1") == "1"
-                    and os.environ.get("PENROZ_BENCH_SMOKE") != "1")
-
-        def envint(name, default):
-            try:
-                return int(os.environ.get(name) or default)
-            except ValueError:
-                return default
-
-        # Seed from the operator's pinned env config (sweep off / smoke:
-        # honor it verbatim instead of clobbering it with literals).
-        best = (envint("PENROZ_FLASH_BLOCK_Q", 512),
-                envint("PENROZ_FLASH_BLOCK_K", 512), batch)
-        failed = []
-        if sweep_on:
-            sweep = {}
-            # (block_q, block_k, batch): env/defaults first, then narrower
-            # q blocks (more grid parallelism for the dq pass), wider k
-            # streams (fewer carry updates), and batch=2 (row headroom).
-            cands = [best, (256, 512, batch), (512, 1024, batch),
-                     (1024, 512, batch), (512, 512, 2 * batch)]
-            seen = set()
-            cands = [c for c in cands
-                     if not (c in seen or seen.add(c))]
-            best_tps = 0.0
-            for bq, bk, b in cands:
-                label = f"bq{bq}_bk{bk}_b{b}"
-                try:
-                    tps, mfu = run_cfg(bq, bk, b, tsteps=steps_per_call,
-                                       twarm=1, ttimed=2)
-                except Exception as exc:  # noqa: BLE001 — recorded, re-raised below
-                    sweep[label] = f"failed: {exc!r}"[:300]
-                    failed.append(label)
-                    emit(long_ctx_sweep=dict(sweep))
-                    continue
-                sweep[label] = round(tps, 1)
-                emit(long_ctx_sweep=dict(sweep))
-                if tps > best_tps:
-                    best, best_tps = (bq, bk, b), tps
-        bq, bk, b = best
-        tps, mfu = run_cfg(bq, bk, b, tsteps=steps_per_call, twarm=2,
-                           ttimed=timed)
-        emit(long_ctx_tokens_per_sec=round(tps, 1),
-             long_ctx_mfu=None if mfu is None else round(mfu, 4),
-             long_ctx_block=block, long_ctx_cfg=f"bq{bq}_bk{bk}_b{b}")
-        if failed:
-            raise RuntimeError(f"long-context sweep candidates failed: "
-                               f"{failed} (see long_ctx_sweep)")
-    finally:
-        for var, prev in (("PENROZ_FLASH_BLOCK_Q", prev_q),
-                          ("PENROZ_FLASH_BLOCK_K", prev_k)):
-            if prev is None:
-                os.environ.pop(var, None)
-            else:
-                os.environ[var] = prev
+    layers = presets.gpt2_custom(d=d_model, heads=heads, depth=depth,
+                                 vocab=50304, block=block)
+    mapper = Mapper(layers, OPTIMIZER)
+    arch = CompiledArch(mapper.layers)
+    params, _ = mapper.init_params(arch.mods, seed=0)
+    n_params = sum(int(np.prod(p.shape)) for p in params.values())
+    n_matmul = n_params - sum(int(np.prod(p.shape))
+                              for k, p in params.items()
+                              if k.startswith("layers.0."))
+    tps, _ = bench_train(arch, mapper, params, batch=batch, block=block,
+                         steps_per_call=steps_per_call, warmup=2,
+                         timed=timed, remat=False)
+    mfu = _mfu(tps, n_matmul, depth, d_model, block)
+    emit(long_ctx_tokens_per_sec=round(tps, 1),
+         long_ctx_mfu=None if mfu is None else round(mfu, 4),
+         long_ctx_block=block)
 
 
 def bench_dispatch_floor():

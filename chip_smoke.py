@@ -75,11 +75,12 @@ def sizes(tiny: bool) -> dict:
     if tiny:
         return dict(d=64, heads=4, depth=2, vocab=512, block=64, batch=4,
                     epochs=40, prompt_lens=[3, 9, 40, 5, 17, 50], new_tokens=8,
-                    page=8, kernel_T=128, kernel_rows=4, kernel_vocab=2048)
+                    page=8, kernel_T=128, kernel_rows=4, kernel_vocab=2048,
+                    cell_rows=2)
     return dict(d=768, heads=12, depth=12, vocab=50304, block=1024, batch=8,
                 epochs=100, prompt_lens=[5, 40, 300, 17, 129, 600],
                 new_tokens=24, page=16, kernel_T=1024, kernel_rows=8,
-                kernel_vocab=50304)
+                kernel_vocab=50304, cell_rows=12)
 
 
 # ---------------------------------------------------------------------------
@@ -448,6 +449,19 @@ def normalized_error(got, want) -> float:
     return float(np.max(np.abs(got - want)) / (np.max(np.abs(want)) + 1e-12))
 
 
+def median_ms(fn, *args, calls: int = 20) -> float:
+    """Median wall time of ``calls`` calls of a jitted ``fn``, each waited
+    for, after two that warm it up."""
+    import jax
+    import statistics
+    times = []
+    for i in range(calls + 2):
+        t0 = time.perf_counter()
+        jax.block_until_ready(fn(*args))
+        times.append(time.perf_counter() - t0)
+    return 1e3 * statistics.median(times[2:])
+
+
 def phase_kernels(sz: dict, on_tpu: bool):
     """Each main-path kernel, compiled on the chip through the dispatchers
     the model uses, against its jnp oracle on the same inputs.  Error is
@@ -501,6 +515,24 @@ def phase_kernels(sz: dict, on_tpu: bool):
                 A.causal_attention_reference, q, k, v),
                 argnums=(0, 1, 2), has_aux=True),
             (q, k, v), BF16)
+
+    # the same at the benchmark cell's micro-batch (gpt2s-train-1chip:
+    # 12 x 12 x 1024 x 64), and timed: the before/after of a kernel change
+    qc, kc, vc = (rand(sz["cell_rows"], H, T, D) for _ in range(3))
+    flash_fwd = jax.jit(lambda q, k, v: A.causal_attention(
+        q, k, v, platform=hint))
+    flash_fwd_bwd = jax.value_and_grad(lambda q, k, v: flash_loss(
+        lambda *a: A.causal_attention(*a, platform=hint), q, k, v),
+        argnums=(0, 1, 2), has_aux=True)
+    compare("flash_fwd_bwd_cell", flash_fwd_bwd,
+            jax.value_and_grad(lambda q, k, v: flash_loss(
+                A.causal_attention_reference, q, k, v),
+                argnums=(0, 1, 2), has_aux=True),
+            (qc, kc, vc), BF16)
+    fwd_ms = median_ms(flash_fwd, qc, kc, vc)
+    timings = {"flash_fwd_ms": round(fwd_ms, 4),
+               "flash_bwd_ms": round(median_ms(jax.jit(flash_fwd_bwd),
+                                               qc, kc, vc) - fwd_ms, 4)}
 
     # contiguous decode, ragged lengths
     lengths = jnp.asarray(rng.integers(1, T + 1, B), jnp.int32)
@@ -589,7 +621,7 @@ def phase_kernels(sz: dict, on_tpu: bool):
             ssm.gla_full_reference, (sq, sk, sv, sg), F32)
 
     emit(phase="kernels", normalized_max_error=report,
-         tolerance={"bf16": BF16, "f32": F32})
+         tolerance={"bf16": BF16, "f32": F32}, **timings)
 
 
 def phase_train_one_chip(svc: Service, sz: dict, device: str, on_tpu: bool):

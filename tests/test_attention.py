@@ -970,3 +970,249 @@ def test_softcap_reference_fallback_warns_once(monkeypatch):
     assert len(warnings) == 1 and "softcap" in warnings[0], warnings
     A.causal_attention(q, k, v, softcap=2.0)  # one-time: no repeat spam
     assert len(warnings) == 1
+
+
+# -- flash plans: every way the kernels may tile and walk the score matrix ----
+
+
+def _plan_inputs(seed, B=1, Hq=4, Hkv=2, T=512, D=64):
+    rng = np.random.default_rng(seed)
+    return (jnp.asarray(rng.normal(size=(B, Hq, T, D)).astype(np.float32)),
+            jnp.asarray(rng.normal(size=(B, Hkv, T, D)).astype(np.float32)),
+            jnp.asarray(rng.normal(size=(B, Hkv, T, D)).astype(np.float32)))
+
+
+def _noncausal_oracle(q, k, v):
+    B, Hq, T, D = q.shape
+    group = Hq // k.shape[1]
+    out = A._attend(A._group_query_heads(q, group), k, v,
+                    jnp.ones((T, k.shape[2]), bool))
+    return out.reshape(B, Hq, T, D)
+
+
+# feature → (flash_attention kwargs, oracle); GQA (4 query heads on 2) in all
+_FLASH_FEATURES = {
+    "causal": ({}, lambda q, k, v: A.causal_attention_reference(q, k, v)),
+    "noncausal": ({"causal": False}, _noncausal_oracle),
+    "window": ({"window": 200}, lambda q, k, v:
+               A.causal_attention_reference(q, k, v, window=200)),
+    "window_in_tile": ({"window": 64}, lambda q, k, v:
+                       A.causal_attention_reference(q, k, v, window=64)),
+    "alibi": ({"alibi": A.alibi_slopes(4)}, lambda q, k, v:
+              A.causal_attention_reference(q, k, v,
+                                           alibi=A.alibi_slopes(4))),
+    "dropout": ({"dropout_rate": 0.3, "seed": 1234}, lambda q, k, v:
+                _masked_dropout_oracle(q, k, v, 0.3, 1234)),
+    "scale": ({"scale": 0.05}, lambda q, k, v:
+              A.causal_attention_reference(q, k, v, scale=0.05)),
+}
+# plan → (block_q, block_k, vmem_budget or None for the default)
+_FLASH_PLANS = {
+    "resident128": (128, 128, None),
+    "resident256": (256, 256, None),
+    "resident512": (512, 512, None),
+    "resident128x256": (128, 256, None),
+    "resident256x128": (256, 128, None),
+    "chunked128": (128, 128, 1),
+    "chunked256x128": (256, 128, 1),
+}
+
+
+@pytest.mark.parametrize("plan_name", list(_FLASH_PLANS))
+@pytest.mark.parametrize("feature", list(_FLASH_FEATURES))
+def test_flash_plans_match_oracle(feature, plan_name):
+    """Forward and dq/dk/dv (interpret) against the jnp oracle under every
+    plan the function can return: K/V resident with in-kernel walks and the
+    one-pass backward (several heads a grid step), and the chunked kernels
+    with the two-kernel backward a small budget forces."""
+    from penroz_tpu.ops.pallas import flash_attention as FA
+    kwargs, oracle = _FLASH_FEATURES[feature]
+    block_q, block_k, budget = _FLASH_PLANS[plan_name]
+    q, k, v = _plan_inputs(len(feature) + len(plan_name))
+    plan = FA.plan_flash(512, 512, 64, 4, kwargs.get("causal", True),
+                         kwargs.get("window"), heads=4, group=2,
+                         block_q=block_q, block_k=block_k,
+                         **({} if budget is None
+                            else {"vmem_budget": budget}))
+    if budget is None:
+        assert plan.resident and plan.fused_bwd
+        # several heads a grid step, but where 512-tiles leave no room
+        assert plan.heads_per_step > 1 or block_q == 512
+    else:
+        assert not plan.resident and not plan.fused_bwd
+    assert (plan.block_q, plan.block_k) == (block_q, block_k)
+
+    def attend(q, k, v):
+        return FA.flash_attention(
+            q, k, v, block_q=block_q, block_k=block_k, interpret=True,
+            **kwargs, **({} if budget is None else {"vmem_budget": budget}))
+
+    np.testing.assert_allclose(np.asarray(attend(q, k, v)),
+                               np.asarray(oracle(q, k, v)), atol=2e-5)
+    # a non-uniform cotangent: .sum() alone would leave δ = Σ dO·O blind
+    w = jnp.asarray(np.random.default_rng(9).normal(size=q.shape)
+                    .astype(np.float32))
+    gf = jax.grad(lambda q, k, v: (attend(q, k, v) * w).sum(),
+                  (0, 1, 2))(q, k, v)
+    gr = jax.grad(lambda q, k, v: (oracle(q, k, v) * w).sum(),
+                  (0, 1, 2))(q, k, v)
+    _grad_close(gf, gr)
+
+
+@pytest.mark.parametrize("case,want", [
+    # GPT-2 124M, the benchmark cell's attention: (12, 12, 1024, 64) bf16
+    (dict(T=1024, S=1024, D=64, itemsize=2, heads=12),
+     dict(resident=True, q_rows=1024, fused_bwd=True)),
+    # GPT-2-large: D = 64 × 20 heads
+    (dict(T=1024, S=1024, D=64, itemsize=2, heads=20),
+     dict(resident=True, q_rows=1024, fused_bwd=True)),
+    # long context, GQA: K/V of a head still fit, seven (T, D) operands of
+    # the one-pass backward do not
+    (dict(T=4096, S=4096, D=128, itemsize=2, heads=32, group=4),
+     dict(resident=True, fused_bwd=False, heads_per_step=1)),
+    # one tile
+    (dict(T=128, S=128, D=64, itemsize=2, heads=4),
+     dict(block_q=128, block_k=128, bwd_block_q=128, bwd_block_k=128,
+          resident=True, q_rows=128, fused_bwd=True, heads_per_step=4)),
+    # K/V of a head past the budget: stream them
+    (dict(T=32768, S=32768, D=128, itemsize=2, heads=8),
+     dict(resident=False, fused_bwd=False, heads_per_step=1)),
+    (dict(T=1024, S=1024, D=64, itemsize=2, heads=12, vmem_budget=2 ** 20),
+     dict(resident=False, fused_bwd=False, heads_per_step=1)),
+    # T = 4096, D = 64: resident forward in bf16 (on part of the queries a
+    # step), streamed in f32
+    (dict(T=4096, S=4096, D=64, itemsize=2, heads=1),
+     dict(resident=True, fused_bwd=False, heads_per_step=1)),
+    (dict(T=4096, S=4096, D=64, itemsize=4, heads=1),
+     dict(resident=False, fused_bwd=False, heads_per_step=1)),
+])
+def test_flash_plan_function(case, want):
+    from penroz_tpu.ops.pallas import flash_attention as FA
+    plan = FA.plan_flash(**case)
+    got = {key: getattr(plan, key) for key in want}
+    assert got == want, plan.describe()
+    heads, group = case["heads"], case.get("group", 1)
+    hps = plan.heads_per_step
+    # a step owns whole K/V heads or a share of one
+    assert heads % hps == 0 and (hps % group == 0 or group % hps == 0)
+    for block, n in ((plan.block_q, case["T"]), (plan.block_k, case["S"]),
+                     (plan.bwd_block_q, case["T"]),
+                     (plan.bwd_block_k, case["S"])):
+        assert n % block == 0 and block % 128 == 0
+    assert case["T"] % plan.q_rows == 0 and plan.q_rows % plan.block_q == 0
+    if not plan.resident:
+        assert plan.q_rows == plan.block_q
+    # the estimates the plan was chosen by stay inside the budget
+    budget = case.get("vmem_budget", FA.VMEM_BUDGET)
+    kvh = FA._kv_heads_per_step(hps, group)
+    if plan.resident:
+        assert FA._fwd_resident_bytes(
+            plan.q_rows, case["S"], case["D"], case["itemsize"], hps, kvh,
+            plan.block_q, plan.block_k) <= budget
+    if plan.fused_bwd:
+        assert FA._bwd_fused_bytes(
+            case["T"], case["S"], case["D"], case["itemsize"], hps, kvh,
+            plan.bwd_block_q, plan.bwd_block_k) <= budget
+    # pure: the same shapes give the same plan, and it names itself
+    assert FA.plan_flash(**case) == plan
+    assert f"heads_per_step={hps}" in plan.describe()
+
+
+def test_flash_plan_honours_explicit_tiles_and_rejects_ragged_lengths():
+    from penroz_tpu.ops.pallas import flash_attention as FA
+    plan = FA.plan_flash(1024, 1024, 64, 2, heads=12, block_q=128,
+                         block_k=512)
+    assert (plan.block_q, plan.block_k, plan.bwd_block_q,
+            plan.bwd_block_k) == (128, 512, 128, 512)
+    # a tile that does not divide T falls to one that does (384 = 3 × 128)
+    assert FA.plan_flash(384, 384, 64, 4, block_q=256,
+                         block_k=256).block_q == 128
+    with pytest.raises(ValueError, match="requires T%"):
+        FA.plan_flash(200, 200 + 128, 64, 4, block_q=128, block_k=128)
+
+
+@pytest.mark.parametrize("window", [None, 1, 64, 128, 200, 500, 4000])
+@pytest.mark.parametrize("block_q,block_k", [(128, 128), (256, 256),
+                                             (512, 512), (128, 512),
+                                             (512, 128), (256, 128)])
+def test_flash_walks_exactly_the_live_tiles(block_q, block_k, window):
+    """The key tiles a query tile walks (forward, dq) and the query tiles a
+    key tile walks (one-pass backward, dkv) are exactly the tiles the band
+    meets; the unmasked walk takes exactly those wholly inside it; and a
+    dead step of the chunked grid is clamped onto a live tile."""
+    from penroz_tpu.ops.pallas import flash_attention as FA
+    T = S = 1024
+    q_pos, k_pos = np.arange(T)[:, None], np.arange(S)[None, :]
+    band = k_pos <= q_pos
+    if window is not None:
+        band &= k_pos > q_pos - window
+    num_q, num_k = T // block_q, S // block_k
+    tiles = band.reshape(num_q, block_q, num_k, block_k)
+    live = tiles.any(axis=(1, 3))
+    full = tiles.all(axis=(1, 3))
+
+    walked = np.zeros_like(live)
+    unmasked = np.zeros_like(live)
+    clamp = FA._clamped(FA.key_tile_ranges, block_q, block_k, num_k, True,
+                        window)
+    for qi in range(num_q):
+        lo, full_lo, full_hi, hi = FA.key_tile_ranges(
+            qi, block_q, block_k, num_k, True, window)
+        assert 0 <= lo <= full_lo <= full_hi <= hi <= num_k
+        walked[qi, lo:hi] = True
+        unmasked[qi, full_lo:full_hi] = True
+        for kj in range(num_k):
+            assert live[qi, clamp(qi, kj)]
+            assert clamp(qi, kj) == kj or not live[qi, kj]
+    np.testing.assert_array_equal(walked, live)
+    np.testing.assert_array_equal(unmasked, full)
+
+    walked[:] = unmasked[:] = False
+    clamp = FA._clamped(FA.query_tile_ranges, block_q, block_k, num_q, True,
+                        window)
+    for kj in range(num_k):
+        lo, full_lo, full_hi, hi = FA.query_tile_ranges(
+            kj, block_q, block_k, num_q, True, window)
+        assert 0 <= lo <= full_lo <= full_hi <= hi <= num_q
+        walked[lo:hi, kj] = True
+        unmasked[full_lo:full_hi, kj] = True
+        if live[:, kj].any():
+            for qi in range(num_q):
+                assert live[clamp(kj, qi), kj]
+    np.testing.assert_array_equal(walked, live)
+    np.testing.assert_array_equal(unmasked, full)
+    # non-causal: every tile, none masked
+    assert FA.key_tile_ranges(1, block_q, block_k, num_k, False, None) == \
+        (0, 0, num_k, num_k)
+
+
+def test_flash_plan_is_logged_once_and_spanned_per_trace(caplog):
+    """The counter that says which plan engaged: one INFO line per distinct
+    (shape, plan), and a ``penroz/flash_plan`` span with the plan's fields
+    under whatever span of a job's trace is compiling."""
+    import logging
+    from penroz_tpu.ops.pallas import flash_attention as FA
+    from penroz_tpu.utils import tracing
+    q, k, v = _plan_inputs(0, Hq=2, Hkv=2, T=384)
+    FA._log_plan.cache_clear()
+    tracing.reset()
+    trace = tracing.maybe_trace("flash-plan-job", job=True, route="/train/")
+    with caplog.at_level(logging.INFO, logger=FA.__name__), \
+            tracing.use(trace), tracing.span("penroz/train_dispatch"):
+        for _ in range(2):
+            jax.jit(lambda q, k, v: FA.flash_attention(
+                q, k, v, interpret=True)).lower(q, k, v)
+    lines = [r.getMessage() for r in caplog.records
+             if r.getMessage().startswith("flash plan:")]
+    assert len(lines) == 1, lines
+    assert lines[0].startswith("flash plan: T=384 S=384 D=64 bq=384 bk=384")
+    assert "resident" in lines[0] and "fused_bwd" in lines[0]
+    dispatch = trace.to_dict()["root"]["children"][0]
+    spans = [c for c in dispatch["children"]
+             if c["name"] == "penroz/flash_plan"]
+    assert len(spans) == 2
+    meta = spans[0]["meta"]
+    assert (meta["T"], meta["S"], meta["D"]) == (384, 384, 64)
+    assert meta["block_q"] == 384 and meta["resident"] is True
+    assert meta["fused_bwd"] is True and meta["heads_per_step"] == 2
+    trace.finish("completed")

@@ -62,20 +62,24 @@ def _pool(page, dtype):
         ((ROWS, BLOCK // page), jnp.int32)
 
 
-def _flash_fwd():
-    from penroz_tpu.ops.pallas import flash_attention as fa
-    qkv = ((ROWS, HEADS, BLOCK, HEAD_DIM), jnp.bfloat16)
-    return (lambda q, k, v: fa.flash_attention(q, k, v)), [qkv] * 3
+CELL_ROWS = 12      # gpt2s-train-1chip's micro-batch: (12, 12, 1024, 64)
 
 
-def _flash_bwd():
+def _flash_fwd(q=(ROWS, HEADS, BLOCK, HEAD_DIM), kv=None, **kwargs):
     from penroz_tpu.ops.pallas import flash_attention as fa
-    qkv = ((ROWS, HEADS, BLOCK, HEAD_DIM), jnp.bfloat16)
+    shapes = [(q, jnp.bfloat16)] + [(kv or q, jnp.bfloat16)] * 2
+    return (lambda q, k, v: fa.flash_attention(q, k, v, **kwargs)), shapes
+
+
+def _flash_bwd(q=(ROWS, HEADS, BLOCK, HEAD_DIM), kv=None, **kwargs):
+    from penroz_tpu.ops.pallas import flash_attention as fa
+    shapes = [(q, jnp.bfloat16)] + [(kv or q, jnp.bfloat16)] * 2
 
     def loss(q, k, v):
-        return fa.flash_attention(q, k, v).astype(jnp.float32).sum()
+        return fa.flash_attention(q, k, v, **kwargs).astype(
+            jnp.float32).sum()
 
-    return jax.grad(loss, argnums=(0, 1, 2)), [qkv] * 3
+    return jax.grad(loss, argnums=(0, 1, 2)), shapes
 
 
 def _decode(quantized, dtype=jnp.bfloat16):
@@ -146,6 +150,18 @@ def _ssm_scan():
 CASES = {
     "flash_fwd": _flash_fwd,
     "flash_bwd": _flash_bwd,
+    # the benchmark cell's own shape: K/V resident, one-pass backward
+    "flash_fwd_cell": lambda: _flash_fwd((CELL_ROWS, HEADS, BLOCK, HEAD_DIM)),
+    "flash_bwd_cell": lambda: _flash_bwd((CELL_ROWS, HEADS, BLOCK, HEAD_DIM)),
+    # long context, D = 128, 4 query heads a K/V head: resident forward on
+    # part of the queries a step, two-kernel backward
+    "flash_fwd_t4096_gqa": lambda: _flash_fwd((2, 8, 4096, 128),
+                                              (2, 2, 4096, 128)),
+    "flash_bwd_t4096_gqa": lambda: _flash_bwd((2, 8, 4096, 128),
+                                              (2, 2, 4096, 128)),
+    # the chunked kernels a long S falls to, with a window's clamped walks
+    "flash_fwd_chunked": lambda: _flash_fwd(window=700, vmem_budget=2 ** 20),
+    "flash_bwd_chunked": lambda: _flash_bwd(window=700, vmem_budget=2 ** 20),
     "decode_bf16": lambda: _decode(False),
     "decode_int8": lambda: _decode(True),
     "paged_bf16_page128": lambda: _paged(128, False),
@@ -185,20 +201,26 @@ def test_kernel_compiles_for_v5e(chip, name):
 # the kernel over the mesh named by their ``platform`` hint.  These go
 # through those dispatchers, as the model does.
 
-def _mesh_train(hint):
+def _mesh_train(hint, rows=ROWS):
     """Flash fwd+bwd and fused CE fwd+bwd, batch split over ``data``."""
     from penroz_tpu.ops import attention as A
     from penroz_tpu.ops import losses
-    qkv = ((ROWS, HEADS, BLOCK, HEAD_DIM), jnp.bfloat16, P("data", "model"))
+    qkv = ((rows, HEADS, BLOCK, HEAD_DIM), jnp.bfloat16, P("data", "model"))
     w = ((HEADS * HEAD_DIM, 2048), jnp.bfloat16, P())
-    y = ((ROWS, BLOCK), jnp.int32, P("data"))
+    y = ((rows, BLOCK), jnp.int32, P("data"))
 
     def loss(q, k, v, w, y):
         out = A.causal_attention(q, k, v, platform=hint)
-        logits = out.transpose(0, 2, 1, 3).reshape(ROWS, BLOCK, -1) @ w
+        logits = out.transpose(0, 2, 1, 3).reshape(rows, BLOCK, -1) @ w
         return losses.fused_cross_entropy_mean(logits, y, 512, hint)
 
     return jax.grad(loss, argnums=(0, 1, 2, 3)), [qkv, qkv, qkv, w, y]
+
+
+def _mesh_train_cell(hint):
+    """The cell's micro-batch over ``data=4``: 3 rows a chip (the planned
+    ``gpt2s-train-dp4``)."""
+    return _mesh_train(hint, CELL_ROWS)
 
 
 def _mesh_ragged(hint):
@@ -212,6 +234,7 @@ def _mesh_ragged(hint):
 
 
 @pytest.mark.parametrize("case,axes", [(_mesh_train, {"data": 4}),
+                                       (_mesh_train_cell, {"data": 4}),
                                        (_mesh_train, {"model": 2}),
                                        (_mesh_ragged, {"model": 4})])
 def test_kernels_compile_partitioned_for_v5e(chips, case, axes):
@@ -224,3 +247,38 @@ def test_kernels_compile_partitioned_for_v5e(chips, case, axes):
             for shape, dtype, spec in shapes]
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_flash_kernels_are_where_the_benchmark_looks_for_them(chip):
+    """``benchmark/metrics/flash_roofline_pct.py`` finds the flash kernels in
+    a device trace by what their HLO instructions look like, not by a name:
+    a custom call under a ``jvp`` name stack whose results are the output
+    then the logsumexp, and custom call(s) under ``transpose(jvp`` with a
+    result of the output's shape.  A kernel change that would blind that
+    reader fails here, on the CPU, at the cell's own shape."""
+    import re
+    from penroz_tpu.ops import attention as A
+    shape = (CELL_ROWS, HEADS, BLOCK, HEAD_DIM)
+    qkv = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=chip)
+
+    def loss(q, k, v):
+        return A.causal_attention(q, k, v, platform="tpu").astype(
+            jnp.float32).sum()
+
+    hlo = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        qkv, qkv, qkv).compile().as_text()
+    calls = []      # (instruction name, result types) of every custom call
+    for line in hlo.splitlines():
+        head, call, _ = line.strip().partition(" custom-call(")
+        if call and "tpu_custom_call" in line:
+            calls.append(head.removeprefix("ROOT ").partition(" = ")[::2])
+    # the reader's own patterns (kernel_time: search on name and result)
+    out = r"bf16\[12,12,1024,64\]"
+    lse = r"f32\[12,12,1024,1\]"
+    fwd = [c for c in calls if re.search(r"^%jvp_", c[0])
+           and re.search(out + ".*" + lse, c[1])]
+    bwd = [c for c in calls if re.search(r"^%transpose_jvp_", c[0])
+           and re.search(out, c[1])]
+    assert len(fwd) == 1, calls
+    assert 1 <= len(bwd) <= 2, calls
+    assert len(calls) == len(fwd) + len(bwd), calls

@@ -36,6 +36,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from penroz_tpu.ops import attention as attn_ops
+from penroz_tpu.utils import tracing
+
+log = logging.getLogger(__name__)
 
 
 class Ctx:
@@ -167,23 +170,32 @@ def _uniform(rng, shape, bound, dtype=jnp.float32):
 _GATHER_BWD_CHUNK = 1024
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
-def _gather_rows(table, ids, num_rows: int, dtype_name: str):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4))
+def _gather_rows(table, ids, num_rows: int, dtype_name: str, platform=None):
+    """``table[ids]`` whose backward sums in fp32 (``platform``: the
+    placement hint, whose mesh decides how: :func:`_gather_rows_bwd`)."""
     return jnp.take(table, ids, axis=0)
 
 
-def _gather_rows_fwd(table, ids, num_rows: int, dtype_name: str):
+def _gather_rows_fwd(table, ids, num_rows: int, dtype_name: str, platform):
     return jnp.take(table, ids, axis=0), ids
 
 
-def _gather_rows_bwd(num_rows: int, dtype_name: str, ids, g):
-    """one-hotᵀ @ g instead of scatter-add: XLA TPU lowers row-scatter with
-    thousands of update rows to a serialized loop, while the matmul rides
-    the MXU (the dense AdamW update over the full table dominates the
-    optimizer step anyway, so a dense gradient costs nothing extra there).
-    The contraction streams id-chunks through a scan so the transient
-    one-hot operand stays at (num_rows, chunk) — ~100 MB for a GPT-2 vocab —
-    instead of a full (num_rows, B·T) buffer in HBM."""
+def _scatter_rows_grad(ids, g, num_rows: int, dtype):
+    """XLA's own scatter-add into an fp32 table, rounded once: on a v5e a
+    native ``scatter`` fusion with no loop, 0.86 ms for 12 288 rows of 768
+    into GPT-2's 50 304, whatever the ids (PERF.md §6, PR 30)."""
+    d = g.shape[-1]
+    dw = jnp.zeros((num_rows, d), jnp.float32).at[ids.reshape(-1)].add(
+        g.reshape(-1, d).astype(jnp.float32))
+    return dw.astype(dtype)
+
+
+def _onehot_rows_grad(ids, g, num_rows: int, dtype):
+    """one-hotᵀ @ g over the whole table — 2·V·N·d FLOPs to add N rows —
+    with id-chunks streamed through a scan so the transient one-hot operand
+    stays at (num_rows, chunk) instead of a full (num_rows, B·T) buffer in
+    HBM."""
     flat_ids = ids.reshape(-1)
     d = g.shape[-1]
     gf = g.reshape(-1, d)
@@ -206,7 +218,41 @@ def _gather_rows_bwd(num_rows: int, dtype_name: str, ids, g):
 
     acc0 = jnp.zeros((num_rows, d), jnp.float32)
     dw, _ = jax.lax.scan(step, acc0, (idc, gc))
-    return (dw.astype(jnp.dtype(dtype_name)),
+    return dw.astype(dtype)
+
+
+@functools.lru_cache(maxsize=None)
+def _log_grad_plan(num_rows: int, n: int, d: int, path: str) -> None:
+    """Once per distinct (shape, path) of the process."""
+    log.info("embedding grad plan: V=%d N=%d d=%d path=%s", num_rows, n, d,
+             path)
+
+
+def _gather_rows_bwd(num_rows: int, dtype_name: str, platform, ids, g):
+    """Sum the rows of ``g`` by id in fp32, rounded once to the table's
+    dtype (``jnp.take``'s own VJP would add in the table's dtype: under
+    bf16 compute a rounding at every repeated id).
+
+    On one device: XLA's scatter.  (It once lowered to a serialized loop on
+    a TPU, which is why the one-hot matmul was written; it no longer does,
+    and the matmul multiplies the whole vocabulary by every token.)  Under a
+    mesh: the one-hot scan, as before — GSPMD gathers the ids and rows for
+    it (N·d values), where it would give the scatter an all-reduce of the
+    partial (V, d) tables, in the table's dtype; not measured on four chips.
+
+    Which path a traced program got is counted: an INFO line per distinct
+    shape and a ``penroz/embed_grad_plan`` span under whatever span is
+    compiling (a /train/ job's first epochs, beside ``penroz/flash_plan``).
+    """
+    n, d = int(np.prod(ids.shape)), g.shape[-1]
+    path, grad = (("onehot_scan", _onehot_rows_grad)
+                  if isinstance(platform, attn_ops.Placement)
+                  else ("scatter", _scatter_rows_grad))
+    _log_grad_plan(num_rows, n, d, path)
+    with tracing.span("penroz/embed_grad_plan", V=num_rows, N=n, d=d,
+                      path=path):
+        pass
+    return (grad(ids, g, num_rows, jnp.dtype(dtype_name)),
             np.zeros(ids.shape, dtype=jax.dtypes.float0))
 
 
@@ -228,8 +274,9 @@ class Embedding(Module):
     def apply(self, x, ctx):
         w = self._p(ctx, "weight")
         if attn_ops._tpu_platform(w, ctx.platform):
-            # TPU: matmul-based backward (see _gather_rows_bwd).
-            return _gather_rows(w, x, self.num_embeddings, w.dtype.name)
+            # TPU (bf16 compute): fp32-summed backward, _gather_rows_bwd.
+            return _gather_rows(w, x, self.num_embeddings, w.dtype.name,
+                                ctx.platform)
         return jnp.take(w, x, axis=0)  # CPU scatter-add VJP is fine
 
 

@@ -1,0 +1,82 @@
+"""scripts/epoch_hlo_groups.py: the reading of a compiled epoch program's
+op groups, on a hand-written HLO module (the real compile takes half a
+minute and is the script's own to run)."""
+
+import importlib.util
+import os
+
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+@pytest.fixture(scope="module")
+def groups():
+    spec = importlib.util.spec_from_file_location(
+        "epoch_hlo_groups", os.path.join(ROOT, "scripts",
+                                         "epoch_hlo_groups.py"))
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+HLO = """HloModule jit_epoch
+
+%fused_computation.7 (param_0.1: bf16[1024,768], param_1.2: bf16[768,3072]) -> bf16[1024,3072] {
+  %param_0.1 = bf16[1024,768]{1,0:T(8,128)(2,1)} parameter(0)
+  %param_1.2 = bf16[768,3072]{1,0:T(8,128)(2,1)} parameter(1)
+  ROOT %convolution.3 = bf16[1024,3072]{1,0:T(8,128)(2,1)} convolution(%param_0.1, %param_1.2), dim_labels=bf_io->bf
+}
+
+%cond.1 (arg.1: (s32[], bf16[1024,768])) -> pred[] {
+  %arg.1 = (s32[]{:T(128)}, bf16[1024,768]{1,0}) parameter(0)
+  %constant.9 = s32[]{:T(128)} constant(12)
+  %get-tuple-element.1 = s32[]{:T(128)} get-tuple-element(%arg.1), index=0
+  ROOT %lt.1 = pred[]{:T(512)} compare(%get-tuple-element.1, %constant.9), direction=LT
+}
+
+%body.1 (arg.2: (s32[], bf16[1024,768])) -> (s32[], bf16[1024,768]) {
+  %arg.2 = (s32[]{:T(128)}, /*index=1*/bf16[1024,768]{1,0:T(8,128)(2,1)}) parameter(0)
+  %x.1 = bf16[1024,768]{1,0:T(8,128)(2,1)} get-tuple-element(%arg.2), index=1
+  %w.1 = bf16[768,3072]{1,0:T(8,128)(2,1)} constant({...})
+  %convolution_add_fusion.12 = bf16[1024,3072]{1,0:T(8,128)(2,1)} fusion(%x.1, %w.1), kind=kOutput, calls=%fused_computation.7
+  %copy-start.4 = (bf16[1024,768]{1,0}, bf16[1024,768]{1,0:S(1)}, u32[]{:S(2)}) copy-start(%x.1)
+  %copy-done.4 = bf16[1024,768]{1,0:S(1)} copy-done(%copy-start.4)
+  %i.1 = s32[]{:T(128)} get-tuple-element(%arg.2), index=0
+  ROOT %tuple.3 = (s32[]{:T(128)}, bf16[1024,768]{1,0}) tuple(%i.1, %copy-done.4)
+}
+
+ENTRY %main.5 (p.1: bf16[1024,768]) -> bf16[1024,768] {
+  %p.1 = bf16[1024,768]{1,0:T(8,128)(2,1)} parameter(0)
+  %zero.1 = s32[]{:T(128)} constant(0)
+  %tuple.1 = (s32[]{:T(128)}, bf16[1024,768]{1,0}) tuple(%zero.1, %p.1)
+  %while.2 = (s32[]{:T(128)}, /*index=1*/bf16[1024,768]{1,0:T(8,128)(2,1)}) while(%tuple.1), condition=%cond.1, body=%body.1
+  %out.1 = bf16[1024,768]{1,0} get-tuple-element(%while.2), index=1
+  ROOT %copy.8 = bf16[1024,768]{1,0:T(8,128)(2,1)} copy(%out.1)
+}
+"""
+
+
+def test_groups_follow_the_benchmarks_rule(groups):
+    """Restated, not imported: must stay what the ledger's breakdown uses."""
+    from benchmark.lib import trace_reduce
+    for name in ("%fusion.123", "select_add_fusion.7", "copy",
+                 "%convolution.269.clone.3", "transpose(jvp())", "%while.4",
+                 "slice-done.12"):
+        assert groups.op_group(name) == trace_reduce.op_group(name), name
+
+
+def test_walk_counts_flops_bytes_and_trip_counts(groups):
+    comps = groups.parse_computations(HLO)
+    rows = []
+    groups.walk(comps, groups.result_types(comps), "__entry__", 1, rows)
+    by_group = {g: (runs, flops, nbytes) for g, runs, flops, nbytes in rows}
+    # the scan's length comes from its condition; its body's work runs 12×
+    matmul = 2.0 * 1024 * 3072 * 768
+    io = 2 * (1024 * 768 + 768 * 3072 + 1024 * 3072)
+    assert by_group["convolution_add_fusion"] == (12, matmul, io)
+    # an asynchronous copy is charged once, at its -done: read + write
+    assert by_group["copy-done"] == (12, 0.0, 2 * 2 * 1024 * 768)
+    assert "copy-start" not in by_group and "while" not in by_group
+    assert by_group["copy"] == (1, 0.0, 2 * 2 * 1024 * 768)
+    assert set(by_group) == {"convolution_add_fusion", "copy-done", "copy"}

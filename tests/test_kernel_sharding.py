@@ -169,3 +169,30 @@ def test_ssm_scan_mapped(hint):
     want = ssm.gla_full_reference(q, k, v, g)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("axes", [{"data": 4}, {"data": 2, "model": 2}])
+def test_embedding_grad_sums_once_over_the_data_axis(axes):
+    """The token embedding's backward under a mesh (no Pallas kernel: the
+    one-hot scan, partitioned by GSPMD), ids and cotangent rows split over
+    ``data``: the one-device table, not a shard's share of it nor ``data``
+    times it."""
+    from penroz_tpu.ops import modules as M
+    mesh = mesh_lib.make_mesh(jax.devices()[:4], model=axes.get("model", 1))
+    placed = A.Placement("tpu", mesh)
+    rng = np.random.default_rng(7)
+    num_rows, rows, T, d = 300, 4, 256, 32
+    table = _put(placed, _rand(rng, num_rows, d))
+    ids = _put(placed, jnp.asarray(rng.integers(0, num_rows, (rows, T)),
+                                   jnp.int32), "data")
+    cot = _put(placed, _rand(rng, rows, T, d), "data")
+
+    def grad(platform):
+        return jax.jit(jax.grad(lambda t, ids, cot: (M._gather_rows(
+            t, ids, num_rows, "float32", platform) * cot).sum()))(
+                table, ids, cot)
+
+    want = jax.grad(lambda t: (jnp.take(t, ids, axis=0) * cot).sum())(table)
+    for platform in (placed, "tpu"):
+        np.testing.assert_allclose(np.asarray(grad(platform)),
+                                   np.asarray(want), rtol=1e-5, atol=1e-4)

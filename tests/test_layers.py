@@ -193,17 +193,28 @@ def test_two_block_gpt_stack(toy_gpt_layers):
     np.testing.assert_allclose(np.asarray(h).sum(-1), np.ones(2), rtol=1e-4)
 
 
-def test_gather_rows_matmul_backward_matches_scatter():
-    """The TPU embedding backward (chunked one-hotᵀ@g matmul,
-    modules._gather_rows_bwd) must equal jnp.take's native scatter-add VJP —
-    including repeated ids, non-chunk-multiple counts, and 2-D id arrays."""
+def _hints():
+    """One device (the scatter) and a mesh (the one-hot scan): the two
+    placements modules._gather_rows_bwd tells apart."""
+    from penroz_tpu.ops.attention import Placement
+    from penroz_tpu.parallel import mesh as mesh_lib
+    return [None, Placement("tpu", mesh_lib.make_mesh(jax.devices()[:2]))]
+
+
+@pytest.mark.parametrize("meshed", [False, True])
+def test_gather_rows_matmul_backward_matches_scatter(meshed):
+    """The TPU embedding backward (fp32 scatter on one device, chunked
+    one-hotᵀ@g matmul under a mesh: modules._gather_rows_bwd) must equal
+    jnp.take's native scatter-add VJP — including repeated ids,
+    non-chunk-multiple counts, and 2-D id arrays."""
     rng = np.random.default_rng(0)
     table = jnp.asarray(rng.normal(size=(17, 8)), jnp.float32)
     ids = jnp.asarray(rng.integers(0, 17, (3, 5)), jnp.int32)  # repeats likely
     cot = jnp.asarray(rng.normal(size=(3, 5, 8)), jnp.float32)
+    hint = _hints()[meshed]
 
     def via_custom(t):
-        return (M._gather_rows(t, ids, 17, "float32") * cot).sum()
+        return (M._gather_rows(t, ids, 17, "float32", hint) * cot).sum()
 
     def via_take(t):
         return (jnp.take(t, ids, axis=0) * cot).sum()
@@ -214,18 +225,20 @@ def test_gather_rows_matmul_backward_matches_scatter():
                                rtol=1e-5, atol=1e-6)
 
 
-def test_gather_rows_backward_chunking():
+@pytest.mark.parametrize("meshed", [False, True])
+def test_gather_rows_backward_chunking(meshed):
     """Id counts above the scan chunk exercise padding + accumulation."""
     rng = np.random.default_rng(1)
     n = M._GATHER_BWD_CHUNK + 37  # forces pad + 2 scan steps
     table = jnp.asarray(rng.normal(size=(23, 4)), jnp.bfloat16)
     ids = jnp.asarray(rng.integers(0, 23, (n,)), jnp.int32)
     cot = jnp.asarray(rng.normal(size=(n, 4)), jnp.bfloat16)
+    hint = _hints()[meshed]
 
-    g = jax.grad(lambda t: (M._gather_rows(t, ids, 23, "bfloat16")
+    g = jax.grad(lambda t: (M._gather_rows(t, ids, 23, "bfloat16", hint)
                             * cot).astype(jnp.float32).sum())(table)
     # fp32 oracle: the bf16 scatter-add VJP itself drifts (per-add rounding);
-    # the chunked matmul accumulates in fp32, so compare against exact math.
+    # both paths accumulate in fp32, so compare against exact math.
     want_f32 = jax.grad(
         lambda t: (jnp.take(t, ids, axis=0)
                    * cot.astype(jnp.float32)).sum())(

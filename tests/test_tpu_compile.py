@@ -249,6 +249,53 @@ def test_kernels_compile_partitioned_for_v5e(chips, case, axes):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def _embedding_backward(hint, spec):
+    """The embedding lookup's backward as the model takes it, at
+    gpt2s-train-1chip's micro-batch: (V, N, d) = (50304, 12288, 768)."""
+    from penroz_tpu.ops import modules as M
+    width = HEADS * HEAD_DIM
+    shapes = [((VOCAB, width), jnp.bfloat16, P()),
+              ((CELL_ROWS, BLOCK), jnp.int32, spec),
+              ((CELL_ROWS, BLOCK, width), jnp.bfloat16, spec)]
+
+    def loss(t, ids, cot):
+        return (M._gather_rows(t, ids, VOCAB, "bfloat16", hint)
+                * cot).astype(jnp.float32).sum()
+
+    return jax.grad(loss), shapes
+
+
+def test_embedding_backward_is_a_native_scatter_on_one_chip(chip):
+    """``modules._scatter_rows_grad`` rests on what the v5e compiler makes
+    of a row scatter-add: one ``scatter`` inside a fusion, no ``while``
+    walking the 12 288 update rows (it once was one, and the one-hot matmul
+    was written to avoid it).  A compiler that goes back to the loop fails
+    here, before a chip shows it as a slower step."""
+    fn, shapes = _embedding_backward("tpu", P())
+    hlo = jax.jit(fn).lower(*(
+        jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+        for shape, dtype, _ in shapes)).compile().as_text()
+    assert " scatter(" in hlo
+    assert " while(" not in hlo and " convolution(" not in hlo
+
+
+def test_embedding_backward_under_a_mesh_gathers_rows_not_tables(chips):
+    """Under ``data=4`` the backward stays on the one-hot scan: GSPMD
+    gathers the ids and the cotangent rows for it and moves no (V, d) table
+    between chips (the scatter would get an all-reduce of bf16 partial
+    tables)."""
+    from penroz_tpu.ops.attention import Placement
+    from penroz_tpu.parallel import mesh as mesh_lib
+    mesh = mesh_lib.make_mesh(chips, model=1)
+    fn, shapes = _embedding_backward(Placement("tpu", mesh), P("data"))
+    hlo = jax.jit(fn, out_shardings=NamedSharding(mesh, P())).lower(*(
+        jax.ShapeDtypeStruct(shape, dtype, sharding=NamedSharding(mesh, spec))
+        for shape, dtype, spec in shapes)).compile().as_text()
+    assert " scatter(" not in hlo and " convolution(" in hlo
+    assert "all-gather" in hlo
+    assert "all-reduce" not in hlo and "reduce-scatter" not in hlo
+
+
 def test_flash_kernels_are_where_the_benchmark_looks_for_them(chip):
     """``benchmark/metrics/flash_roofline_pct.py`` finds the flash kernels in
     a device trace by what their HLO instructions look like, not by a name:

@@ -47,8 +47,7 @@ from penroz_tpu.ops import modules as M
 from penroz_tpu.parallel import dist
 from penroz_tpu.parallel import mesh as mesh_lib
 from penroz_tpu.parallel import sharding as sharding_lib
-from penroz_tpu.utils import (checkpoint, profiling, stats as stats_lib,
-                              tracing)
+from penroz_tpu.utils import checkpoint, stats as stats_lib, tracing
 
 log = logging.getLogger(__name__)
 
@@ -137,27 +136,6 @@ def _sharded_zero_grads(params: dict) -> dict:
         out[k] = jax.make_array_from_callback(v.shape, sharding,
                                               shard_zeros)
     return out
-
-
-def run_microstepped_epoch(micro_fn, finalize_fn, params, opt_state,
-                           buffers, xs, ys, rng, num_steps: int,
-                           yield_cb=None):
-    """Drive one epoch through ``CompiledArch.train_micro_fns`` programs:
-    one device dispatch per micro-step with a decode-priority window
-    (``yield_cb``, default :func:`_yield_to_decodes`) opened between
-    them.  Shared by the /train/ path and bench.py's background trainer
-    so the TTFT benchmark measures exactly the production policy."""
-    if yield_cb is None:
-        yield_cb = _yield_to_decodes
-    grads = _sharded_zero_grads(params)
-    cost = jnp.zeros((), jnp.float32)
-    bufs = buffers
-    for i in range(num_steps):
-        if i:
-            yield_cb()
-        bufs, grads, cost = micro_fn(params, bufs, grads, cost,
-                                     xs[i], ys[i], rng, i)
-    return finalize_fn(params, opt_state, grads, bufs, cost)
 
 
 def _check_pipe_composition(pipe: int, seq: int) -> None:
@@ -1647,17 +1625,24 @@ class NeuralNetworkModel:
                                   out_shardings, sp_mode, ep_mesh,
                                   with_ratios: bool):
         """Decode-priority epoch: one device program per micro-step, with a
-        priority window opened before each so pending ``/generate/``
-        dispatches interleave at micro-step granularity (see
-        ``CompiledArch.train_micro_fns`` for the numerics contract)."""
+        priority window (:func:`_yield_to_decodes`) opened between them so
+        pending ``/generate/`` dispatches interleave at micro-step
+        granularity (see ``CompiledArch.train_micro_fns`` for the numerics
+        contract)."""
         micro_fn, finalize_fn = self.arch.train_micro_fns(
             self.optimizer_config, num_steps, remat=remat,
             compute_dtype=compute_dtype, sp_mesh=sp_mesh,
             platform=self._placement, with_ratios=with_ratios,
             out_shardings=out_shardings, sp_mode=sp_mode, ep_mesh=ep_mesh)
-        return run_microstepped_epoch(micro_fn, finalize_fn, self.params,
-                                      self.opt_state, self.buffers, xs, ys,
-                                      call_rng, num_steps)
+        grads = _sharded_zero_grads(self.params)
+        cost = jnp.zeros((), jnp.float32)
+        bufs = self.buffers
+        for i in range(num_steps):
+            if i:
+                _yield_to_decodes()
+            bufs, grads, cost = micro_fn(self.params, bufs, grads, cost,
+                                         xs[i], ys[i], call_rng, i)
+        return finalize_fn(self.params, self.opt_state, grads, bufs, cost)
 
     def _training_mesh(self, micro_batch: int, block_size: int):
         """Device mesh for the training run (None = single device).
@@ -2615,7 +2600,7 @@ class NeuralNetworkModel:
                 t0 = time.monotonic()
                 rng = jax.random.fold_in(call_rng, dispatch)
                 if at_boundary:
-                    with profiling.span("penroz/prefill"):
+                    with tracing.span("penroz/prefill"):
                         kv = kv.reset()
                         feed = context[-block_size:]
                         x = jnp.asarray(np.asarray(feed, np.int64)[None, :],
@@ -2632,7 +2617,7 @@ class NeuralNetworkModel:
                         last_dev = tok_arr
                         dispatched += 1
                 else:
-                    with profiling.span("penroz/decode_chunk"):
+                    with tracing.span("penroz/decode_chunk"):
                         room = block_size - cache_len
                         remaining = max_new_tokens - dispatched
                         chunk = _decode_chunk_size(
@@ -2845,7 +2830,7 @@ class NeuralNetworkModel:
         x = jnp.asarray(np.asarray(tokens, np.int64)[None, :], jnp.int32)
         aidx = (jnp.asarray([adapter_slot], jnp.int32)
                 if lora is not None else None)
-        with profiling.span("penroz/decode_prefill_chunk"):
+        with tracing.span("penroz/decode_prefill_chunk"):
             tok, kv_out = fn(self.params, self.buffers, kv_batch, x,
                              jnp.asarray(row, jnp.int32),
                              jnp.asarray(row_len, jnp.int32), rng, temp,
@@ -2896,7 +2881,7 @@ class NeuralNetworkModel:
         x = jnp.asarray(np.asarray(tokens, np.int64)[None, :], jnp.int32)
         aidx = (jnp.asarray([adapter_slot], jnp.int32)
                 if lora is not None else None)
-        with profiling.span("penroz/decode_verify_row"):
+        with tracing.span("penroz/decode_verify_row"):
             out, kv_out = fn(self.params, self.buffers, kv_batch, x,
                              jnp.asarray(row, jnp.int32),
                              jnp.asarray(row_len, jnp.int32), rng, temp,
@@ -2961,7 +2946,7 @@ class NeuralNetworkModel:
             fn = arch._jit_cache[key] = jax.jit(step, donate_argnums=(2,))
         aidx = (jnp.asarray(row_adapter, jnp.int32)
                 if lora is not None else None)
-        with profiling.span("penroz/decode_step_batched"):
+        with tracing.span("penroz/decode_step_batched"):
             return fn(self.params, self.buffers, kv,
                       jnp.asarray(last_tokens, jnp.int32),
                       jnp.asarray(lengths, jnp.int32), rng,
@@ -2975,9 +2960,8 @@ class NeuralNetworkModel:
         """Run up to ``n`` shared decode+sample steps in ONE jitted
         dispatch — a ``lax.scan`` over the exact per-step program of
         :meth:`decode_step_batched`, so the host dispatch floor (sync
-        lengths, check stop tokens, launch again — 73–107 ms/dispatch in
-        the bench captures) is paid once per ``n`` tokens instead of once
-        per token.
+        lengths, check stop tokens, launch again) is paid once per ``n``
+        tokens instead of once per token.
 
         The scan carry is ``(kv, last_tok, lengths, active, emitted)``:
 
@@ -3050,7 +3034,7 @@ class NeuralNetworkModel:
             fn = arch._jit_cache[key] = jax.jit(run, donate_argnums=(2,))
         aidx = (jnp.asarray(row_adapter, jnp.int32)
                 if lora is not None else None)
-        with profiling.span("penroz/decode_superstep"):
+        with tracing.span("penroz/decode_superstep"):
             return fn(self.params, self.buffers, kv,
                       jnp.asarray(last_tokens, jnp.int32),
                       jnp.asarray(lengths, jnp.int32),
@@ -3159,7 +3143,7 @@ class NeuralNetworkModel:
               else np.zeros((n, Tp), np.int32))
         rid = (np.asarray(row_ids, np.int32) if row_ids is not None
                else np.full((n, Tp), -1, np.int32))
-        with profiling.span("penroz/decode_mixed_step"):
+        with tracing.span("penroz/decode_mixed_step"):
             return fn(self.params, self.buffers, kv,
                       jnp.asarray(descs), jnp.asarray(tok_lit),
                       jnp.asarray(tok_src, jnp.int32).reshape(n, Tp),
@@ -3252,7 +3236,7 @@ class NeuralNetworkModel:
             kv_stage = KV.restage_shared(kv_stage, repl)
             if s > 0 and isinstance(x, jax.Array):
                 x = jax.device_put(x, repl)
-        with profiling.span("penroz/decode_pipe_stage"):
+        with tracing.span("penroz/decode_pipe_stage"):
             return fn(params, buffers, kv_stage, x, jnp.asarray(descs),
                       jnp.asarray(positions.reshape(Tp)),
                       jnp.asarray(np.asarray(row_ids,

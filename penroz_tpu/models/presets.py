@@ -1,4 +1,4 @@
-"""Ready-made layer-DSL configs for the benchmark/baseline model families.
+"""Ready-made layer-DSL configs for the baseline model families.
 
 The reference embeds exactly one config — the GPT-2-124M `/model/` OpenAPI
 example (reference main.py:53-93); these builders generate that same DSL
@@ -38,7 +38,8 @@ def gpt2_custom(d: int, heads: int, depth: int, vocab: int = 50304,
                 block: int = 1024, dropout: float = 0.0) -> list:
     """GPT-2-shaped DSL at arbitrary dimensions — the single source for the
     ladder sizes above, the driver contract's flagship config
-    (``__graft_entry__._gpt2_dsl``), and the scaling bench's shrunken stack.
+    (``__graft_entry__._gpt2_dsl``), ``chip_smoke.py`` and the benchmark's
+    configurations (``benchmark/configs/``).
     (The HF-config→DSL builder in models/dsl.py stays separate: it is
     table-driven against the reference's ``mappers.py:121-176`` field
     mapping, which is its own parity contract.)"""

@@ -171,7 +171,7 @@ from penroz_tpu.serve import spec_decode
 from penroz_tpu.serve import streams
 from penroz_tpu.serve import tierstore
 from penroz_tpu.serve.qos import TenantQuotaExceeded  # noqa: F401 — re-export
-from penroz_tpu.utils import bucketing, checkpoint, faults, profiling
+from penroz_tpu.utils import bucketing, checkpoint, faults, tracing
 from penroz_tpu.utils import metrics as metrics_util
 from penroz_tpu.utils import stats as stats_util
 
@@ -1255,7 +1255,7 @@ class DecodeEngine:
         t0 = time.monotonic()
         self._dispatch_t0 = t0
         try:
-            with profiling.span("penroz/sched_tick"):
+            with tracing.span("penroz/sched_tick"):
                 self._prefill_tick()
                 if self._decoding_rows():
                     n = self._plan_superstep()
@@ -1314,7 +1314,7 @@ class DecodeEngine:
         self._dispatch_t0 = t0
         superstep = 0
         try:
-            with profiling.span("penroz/sched_tick"):
+            with tracing.span("penroz/sched_tick"):
                 if self._pipe is not None and self._lora_pack is None:
                     plans = self._plan_mixed_blocks()
                     if not plans:
@@ -1509,7 +1509,7 @@ class DecodeEngine:
         self._dispatch += n
         t0 = time.monotonic()
         with model_mod.decode_priority(), \
-                profiling.span("penroz/sched_mixed"):
+                tracing.span("penroz/sched_mixed"):
             sampled, self._kv = self._model.decode_mixed_step(
                 self._kv, plan["descs"], plan["tok_lit"], plan["tok_src"],
                 plan["positions"], plan["sample_slot"], self._last_tok,
@@ -1701,7 +1701,7 @@ class DecodeEngine:
         live = set(range(len(blocks)))
         ticks = bubbles = 0
         with model_mod.decode_priority(), \
-                profiling.span("penroz/sched_pipeline"):
+                tracing.span("penroz/sched_pipeline"):
             while live:
                 ran_stage = 0
                 for s in reversed(range(S)):
@@ -2302,7 +2302,7 @@ class DecodeEngine:
         # history for a resumed row, req.prompt otherwise) and is static
         # for the whole PREFILLING phase — tokens only append post-prefill.
         with model_mod.decode_priority(), \
-                profiling.span("penroz/sched_prefill_chunk"):
+                tracing.span("penroz/sched_prefill_chunk"):
             tok, self._kv = self._model.decode_prefill_chunk(
                 self._kv, row, state.history[start:start + size], start, rng,
                 self.temperature, self.top_k, lora=self._lora_pack,
@@ -2871,7 +2871,7 @@ class DecodeEngine:
         dispatch = self._dispatch
         self._dispatch += 1
         t0 = time.monotonic()
-        with model_mod.decode_priority(), profiling.span("penroz/sched_step"):
+        with model_mod.decode_priority(), tracing.span("penroz/sched_step"):
             toks, self._kv = self._model.decode_step_batched(
                 self._kv, self._last_tok[:, None], self._lengths, self._rng,
                 self.temperature, self.top_k, lora=self._lora_pack,
@@ -2982,7 +2982,7 @@ class DecodeEngine:
         # non-greedy outputs are invariant under the superstep size.
         self._dispatch += n
         with model_mod.decode_priority(), \
-                profiling.span("penroz/sched_superstep"):
+                tracing.span("penroz/sched_superstep"):
             toks, emit, lens, self._kv = self._model.decode_superstep(
                 self._kv, self._last_tok[:, None], self._lengths, active,
                 stop, remaining, self._rng, dispatch, n,
@@ -3085,7 +3085,7 @@ class DecodeEngine:
                                    drafted=len(draft))
               if state.req.trace is not None else None)
         with model_mod.decode_priority(), \
-                profiling.span("penroz/sched_verify"):
+                tracing.span("penroz/sched_verify"):
             out, self._kv = self._model.decode_verify_row(
                 self._kv, row, tokens, start, rng, self.temperature,
                 self.top_k, lora=self._lora_pack,

@@ -8,8 +8,8 @@ path is part of the cache key, so it is never built from a temp name, a
 pid, a clock or a host fingerprint.
 
 Imports no jax at module level: parents that must stay off the accelerator
-(``__graft_entry__.dryrun_multichip``, ``bench_scaling.main``) call
-:func:`cache_dir` to hand the same directory to their children.
+(``__graft_entry__.dryrun_multichip``) call :func:`cache_dir` to hand the
+same directory to their children.
 """
 
 from __future__ import annotations

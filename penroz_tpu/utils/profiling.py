@@ -7,12 +7,11 @@ a real profile hook on top: these helpers expose
 
 - ``start(log_dir)`` / ``stop()`` — capture an XLA/TPU trace viewable in
   TensorBoard or Perfetto (device kernels, HBM transfers, host callbacks);
-- ``span(name)`` — a trace annotation context for hot-path regions (train
-  epoch, decode dispatch) so captured traces carry framework-level names
-  (``utils/tracing.py::span``, which also records into the current trace);
 - ``maybe_start_server()`` — a live-profiling gRPC endpoint
   (``PENROZ_PROFILER_PORT``) for `tensorboard --logdir` capture on a
   running service.
+
+The named regions a capture shows are ``utils/tracing.py::span``'s.
 
 All helpers are no-op-safe: profiling failures must never take down
 training or serving.
@@ -25,8 +24,6 @@ import os
 import threading
 
 import jax
-
-from penroz_tpu.utils import tracing
 
 log = logging.getLogger(__name__)
 
@@ -75,12 +72,6 @@ def stop() -> str | None:
         _active_dir = None
         log.info("Profiler trace stopped → %s", log_dir)
         return log_dir
-
-
-# Named region visible in captured traces (cheap when nothing is capturing)
-# and, when a trace is current, in that trace's span tree: one
-# implementation, in utils/tracing.py.
-span = tracing.span
 
 
 def maybe_start_server() -> bool:

@@ -1,15 +1,16 @@
 #!/usr/bin/env python
 """Tier-1 gate wall-clock budget report from pytest ``--durations`` output.
 
-The tier-1 gate (ROADMAP.md) runs the whole not-slow suite under
-``timeout -k 10 1080`` — an 18-minute hard wall.  Every PR that adds
-serving tests nibbles at that budget, and until now the "which tests
-should move to the slow lane" call was eyeballed from raw pytest output.
-This script turns it into a report:
+The driver runs the whole not-slow suite under ``timeout -k 10 1470`` with
+six xdist workers (``-n 6 --dist loadfile``; the command is in
+``/root/TESTS_LAST_RUN.json``) and took 374 s of it at PR 30.  The slow
+lane was sized for an earlier serial 1080 s wall: which marks earn a place
+back is a measurement this script reports (ROADMAP.md D14), from a log of
+the gate run with ``--durations``:
 
     # from a saved log (the gate already tees /tmp/_t1.log):
-    python -m pytest tests/ -q -m 'not slow' --durations=50 2>&1 \
-        | tee /tmp/_t1.log
+    python -m pytest tests/ -q -m 'not slow' -n 6 --dist loadfile \
+        --durations=50 2>&1 | tee /tmp/_t1.log
     python scripts/tier1_budget.py /tmp/_t1.log
 
     # or pipe it:
@@ -30,8 +31,10 @@ test, and prints:
   (a lower bound — pytest only reports the slowest N phases).
 
 Exit status: 0 when the projected wall fits inside the budget scaled by
-``--headroom`` (default 0.85 — an 18-min gate should cruise at ~15 min,
-the last 15% absorbs CI jitter), 2 when it does not, 1 on a parse error.
+``--headroom`` (default 0.85: the last 15 % absorbs a loaded machine), 2
+when it does not, 1 on a parse error.  Under xdist the durations sum is work
+summed over the workers, not wall time: trust the projection only when the
+log carries pytest's tail summary.
 No dependencies beyond the standard library; the report is plain text so
 it can ride in a PR description verbatim.
 """
@@ -43,7 +46,7 @@ import re
 import subprocess
 import sys
 
-BUDGET_S = 1080.0  # the gate's `timeout -k 10 1080` wall (18 min)
+BUDGET_S = 1470.0  # the driver's `timeout -k 10 1470` wall
 
 # "12.34s call     tests/test_x.py::test_y[param]"
 _DUR_RE = re.compile(
@@ -152,6 +155,7 @@ def main(argv=None) -> int:
         proc = subprocess.run(
             [sys.executable, "-m", "pytest", "tests/", "-q", "-m",
              "not slow", "--continue-on-collection-errors",
+             "-n", "6", "--dist", "loadfile",
              f"--durations={args.durations}", "-p", "no:cacheprovider"],
             capture_output=True, text=True, env=env)
         lines = (proc.stdout + proc.stderr).splitlines()

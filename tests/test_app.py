@@ -162,9 +162,8 @@ def test_generate_while_training(client, workdir):
     served from the latest checkpoint (it never shares the training
     thread's in-memory params) while the epoch loop owns the device; it
     must return 200 with valid tokens, and training must still complete.
-    The latency cost of the device contention is measured on-chip by
-    bench.py (ttft_under_train_ms_p50); see README "Serving while
-    training"."""
+    What the device contention costs in latency has no benchmark cell yet
+    (not measured); see README "Serving while training"."""
     import time
     _create_model(client)
     _make_shards(workdir)

@@ -160,7 +160,7 @@ def test_flash_backward_gqa_group_sum():
 
 
 def test_flash_backward_long_context_t4096():
-    """VERDICT done-criterion: grad parity vs the oracle at T≥4096 — the
+    """Grad parity vs the oracle at T≥4096 — the
     K-grid-tiled kernels never hold (T, S) scores or full (S, D) K/V in
     VMEM, so long context lowers and matches."""
     from penroz_tpu.ops.pallas import flash_attention as FA

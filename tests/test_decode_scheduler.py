@@ -9,6 +9,7 @@ recycling.
 
 import asyncio
 import queue
+import threading
 import time
 
 import numpy as np
@@ -71,13 +72,28 @@ class _Collector:
     """Thread-queue consumer for engine-level tests (the async layer is
     exercised separately through the HTTP routes)."""
 
-    def __init__(self, prompt):
+    def __init__(self, prompt, hold_at=None):
         self.q = queue.Queue()
         self.tokens = list(prompt)
         self.received = 0
+        # ``hold_at=n`` parks the engine's WORKER inside the delivery of
+        # the n-th token (callbacks run on its thread, outside its lock)
+        # until ``release`` is set: a test that needs "B arrives while A is
+        # mid-decode" submits B in that window instead of racing A's
+        # remaining tokens against the wall clock.
+        self._hold_at = hold_at
+        self._delivered = 0
+        self.held = threading.Event()
+        self.release = threading.Event()
 
     def on_event(self, kind, value):
         self.q.put((kind, value))
+        if kind == "token":
+            self._delivered += 1
+            if self._delivered == self._hold_at:
+                self.held.set()
+                if not self.release.wait(timeout=120):
+                    raise TimeoutError("test never released the worker")
 
     def result(self, timeout=180):
         deadline = time.monotonic() + timeout
@@ -93,9 +109,10 @@ class _Collector:
                 raise value
 
 
-def _submit(engine, prompt, max_new, stop_token=None, timeout_ms=None):
+def _submit(engine, prompt, max_new, stop_token=None, timeout_ms=None,
+            hold_at=None):
     from penroz_tpu.serve import decode_scheduler
-    collector = _Collector(prompt)
+    collector = _Collector(prompt, hold_at=hold_at)
     engine.submit(decode_scheduler.Request(prompt, max_new, stop_token,
                                            collector.on_event,
                                            timeout_ms=timeout_ms))
@@ -215,6 +232,19 @@ def _json(client_loop, method, path, **kw):
         return resp.status, (_json_mod.loads(body) if body else None)
 
     return loop.run_until_complete(go())
+
+
+def _wait_stats(client_loop, predicate, what, timeout=60):
+    """Poll ``/serving_stats/`` until ``predicate(stats)`` holds: for what
+    the worker does on its own thread AFTER it answered the request (the
+    response alone does not order it)."""
+    deadline = time.monotonic() + timeout
+    while True:
+        _, stats = _json(client_loop, "GET", "/serving_stats/")
+        if predicate(stats):
+            return stats
+        assert time.monotonic() < deadline, f"{what}: {stats}"
+        time.sleep(0.02)
 
 
 def _gen_payload(**overrides):
@@ -418,10 +448,13 @@ def test_prefix_cache_hit_miss_parity(gpt_model, make_engine, prefix_env):
     different suffix), (repeat hit) — every stream token-identical to the
     standalone path, with the hits aliasing the shared prefix's pages
     (hit_tokens counts the skipped prefill)."""
+    from penroz_tpu.serve import metrics as serve_metrics
     prefix = [1, 2, 3, 4, 5, 6, 7, 8]          # 2 full pages
     px, py = prefix + [9, 10], prefix + [11]
     base_x = gpt_model.generate_tokens([px], BLOCK, 4, temperature=0.0)
     base_y = gpt_model.generate_tokens([py], BLOCK, 4, temperature=0.0)
+    hits0 = serve_metrics.PREFIX_HITS.value()
+    misses0 = serve_metrics.PREFIX_MISSES.value()
     engine = make_engine("schedgpt", BLOCK, 0.0, None, capacity=2)
     assert _submit(engine, px, 4).result() == base_x   # miss
     assert _submit(engine, py, 4).result() == base_y   # hit (shared prefix)
@@ -430,6 +463,9 @@ def test_prefix_cache_hit_miss_parity(gpt_model, make_engine, prefix_env):
     assert pc["misses"] == 1 and pc["hits"] == 2, pc
     assert pc["hit_tokens"] == 16  # 2 pages x 4 tokens x 2 hits
     assert pc["hit_rate"] == pytest.approx(2 / 3)
+    # /metrics counts the same admissions
+    assert serve_metrics.PREFIX_HITS.value() - hits0 == 2
+    assert serve_metrics.PREFIX_MISSES.value() - misses0 == 1
 
 
 def test_prefix_cache_eviction_then_rematch_parity(gpt_model, make_engine,
@@ -549,12 +585,14 @@ def test_queue_full_sheds_while_inflight_keeps_parity(gpt_model,
     in-flight nor the queued request's tokens change (no cross-request
     corruption under shedding)."""
     from penroz_tpu.serve import decode_scheduler
+    from penroz_tpu.serve import metrics as serve_metrics
     from penroz_tpu.utils import faults
     pa, pb, pc = [1, 2, 3], [5], [7, 8]
     base_a = gpt_model.generate_tokens([pa], BLOCK, 6, temperature=0.0)
     base_b = gpt_model.generate_tokens([pb], BLOCK, 4, temperature=0.0)
     monkeypatch.setenv(decode_scheduler.MAX_QUEUE_ENV, "1")
     monkeypatch.setenv(faults.ENV, "decode.step:sleep@80")  # slow decode
+    shed0 = serve_metrics.QUEUE_REJECTIONS.value()
     engine = make_engine("schedgpt", BLOCK, 0.0, None, capacity=1)
     ca = _submit(engine, pa, 6)
     _wait_tokens(ca, 1)          # A admitted: pending queue is empty
@@ -565,6 +603,7 @@ def test_queue_full_sheds_while_inflight_keeps_parity(gpt_model,
     assert cb.result() == base_b
     stats = engine.stats()
     assert stats["queue_rejections"] == 1
+    assert serve_metrics.QUEUE_REJECTIONS.value() - shed0 == 1
     assert stats["queue_wait_ms_p99"] is not None
 
 
@@ -712,17 +751,13 @@ def test_max_stall_budget_runs_multiple_chunks(gpt_model, make_engine,
     base_a = gpt_model.generate_tokens([pa], BLOCK, 8, temperature=0.0)
     base_b = gpt_model.generate_tokens([pb], BLOCK, 4, temperature=0.0)
     engine = make_engine("schedgpt", BLOCK, 0.0, None, capacity=2)
-    ca = _submit(engine, pa, 8)
-    deadline = time.monotonic() + 120
-    while ca.received < 2:
-        assert time.monotonic() < deadline, "A never started decoding"
-        try:
-            kind, value = ca.q.get(timeout=1.0)
-        except queue.Empty:
-            continue
-        ca.tokens.append(value)
-        ca.received += 1
+    # B is queued while the worker sits in the delivery of A's first token,
+    # so it is admitted at the next boundary with 6 of A's 8 tokens still
+    # to come: its chunks provably run against a decoding row.
+    ca = _submit(engine, pa, 8, hold_at=1)
+    assert ca.held.wait(timeout=120), "A never started decoding"
     cb = _submit(engine, pb, 4)
+    ca.release.set()
     assert cb.result() == base_b
     assert ca.result() == base_a
     # all 6 of B's 1-token chunks fit one boundary under the huge budget
@@ -887,10 +922,12 @@ def test_http_breaker_503_readyz_and_probe_recovery(client, gpt_model,
     assert status == 503                     # breaker sheds during cooldown
     assert "circuit breaker" in body["detail"]
 
-    _, stats = _json(client, "GET", "/serving_stats/")
+    # the breaker opens before the crashed request is failed; the reset
+    # runs after it, on the worker's thread
+    stats = _wait_stats(client, lambda s: s["engine_resets"] == 1,
+                        "engine never reset after the crash")
     assert stats["breaker_open"] is True
     assert stats["crashes_total"] == 1
-    assert stats["engine_resets"] == 1
 
     # cooldown over (0ms), fault disarmed: the next request is the probe
     monkeypatch.setenv(decode_scheduler.BREAKER_COOLDOWN_ENV, "0")
@@ -977,6 +1014,7 @@ def test_spec_parity_matrix(gpt_model, make_engine, monkeypatch,
     token-identical to spec-off across prefix cache on/off, int8 KV
     on/off (all four cache variants) and chunked/one-shot prefill — with
     the verify path provably engaged (oracle drafts, full acceptance)."""
+    from penroz_tpu.serve import metrics as serve_metrics
     from penroz_tpu.serve import spec_decode
     if paged_prefix:
         monkeypatch.setenv("PAGED_KV_CACHE", "1")
@@ -991,6 +1029,8 @@ def test_spec_parity_matrix(gpt_model, make_engine, monkeypatch,
     base = gpt_model.generate_tokens([REP_PROMPT], BLOCK, 6,
                                      temperature=0.0)
     monkeypatch.setattr(spec_decode, "propose", _oracle_drafter([base]))
+    drafted0 = serve_metrics.SPEC_DRAFTED.value()
+    accepted0 = serve_metrics.SPEC_ACCEPTED.value()
     engine = make_engine("schedgpt", BLOCK, 0.0, None, capacity=2)
     assert _submit(engine, REP_PROMPT, 6).result() == base
     # second request: a prefix-cache HIT when the cache is on
@@ -1001,6 +1041,11 @@ def test_spec_parity_matrix(gpt_model, make_engine, monkeypatch,
     assert stats["spec_drafted_tokens"] > 0
     assert stats["spec_accept_rate"] == 1.0          # oracle drafts
     assert stats["tokens_per_decode_step"] > 1.0
+    # /metrics counts the same drafts
+    assert serve_metrics.SPEC_DRAFTED.value() - drafted0 \
+        == stats["spec_drafted_tokens"]
+    assert serve_metrics.SPEC_ACCEPTED.value() - accepted0 \
+        == stats["spec_accepted_tokens"]
     if paged_prefix:
         assert stats["prefix_cache"]["hits"] >= 1
 
@@ -1047,16 +1092,24 @@ def test_spec_stop_token_inside_accepted_draft(gpt_model, make_engine,
     plain path would: the tokens after the stop are discarded even though
     the verify step accepted them."""
     from penroz_tpu.serve import spec_decode
-    base = gpt_model.generate_tokens([REP_PROMPT], BLOCK, 8,
-                                     temperature=0.0)
-    stop = base[len(REP_PROMPT) + 2]               # third generated token
-    base_stop = gpt_model.generate_tokens([REP_PROMPT], BLOCK, 8,
+    # REP_PROMPT's greedy continuation is one token repeated, so its
+    # "third" token is also the one prefill emits and no draft is ever
+    # built; this prompt's third generated token differs from the first two.
+    prompt = [5]
+    base = gpt_model.generate_tokens([prompt], BLOCK, 8, temperature=0.0)
+    stop = base[len(prompt) + 2]                   # third generated token
+    assert stop not in base[len(prompt):len(prompt) + 2], base
+    base_stop = gpt_model.generate_tokens([prompt], BLOCK, 8,
                                           temperature=0.0, stop_token=stop)
+    assert base_stop == base[:len(prompt) + 3]
     spec_env.setattr(spec_decode, "propose", _oracle_drafter([base]))
     engine = make_engine("schedgpt", BLOCK, 0.0, None, capacity=2)
-    assert _submit(engine, REP_PROMPT, 8, stop_token=stop).result() \
+    assert _submit(engine, prompt, 8, stop_token=stop).result() \
         == base_stop
-    assert engine.stats()["spec_verify_steps"] > 0
+    stats = engine.stats()
+    assert stats["spec_verify_steps"] > 0
+    # the oracle drafted past the stop and the verify step accepted it
+    assert stats["spec_accepted_tokens"] > 2
     assert engine.active_rows == 0
 
 
@@ -1390,11 +1443,14 @@ def test_superstep_dispatch_accounting(gpt_model, make_engine,
     fused blocks and tokens_per_decode_step pinned at 1.0 (fusing is not
     speculation)."""
     from penroz_tpu.serve import decode_scheduler
+    from penroz_tpu.serve import metrics as serve_metrics
     monkeypatch.setenv(decode_scheduler.SUPERSTEP_ENV, "8")
+    dispatches0 = serve_metrics.DISPATCHES.value()
     engine = make_engine("schedgpt", BLOCK, 0.0, None, capacity=1)
     _submit(engine, [1], 12).result()
     stats = _settled_stats(engine)
     assert stats["dispatches_total"] == 3
+    assert serve_metrics.DISPATCHES.value() - dispatches0 == 3
     assert stats["decode_tokens"] == 11     # 12 minus the prefill token
     assert stats["decode_steps"] == 11
     assert stats["tokens_per_decode_step"] == pytest.approx(1.0)
@@ -1556,15 +1612,13 @@ def test_unified_tick_fuses_chunks_and_drafts(gpt_model, make_engine,
     monkeypatch.setattr(spec_decode, "propose",
                         _oracle_drafter([base_a, base_b]))
     engine = make_engine("schedgpt", BLOCK, 0.0, None, capacity=2)
-    ca = _submit(engine, pa, 8)
-    # wait until row A is decoding (first token out) before admitting the
-    # long chunked prompt, so some later tick plans A's verify span
-    # alongside B's prefill chunks
-    deadline = time.monotonic() + 60
-    while ca.q.qsize() == 0 and time.monotonic() < deadline:
-        time.sleep(0.005)
-    assert ca.q.qsize() > 0, "row A produced no token within 60s"
+    # Queue the long chunked prompt while the worker sits in the delivery
+    # of A's first token: B is admitted at the next boundary with A still
+    # decoding, so a later tick plans A's verify span alongside B's chunks.
+    ca = _submit(engine, pa, 8, hold_at=1)
+    assert ca.held.wait(timeout=120), "row A produced no token"
     cb = _submit(engine, pb, 4)
+    ca.release.set()
     assert ca.result() == base_a
     assert cb.result() == base_b
     fused_mixed = [e for e in engine.stats()["tick_timeline"]

@@ -5,9 +5,65 @@ answer with the CPU: ``"tpu"`` resolving to a CPU device, a kernel gate
 returning False on any exception, a benchmark falling back to the CPU and
 dividing by an assumed peak, a compile cache nobody outside could place,
 router replicas all built on device 0.
+
+What the pre-chip serving benchmark's smokes held, and where it is held now
+-----------------------------------------------------------------------------
+PR 31 deleted the serving benchmark script of the rounds before the chip, the
+shell loop that drove its ``--chaos`` scenario over every fault site, and the
+test file that ran them (12 tests, every one ``slow``: the gate never ran
+them).  Each smoke asserted a timing ratio on the CPU at toy widths,
+which went with it, and a parity or an accounting invariant, which tier 1
+holds (every test named below runs under ``-m 'not slow'``; "+" marks an
+assertion PR 31 added to that test because no tier-1 test had it):
+
+==================  ====================================  =====================
+smoke (flag)        parity / invariant                    tier-1 test
+==================  ====================================  =====================
+--shared-prefix     greedy parity cache on/off, hits,     test_decode_scheduler::test_prefix_cache_hit_miss_parity
+                    hit rate, /metrics hit counters (+)   (+ ..._eviction_then_rematch_parity, test_kv_cache::test_radix_*)
+--speculative       parity with drafts; drafted and       test_decode_scheduler::test_spec_parity_matrix (8 cases),
+                    accepted counts and their /metrics    ::test_spec_real_drafter_parity,
+                    twins (+); 1.0 tokens/step when       ::test_spec_adversarial_drafter_zero_accept_keeps_parity,
+                    nothing is accepted                   ::test_spec_stop_token_inside_accepted_draft
+--multi-adapter     per-row adapter parity, 2 live        test_lora_serving::test_mixed_adapter_superstep_parity[8]
+                    adapters, per-adapter tokens (+)
+--overload          admitted streams unchanged under      test_decode_scheduler::test_queue_full_sheds_while_inflight_keeps_parity
+                    shedding; 429 + Retry-After;          (+ /metrics twin), ::test_http_queue_full_429_with_retry_after,
+                    rejections counted (+ /metrics)       test_qos::test_per_class_bound_sheds_only_that_class
+--chaos, and        clean statuses only, reset, replay    test_memledger::test_chaos_fault_sites_leave_clean_ledger[step|prefill_chunk|verify],
+its loop over sites parity, strict ledger after a fault;  test_qos::test_preempt_crash_recovers_with_no_leaked_pins[1|8] (+ the
+                    qos.preempt at superstep 8 (+ case)   superstep-8 case), test_decode_scheduler::test_http_breaker_503_readyz_and_probe_recovery,
+                                                          and one test per remaining site: test_router (disagg.*),
+                                                          test_tierstore (tier.*), test_journal, test_streams,
+                                                          test_pipeline_serving (pipe.*), test_ssm_serving (ssm.*)
+--replicas          same tokens from every replica;       test_router::test_router_greedy_parity_matrix (8 cases),
+                    affinity steers a family; a refusing  ::test_router_prefix_affinity_steers_family_to_one_replica,
+                    replica is passed over                ::test_router_failover_then_probe_readmission
+--disagg            same tokens across the hand-off;      test_router::test_router_disagg_greedy_parity_matrix (8 cases;
+                    exports == imports, no failures;      + the /metrics hand-off counter per transport)
+                    the prefill replica never decodes
+--disagg-elastic    parity on both transports; a flip     same matrix [d2d|host],
+                    happens and is counted                test_router::test_router_elastic_shrink_flips_idle_prefill_to_decode
+--multistep         superstep parity with step-by-step;   test_decode_scheduler::test_superstep_parity_matrix (12 cases),
+                    exact dispatch counts (+ /metrics)    ::test_superstep_dispatch_accounting
+--mixed-slo         class admission; a preempted row      test_qos::test_interactive_backlog_outdrains_batch_flood,
+                    resumes to the same tokens from its   ::test_preempt_resume_parity_matrix[int8-8],
+                    cached pages; only the offender shed  ::test_quota_sheds_offender_only
+--ragged            unified tick parity with phased;      test_decode_scheduler::test_unified_parity_matrix (8 cases),
+                    chunks and drafts in a fused block    ::test_unified_tick_fuses_chunks_and_drafts,
+                                                          test_ragged_attention::test_ragged_kernel_matches_reference_interpret
+--sessions          hibernate / resume parity from HBM,   test_tierstore::test_hibernate_resume_parity_matrix[int8-step8]
+                    host and disk; promotions counted     (+ /metrics twins), ::test_cross_replica_wake_without_session_id,
+                    (+ /metrics)                          ::test_disk_wake_survives_engine_reset
+==================  ====================================  =====================
+
+``--memory``, ``--pipeline``, ``--restart`` and ``--hybrid`` had no smoke;
+their parity tests are test_memledger, test_pipeline_serving, test_journal
+and test_ssm_serving.
 """
 
 import os
+import shutil
 import subprocess
 import sys
 
@@ -183,53 +239,62 @@ def test_only_the_helper_sets_the_compile_cache_dir():
     assert offenders == []
 
 
-# -- bench.py ---------------------------------------------------------------
+# -- the benchmark and the smoke: no chip, no number -------------------------
+# Read from tests/, never edited from here: benchmark/ is the yardstick.
 
-class _Device:
-    def __init__(self, kind):
-        self.device_kind = kind
-
-
-def test_peak_flops_known_kinds_and_no_default():
-    import bench
-    assert bench.peak_flops(_Device("TPU v5 lite")) == 197e12
-    assert bench.peak_flops(_Device("TPU v5p")) == 459e12
+def test_benchmark_peaks_known_kinds_and_no_default():
+    from benchmark.lib import peaks
+    for kind in ("TPU v5 lite", "TPU v5e"):
+        assert peaks.peaks_for(kind)["flops_bf16"] == 197e12
+        assert peaks.peaks_for(kind)["hbm_bytes_per_s"] == 819e9
     for kind in ("cpu", "TPU v9", ""):
-        with pytest.raises(ValueError, match="no published bf16 peak"):
-            bench.peak_flops(_Device(kind))
+        with pytest.raises(ValueError, match="no published peaks"):
+            peaks.peaks_for(kind)
 
 
-def test_bench_phase_failure_is_recorded_and_fails_the_run(monkeypatch,
-                                                           tmp_path):
-    import bench
-    monkeypatch.setattr(bench, "PARTIAL_PATH", str(tmp_path / "partial.json"))
-    monkeypatch.setattr(bench, "_partial", {})
-    monkeypatch.setattr(bench, "_failed_phases", {})
-
-    def boom():
-        raise RuntimeError("kernel refused")
-
-    bench._phase("decode", boom)
-    bench._phase("fine", lambda: bench.emit(x=1))
-    assert "kernel refused" in bench._failed_phases["decode"]
-    import json
-    with open(bench.PARTIAL_PATH) as fh:
-        partial = json.load(fh)
-    assert partial["x"] == 1 and "decode" in partial["failed_phases"]
-
-
-def test_bench_without_a_chip_exits_nonzero_and_measures_nothing(tmp_path):
-    env = {k: v for k, v in os.environ.items()
-           if not k.startswith("PENROZ_BENCH")}
-    env.update(JAX_PLATFORMS="cpu",
-               PENROZ_BENCH_PARTIAL=str(tmp_path / "partial.json"))
-    out = subprocess.run([sys.executable, os.path.join(REPO, "bench.py")],
-                         env=env, cwd=str(tmp_path), capture_output=True,
-                         text=True, timeout=300)
+def test_benchmark_without_a_chip_exits_nonzero_and_measures_nothing():
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    out = subprocess.run(
+        [sys.executable, os.path.join(REPO, "benchmark", "run.py"),
+         "--workload", "gpt2s-train-1chip", "--seed", "1", "--seconds", "51"],
+        env=env, cwd=REPO, capture_output=True, text=True, timeout=300)
+    # run.py makes the cell's (still empty) work directory before it asks
+    # for the device; .gitignore lists .bench_work/
+    shutil.rmtree(os.path.join(REPO, ".bench_work", "gpt2s-train-1chip"),
+                  ignore_errors=True)
     assert out.returncode != 0
-    assert "no TPU attached" in out.stderr
+    assert "no TPU" in out.stderr
     assert out.stdout.strip() == ""
-    assert not (tmp_path / "partial.json").exists()
+
+
+def test_chip_smoke_phase_failure_is_recorded_and_fails_the_run(
+        monkeypatch, capsys):
+    """A phase that raises does not stop the run, and cannot pass it: the
+    ``end`` line names it in ``failed_phases`` and ``main`` returns
+    non-zero."""
+    import json
+
+    import chip_smoke
+
+    def one_phase_raises(sz, seed, device, on_tpu, phase):
+        def boom():
+            raise RuntimeError("kernel refused")
+        phase("kernels", boom)
+        phase("fine", lambda: chip_smoke.emit(phase="fine", x=1))
+
+    one = jax.devices()[:1]          # the tests' 8 virtual devices: --chips 1
+    monkeypatch.setattr(jax, "devices", lambda *a: one)
+    monkeypatch.setattr(chip_smoke, "run_one_chip", one_phase_raises)
+    assert chip_smoke.main(["--tiny"]) != 0
+    lines = [json.loads(line) for line in capsys.readouterr().out.splitlines()
+             if line.startswith("{")]
+    end = next(line for line in lines if line.get("phase") == "end")
+    assert end["failed_phases"] == ["kernels"]
+    failed = next(line for line in lines if line.get("ok") is False
+                  and line.get("phase") == "kernels")
+    assert "kernel refused" in failed["error"]
+    assert {"phase": "fine", "x": 1} in lines    # later phases still ran
+    assert lines[-1]["ok"] is False
 
 
 def test_chip_smoke_without_a_chip_exits_nonzero_with_no_result(tmp_path):

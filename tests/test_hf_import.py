@@ -123,8 +123,8 @@ def test_import_rejects_mismatched_state_dict(workdir):
 
 def test_import_is_torch_free(workdir, monkeypatch):
     """/import/ of a local safetensors GPT-2 succeeds with torch import
-    blocked — the VERDICT r2 acceptance bar (safetensors→numpy direct
-    load, SURVEY §2.3; torch remains only this file's oracle)."""
+    blocked (safetensors→numpy direct load, SURVEY §2.3; torch remains
+    only this file's oracle)."""
     import sys
     import transformers.configuration_utils as tcu
     _, torch_model = _tiny_gpt2()

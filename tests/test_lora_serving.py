@@ -558,6 +558,8 @@ def test_mixed_adapter_superstep_parity(gpt_model, tenants, make_engine,
                 f"{superstep}"
     stats = engine.stats()
     assert stats["lora_active_adapters"] == 2
+    assert stats["lora_adapter_tokens"] == {"tenA": 2 * max_new,
+                                            "tenB": 2 * max_new}
     if superstep > 1:
         assert any(e["superstep"] > 1 for e in stats["tick_timeline"])
 

@@ -187,8 +187,8 @@ def test_train_resets_progress_and_stats(workdir, toy_gpt_layers,
 def test_compute_stats_multihost_uses_local_copy(workdir, toy_gpt_layers):
     """Params spanning hosts (not fully addressable, fully replicated)
     must not skip stats: the instrumented pass runs on a process-local
-    copy of the params (VERDICT: reference always produces stats on
-    master, neural_net_model.py:705-709)."""
+    copy of the params (the reference always produces stats on master,
+    neural_net_model.py:705-709)."""
     model = NeuralNetworkModel("mhstats", Mapper(toy_gpt_layers, SGD))
 
     class FakeGlobalArray:

@@ -1,7 +1,7 @@
 """REAL multi-host training: two OS processes, cross-process collectives.
 
-Round-1 recorded multi-host as "only mock-tested (unavoidable here)"
-(VERDICT.md §coverage row 25).  It is avoidable: ``jax.distributed`` works
+Round 1 recorded multi-host as "only mock-tested (unavoidable here)".
+It is avoidable: ``jax.distributed`` works
 on the CPU backend across local processes, so these tests launch two
 workers with the production env wiring (coordinator address + process ids,
 two virtual CPU devices each → a 4-device global mesh) and drive the full

@@ -529,13 +529,15 @@ def test_preempt_resume_parity_with_lora_adapter(gpt_model, make_engine,
         adapters.REGISTRY.reset()
 
 
+@pytest.mark.parametrize("superstep", [1, 8])
 def test_preempt_crash_recovers_with_no_leaked_pins(gpt_model, make_engine,
-                                                    monkeypatch):
+                                                    monkeypatch, superstep):
     """Acceptance: a crash injected at ``qos.preempt`` fails the tick,
     ``_alloc_state`` rebuilds KV + a fresh radix cache (no pin can outlive
-    the state it guards), and both replays are greedy-identical."""
+    the state it guards), and both replays are greedy-identical — on the
+    unified tick at one step and at a fused superstep of 8."""
     from penroz_tpu.utils import faults
-    _preempt_env(monkeypatch, 1, 0)
+    _preempt_env(monkeypatch, superstep, 0)
     monkeypatch.setenv(faults.ENV,
                        "qos.preempt:raise@1,decode.step:sleep@120")
     pa, pb = [1, 2, 3, 4, 5, 6], [9, 10]

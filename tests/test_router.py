@@ -265,6 +265,7 @@ def test_router_disagg_greedy_parity_matrix(gpt_model, monkeypatch, int8,
     request provably travelled the export → import seam (no silent
     monolithic fallback)."""
     from penroz_tpu.serve import decode_scheduler
+    from penroz_tpu.serve import metrics as serve_metrics
     from penroz_tpu.serve import router as router_mod
     _disagg_env(monkeypatch, prefix=prefix)
     monkeypatch.setenv(decode_scheduler.DISAGG_TRANSPORT_ENV, transport)
@@ -277,12 +278,16 @@ def test_router_disagg_greedy_parity_matrix(gpt_model, monkeypatch, int8,
     # legacy baseline under the same KV env flags
     bases = [gpt_model.generate_tokens([p], BLOCK, 5, temperature=0.0)
              for p in prompts]
+    handoffs0 = serve_metrics.DISAGG_HANDOFFS.value(outcome="ok",
+                                                    transport=transport)
     router = _get_router(monkeypatch, n=2)
     assert [e.role for e in router.replicas] == ["prefill", "decode"]
     collectors = [_submit(router, p, 5) for p in prompts]
     for collector, base in zip(collectors, bases):
         assert collector.result() == base
     per = [e.stats() for e in router.replicas]
+    assert serve_metrics.DISAGG_HANDOFFS.value(
+        outcome="ok", transport=transport) - handoffs0 == len(prompts)
     assert sum(p["disagg_exports"] for p in per) == len(prompts)
     assert sum(p["disagg_imports"] for p in per) == len(prompts)
     assert sum(p["disagg_handoff_failures"] for p in per) == 0

@@ -324,6 +324,27 @@ def _wait_tier(sid, tier, timeout=60):
         time.sleep(0.02)
 
 
+def _wait_pins_released(engine, timeout=60):
+    """The worker pops a hibernation hold, exports (or, for a dropped
+    session, skips) and only then unpins: between the two the books are
+    mid-transition by design, and every in-program audit runs on the
+    worker's own thread after the unpin.  A test auditing from its own
+    thread waits for the engine to get there: no hold queued and no radix
+    node pinned (no row is live when this is called)."""
+    deadline = time.monotonic() + timeout
+    while True:
+        with engine._cond:
+            pinned = [nd.page for nd in engine._prefix_cache.iter_nodes()
+                      if nd.refs]
+            settled = not (pinned or engine._hib_holds
+                           or engine._hib_pending)
+        if settled:
+            return
+        assert time.monotonic() < deadline, \
+            f"hibernation pins never released: pages {pinned}"
+        time.sleep(0.02)
+
+
 @pytest.mark.parametrize("int8,superstep", [
     # fp step-1 rides the slow lane too (tier1_budget): the int8-step8
     # diagonal keeps hibernate/resume parity fast
@@ -339,9 +360,13 @@ def test_hibernate_resume_parity_matrix(gpt_model, make_engine, tier_env,
     (b) the host blob on a FRESH engine after ``decode_scheduler.reset()``
     dropped the radix pages — across int8 KV and superstep sizes."""
     from penroz_tpu.serve import decode_scheduler, tierstore
+    from penroz_tpu.serve import metrics as serve_metrics
     if int8:
         tier_env.setenv("TURBO_QUANT_KV_CACHE", "1")
     tier_env.setenv("PENROZ_SCHED_SUPERSTEP", str(superstep))
+    hibernated0 = serve_metrics.SESSIONS_HIBERNATED.value()
+    promoted0 = serve_metrics.TIER_PROMOTIONS.value(tier="host",
+                                                    outcome="ok")
     prompt = [1, 2, 3, 4, 5, 6, 7]
     out = gpt_model.generate_tokens([prompt], BLOCK, 4, temperature=0.0)
     cont = out + [9]                       # next turn extends the history
@@ -364,6 +389,11 @@ def test_hibernate_resume_parity_matrix(gpt_model, make_engine, tier_env,
     assert _submit(engine2, cont, 3).result() == base
     assert engine2.stats()["session_promotions"] == 1
     assert tierstore.TIERS.promotions[("host", "ok")] == 1
+    # /metrics counts the same hibernations and the one blob import
+    assert serve_metrics.SESSIONS_HIBERNATED.value() - hibernated0 \
+        == stats["sessions_hibernated"]
+    assert serve_metrics.TIER_PROMOTIONS.value(
+        tier="host", outcome="ok") - promoted0 == 1
 
 
 def test_cross_replica_wake_without_session_id(gpt_model, make_engine,
@@ -447,6 +477,7 @@ def test_memledger_hibernating_state_balances(gpt_model, make_engine,
     prompt = [1, 2, 3, 4, 5, 6, 7]
     _submit(engine, prompt, 4, session_id="ledger").result()
     _wait_tier("ledger", "host")
+    _wait_pins_released(engine)
     snap = engine.memory_snapshot()
     pool = snap["pool_pages"]
     # demoted: the hold is released, pages are evictable cache residents
@@ -463,7 +494,10 @@ def test_memledger_hibernating_state_balances(gpt_model, make_engine,
     cont.result()
     assert tierstore.TIERS.drop("ledger2", "api")
     _wait_tier("ledger", "host")     # original still resident
+    _wait_pins_released(engine)
     engine._ledger.audit("test.after_drop")
+    assert engine.memory_snapshot()["pool_pages"]["hibernating"] == 0
+    assert tierstore.TIERS.get("ledger2") is None
 
 
 @pytest.mark.parametrize("site", ["tier.demote", "tier.promote"])

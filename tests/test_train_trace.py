@@ -16,7 +16,7 @@ from aiohttp.test_utils import TestClient, TestServer
 
 from penroz_tpu.serve import app as app_mod
 from penroz_tpu.serve import metrics as serve_metrics
-from penroz_tpu.utils import checkpoint, profiling, tracing
+from penroz_tpu.utils import checkpoint, tracing
 
 pytestmark = pytest.mark.runtime
 
@@ -382,8 +382,7 @@ def test_span_without_a_trace_is_the_annotation_and_nothing_more(
 
     monkeypatch.setattr(jax.profiler, "TraceAnnotation", Annotation)
     tracing.reset()
-    assert profiling.span is tracing.span
-    with profiling.span("penroz/sched_tick"):
+    with tracing.span("penroz/sched_tick"):
         pass
     with tracing.span("penroz/ckpt_write", bytes=7) as sp:
         sp.set(more=1)

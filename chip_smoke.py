@@ -534,6 +534,62 @@ def phase_kernels(sz: dict, on_tpu: bool):
                "flash_bwd_ms": round(median_ms(jax.jit(flash_fwd_bwd),
                                                qc, kc, vc) - fwd_ms, 4)}
 
+    # the same attention in the model's own layout, (B, T, lanes), whose
+    # entry is the kernels' alone (off the chip there is nothing to compare)
+    if on_tpu:
+        def heads_first(x, heads):
+            return x.reshape(*x.shape[:2], heads, -1).transpose(0, 2, 1, 3)
+
+        def in_btd(attend, heads, kv_heads):
+            """``attend`` on (B, H, T, D) operands as a function of
+            (B, T, H·D) ones: the relayouts the module made before PR 32."""
+            def fn(q, k, v):
+                out = attend(heads_first(q, heads), heads_first(k, kv_heads),
+                             heads_first(v, kv_heads))
+                return out.transpose(0, 2, 1, 3).reshape(q.shape)
+            return fn
+
+        def value_and_grads(attend):
+            return jax.value_and_grad(
+                lambda *a: flash_loss(attend, *a),
+                argnums=(0, 1, 2), has_aux=True)
+
+        # as the training phases below run it: the fused (B, T, 3·H·D)
+        # projection read in place, two D = 64 heads to a lane block
+        def fused(attend):
+            def loss(qkv):
+                out = attend(qkv)
+                return (out.astype(jnp.float32) ** 2).sum(), out
+            return jax.value_and_grad(loss, has_aux=True)
+
+        w = H * D
+        qkv_cell = rand(sz["cell_rows"], T, 3 * w)
+        flash_btd = fused(lambda qkv: A.causal_attention_btd(
+            qkv, heads=H, kv_heads=H, platform=hint))
+        compare("flash_btd_fwd_bwd_cell", flash_btd,
+                fused(lambda qkv: in_btd(A.causal_attention_reference, H, H)(
+                    qkv[..., :w], qkv[..., w:2 * w], qkv[..., 2 * w:])),
+                (qkv_cell,), BF16)
+        timings["flash_btd_fwd_bwd_ms"] = round(
+            median_ms(jax.jit(flash_btd), qkv_cell), 4)
+
+        # three arrays (what RoPE / qk-norm models hand over), D = 128,
+        # grouped-query: 8 query heads on 2 K/V heads, one head a lane block
+        hq, hkv, d = 8, 2, 128
+        qg = rand(4, T, hq * d)
+        kg, vg = (rand(4, T, hkv * d) for _ in range(2))
+        flash_gqa = value_and_grads(lambda q, k, v: A.causal_attention_btd(
+            q, k, v, heads=hq, kv_heads=hkv, platform=hint))
+        compare("flash_btd_gqa128_fwd_bwd", flash_gqa,
+                value_and_grads(in_btd(A.causal_attention_reference,
+                                       hq, hkv)), (qg, kg, vg), BF16)
+        timings["flash_btd_gqa128_fwd_bwd_ms"] = round(
+            median_ms(jax.jit(flash_gqa), qg, kg, vg), 4)
+        timings["flash_bhtd_gqa128_fwd_bwd_ms"] = round(median_ms(
+            jax.jit(value_and_grads(in_btd(
+                lambda *a: A.causal_attention(*a, platform=hint),
+                hq, hkv))), qg, kg, vg), 4)
+
     # contiguous decode, ragged lengths
     lengths = jnp.asarray(rng.integers(1, T + 1, B), jnp.int32)
     q1 = rand(B, H, 1, D)

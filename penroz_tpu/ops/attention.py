@@ -161,8 +161,10 @@ def _rotate_half(x):
 
 
 def apply_rope(q, k, theta: float, offset, scaling: Optional[dict] = None,
-               rotary_dim: Optional[int] = None):
-    """Apply rotary embeddings to (B, H, T, D) query/key tensors.
+               rotary_dim: Optional[int] = None, seq_axis: int = 2):
+    """Apply rotary embeddings to (B, H, T, D) query/key tensors — or to
+    (B, T, H, D) ones with ``seq_axis=1`` (the rotation is elementwise, so
+    the layout is the caller's).
 
     ``rotary_dim`` < D applies partial rotary (GPT-NeoX/Pythia
     ``rotary_pct``): only the first ``rotary_dim`` feature dims are
@@ -170,18 +172,21 @@ def apply_rope(q, k, theta: float, offset, scaling: Optional[dict] = None,
     head_dim = q.shape[-1]
 
     def expand(tbl):
-        # (L, rd) → (1, 1, L, rd); (B, L, rd) ragged → (B, 1, L, rd)
+        # (L, rd) → (1, 1, L, rd); (B, L, rd) ragged → (B, 1, L, rd) —
+        # the heads' axis of size 1 after the sequence's when that is first
+        if seq_axis == 1:
+            return tbl[:, :, None] if tbl.ndim == 3 else tbl[None, :, None]
         return tbl[:, None] if tbl.ndim == 3 else tbl[None, None]
 
     if rotary_dim is None or rotary_dim >= head_dim:
-        cos, sin = rope_cos_sin(head_dim, theta, offset, q.shape[2], q.dtype,
-                                scaling=scaling)
+        cos, sin = rope_cos_sin(head_dim, theta, offset, q.shape[seq_axis],
+                                q.dtype, scaling=scaling)
         cos, sin = expand(cos), expand(sin)
         q = q * cos + _rotate_half(q) * sin
         k = k * cos + _rotate_half(k) * sin
         return q, k
-    cos, sin = rope_cos_sin(rotary_dim, theta, offset, q.shape[2], q.dtype,
-                            scaling=scaling)
+    cos, sin = rope_cos_sin(rotary_dim, theta, offset, q.shape[seq_axis],
+                            q.dtype, scaling=scaling)
     cos, sin = expand(cos), expand(sin)
     q_rot, q_pass = q[..., :rotary_dim], q[..., rotary_dim:]
     k_rot, k_pass = k[..., :rotary_dim], k[..., rotary_dim:]
@@ -324,25 +329,13 @@ def causal_attention(q, k, v, dropout_rate=0.0, dropout_rng=None,
                                           softcap=softcap)
     if _use_flash(q, k, platform):
         from penroz_tpu.ops.pallas import flash_attention as fa
-        dropping = dropout_rate > 0.0 and dropout_rng is not None
-        # Stay fused under dropout (the reference keeps fused SDPA with
-        # dropout): the kernel derives its keep-mask from an int32 seed
-        # via an in-kernel position hash — distributional parity with
-        # the bernoulli fallback, zero HBM mask traffic.
-        seed = (jax.random.randint(dropout_rng, (), 0,
-                                   jnp.iinfo(jnp.int32).max, dtype=jnp.int32)
-                if dropping else jnp.zeros((), jnp.int32))
+        rate, seed = _flash_dropout(dropout_rate, dropout_rng)
 
         def kernel(q, k, v, seed):
-            if dropping and isinstance(platform, Placement):
-                # the hash mixes LOCAL (batch, head) ids: give each shard
-                # its own seed or shards would repeat one another's masks
-                for ax in (DATA_AXIS, MODEL_AXIS):
-                    seed = seed * 31 + jax.lax.axis_index(ax)
             return fa.flash_attention(
-                q, k, v, causal=True,
-                dropout_rate=float(dropout_rate) if dropping else 0.0,
-                seed=seed, window=window, alibi=alibi, scale=scale)
+                q, k, v, causal=True, dropout_rate=rate,
+                seed=_shard_seed(seed, rate, platform), window=window,
+                alibi=alibi, scale=scale)
 
         bhtd = "b" + _head_dim_name(alibi) + ".."
         return _on_shards(kernel, platform, (bhtd, bhtd, bhtd, ""), bhtd,
@@ -350,6 +343,87 @@ def causal_attention(q, k, v, dropout_rate=0.0, dropout_rng=None,
     return causal_attention_reference(q, k, v, dropout_rate, dropout_rng,
                                       window=window, alibi=alibi,
                                       scale=scale)
+
+
+def _flash_dropout(dropout_rate, dropout_rng):
+    """``(rate, seed)`` for the flash kernels.  They stay fused under
+    dropout (the reference keeps fused SDPA with dropout): the kernel
+    derives its keep-mask from an int32 seed via an in-kernel position
+    hash — distributional parity with the bernoulli fallback, zero HBM
+    mask traffic."""
+    if dropout_rate > 0.0 and dropout_rng is not None:
+        return float(dropout_rate), jax.random.randint(
+            dropout_rng, (), 0, jnp.iinfo(jnp.int32).max, dtype=jnp.int32)
+    return 0.0, jnp.zeros((), jnp.int32)
+
+
+def _shard_seed(seed, rate: float, platform):
+    """Inside a mapped kernel call: the hash mixes LOCAL (batch, head) ids,
+    so give each shard its own seed or shards would repeat one another's
+    masks."""
+    if rate > 0.0 and isinstance(platform, Placement):
+        for ax in (DATA_AXIS, MODEL_AXIS):
+            seed = seed * 31 + jax.lax.axis_index(ax)
+    return seed
+
+
+def stays_in_model_layout(x, T: int, head_dim: int, heads: int,
+                          kv_heads: int, platform=None,
+                          softcap: Optional[float] = None) -> bool:
+    """Whether no-cache causal attention over a ``(B, T, ·)`` projection
+    ``x`` can run without leaving that layout (:func:`causal_attention_btd`).
+
+    Decided from what is observed, never from a knob: the flash kernels
+    apply at all (a TPU, ``T`` a multiple of 128, head size 64/128/256;
+    no logit softcap: :func:`causal_attention` sends that to the jnp
+    reference and says so itself); whole 128-lane
+    blocks of heads, and at head size 64 as many K/V heads as query heads
+    (``flash_attention.btd_refusal``); heads not split over a mesh's
+    ``model`` axis (the lane blocks of a fused projection are not a dim
+    ``_on_shards`` can split; a ``data``-only mesh splits the batch and
+    stays).  A module the kernels would serve that still falls back says
+    why, once."""
+    if (softcap is not None or _flash_disabled()
+            or not _tpu_platform(x, platform)
+            or not _flash_shapes(T, head_dim, heads, kv_heads)):
+        return False
+    from penroz_tpu.ops.pallas import flash_attention as fa
+    if (isinstance(platform, Placement)
+            and platform.mesh.shape.get(MODEL_AXIS, 1) > 1):
+        why = "heads are split over the mesh's model axis"
+    else:
+        why = fa.btd_refusal(head_dim, heads, kv_heads)
+    if why:
+        _warn_once(f"leaves_model_layout: {why}",
+                   "attention leaves the (B, T, H·D) layout for "
+                   "(B, H, T, D) kernels and the transposes around them: "
+                   "%s", why)
+    return not why
+
+
+def causal_attention_btd(q, k=None, v=None, *, heads: int, kv_heads: int,
+                         dropout_rate=0.0, dropout_rng=None, platform=None,
+                         window: Optional[int] = None,
+                         alibi: Optional[np.ndarray] = None,
+                         scale: Optional[float] = None):
+    """:func:`causal_attention` in the model's own layout, for shapes
+    :func:`stays_in_model_layout` admits: ``q`` alone is the fused
+    ``(B, T, (heads + 2·kv_heads)·D)`` projection, read in place; with
+    ``k`` and ``v``, three ``(B, T, H·D)`` arrays.  Returns
+    ``(B, T, heads·D)``.  Under a mesh the batch splits over ``data``."""
+    from penroz_tpu.ops.pallas import flash_attention as fa
+    rate, seed = _flash_dropout(dropout_rate, dropout_rng)
+    arrays = (q,) if k is None else (q, k, v)
+
+    def kernel(*args):
+        *arrays, seed = args
+        return fa.flash_attention_btd(
+            *arrays, heads=heads, kv_heads=kv_heads, causal=True,
+            dropout_rate=rate, seed=_shard_seed(seed, rate, platform),
+            window=window, alibi=alibi, scale=scale)
+
+    return _on_shards(kernel, platform, ("b..",) * len(arrays) + ("",),
+                      "b..", *arrays, seed)
 
 
 def cached_attention(q, k_full, v_full, offset, length,
@@ -679,7 +753,10 @@ def _use_flash(q, k, platform=None) -> bool:
     if _flash_disabled() or not _tpu_platform(q, platform):
         return False
     B, Hq, T, D = q.shape
-    Hkv = k.shape[1]
+    return _flash_shapes(T, D, Hq, k.shape[1])
+
+
+def _flash_shapes(T: int, D: int, Hq: int, Hkv: int) -> bool:
     # MXU-friendly: head dim multiple of 128 lane requirement handled by the
     # kernel via padding; sequence must be long enough to tile.
     return T >= 128 and T % 128 == 0 and D in (64, 128, 256) and Hq % Hkv == 0

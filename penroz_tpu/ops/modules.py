@@ -1046,6 +1046,16 @@ class CausalSelfAttention(Module):
     When a KV cache is present in the Ctx, new K/V are written at the current
     cache length (pre-GQA-expansion — unlike the reference, which expands KV
     heads before caching, we store only ``num_kv_heads`` heads in HBM).
+
+    Layout.  Without a cache and without sequence parallelism the flash
+    kernels run on the ``(B, T, ·)`` arrays as they are
+    (:meth:`_apply_in_model_layout`): no slice-and-transpose to ``(B, H, T,
+    D)`` and back.  Everything else — a KV cache, an SP axis or mesh, a
+    logit softcap, head counts that leave a 128-lane block half full, GQA at
+    head size 64, heads split over a mesh's ``model`` axis, no TPU, ``T``
+    not a multiple of 128 — takes the ``(B, H, T, D)`` path below;
+    ``ops/attention.py::stays_in_model_layout`` is the one place that
+    decides, from what it observes.
     """
 
     def __init__(self, num_heads: int, dropout: float = 0.0,
@@ -1190,44 +1200,90 @@ class CausalSelfAttention(Module):
         # Qwen3/LlamaRMSNorm order: weight * normed.to(input_dtype).
         return ((xf * norm).astype(x.dtype) * w).astype(x.dtype)
 
+    def _rotary_dim(self, head_dim: int):
+        """Feature dims RoPE rotates, or None for all of them."""
+        if self.rope_dim is not None:
+            return None if self.rope_dim >= head_dim else self.rope_dim
+        if self.rope_pct is not None and self.rope_pct < 1.0:
+            return int(head_dim * self.rope_pct) // 2 * 2
+        return None
+
+    def _flat_norm(self, q_flat, k_flat, ctx):
+        """OLMo-2: normalize the whole projection BEFORE the head split."""
+        if self.qk_norm and self.qk_norm_scope == "flat":
+            q_flat = self._head_rmsnorm(q_flat, self._p(ctx, "q_norm.weight"))
+            k_flat = self._head_rmsnorm(k_flat, self._p(ctx, "k_norm.weight"))
+        return q_flat, k_flat
+
+    def _head_norm_and_rope(self, q, k, ctx, offset, seq_axis: int):
+        """Per-head qk-norm, then RoPE from ``offset``, on head-split q, k
+        whose sequence is axis ``seq_axis``: both are elementwise per head,
+        so ``(B, H, T, D)`` and ``(B, T, H, D)`` serve alike."""
+        if self.qk_norm and self.qk_norm_scope == "head":
+            q = self._head_rmsnorm(q, self._p(ctx, "q_norm.weight"))
+            k = self._head_rmsnorm(k, self._p(ctx, "k_norm.weight"))
+        if self.rope_theta is not None:
+            q, k = attn_ops.apply_rope(
+                q, k, self.rope_theta, offset, scaling=self.rope_scaling,
+                rotary_dim=self._rotary_dim(q.shape[-1]), seq_axis=seq_axis)
+        return q, k
+
+    def _apply_in_model_layout(self, qkv, ctx, head_dim: int):
+        """No cache, no sequence parallelism, shapes the ``btd`` flash entry
+        takes: q, k, v never leave ``(B, T, ·)``.  With nothing between the
+        projection and the kernels (no qk-norm, no RoPE) they read the fused
+        array in place; the norms and the rotation are elementwise per head
+        and run on ``(B, T, H, D)`` views, no transpose."""
+        B, T, _ = qkv.shape
+        heads, kv_heads = self.num_heads, self.num_kv_heads
+        q_dim, kv_dim = heads * head_dim, kv_heads * head_dim
+        arrays = (qkv,)
+        if self.qk_norm or self.rope_theta is not None:
+            q, k = self._flat_norm(qkv[..., :q_dim],
+                                   qkv[..., q_dim:q_dim + kv_dim], ctx)
+            q, k = self._head_norm_and_rope(
+                q.reshape(B, T, heads, head_dim),
+                k.reshape(B, T, kv_heads, head_dim), ctx, ctx.offset(),
+                seq_axis=1)
+            arrays = (q.reshape(B, T, q_dim), k.reshape(B, T, kv_dim),
+                      qkv[..., q_dim + kv_dim:])
+        dropout_rate = self.dropout if ctx.training else 0.0
+        return attn_ops.causal_attention_btd(
+            *arrays, heads=heads, kv_heads=kv_heads,
+            dropout_rate=dropout_rate,
+            dropout_rng=ctx.next_rng() if dropout_rate > 0.0 else None,
+            platform=ctx.platform, window=self.sliding_window,
+            alibi=attn_ops.alibi_slopes(heads) if self.alibi else None,
+            scale=self.attn_scale)
+
     def apply(self, qkv, ctx):
         B, T, total_dim = qkv.shape
         head_dim = total_dim // (self.num_heads + 2 * self.num_kv_heads)
         q_dim = self.num_heads * head_dim
         kv_dim = self.num_kv_heads * head_dim
 
-        q_flat = qkv[..., :q_dim]
-        k_flat = qkv[..., q_dim:q_dim + kv_dim]
-        if self.qk_norm and self.qk_norm_scope == "flat":
-            # OLMo-2: normalize the whole projection BEFORE the head split.
-            q_flat = self._head_rmsnorm(q_flat, self._p(ctx, "q_norm.weight"))
-            k_flat = self._head_rmsnorm(k_flat, self._p(ctx, "k_norm.weight"))
+        if (ctx.kv is None and ctx.sp_manual_axis is None
+                and ctx.sp_mesh is None
+                and attn_ops.stays_in_model_layout(
+                    qkv, T, head_dim, self.num_heads, self.num_kv_heads,
+                    ctx.platform, self.logit_softcap)):
+            return self._apply_in_model_layout(qkv, ctx, head_dim)
+
+        q_flat, k_flat = self._flat_norm(qkv[..., :q_dim],
+                                         qkv[..., q_dim:q_dim + kv_dim], ctx)
         q = q_flat.reshape(B, T, self.num_heads, head_dim)
         k = k_flat.reshape(B, T, self.num_kv_heads, head_dim)
         v = qkv[..., q_dim + kv_dim:].reshape(B, T, self.num_kv_heads, head_dim)
         # to (B, H, T, D)
         q, k, v = (t.transpose(0, 2, 1, 3) for t in (q, k, v))
 
-        if self.qk_norm and self.qk_norm_scope == "head":
-            q = self._head_rmsnorm(q, self._p(ctx, "q_norm.weight"))
-            k = self._head_rmsnorm(k, self._p(ctx, "k_norm.weight"))
-
         offset = ctx.offset()
-        if self.rope_theta is not None:
-            if ctx.sp_manual_axis is not None:
-                # Manual sequence sharding (GPipe×Ulysses): this shard
-                # holds rows r·T_local..(r+1)·T_local-1 of the global
-                # sequence — rotate with GLOBAL positions, not 0..T_local.
-                offset = offset + jax.lax.axis_index(ctx.sp_manual_axis) * T
-            rotary_dim = None
-            if self.rope_dim is not None:
-                rotary_dim = None if self.rope_dim >= head_dim \
-                    else self.rope_dim
-            elif self.rope_pct is not None and self.rope_pct < 1.0:
-                rotary_dim = int(head_dim * self.rope_pct) // 2 * 2
-            q, k = attn_ops.apply_rope(q, k, self.rope_theta, offset,
-                                       scaling=self.rope_scaling,
-                                       rotary_dim=rotary_dim)
+        if self.rope_theta is not None and ctx.sp_manual_axis is not None:
+            # Manual sequence sharding (GPipe×Ulysses): this shard
+            # holds rows r·T_local..(r+1)·T_local-1 of the global
+            # sequence — rotate with GLOBAL positions, not 0..T_local.
+            offset = offset + jax.lax.axis_index(ctx.sp_manual_axis) * T
+        q, k = self._head_norm_and_rope(q, k, ctx, offset, seq_axis=2)
 
         dropout_rate = self.dropout if ctx.training else 0.0
         dropout_rng = ctx.next_rng() if (dropout_rate > 0.0 and ctx.training) else None

@@ -34,6 +34,43 @@ The backward recomputes probabilities from the forward's saved logsumexp:
 
 GQA: per-query-head dK/dV, summed over the group outside the kernels.
 
+**Two layouts, one set of kernels** (``FlashPlan.layout``).  The tile maths
+above never looks at how a head is addressed; an indexer chosen by the
+entry does that (:class:`_HeadMajor`, :class:`_LaneBlocks`: the same
+methods, no flag).  One thing of the layout reaches the kernel bodies: the
+split backward orients its tiles by the form the layout stores logsumexp
+and δ in (``stat_rows``) — ``bhtd`` keeps the columns it had, so that its
+programs are the ones they were; moving it to rows, as the one-pass
+backward reads them in both layouts, would leave one orientation
+(ROADMAP.md S2).
+
+- ``bhtd`` — :func:`flash_attention`: q ``(B, Hq, T, D)``, k/v ``(B, Hkv, S,
+  D)``; a head is a block index.  What sequence parallelism and callers with
+  head-major arrays use.
+- ``btd`` — :func:`flash_attention_btd`: the model's own layout.  q, k, v are
+  lane ranges of ``(B, T, ·)`` arrays — the *same* fused ``(B, T, (Hq +
+  2·Hkv)·D)`` projection for all three when nothing sits between the
+  projection and the kernel (``fused_qkv``), separate ``(B, T, H·D)`` arrays
+  otherwise — and ``o`` is ``(B, T, Hq·D)``: no head transposes around the
+  kernels and no ``(…, 64)`` minor dim padded to 128 lanes in HBM.  Blocks
+  are ``(1, rows, max(D, 128))``: at D = 64 a block holds two heads
+  (``heads_per_block``), told apart by zeroing the other head's lanes in one
+  matmul operand (a contraction of 64 or an output of 64 lanes already costs
+  a full MXU pass, so the passes are the same count) and merging results
+  with a lane select on ``(rows, 128)`` tiles.  Logsumexp and δ are
+  lane-dense ``(B, Hq/heads_per_block, heads_per_block, T)`` rows.  Takes:
+  D in {64, 128, 256}; ``heads_per_block`` dividing Hq and Hkv; at D = 64
+  Hq = Hkv (a block's two query heads need their two K/V heads at the same
+  lanes); any GQA group at D ≥ 128.  :func:`btd_refusal` says why not.
+
+The Pallas calls are named ``penroz_flash_fwd``, ``penroz_flash_bwd`` (one
+pass) and ``penroz_flash_bwd_dq`` / ``penroz_flash_bwd_dkv`` (the split) in
+both layouts; ``btd`` adds ``penroz_flash_bwd_delta``, which sums δ = Σ dO·O
+over each head's lanes (``bhtd`` leaves that to an XLA reduction).  The
+benchmark finds the kernels in a device trace by these names
+(``benchmark/metrics/penroz_flash_roofline.py``): a rename blinds it, and
+``tests/test_tpu_compile.py`` fails first.
+
 Dropout runs *inside* the kernels via a counter-based position hash
 (lowbias32-style mixer over (q_pos, k_pos, seed)), so the keep-mask needs no
 HBM storage, is identical across the forward and the backward kernels by
@@ -242,14 +279,21 @@ class FlashPlan:
     q_rows: int             # query rows of one forward grid step
     heads_per_step: int     # resident forward and fused backward
     fused_bwd: bool
+    layout: str = "bhtd"    # "bhtd" | "btd" (module docstring)
+    heads_per_block: int = 1    # btd: heads a lane block holds
+    fused_qkv: bool = False     # btd: q, k, v are ranges of one array
 
     def describe(self) -> str:
-        return (f"bq={self.block_q} bk={self.block_k} "
+        text = (f"bq={self.block_q} bk={self.block_k} "
                 f"bwd_bq={self.bwd_block_q} bwd_bk={self.bwd_block_k} "
                 f"{'resident' if self.resident else 'chunked'} "
                 f"q_rows={self.q_rows} "
                 f"{'fused_bwd' if self.fused_bwd else 'split_bwd'} "
-                f"heads_per_step={self.heads_per_step}")
+                f"heads_per_step={self.heads_per_step} "
+                f"layout={self.layout}")
+        if self.layout == "btd":
+            text += f" heads_per_block={self.heads_per_block}"
+        return text + (" fused_qkv" if self.fused_qkv else "")
 
 
 def _padded(rows: int, D: int, itemsize: int) -> int:
@@ -275,6 +319,12 @@ def _bwd_fused_bytes(T, S, D, itemsize, heads, kv_heads, bq, bk):
     return 2 * blocks + scratch + 6 * bq * bk * 4
 
 
+def _heads_per_block(D: int) -> int:
+    """Heads of size ``D`` a ``btd`` lane block — ``max(D, 128)`` lanes —
+    holds."""
+    return max(_LANES // D, 1)
+
+
 def _kv_heads_per_step(heads_per_step: int, group: int) -> int:
     return max(heads_per_step // group, 1)
 
@@ -282,11 +332,18 @@ def _kv_heads_per_step(heads_per_step: int, group: int) -> int:
 def plan_flash(T: int, S: int, D: int, itemsize: int, causal: bool = True,
                window=None, *, heads: int = 1, group: int = 1,
                block_q: int | None = None, block_k: int | None = None,
-               vmem_budget: int = VMEM_BUDGET) -> FlashPlan:
+               vmem_budget: int = VMEM_BUDGET, layout: str = "bhtd",
+               fused_qkv: bool = False) -> FlashPlan:
     """The plan for ``(T, S, D, itemsize, causal, window)`` under
     ``vmem_budget`` bytes; ``heads`` query heads in groups of ``group`` per
     K/V head bound the heads a grid step may own.  ``block_q``/``block_k``
-    override the tile sizes of both directions (tests)."""
+    override the tile sizes of both directions (tests).  ``layout="btd"``:
+    a step owns one lane block — ``max(D, 128)`` lanes, ``heads_per_block``
+    heads — whose VMEM is that of one head as wide as the block."""
+    heads_per_block = 1
+    if layout == "btd":
+        heads_per_block = _heads_per_block(D)
+        D, heads, group = D * heads_per_block, 1, 1
     fq, fk = (block_q or _FWD_TILE[0]), (block_k or _FWD_TILE[1])
     gq, gk = (block_q or _BWD_TILE[0]), (block_k or _BWD_TILE[1])
     fq, gq = _largest_dividing_block(T, fq), _largest_dividing_block(T, gq)
@@ -334,8 +391,10 @@ def plan_flash(T: int, S: int, D: int, itemsize: int, causal: bool = True,
                 break
             hps = cand
     return FlashPlan(block_q=fq, block_k=fk, bwd_block_q=gq, bwd_block_k=gk,
-                     resident=resident, q_rows=q_rows, heads_per_step=hps,
-                     fused_bwd=fused_bwd)
+                     resident=resident, q_rows=q_rows,
+                     heads_per_step=hps * heads_per_block,
+                     fused_bwd=fused_bwd, layout=layout,
+                     heads_per_block=heads_per_block, fused_qkv=fused_qkv)
 
 
 @functools.lru_cache(maxsize=None)
@@ -352,6 +411,324 @@ def _record_plan(T, S, D, plan: FlashPlan) -> None:
     _log_plan(T, S, D, plan)
     with tracing.span("penroz/flash_plan", T=T, S=S, D=D, **dataclasses.asdict(plan)):
         pass
+
+
+# ---------------------------------------------------------------------------
+# the indexer: how a kernel finds a head in its blocks
+# ---------------------------------------------------------------------------
+
+
+@dataclasses.dataclass(frozen=True)
+class _HeadMajor:
+    """The ``bhtd`` indexer.  An indexer says where head ``hh`` of a grid
+    step lives in the step's blocks, which BlockSpecs bring those blocks in
+    (``at(*grid ids) -> (batch, head block, row block)``), what shapes the
+    results have and what XLA does around the kernels; :class:`_LaneBlocks`
+    has the same methods (module docstring, "Two layouts").
+
+    Blocks are ``(1, heads, rows, D)``; a head is an index and its K/V head
+    ``hh // group``.  Logsumexp and δ are ``(B, H, T, 1)`` columns (Mosaic
+    requires the last two block dims be (8, 128)-divisible or equal to the
+    array's): the one-pass backward takes them reshaped to rows, the split
+    backward as they are (``stat_rows``)."""
+    D: int
+    heads: int
+    kv_heads: int
+
+    layout = "bhtd"
+    hpb = 1
+    stat_rows = False       # split backward: (block_q, block_k) tiles
+
+    @property
+    def group(self) -> int:
+        return self.heads // self.kv_heads
+
+    @property
+    def width(self) -> int:
+        return self.D
+
+    # -- inside a kernel ----------------------------------------------------
+
+    def rows(self, ref) -> int:
+        return ref.shape[2]
+
+    def get(self, ref, hh, rows):
+        """Rows of a query-side block (q, dO) that hold head ``hh``."""
+        return ref[0, hh, rows, :]
+
+    def kv_head(self, hh):
+        """Index of head ``hh``'s K/V head in the step's K/V blocks."""
+        return hh // self.group
+
+    def kv(self, ref, hkv, rows):
+        return ref[0, hkv, rows, :]
+
+    def own(self, x, hh):
+        """``x`` as a matmul operand that contributes head ``hh`` alone."""
+        return x
+
+    def scaled_q(self, ref, hh, rows, sm_scale: float):
+        """Head ``hh``'s queries times the softmax scale."""
+        return _scaled(self.get(ref, hh, rows), sm_scale)
+
+    def put(self, ref, hh, rows, value):
+        ref[0, hh, rows, :] = value
+
+    def lse_form(self, tile):
+        """The forward's lane-replicated ``(rows, 128)`` logsumexp as it is
+        stored: a ``(rows, 1)`` column."""
+        return tile[:, :1]
+
+    def put_lse(self, ref, hh, rows, lse):
+        ref[0, hh, rows, :] = lse
+
+    def row(self, ref, hh, rows):
+        """``(1, rows)`` of a logsumexp / δ block stored as rows."""
+        return ref[0, hh, :, rows]
+
+    def stat(self, ref, hh):
+        """A split-backward step's logsumexp / δ: a ``(block_q, 1)``
+        column."""
+        return ref[0, hh]
+
+    # -- BlockSpecs ---------------------------------------------------------
+
+    def spec(self, heads, rows, at, operand=None):
+        """A query-side operand's block (``operand``: see
+        :meth:`_LaneBlocks.spec`)."""
+        return pl.BlockSpec((1, heads, rows, self.D),
+                            lambda *g: (*at(*g), 0))
+
+    def kv_spec(self, heads, rows, at, operand):
+        """K or V: the K/V heads of the step's query heads."""
+        kvh = _kv_heads_per_step(heads, self.group)
+
+        def index(*g):
+            b, h, r = at(*g)
+            return b, h * heads // (self.group * kvh), r, 0
+
+        return pl.BlockSpec((1, kvh, rows, self.D), index)
+
+    def stat_spec(self, heads, rows, at, as_rows: bool):
+        if as_rows:
+            return pl.BlockSpec((1, heads, 1, rows),
+                                lambda *g: (*at(*g)[:2], 0, at(*g)[2]))
+        return pl.BlockSpec((1, heads, rows, 1), lambda *g: (*at(*g), 0))
+
+    # -- shapes, and what XLA does around the kernels -----------------------
+
+    def dims(self, q, k):
+        """``(B, T, S)`` of the operands."""
+        return q.shape[0], q.shape[2], k.shape[2]
+
+    def like_q(self, B, rows):
+        """Shape of an array with ``rows`` positions of every query head."""
+        return B, self.heads, rows, self.D
+
+    def lse_shape(self, B, T):
+        return B, self.heads, T, 1
+
+    def as_rows(self, stat):
+        """A logsumexp / δ array as the one-pass backward's transposed tiles
+        want it: lane-dense ``(1, T)`` rows."""
+        B, H, T, _ = stat.shape
+        return stat.reshape(B, H, 1, T)
+
+    def delta(self, out, g, block_q: int, interpret: bool):
+        """δ_i = Σ_d dO_id · O_id — the softmax-backward row term; O(B·H·T·D),
+        outside the backward kernels, shaped like the forward's logsumexp.
+        Here an XLA reduction over the minor dim."""
+        return jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
+                       axis=-1, keepdims=True)
+
+    def sum_groups(self, x):
+        """Per-query-head dK or dV summed over each K/V head's group."""
+        B, _, S, D = x.shape
+        return x.reshape(B, self.kv_heads, self.group, S, D).sum(axis=2)
+
+
+@dataclasses.dataclass(frozen=True)
+class _LaneBlocks:
+    """The ``btd`` indexer (methods as :class:`_HeadMajor`).  Blocks are
+    ``(1, rows, width)`` with ``width = max(D, 128)`` lanes holding ``hpb``
+    heads; a head is a lane range, its K/V head the same range of the K/V
+    block the index map chose.  ``fused``: q, k and v are lane ranges of one
+    array.  Logsumexp and δ are lane-dense ``(B, heads / hpb, hpb, T)``
+    rows, so every backward tile is transposed."""
+    D: int
+    heads: int
+    kv_heads: int
+    fused: bool
+
+    layout = "btd"
+    stat_rows = True        # split backward: (block_k, block_q) tiles
+
+    @property
+    def group(self) -> int:
+        return self.heads // self.kv_heads
+
+    @property
+    def hpb(self) -> int:
+        return _heads_per_block(self.D)
+
+    @property
+    def width(self) -> int:
+        return self.D * self.hpb
+
+    @property
+    def offsets(self) -> tuple:
+        """The first lane block of q, k and v in their arrays."""
+        if not self.fused:
+            return 0, 0, 0
+        return (0, self.heads // self.hpb,
+                (self.heads + self.kv_heads) // self.hpb)
+
+    # -- inside a kernel ----------------------------------------------------
+
+    def rows(self, ref) -> int:
+        return ref.shape[1]
+
+    def get(self, ref, hh, rows):
+        return ref[0, rows, :]
+
+    def kv_head(self, hh):
+        return hh
+
+    def kv(self, ref, hkv, rows):
+        return ref[0, rows, :]
+
+    def _own(self, shape, hh):
+        """Lanes of a ``(rows, width)`` tile that are head ``hh``'s."""
+        lane = jax.lax.broadcasted_iota(jnp.int32, shape, len(shape) - 1)
+        return (lane >= hh * self.D) & (lane < (hh + 1) * self.D)
+
+    def own(self, x, hh):
+        """``x`` with the lanes of the block's other heads zeroed: a
+        contraction over the block's lanes then sums head ``hh`` alone."""
+        if self.hpb == 1:
+            return x
+        return jnp.where(self._own(x.shape, hh), x, jnp.zeros_like(x))
+
+    def scaled_q(self, ref, hh, rows, sm_scale: float):
+        """:meth:`own` folded into the multiply by the softmax scale."""
+        x = self.get(ref, hh, rows)
+        if self.hpb == 1:
+            return _scaled(x, sm_scale)
+        scale = jnp.where(self._own((1, x.shape[-1]), hh), sm_scale, 0.0)
+        return (x.astype(jnp.float32) * scale).astype(x.dtype)
+
+    def put(self, ref, hh, rows, value):
+        """Write head ``hh``'s ``(rows, width)`` result; the lanes of the
+        block's other heads (theirs, or not yet written) stay as they are."""
+        if self.hpb == 1:
+            ref[0, rows, :] = value
+        else:
+            ref[0, rows, :] = jnp.where(self._own(value.shape, hh), value,
+                                        ref[0, rows, :])
+
+    def lse_form(self, tile):
+        """… stored as a lane-dense ``(1, rows)`` row."""
+        return tile.T[:1, :]
+
+    def put_lse(self, ref, hh, rows, lse):
+        ref[0, 0, pl.ds(hh, 1), rows] = lse
+
+    def row(self, ref, hh, rows):
+        return ref[0, 0, pl.ds(hh, 1), rows]
+
+    def stat(self, ref, hh):
+        """… a ``(1, block_q)`` row."""
+        return ref[0, 0, pl.ds(hh, 1), :]
+
+    # -- BlockSpecs ---------------------------------------------------------
+
+    def _lane_block(self, rows, at, first, of_step):
+        def index(*g):
+            b, h, r = at(*g)
+            return b, r, of_step(h) + first
+
+        return pl.BlockSpec((1, rows, self.width), index)
+
+    def spec(self, heads, rows, at, operand=None):
+        """``operand`` 0 for q, whose lane range of a fused array starts at
+        ``offsets[0]``; None for arrays of their own (o, dO, dq and the
+        per-query-head dk, dv)."""
+        first = 0 if operand is None else self.offsets[operand]
+        return self._lane_block(rows, at, first, lambda h: h)
+
+    def kv_spec(self, heads, rows, at, operand):
+        """K (``operand`` 1) or V (2)."""
+        return self._lane_block(
+            rows, at, self.offsets[operand],
+            lambda h: h * self.hpb // self.group // self.hpb)
+
+    def stat_spec(self, heads, rows, at, as_rows: bool):
+        return pl.BlockSpec((1, 1, self.hpb, rows),
+                            lambda *g: (*at(*g)[:2], 0, at(*g)[2]))
+
+    # -- shapes, and what XLA does around the kernels -----------------------
+
+    def dims(self, q, k):
+        return q.shape[0], q.shape[1], k.shape[1]
+
+    def like_q(self, B, rows):
+        return B, rows, self.heads * self.D
+
+    def lse_shape(self, B, T):
+        return B, self.heads // self.hpb, self.hpb, T
+
+    def as_rows(self, stat):
+        return stat
+
+    def delta(self, out, g, block_q: int, interpret: bool):
+        """… a kernel of its own, bandwidth-bound: XLA relays the whole f32
+        product out to reduce over a 64-lane share of the minor dim, and
+        inside the backward kernels the product is VPU work they have no
+        room for (CHANGES.md PR 32)."""
+        B, T, _ = out.shape
+        rows = _largest_dividing_block(T, 2048)     # of block_q-row tiles
+        at = lambda b, h, i: (b, h, i)
+        block = self.spec(self.hpb, rows, at)
+        return pl.pallas_call(
+            functools.partial(_delta_kernel, ix=self, block_q=block_q),
+            grid=(B, self.heads // self.hpb, T // rows),
+            in_specs=[block, block],
+            out_specs=self.stat_spec(self.hpb, rows, at, as_rows=True),
+            out_shape=jax.ShapeDtypeStruct(self.lse_shape(B, T),
+                                           jnp.float32),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "parallel")),
+            cost_estimate=pl.CostEstimate(
+                flops=2 * out.size, transcendentals=0,
+                bytes_accessed=2 * out.size * out.dtype.itemsize),
+            interpret=interpret,
+            name="penroz_flash_bwd_delta",
+        )(out, g)
+
+    def sum_groups(self, x):
+        """… as adds of lane ranges, one fusion: a reshape that splits the
+        lanes into ``(group, D)`` relays the whole array out first (0.29 of
+        a 3.8 ms layer at 32 on 8 heads, T = 2048; PERF.md §6, PR 32)."""
+        D, group = self.D, self.group
+        head = lambda h: x[..., h * D:(h + 1) * D].astype(jnp.float32)
+        return jnp.concatenate(
+            [sum(head(kv * group + i) for i in range(group))
+             for kv in range(self.kv_heads)], axis=-1).astype(x.dtype)
+
+
+def _head_index(hs, heads_per_step: int, hh):
+    """Head ``hh`` of grid step ``hs`` among all query heads."""
+    return hs if heads_per_step == 1 else hs * heads_per_step + hh
+
+
+def _per_head(heads: int, shape: tuple) -> tuple:
+    """Shape of a chunked kernel's scratch that every head of the step's
+    block needs its own of, across grid steps."""
+    return shape if heads == 1 else (heads, *shape)
+
+
+def _at(ref, hh, heads: int):
+    return ref if heads == 1 else ref.at[hh]
 
 
 # ---------------------------------------------------------------------------
@@ -416,7 +793,7 @@ def _fwd_tile(q, k, v, m_scr, l_scr, acc_scr, q0, k0, slope, seed, *,
         # after softmax normalization); only the V-contraction drops.
         keep = _keep_mask(*pos, seed, dropout_rate)
         p = jnp.where(keep, p, 0.0) * (1.0 / (1.0 - dropout_rate))
-    acc_scr[...] = (acc_scr[...] * _lanes(alpha, acc_scr.shape[1])
+    acc_scr[...] = (acc_scr[...] * _lanes(alpha, acc_scr.shape[-1])
                     + _dot(p.astype(v.dtype), v, (1, 0)))
 
 
@@ -435,11 +812,13 @@ def _fwd_init(m_scr, l_scr, acc_scr):
     acc_scr[...] = jnp.zeros_like(acc_scr)
 
 
-def _fwd_result(m_scr, l_scr, acc_scr, dtype):
+def _fwd_result(m_scr, l_scr, acc_scr, dtype, ix):
+    """``(out, logsumexp)`` of the walked tiles, the second in the form the
+    layout stores it."""
     l = l_scr[...]
     l_safe = jnp.where(l == 0.0, 1.0, l)
-    return ((acc_scr[...] / _lanes(l_safe, acc_scr.shape[1])).astype(dtype),
-            (m_scr[...] + jnp.log(l_safe))[:, :1])
+    return ((acc_scr[...] / _lanes(l_safe, acc_scr.shape[-1])).astype(dtype),
+            ix.lse_form(m_scr[...] + jnp.log(l_safe)))
 
 
 def _loop(lo, hi, body):
@@ -483,39 +862,41 @@ def _tile(i, block: int):
     return pl.ds(pl.multiple_of(i * block, block), block)
 
 
+_ALL = slice(None)
+
+
 def _fwd_resident_kernel(seed_ref, alibi_ref, q_ref, k_ref, v_ref, o_ref,
-                         lse_ref, m_scr, l_scr, acc_scr, *, causal: bool,
-                         sm_scale: float, block_q: int, block_k: int,
-                         num_k: int, num_heads: int, heads_per_step: int,
-                         group: int, dropout_rate: float, window,
-                         use_alibi: bool):
+                         lse_ref, m_scr, l_scr, acc_scr, *, ix,
+                         causal: bool, sm_scale: float, block_q: int,
+                         block_k: int, num_k: int, heads_per_step: int,
+                         dropout_rate: float, window, use_alibi: bool):
     b, hs, qr = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-    tiles_per_step = q_ref.shape[2] // block_q
+    tiles_per_step = ix.rows(q_ref) // block_q
 
     def head(hh):
         h = hs * heads_per_step + hh
-        hkv = hh // group
-        slope, seed = _head_operands(seed_ref, alibi_ref, b, h, num_heads,
+        hkv = ix.kv_head(hh)
+        slope, seed = _head_operands(seed_ref, alibi_ref, b, h, ix.heads,
                                      use_alibi, dropout_rate)
 
         def query_tile(qt):
             qi = qr * tiles_per_step + qt
             rows = _tile(qt, block_q)
-            q = _scaled(q_ref[0, hh, rows, :], sm_scale)
+            q = ix.scaled_q(q_ref, hh, rows, sm_scale)
             _fwd_init(m_scr, l_scr, acc_scr)
 
             def key_tile(kj, masked):
                 cols = _tile(kj, block_k)
-                _fwd_tile(q, k_ref[0, hkv, cols, :], v_ref[0, hkv, cols, :],
+                _fwd_tile(q, ix.kv(k_ref, hkv, cols), ix.kv(v_ref, hkv, cols),
                           m_scr, l_scr, acc_scr, qi * block_q, kj * block_k,
                           slope, seed, masked=masked, window=window,
                           dropout_rate=dropout_rate)
 
             _walk(key_tile_ranges(qi, block_q, block_k, num_k, causal,
                                   window), key_tile)
-            out, lse = _fwd_result(m_scr, l_scr, acc_scr, o_ref.dtype)
-            o_ref[0, hh, rows, :] = out
-            lse_ref[0, hh, rows, :] = lse
+            out, lse = _fwd_result(m_scr, l_scr, acc_scr, o_ref.dtype, ix)
+            ix.put(o_ref, hh, rows, out)
+            ix.put_lse(lse_ref, hh, rows, lse)
 
         _loop(0, tiles_per_step, query_tile)
 
@@ -524,33 +905,52 @@ def _fwd_resident_kernel(seed_ref, alibi_ref, q_ref, k_ref, v_ref, o_ref,
 
 def _fwd_chunked_kernel(seed_ref, alibi_ref, q_ref, k_ref, v_ref, o_ref,
                         lse_ref, m_scr, l_scr, acc_scr, qs_scr, *,
-                        causal: bool, sm_scale: float, block_q: int,
-                        block_k: int, num_k: int, num_heads: int,
-                        dropout_rate: float, window, use_alibi: bool):
-    b, h, qi, kj = (pl.program_id(0), pl.program_id(1), pl.program_id(2),
-                    pl.program_id(3))
+                        ix, causal: bool, sm_scale: float,
+                        block_q: int, block_k: int, num_k: int,
+                        heads_per_step: int, dropout_rate: float, window,
+                        use_alibi: bool):
+    b, hs, qi, kj = (pl.program_id(0), pl.program_id(1), pl.program_id(2),
+                     pl.program_id(3))
+    own = lambda ref, hh: _at(ref, hh, heads_per_step)
+
+    def heads(body):
+        _loop(0, heads_per_step, body)
 
     @pl.when(kj == 0)
     def _init():
         _fwd_init(m_scr, l_scr, acc_scr)
-        qs_scr[...] = _scaled(q_ref[0, 0], sm_scale)
 
+        def scale(hh):
+            own(qs_scr, hh)[...] = ix.scaled_q(q_ref, hh, _ALL, sm_scale)
+
+        heads(scale)
 
     def step(masked):
-        _fwd_tile(qs_scr[...], k_ref[0, 0], v_ref[0, 0], m_scr, l_scr,
-                  acc_scr, qi * block_q, kj * block_k,
-                  *_head_operands(seed_ref, alibi_ref, b, h, num_heads,
-                                  use_alibi, dropout_rate),
-                  masked=masked, window=window, dropout_rate=dropout_rate)
+        def head(hh):
+            hkv = ix.kv_head(hh)
+            _fwd_tile(own(qs_scr, hh)[...], ix.kv(k_ref, hkv, _ALL),
+                      ix.kv(v_ref, hkv, _ALL), own(m_scr, hh), own(l_scr, hh),
+                      own(acc_scr, hh), qi * block_q, kj * block_k,
+                      *_head_operands(seed_ref, alibi_ref, b,
+                                      _head_index(hs, heads_per_step, hh),
+                                      ix.heads, use_alibi, dropout_rate),
+                      masked=masked, window=window,
+                      dropout_rate=dropout_rate)
+
+        heads(head)
 
     _when_live(key_tile_ranges(qi, block_q, block_k, num_k, causal, window),
                kj, step, causal)
 
     @pl.when(kj == num_k - 1)
     def _finish():
-        out, lse = _fwd_result(m_scr, l_scr, acc_scr, o_ref.dtype)
-        o_ref[0, 0] = out
-        lse_ref[0, 0] = lse
+        def head(hh):
+            out, lse = _fwd_result(own(m_scr, hh), own(l_scr, hh),
+                                   own(acc_scr, hh), o_ref.dtype, ix)
+            ix.put(o_ref, hh, _ALL, out)
+            ix.put_lse(lse_ref, hh, _ALL, lse)
+
+        heads(head)
 
 
 def _smem_operands(seed, alibi):
@@ -575,79 +975,79 @@ def _clamped(ranges_fn, *args):
     return clamp
 
 
+def _bhtd(q, k) -> _HeadMajor:
+    return _HeadMajor(D=q.shape[-1], heads=q.shape[1], kv_heads=k.shape[1])
+
+
 def _flash_forward(q, k, v, causal: bool = True,
                    block_q: int | None = None, block_k: int | None = None,
                    dropout_rate: float = 0.0, seed=None,
                    interpret: bool = False, return_lse: bool = False,
                    window=None, alibi=None, scale=None,
-                   plan: FlashPlan | None = None):
-    B, Hq, T, D = q.shape
-    Hkv, S = k.shape[1], k.shape[2]
-    group = Hq // Hkv
+                   plan: FlashPlan | None = None, ix=None):
+    """The forward call.  ``ix`` None: q ``(B, Hq, T, D)``, k/v ``(B, Hkv,
+    S, D)``; a ``btd`` indexer: ``(B, T, ·)`` arrays (module docstring)."""
+    ix = ix or _bhtd(q, k)
+    B, T, S = ix.dims(q, k)
+    D, Hq = ix.D, ix.heads
     if plan is None:
         plan = plan_flash(T, S, D, q.dtype.itemsize, causal, window,
-                          heads=Hq, group=group, block_q=block_q,
-                          block_k=block_k)
+                          heads=Hq, group=ix.group, block_q=block_q,
+                          block_k=block_k,
+                          layout=ix.layout)
     block_q, block_k = plan.block_q, plan.block_k
     sm_scale = float(scale) if scale is not None else 1.0 / (D ** 0.5)
     num_k = S // block_k
     seed, alibi_arr = _smem_operands(seed, alibi)
-    common = dict(causal=causal, sm_scale=sm_scale, block_q=block_q,
-                  block_k=block_k, num_k=num_k, num_heads=Hq,
+    hps = plan.heads_per_step if plan.resident else ix.hpb
+    common = dict(ix=ix, causal=causal, sm_scale=sm_scale, block_q=block_q,
+                  block_k=block_k, num_k=num_k, heads_per_step=hps,
                   dropout_rate=dropout_rate, window=window,
                   use_alibi=alibi is not None)
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
-    stats = [pltpu.VMEM((block_q, _LANES), jnp.float32),
-             pltpu.VMEM((block_q, _LANES), jnp.float32),
-             pltpu.VMEM((block_q, D), jnp.float32)]
+    stats = [(block_q, _LANES), (block_q, _LANES), (block_q, ix.width)]
     if plan.resident:
-        hps = plan.heads_per_step
-        kvh = _kv_heads_per_step(hps, group)
-        kernel = functools.partial(_fwd_resident_kernel, heads_per_step=hps,
-                                   group=group, **common)
+        kernel = functools.partial(_fwd_resident_kernel, **common)
         grid = (B, Hq // hps, T // plan.q_rows)
-        q_spec = pl.BlockSpec((1, hps, plan.q_rows, D),
-                              lambda b, h, i: (b, h, i, 0))
-        kv_spec = pl.BlockSpec((1, kvh, S, D),
-                               lambda b, h, i: (b, h * hps // (group * kvh),
-                                                0, 0))
-        lse_spec = pl.BlockSpec((1, hps, plan.q_rows, 1),
-                                lambda b, h, i: (b, h, i, 0))
-        scratch = stats
+        at = lambda b, h, i: (b, h, i)
+        kv_at = lambda b, h, i: (b, h, 0)
+        q_rows, kv_rows = plan.q_rows, S
+        scratch = [pltpu.VMEM(shape, jnp.float32) for shape in stats]
         semantics = ("parallel", "parallel", "parallel")
     else:
         kernel = functools.partial(_fwd_chunked_kernel, **common)
-        grid = (B, Hq, T // block_q, num_k)
+        grid = (B, Hq // hps, T // block_q, num_k)
         clamp = _clamped(key_tile_ranges, block_q, block_k, num_k, causal,
                          window)
-        q_spec = pl.BlockSpec((1, 1, block_q, D),
-                              lambda b, h, i, j: (b, h, i, 0))
-        kv_spec = pl.BlockSpec((1, 1, block_k, D),
-                               lambda b, h, i, j: (b, h // group,
-                                                   clamp(i, j), 0))
-        lse_spec = pl.BlockSpec((1, 1, block_q, 1),
-                                lambda b, h, i, j: (b, h, i, 0))
-        scratch = stats + [pltpu.VMEM((block_q, D), q.dtype)]
+        at = lambda b, h, i, j: (b, h, i)
+        kv_at = lambda b, h, i, j: (b, h, clamp(i, j))
+        q_rows, kv_rows = block_q, block_k
+        scratch = ([pltpu.VMEM(_per_head(hps, shape), jnp.float32)
+                    for shape in stats]
+                   + [pltpu.VMEM(_per_head(hps, (block_q, ix.width)),
+                                 q.dtype)])
         semantics = ("parallel", "parallel", "parallel", "arbitrary")
+    q_spec = ix.spec(hps, q_rows, at, 0)
     out, lse = pl.pallas_call(
         kernel,
         grid=grid,
-        in_specs=[smem, smem, q_spec, kv_spec, kv_spec],
-        out_specs=[q_spec, lse_spec],
+        in_specs=[smem, smem, q_spec, ix.kv_spec(hps, kv_rows, kv_at, 1),
+                  ix.kv_spec(hps, kv_rows, kv_at, 2)],
+        out_specs=[ix.spec(hps, q_rows, at),
+                   ix.stat_spec(hps, q_rows, at, as_rows=False)],
         out_shape=[
-            jax.ShapeDtypeStruct(q.shape, q.dtype),
-            # (…, 1) trailing lane: Mosaic requires the last two block dims
-            # be (8, 128)-divisible or equal to the array dims.
-            jax.ShapeDtypeStruct((B, Hq, T, 1), jnp.float32),
+            jax.ShapeDtypeStruct(ix.like_q(B, T), q.dtype),
+            jax.ShapeDtypeStruct(ix.lse_shape(B, T), jnp.float32),
         ],
         scratch_shapes=scratch,
         compiler_params=pltpu.CompilerParams(dimension_semantics=semantics),
         cost_estimate=pl.CostEstimate(
             flops=int(4 * B * Hq * T * S * D * _live_share(causal)),
-            bytes_accessed=int((q.size + k.size + v.size + q.size)
-                               * q.dtype.itemsize),
+            bytes_accessed=int((2 * B * Hq * T + 2 * B * ix.kv_heads * S)
+                               * D * q.dtype.itemsize),
             transcendentals=int(B * Hq * T * S * _live_share(causal))),
         interpret=interpret,
+        name="penroz_flash_fwd",
     )(seed, alibi_arr, q, k, v)
     return (out, lse) if return_lse else out
 
@@ -680,39 +1080,41 @@ def _recompute_probs(q, k, lse, q0, k0, slope, seed, *, masked: bool,
 
 def _bwd_fused_kernel(seed_ref, alibi_ref, q_ref, k_ref, v_ref, lse_ref,
                       delta_ref, do_ref, dq_ref, dk_ref, dv_ref,
-                      qs_scr, dq_scr, dk_scr, dv_scr, *, causal: bool,
-                      sm_scale: float, block_q: int, block_k: int,
-                      num_heads: int, heads_per_step: int, group: int,
+                      qs_scr, dq_scr, dk_scr, dv_scr, *, ix,
+                      causal: bool, sm_scale: float, block_q: int,
+                      block_k: int, heads_per_step: int,
                       dropout_rate: float, window, use_alibi: bool):
     """One pass over the live tiles of ``heads_per_step`` heads, key tiles
     outermost, on transposed (block_k, block_q) tiles: dV and dK of a key
     tile accumulate in scratch over its query tiles, dQ of the whole head
-    in a (T, D) f32 scratch that is written once."""
+    in a (T, D) f32 scratch that is written once.  Where a lane block holds
+    two heads the scratch is the block's width and only the head's own
+    lanes of it are kept (:meth:`_LaneBlocks.put`)."""
     b, hs = pl.program_id(0), pl.program_id(1)
-    num_q = q_ref.shape[2] // block_q
-    num_k = k_ref.shape[2] // block_k
+    num_q = ix.rows(q_ref) // block_q
+    num_k = ix.rows(k_ref) // block_k
 
     def head(hh):
         h = hs * heads_per_step + hh
-        hkv = hh // group
-        slope, seed = _head_operands(seed_ref, alibi_ref, b, h, num_heads,
+        hkv = ix.kv_head(hh)
+        slope, seed = _head_operands(seed_ref, alibi_ref, b, h, ix.heads,
                                      use_alibi, dropout_rate)
-        qs_scr[...] = _scaled(q_ref[0, hh], sm_scale)
+        qs_scr[...] = ix.scaled_q(q_ref, hh, _ALL, sm_scale)
         dq_scr[...] = jnp.zeros_like(dq_scr)
 
         def key_tile(kj):
             cols = _tile(kj, block_k)
-            k = k_ref[0, hkv, cols, :]
-            v = v_ref[0, hkv, cols, :]
+            k = ix.kv(k_ref, hkv, cols)
+            v = ix.own(ix.kv(v_ref, hkv, cols), hh)
             dk_scr[...] = jnp.zeros_like(dk_scr)
             dv_scr[...] = jnp.zeros_like(dv_scr)
 
             def query_tile(qi, masked):
                 rows = _tile(qi, block_q)
                 q = qs_scr[rows, :]
-                do = do_ref[0, hh, rows, :]
+                do = ix.get(do_ref, hh, rows)
                 p, drop_scale = _recompute_probs(
-                    q, k, lse_ref[0, hh, :, rows], qi * block_q,
+                    q, k, ix.row(lse_ref, hh, rows), qi * block_q,
                     kj * block_k, slope, seed, masked=masked, window=window,
                     dropout_rate=dropout_rate, transposed=True)
                 dp = _dot(v, do, (1, 1))                  # (dO·Vᵀ)ᵀ
@@ -722,213 +1124,274 @@ def _bwd_fused_kernel(seed_ref, alibi_ref, q_ref, k_ref, v_ref, lse_ref,
                 else:
                     p_drop = p
                 dv_scr[...] += _dot(p_drop.astype(do.dtype), do, (1, 0))
-                ds = (p * (dp - delta_ref[0, hh, :, rows])).astype(q.dtype)
+                ds = (p * (dp - ix.row(delta_ref, hh, rows))).astype(q.dtype)
                 dk_scr[...] += _dot(ds, q, (1, 0))        # q holds sm_scale
                 dq_scr[rows, :] += _dot(ds, k, (0, 0))    # scaled at the end
 
             _walk(query_tile_ranges(kj, block_q, block_k, num_q, causal,
                                     window), query_tile)
-            dk_ref[0, hh, cols, :] = dk_scr[...].astype(dk_ref.dtype)
-            dv_ref[0, hh, cols, :] = dv_scr[...].astype(dv_ref.dtype)
+            ix.put(dk_ref, hh, cols, dk_scr[...].astype(dk_ref.dtype))
+            ix.put(dv_ref, hh, cols, dv_scr[...].astype(dv_ref.dtype))
 
         _loop(0, num_k, key_tile)
-        dq_ref[0, hh] = (dq_scr[...] * sm_scale).astype(dq_ref.dtype)
+        ix.put(dq_ref, hh, _ALL, (dq_scr[...] * sm_scale).astype(dq_ref.dtype))
 
     _loop(0, heads_per_step, head)
 
 
 def _dq_kernel(seed_ref, alibi_ref, q_ref, k_ref, v_ref, lse_ref, delta_ref,
-               do_ref, dq_ref, dq_scr, qs_scr, *, causal: bool,
+               do_ref, dq_ref, dq_scr, qs_scr, *, ix, causal: bool,
                sm_scale: float, block_q: int, block_k: int, num_k: int,
-               num_heads: int, dropout_rate: float, window,
+               heads_per_step: int, dropout_rate: float, window,
                use_alibi: bool):
-    b, h, qi, kj = (pl.program_id(0), pl.program_id(1), pl.program_id(2),
-                    pl.program_id(3))
+    """dQ of one query tile, K/V streaming.  Tiles are (block_q, block_k)
+    against logsumexp / δ columns, or transposed against rows where the
+    layout stores them so (``ix.stat_rows``)."""
+    b, hs, qi, kj = (pl.program_id(0), pl.program_id(1), pl.program_id(2),
+                     pl.program_id(3))
+    own = lambda ref, hh: _at(ref, hh, heads_per_step)
+    t = ix.stat_rows
+
+    def heads(body):
+        _loop(0, heads_per_step, body)
 
     @pl.when(kj == 0)
     def _init():
         dq_scr[...] = jnp.zeros_like(dq_scr)
-        qs_scr[...] = _scaled(q_ref[0, 0], sm_scale)
 
+        def scale(hh):
+            own(qs_scr, hh)[...] = ix.scaled_q(q_ref, hh, _ALL, sm_scale)
+
+        heads(scale)
 
     def step(masked):
-        k = k_ref[0, 0]
-        p, drop_scale = _recompute_probs(
-            qs_scr[...], k, lse_ref[0, 0], qi * block_q, kj * block_k,
-            *_head_operands(seed_ref, alibi_ref, b, h, num_heads, use_alibi,
-                            dropout_rate),
-            masked=masked, window=window, dropout_rate=dropout_rate)
-        dp = _dot(do_ref[0, 0], v_ref[0, 0], (1, 1))
-        if drop_scale is not None:
-            dp = dp * drop_scale
-        ds = p * (dp - delta_ref[0, 0])
-        dq_scr[...] += _dot(ds.astype(k.dtype), k, (1, 0))
+        def head(hh):
+            hkv = ix.kv_head(hh)
+            k = ix.kv(k_ref, hkv, _ALL)
+            p, drop_scale = _recompute_probs(
+                own(qs_scr, hh)[...], k, ix.stat(lse_ref, hh),
+                qi * block_q, kj * block_k,
+                *_head_operands(seed_ref, alibi_ref, b,
+                                _head_index(hs, heads_per_step, hh),
+                                ix.heads, use_alibi, dropout_rate),
+                masked=masked, window=window, dropout_rate=dropout_rate,
+                transposed=t)
+            do = ix.get(do_ref, hh, _ALL)
+            v = ix.own(ix.kv(v_ref, hkv, _ALL), hh)
+            dp = _dot(v, do, (1, 1)) if t else _dot(do, v, (1, 1))
+            if drop_scale is not None:
+                dp = dp * drop_scale
+            ds = p * (dp - ix.stat(delta_ref, hh))
+            own(dq_scr, hh)[...] += _dot(ds.astype(k.dtype), k,
+                                         (0, 0) if t else (1, 0))
+
+        heads(head)
 
     _when_live(key_tile_ranges(qi, block_q, block_k, num_k, causal, window),
                kj, step, causal)
 
     @pl.when(kj == num_k - 1)
     def _finish():
-        dq_ref[0, 0] = (dq_scr[...] * sm_scale).astype(dq_ref.dtype)
+        heads(lambda hh: ix.put(
+            dq_ref, hh, _ALL,
+            (own(dq_scr, hh)[...] * sm_scale).astype(dq_ref.dtype)))
 
 
 def _dkv_kernel(seed_ref, alibi_ref, q_ref, k_ref, v_ref, lse_ref,
                 delta_ref, do_ref, dk_ref, dv_ref, dk_scr, dv_scr, *,
-                causal: bool, sm_scale: float, block_q: int, block_k: int,
-                num_q: int, num_heads: int, dropout_rate: float, window,
-                use_alibi: bool):
-    b, h, kj, qi = (pl.program_id(0), pl.program_id(1), pl.program_id(2),
-                    pl.program_id(3))
+                ix, causal: bool, sm_scale: float, block_q: int,
+                block_k: int, num_q: int, heads_per_step: int,
+                dropout_rate: float, window, use_alibi: bool):
+    """dK, dV of one key tile, Q/dO streaming; tiles as in :func:`_dq_kernel`."""
+    b, hs, kj, qi = (pl.program_id(0), pl.program_id(1), pl.program_id(2),
+                     pl.program_id(3))
+    own = lambda ref, hh: _at(ref, hh, heads_per_step)
+    t = ix.stat_rows
+
+    def heads(body):
+        _loop(0, heads_per_step, body)
 
     @pl.when(qi == 0)
     def _init():
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
-
     def step(masked):
-        q = _scaled(q_ref[0, 0], sm_scale)
-        do = do_ref[0, 0]
-        p, drop_scale = _recompute_probs(
-            q, k_ref[0, 0], lse_ref[0, 0], qi * block_q, kj * block_k,
-            *_head_operands(seed_ref, alibi_ref, b, h, num_heads, use_alibi,
-                            dropout_rate),
-            masked=masked, window=window, dropout_rate=dropout_rate)
-        p_drop = p if drop_scale is None else p * drop_scale
-        dv_scr[...] += _dot(p_drop.astype(do.dtype), do, (0, 0))  # p̃ᵀ·dO
-        dp = _dot(do, v_ref[0, 0], (1, 1))
-        if drop_scale is not None:
-            dp = dp * drop_scale
-        ds = p * (dp - delta_ref[0, 0])
-        dk_scr[...] += _dot(ds.astype(q.dtype), q, (0, 0))        # dSᵀ·Q
+        def head(hh):
+            q = ix.scaled_q(q_ref, hh, _ALL, sm_scale)
+            do = ix.get(do_ref, hh, _ALL)
+            hkv = ix.kv_head(hh)
+            p, drop_scale = _recompute_probs(
+                q, ix.kv(k_ref, hkv, _ALL), ix.stat(lse_ref, hh),
+                qi * block_q, kj * block_k,
+                *_head_operands(seed_ref, alibi_ref, b,
+                                _head_index(hs, heads_per_step, hh),
+                                ix.heads, use_alibi, dropout_rate),
+                masked=masked, window=window, dropout_rate=dropout_rate,
+                transposed=t)
+            p_drop = p if drop_scale is None else p * drop_scale
+            own(dv_scr, hh)[...] += _dot(p_drop.astype(do.dtype), do,
+                                         (1, 0) if t else (0, 0))  # p̃ᵀ·dO
+            v = ix.own(ix.kv(v_ref, hkv, _ALL), hh)
+            dp = _dot(v, do, (1, 1)) if t else _dot(do, v, (1, 1))
+            if drop_scale is not None:
+                dp = dp * drop_scale
+            ds = p * (dp - ix.stat(delta_ref, hh))
+            own(dk_scr, hh)[...] += _dot(ds.astype(q.dtype), q,
+                                         (1, 0) if t else (0, 0))  # dSᵀ·Q
+
+        heads(head)
 
     _when_live(query_tile_ranges(kj, block_q, block_k, num_q, causal,
                                  window), qi, step, causal)
 
     @pl.when(qi == num_q - 1)
     def _finish():
-        dk_ref[0, 0] = dk_scr[...].astype(dk_ref.dtype)
-        dv_ref[0, 0] = dv_scr[...].astype(dv_ref.dtype)
+        def head(hh):
+            ix.put(dk_ref, hh, _ALL, own(dk_scr, hh)[...].astype(dk_ref.dtype))
+            ix.put(dv_ref, hh, _ALL, own(dv_scr, hh)[...].astype(dv_ref.dtype))
+
+        heads(head)
+
+
+def _delta_kernel(o_ref, do_ref, delta_ref, *, ix, block_q: int):
+    """δ rows of one lane block: the sums over each head's lanes as one
+    matmul with a 0/1 matrix (row ``hh`` picks head ``hh``'s lanes), which
+    lands them lane-dense."""
+    shape = (8, ix.width)
+    head = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    pick = jnp.where((lane >= head * ix.D) & (lane < (head + 1) * ix.D),
+                     1.0, 0.0)
+
+    def tile(i):
+        rows = _tile(i, block_q)
+        prod = (do_ref[0, rows, :].astype(jnp.float32)
+                * o_ref[0, rows, :].astype(jnp.float32))
+        delta_ref[0, 0, :, rows] = _dot(pick, prod, (1, 1))[:ix.hpb]
+
+    _loop(0, ix.rows(o_ref) // block_q, tile)
 
 
 def _flash_backward(q, k, v, out, lse, g, causal: bool, plan: FlashPlan,
                     dropout_rate: float, seed, interpret: bool = False,
-                    window=None, alibi=None, scale=None):
-    B, Hq, T, D = q.shape
-    Hkv, S = k.shape[1], k.shape[2]
-    group = Hq // Hkv
+                    window=None, alibi=None, scale=None, ix=None):
+    """``(dq, dk, dv)`` shaped like q, k, v (of their own arrays in the
+    ``btd`` layout, whatever array the forward read them from)."""
+    ix = ix or _bhtd(q, k)
+    B, T, S = ix.dims(q, k)
+    D, Hq, Hkv, group = ix.D, ix.heads, ix.kv_heads, ix.group
     block_q, block_k = plan.bwd_block_q, plan.bwd_block_k
     sm_scale = float(scale) if scale is not None else 1.0 / (D ** 0.5)
     num_q, num_k = T // block_q, S // block_k
     seed, alibi_arr = _smem_operands(seed, alibi)
+    hps = plan.heads_per_step if plan.fused_bwd else ix.hpb
+    itemsize = q.dtype.itemsize
 
-    # δ_i = Σ_d dO_id · O_id — the softmax-backward row term; O(B·H·T·D),
-    # cheap enough to fuse outside the kernels.
-    delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
-                    axis=-1, keepdims=True)
-    common = dict(causal=causal, sm_scale=sm_scale, block_q=block_q,
-                  block_k=block_k, num_heads=Hq, dropout_rate=dropout_rate,
-                  window=window, use_alibi=alibi is not None)
+    delta = ix.delta(out, g, block_q, interpret)
+    if plan.fused_bwd:
+        lse, delta = ix.as_rows(lse), ix.as_rows(delta)
+    common = dict(ix=ix, causal=causal, sm_scale=sm_scale, block_q=block_q,
+                  block_k=block_k, heads_per_step=hps,
+                  dropout_rate=dropout_rate, window=window,
+                  use_alibi=alibi is not None)
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     flops = int(10 * B * Hq * T * S * D * _live_share(causal))
     exps = int(B * Hq * T * S * _live_share(causal))
-    dkv_shape = [jax.ShapeDtypeStruct((B, Hq, S, D), k.dtype),
-                 jax.ShapeDtypeStruct((B, Hq, S, D), v.dtype)]
+    q_bytes, kv_bytes = B * Hq * T * D * itemsize, B * Hkv * S * D * itemsize
+    dkv_bytes = B * Hq * S * D * itemsize
+    dq_shape = jax.ShapeDtypeStruct(ix.like_q(B, T), q.dtype)
+    dkv_shape = [jax.ShapeDtypeStruct(ix.like_q(B, S), k.dtype),
+                 jax.ShapeDtypeStruct(ix.like_q(B, S), v.dtype)]
+    width = ix.width
 
     if plan.fused_bwd:
-        hps = plan.heads_per_step
-        kvh = _kv_heads_per_step(hps, group)
-        q_spec = pl.BlockSpec((1, hps, T, D), lambda b, h: (b, h, 0, 0))
-        kv_spec = pl.BlockSpec((1, kvh, S, D),
-                               lambda b, h: (b, h * hps // (group * kvh),
-                                             0, 0))
-        row_spec = pl.BlockSpec((1, hps, 1, T), lambda b, h: (b, h, 0, 0))
-        dkv_spec = pl.BlockSpec((1, hps, S, D), lambda b, h: (b, h, 0, 0))
+        at = lambda b, h: (b, h, 0)
+        q_spec = ix.spec(hps, T, at, 0)
+        own_spec = ix.spec(hps, T, at)
+        row_spec = ix.stat_spec(hps, T, at, as_rows=True)
+        dkv_spec = ix.spec(hps, S, at)
         dq, dk_ph, dv_ph = pl.pallas_call(
-            functools.partial(_bwd_fused_kernel, heads_per_step=hps,
-                              group=group, **common),
+            functools.partial(_bwd_fused_kernel, **common),
             grid=(B, Hq // hps),
-            in_specs=[smem, smem, q_spec, kv_spec, kv_spec, row_spec,
-                      row_spec, q_spec],
-            out_specs=[q_spec, dkv_spec, dkv_spec],
-            out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype)] + dkv_shape,
-            scratch_shapes=[pltpu.VMEM((T, D), q.dtype),
-                            pltpu.VMEM((T, D), jnp.float32),
-                            pltpu.VMEM((block_k, D), jnp.float32),
-                            pltpu.VMEM((block_k, D), jnp.float32)],
+            in_specs=[smem, smem, q_spec, ix.kv_spec(hps, S, at, 1),
+                      ix.kv_spec(hps, S, at, 2), row_spec, row_spec,
+                      own_spec],
+            out_specs=[own_spec, dkv_spec, dkv_spec],
+            out_shape=[dq_shape] + dkv_shape,
+            scratch_shapes=[pltpu.VMEM((T, width), q.dtype),
+                            pltpu.VMEM((T, width), jnp.float32),
+                            pltpu.VMEM((block_k, width), jnp.float32),
+                            pltpu.VMEM((block_k, width), jnp.float32)],
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel")),
             cost_estimate=pl.CostEstimate(
                 flops=flops, transcendentals=exps,
-                bytes_accessed=int((3 * q.size + 2 * k.size
-                                    + 2 * B * Hq * S * D)
-                                   * q.dtype.itemsize)),
+                bytes_accessed=3 * q_bytes + 2 * kv_bytes + 2 * dkv_bytes),
             interpret=interpret,
-            # lse and δ as lane-dense (1, T) rows of the transposed tiles
-        )(seed, alibi_arr, q, k, v, lse.reshape(B, Hq, 1, T),
-          delta.reshape(B, Hq, 1, T), g)
+            name="penroz_flash_bwd",
+        )(seed, alibi_arr, q, k, v, lse, delta, g)
     else:
         clamp_k = _clamped(key_tile_ranges, block_q, block_k, num_k, causal,
                            window)
-        q_spec = pl.BlockSpec((1, 1, block_q, D),
-                              lambda b, h, i, j: (b, h, i, 0))
-        kv_spec = pl.BlockSpec((1, 1, block_k, D),
-                               lambda b, h, i, j: (b, h // group,
-                                                   clamp_k(i, j), 0))
-        row_spec = pl.BlockSpec((1, 1, block_q, 1),
-                                lambda b, h, i, j: (b, h, i, 0))
+        at = lambda b, h, i, j: (b, h, i)
+        kv_at = lambda b, h, i, j: (b, h, clamp_k(i, j))
+        own_spec = ix.spec(hps, block_q, at)
+        stat_spec = ix.stat_spec(hps, block_q, at, as_rows=False)
         semantics = pltpu.CompilerParams(dimension_semantics=(
             "parallel", "parallel", "parallel", "arbitrary"))
         dq = pl.pallas_call(
             functools.partial(_dq_kernel, num_k=num_k, **common),
-            grid=(B, Hq, num_q, num_k),
-            in_specs=[smem, smem, q_spec, kv_spec, kv_spec, row_spec,
-                      row_spec, q_spec],
-            out_specs=q_spec,
-            out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-            scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32),
-                            pltpu.VMEM((block_q, D), q.dtype)],
+            grid=(B, Hq // hps, num_q, num_k),
+            in_specs=[smem, smem, ix.spec(hps, block_q, at, 0),
+                      ix.kv_spec(hps, block_k, kv_at, 1),
+                      ix.kv_spec(hps, block_k, kv_at, 2), stat_spec,
+                      stat_spec, own_spec],
+            out_specs=own_spec,
+            out_shape=dq_shape,
+            scratch_shapes=[pltpu.VMEM(_per_head(hps, (block_q, width)),
+                                       jnp.float32),
+                            pltpu.VMEM(_per_head(hps, (block_q, width)),
+                                       q.dtype)],
             compiler_params=semantics,
             cost_estimate=pl.CostEstimate(
                 flops=flops // 2, transcendentals=exps,
-                bytes_accessed=int((3 * q.size + 2 * k.size)
-                                   * q.dtype.itemsize)),
+                bytes_accessed=3 * q_bytes + 2 * kv_bytes),
             interpret=interpret,
+            name="penroz_flash_bwd_dq",
         )(seed, alibi_arr, q, k, v, lse, delta, g)
 
         # K/V-resident kernel: Q, dO, lse, δ stream through the inner grid.
         # index maps take (b, h, kj, qi) — q-row specs select on qi (dim 3).
         clamp_q = _clamped(query_tile_ranges, block_q, block_k, num_q,
                            causal, window)
-        q_stream = pl.BlockSpec((1, 1, block_q, D),
-                                lambda b, h, j, i: (b, h, clamp_q(j, i), 0))
-        kv_res = pl.BlockSpec((1, 1, block_k, D),
-                              lambda b, h, j, i: (b, h // group, j, 0))
-        row_stream = pl.BlockSpec((1, 1, block_q, 1),
-                                  lambda b, h, j, i: (b, h, clamp_q(j, i),
-                                                      0))
-        dkv_out = pl.BlockSpec((1, 1, block_k, D),
-                               lambda b, h, j, i: (b, h, j, 0))
+        q_at = lambda b, h, j, i: (b, h, clamp_q(j, i))
+        kv_at = lambda b, h, j, i: (b, h, j)
+        stream = ix.spec(hps, block_q, q_at)
+        stat_stream = ix.stat_spec(hps, block_q, q_at, as_rows=False)
+        dkv_out = ix.spec(hps, block_k, kv_at)
+        dkv_scr = pltpu.VMEM(_per_head(hps, (block_k, width)), jnp.float32)
         dk_ph, dv_ph = pl.pallas_call(
             functools.partial(_dkv_kernel, num_q=num_q, **common),
-            grid=(B, Hq, num_k, num_q),
-            in_specs=[smem, smem, q_stream, kv_res, kv_res, row_stream,
-                      row_stream, q_stream],
+            grid=(B, Hq // hps, num_k, num_q),
+            in_specs=[smem, smem, ix.spec(hps, block_q, q_at, 0),
+                      ix.kv_spec(hps, block_k, kv_at, 1),
+                      ix.kv_spec(hps, block_k, kv_at, 2), stat_stream,
+                      stat_stream, stream],
             out_specs=[dkv_out, dkv_out],
             out_shape=dkv_shape,
-            scratch_shapes=[pltpu.VMEM((block_k, D), jnp.float32),
-                            pltpu.VMEM((block_k, D), jnp.float32)],
+            scratch_shapes=[dkv_scr, dkv_scr],
             compiler_params=semantics,
             cost_estimate=pl.CostEstimate(
                 flops=flops // 2, transcendentals=exps,
-                bytes_accessed=int((3 * q.size + 4 * B * Hq * S * D)
-                                   * q.dtype.itemsize)),
+                bytes_accessed=3 * q_bytes + 4 * dkv_bytes),
             interpret=interpret,
+            name="penroz_flash_bwd_dkv",
         )(seed, alibi_arr, q, k, v, lse, delta, g)
 
     if group > 1:
-        dk = dk_ph.reshape(B, Hkv, group, S, D).sum(axis=2).astype(k.dtype)
-        dv = dv_ph.reshape(B, Hkv, group, S, D).sum(axis=2).astype(v.dtype)
+        dk = ix.sum_groups(dk_ph).astype(k.dtype)
+        dv = ix.sum_groups(dv_ph).astype(v.dtype)
     else:
         dk = dk_ph.astype(k.dtype)
         dv = dv_ph.astype(v.dtype)
@@ -940,33 +1403,60 @@ def _flash_backward(q, k, v, out, lse, g, causal: bool, plan: FlashPlan,
 # ---------------------------------------------------------------------------
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9, 10))
-def _flash(q, k, v, seed, causal, plan, dropout_rate, interpret, window,
-           alibi, scale=None):
-    return _flash_forward(q, k, v, causal, dropout_rate=dropout_rate,
-                          seed=seed, interpret=interpret, window=window,
-                          alibi=alibi, scale=scale, plan=plan)
+def _qkv_of(arrays):
+    """(q, k, v) as the calls take them: three arrays, or the one fused
+    projection three times (the indexer's offsets tell the ranges apart)."""
+    return arrays if len(arrays) == 3 else arrays * 3
 
 
-def _flash_fwd_rule(q, k, v, seed, causal, plan, dropout_rate, interpret,
-                    window, alibi, scale=None):
-    out, lse = _flash_forward(q, k, v, causal, dropout_rate=dropout_rate,
-                              seed=seed, interpret=interpret,
-                              return_lse=True, window=window, alibi=alibi,
-                              scale=scale, plan=plan)
-    return out, (q, k, v, seed, out, lse)
+@functools.partial(jax.custom_vjp, nondiff_argnums=tuple(range(2, 10)))
+def _flash(arrays, seed, ix, causal, plan, dropout_rate, interpret, window,
+           alibi, scale):
+    return _flash_forward(*_qkv_of(arrays), causal,
+                          dropout_rate=dropout_rate, seed=seed,
+                          interpret=interpret, window=window, alibi=alibi,
+                          scale=scale, plan=plan, ix=ix)
 
 
-def _flash_bwd_rule(causal, plan, dropout_rate, interpret, window, alibi,
+def _flash_fwd_rule(arrays, seed, ix, causal, plan, dropout_rate, interpret,
+                    window, alibi, scale):
+    out, lse = _flash_forward(*_qkv_of(arrays), causal,
+                              dropout_rate=dropout_rate, seed=seed,
+                              interpret=interpret, return_lse=True,
+                              window=window, alibi=alibi, scale=scale,
+                              plan=plan, ix=ix)
+    return out, (arrays, seed, out, lse)
+
+
+def _flash_bwd_rule(ix, causal, plan, dropout_rate, interpret, window, alibi,
                     scale, residuals, g):
-    q, k, v, seed, out, lse = residuals
-    dq, dk, dv = _flash_backward(q, k, v, out, lse, g, causal, plan,
-                                 dropout_rate, seed, interpret=interpret,
-                                 window=window, alibi=alibi, scale=scale)
-    return dq, dk, dv, np.zeros((), dtype=jax.dtypes.float0)
+    arrays, seed, out, lse = residuals
+    grads = _flash_backward(*_qkv_of(arrays), out, lse, g, causal, plan,
+                            dropout_rate, seed, interpret=interpret,
+                            window=window, alibi=alibi, scale=scale, ix=ix)
+    if len(arrays) == 1:
+        # the fused projection's cotangent, its three ranges side by side
+        grads = (jnp.concatenate(grads, axis=-1),)
+    return grads, np.zeros((), dtype=jax.dtypes.float0)
 
 
 _flash.defvjp(_flash_fwd_rule, _flash_bwd_rule)
+
+
+def _static_args(seed, alibi, heads: int, window, scale):
+    if seed is None:
+        seed = jnp.zeros((), jnp.int32)
+    if alibi is not None:
+        # static tuple: slopes are a pure function of the head count, so
+        # baking them into the trace costs nothing and keeps the
+        # custom_vjp arity fixed
+        alibi = tuple(float(a) for a in np.asarray(alibi).reshape(-1))
+        if len(alibi) != heads:
+            raise ValueError(f"alibi needs one slope per query head "
+                             f"({heads}), got {len(alibi)}")
+    return (jnp.asarray(seed, jnp.int32),
+            int(window) if window is not None else None, alibi,
+            float(scale) if scale is not None else None)
 
 
 def flash_attention(q, k, v, causal: bool = True,
@@ -990,23 +1480,66 @@ def flash_attention(q, k, v, causal: bool = True,
     """
     B, Hq, T, D = q.shape
     Hkv, S = k.shape[1], k.shape[2]
-    if seed is None:
-        seed = jnp.zeros((), jnp.int32)
-    if alibi is not None:
-        # static tuple: slopes are a pure function of the head count, so
-        # baking them into the trace costs nothing and keeps the
-        # custom_vjp arity fixed
-        alibi = tuple(float(a) for a in np.asarray(alibi).reshape(-1))
-        if len(alibi) != Hq:
-            raise ValueError(f"alibi needs one slope per query head "
-                             f"({Hq}), got {len(alibi)}")
-    window = int(window) if window is not None else None
+    seed, window, alibi, scale = _static_args(seed, alibi, Hq, window, scale)
     plan = plan_flash(T, S, D, q.dtype.itemsize, bool(causal), window,
                       heads=Hq, group=Hq // Hkv,
                       block_q=int(block_q) if block_q else None,
                       block_k=int(block_k) if block_k else None,
                       vmem_budget=int(vmem_budget))
     _record_plan(T, S, D, plan)
-    return _flash(q, k, v, jnp.asarray(seed, jnp.int32), bool(causal), plan,
-                  float(dropout_rate), bool(interpret), window, alibi,
-                  float(scale) if scale is not None else None)
+    return _flash((q, k, v), seed, _bhtd(q, k), bool(causal), plan,
+                  float(dropout_rate), bool(interpret), window, alibi, scale)
+
+
+def btd_refusal(D: int, heads: int, kv_heads: int) -> str | None:
+    """Why :func:`flash_attention_btd` cannot take ``heads`` query heads on
+    ``kv_heads`` K/V heads of size ``D``, or None if it can."""
+    if D not in (64, 128, 256):
+        return f"head size {D} fills no whole 128-lane block"
+    hpb = _heads_per_block(D)
+    if heads % hpb or kv_heads % hpb:
+        return (f"{heads} query / {kv_heads} K/V heads of size {D} do not "
+                f"fill whole 128-lane blocks ({hpb} heads a block)")
+    if hpb > 1 and heads != kv_heads:
+        return (f"grouped-query attention at head size {D}: a lane block's "
+                f"{hpb} query heads need their K/V heads at the same lanes")
+    return None
+
+
+def flash_attention_btd(q, k=None, v=None, *, heads: int,
+                        kv_heads: int | None = None, causal: bool = True,
+                        block_q: int | None = None,
+                        block_k: int | None = None,
+                        dropout_rate: float = 0.0, seed=None,
+                        interpret: bool = False, window=None, alibi=None,
+                        scale=None, vmem_budget: int = VMEM_BUDGET):
+    """:func:`flash_attention` in the model's own layout (module docstring).
+
+    ``q`` alone: the fused projection ``(B, T, (heads + 2·kv_heads)·D)``,
+    read in place — q, k, v are its lane ranges, and its cotangent comes
+    back whole.  With ``k`` and ``v``: q ``(B, T, heads·D)``, k/v ``(B, S,
+    kv_heads·D)``.  Returns ``(B, T, heads·D)``.  Everything else as
+    :func:`flash_attention`; :func:`btd_refusal` names the head shapes this
+    entry does not take."""
+    kv_heads = heads if kv_heads is None else kv_heads
+    fused = k is None
+    if fused != (v is None):
+        raise ValueError("pass the fused projection alone, or q, k and v")
+    B, T, width = q.shape
+    S = T if fused else k.shape[1]
+    D = width // (heads + 2 * kv_heads if fused else heads)
+    refusal = btd_refusal(D, heads, kv_heads)
+    if refusal:
+        raise ValueError(f"flash_attention_btd: {refusal}")
+    seed, window, alibi, scale = _static_args(seed, alibi, heads, window,
+                                              scale)
+    plan = plan_flash(T, S, D, q.dtype.itemsize, bool(causal), window,
+                      heads=heads, group=heads // kv_heads,
+                      block_q=int(block_q) if block_q else None,
+                      block_k=int(block_k) if block_k else None,
+                      vmem_budget=int(vmem_budget), layout="btd",
+                      fused_qkv=fused)
+    _record_plan(T, S, D, plan)
+    ix = _LaneBlocks(D=D, heads=heads, kv_heads=kv_heads, fused=fused)
+    return _flash((q,) if fused else (q, k, v), seed, ix, bool(causal), plan,
+                  float(dropout_rate), bool(interpret), window, alibi, scale)

@@ -1216,3 +1216,358 @@ def test_flash_plan_is_logged_once_and_spanned_per_trace(caplog):
     assert meta["block_q"] == 384 and meta["resident"] is True
     assert meta["fused_bwd"] is True and meta["heads_per_step"] == 2
     trace.finish("completed")
+
+
+# -- the (B, T, lanes) layout: flash_attention_btd and the module's path ------
+
+
+def _heads_first(x, heads):
+    B, T, width = x.shape
+    return x.reshape(B, T, heads, width // heads).transpose(0, 2, 1, 3)
+
+
+def _btd_parts(qkv, Hq, Hkv, D):
+    return (qkv[..., :Hq * D], qkv[..., Hq * D:(Hq + Hkv) * D],
+            qkv[..., (Hq + Hkv) * D:])
+
+
+def _btd_oracle(oracle, Hq, Hkv, D):
+    """A (B, H, T, D) oracle over the ranges of a fused (B, T, ·) array."""
+    def run(qkv):
+        q, k, v = _btd_parts(qkv, Hq, Hkv, D)
+        out = oracle(_heads_first(q, Hq), _heads_first(k, Hkv),
+                     _heads_first(v, Hkv))
+        return out.transpose(0, 2, 1, 3).reshape(*qkv.shape[:2], Hq * D)
+    return run
+
+
+# shape → (B, Hq, Hkv, T, D); the D = 64 cases pair heads in a lane block
+_BTD_SHAPES = {
+    "d64_pairs": (2, 4, 4, 256, 64),
+    "d128": (1, 2, 2, 256, 128),
+    "d128_gqa": (1, 4, 2, 256, 128),
+    "d256_gqa": (1, 2, 1, 128, 256),
+}
+_BTD_FEATURES = {
+    "causal": lambda Hq: ({}, A.causal_attention_reference),
+    "window": lambda Hq: ({"window": 100}, lambda q, k, v:
+                          A.causal_attention_reference(q, k, v, window=100)),
+    "alibi": lambda Hq: ({"alibi": A.alibi_slopes(Hq)}, lambda q, k, v:
+                         A.causal_attention_reference(
+                             q, k, v, alibi=A.alibi_slopes(Hq))),
+    # the same keep-mask as the (B, H, T, D) kernels draw
+    "dropout": lambda Hq: ({"dropout_rate": 0.3, "seed": 1234},
+                           lambda q, k, v: _masked_dropout_oracle(
+                               q, k, v, 0.3, 1234)),
+}
+
+
+@pytest.mark.parametrize("form", ["fused", "split"])
+@pytest.mark.parametrize("feature", list(_BTD_FEATURES))
+@pytest.mark.parametrize("shape", list(_BTD_SHAPES))
+def test_flash_btd_matches_oracle(shape, feature, form):
+    """``flash_attention_btd`` (interpret) against the jnp oracle, output
+    and the gradient of every range of the projection: q, k, v as lane
+    ranges of the one fused array, and as three arrays."""
+    from penroz_tpu.ops.pallas import flash_attention as FA
+    B, Hq, Hkv, T, D = _BTD_SHAPES[shape]
+    kwargs, oracle = _BTD_FEATURES[feature](Hq)
+    rng = np.random.default_rng(len(shape) + len(feature))
+    qkv = jnp.asarray(rng.normal(size=(B, T, (Hq + 2 * Hkv) * D))
+                      .astype(np.float32))
+    w = jnp.asarray(rng.normal(size=(B, T, Hq * D)).astype(np.float32))
+
+    def attend(qkv):
+        arrays = (qkv,) if form == "fused" else _btd_parts(qkv, Hq, Hkv, D)
+        return FA.flash_attention_btd(*arrays, heads=Hq, kv_heads=Hkv,
+                                      block_q=128, block_k=128,
+                                      interpret=True, **kwargs)
+
+    want = _btd_oracle(oracle, Hq, Hkv, D)
+    np.testing.assert_allclose(np.asarray(attend(qkv)),
+                               np.asarray(want(qkv)), atol=2e-5)
+    got_g = jax.grad(lambda x: (attend(x) * w).sum())(qkv)
+    want_g = jax.grad(lambda x: (want(x) * w).sum())(qkv)
+    _grad_close(_btd_parts(got_g, Hq, Hkv, D),
+                _btd_parts(want_g, Hq, Hkv, D))
+
+
+@pytest.mark.parametrize("shape", ["d64_pairs", "d128_gqa"])
+def test_flash_btd_chunked_plan_matches_oracle(shape):
+    """The streamed forward and the two-kernel backward a small budget
+    forces, in the (B, T, lanes) layout, with a window's clamped walks."""
+    from penroz_tpu.ops.pallas import flash_attention as FA
+    B, Hq, Hkv, _, D = _BTD_SHAPES[shape]
+    T = 512
+    plan = FA.plan_flash(T, T, D, 4, True, 200, heads=Hq, group=Hq // Hkv,
+                         block_q=128, block_k=128, vmem_budget=1,
+                         layout="btd")
+    assert not plan.resident and not plan.fused_bwd
+    rng = np.random.default_rng(3)
+    qkv = jnp.asarray(rng.normal(size=(B, T, (Hq + 2 * Hkv) * D))
+                      .astype(np.float32))
+    w = jnp.asarray(rng.normal(size=(B, T, Hq * D)).astype(np.float32))
+    attend = lambda x: FA.flash_attention_btd(
+        x, heads=Hq, kv_heads=Hkv, block_q=128, block_k=128, window=200,
+        vmem_budget=1, interpret=True)
+    want = _btd_oracle(lambda q, k, v: A.causal_attention_reference(
+        q, k, v, window=200), Hq, Hkv, D)
+    np.testing.assert_allclose(np.asarray(attend(qkv)),
+                               np.asarray(want(qkv)), atol=2e-5)
+    got_g = jax.grad(lambda x: (attend(x) * w).sum())(qkv)
+    want_g = jax.grad(lambda x: (want(x) * w).sum())(qkv)
+    _grad_close(_btd_parts(got_g, Hq, Hkv, D),
+                _btd_parts(want_g, Hq, Hkv, D))
+
+
+@pytest.mark.parametrize("D,heads,kv_heads,takes", [
+    (64, 12, 12, True),        # the benchmark cell: head pairs
+    (128, 32, 8, True),        # any group once a head fills a block
+    (256, 8, 1, True),
+    (64, 3, 3, False),         # an odd head count leaves half a block
+    (64, 4, 2, False),         # GQA at D = 64: K/V heads at other lanes
+    (96, 4, 4, False),
+])
+def test_flash_btd_refusal_names_the_shapes_it_does_not_take(D, heads,
+                                                              kv_heads, takes):
+    from penroz_tpu.ops.pallas import flash_attention as FA
+    why = FA.btd_refusal(D, heads, kv_heads)
+    assert (why is None) == takes, why
+    if not takes and D in (64, 128, 256):
+        with pytest.raises(ValueError, match="flash_attention_btd"):
+            FA.flash_attention_btd(
+                jnp.zeros((1, 128, (heads + 2 * kv_heads) * D)),
+                heads=heads, kv_heads=kv_heads, interpret=True)
+
+
+def test_flash_plan_names_its_layout(caplog):
+    """The plan's counter carries the layout: ``layout=btd
+    heads_per_block=2 fused_qkv`` on the INFO line and in the span of a
+    cell-shaped call, ``layout=bhtd`` for a (B, H, T, D) caller."""
+    import logging
+    from penroz_tpu.ops.pallas import flash_attention as FA
+    from penroz_tpu.utils import tracing
+    plan = FA.plan_flash(1024, 1024, 64, 2, heads=12, layout="btd",
+                         fused_qkv=True)
+    assert (plan.layout, plan.heads_per_block, plan.heads_per_step,
+            plan.fused_qkv) == ("btd", 2, 2, True)
+    assert plan.resident and plan.fused_bwd and plan.q_rows == 1024
+    assert plan.describe().endswith("layout=btd heads_per_block=2 fused_qkv")
+    assert FA.plan_flash(1024, 1024, 128, 2, heads=8, group=4,
+                         layout="btd").heads_per_block == 1
+    old = FA.plan_flash(1024, 1024, 64, 2, heads=12)
+    assert old.layout == "bhtd" and old.describe().endswith("layout=bhtd")
+
+    qkv = jnp.zeros((1, 256, 3 * 2 * 64), jnp.float32)
+    FA._log_plan.cache_clear()
+    tracing.reset()
+    trace = tracing.maybe_trace("flash-layout-job", job=True, route="/train/")
+    with caplog.at_level(logging.INFO, logger=FA.__name__), \
+            tracing.use(trace), tracing.span("penroz/train_dispatch"):
+        jax.jit(lambda x: FA.flash_attention_btd(
+            x, heads=2, interpret=True)).lower(qkv)
+    lines = [r.getMessage() for r in caplog.records
+             if r.getMessage().startswith("flash plan:")]
+    assert len(lines) == 1 and lines[0].endswith(
+        "layout=btd heads_per_block=2 fused_qkv"), lines
+    span = trace.to_dict()["root"]["children"][0]["children"][0]
+    assert span["name"] == "penroz/flash_plan"
+    assert span["meta"]["layout"] == "btd"
+    assert span["meta"]["heads_per_block"] == 2
+    assert span["meta"]["fused_qkv"] is True
+    trace.finish("completed")
+
+
+def _interpreted_kernels(monkeypatch, calls):
+    """Both flash entries in interpret mode, each call noted by layout: what
+    ``platform="tpu"`` dispatches to on a CPU."""
+    from penroz_tpu.ops.pallas import flash_attention as FA
+    real = {name: getattr(FA, name)
+            for name in ("flash_attention", "flash_attention_btd")}
+
+    def entry(name, layout):
+        def run(*args, **kwargs):
+            calls.append(layout)
+            return real[name](*args, interpret=True, **kwargs)
+        return run
+
+    monkeypatch.setattr(FA, "flash_attention", entry("flash_attention",
+                                                     "bhtd"))
+    monkeypatch.setattr(FA, "flash_attention_btd",
+                        entry("flash_attention_btd", "btd"))
+
+
+_MODULE_CASES = {
+    # GPT-2: nothing between the projection and the kernels
+    "fused": (dict(num_heads=4), 64),
+    "alibi_window": (dict(num_heads=4, alibi=True, sliding_window=100), 64),
+    # RoPE and qk-norm run on (B, T, H, D) views, then three arrays
+    "rope_gqa": (dict(num_heads=4, num_kv_heads=2, rope_theta=1e4), 128),
+    "partial_rope": (dict(num_heads=2, rope_theta=1e4, rope_pct=0.5), 128),
+    "qk_norm_head": (dict(num_heads=2, qk_norm=True, head_dim=128,
+                          rope_theta=1e4), 128),
+    "qk_norm_flat": (dict(num_heads=4, qk_norm=True, head_dim=64,
+                          qk_norm_scope="flat"), 64),
+}
+
+
+@pytest.mark.parametrize("case", list(_MODULE_CASES))
+def test_attention_module_paths_agree(case, monkeypatch):
+    """``CausalSelfAttention`` on the same weights through the model's own
+    layout and through the (B, H, T, D) path it had (PENROZ_DISABLE_FLASH
+    aside, a model-axis mesh is what sends a module there): forward and
+    the projection's gradient."""
+    from penroz_tpu.ops import modules as M
+    kwargs, D = _MODULE_CASES[case]
+    mod = M.CausalSelfAttention(**kwargs)
+    mod.bind("attn")
+    heads, kv_heads = mod.num_heads, mod.num_kv_heads
+    rng = np.random.default_rng(len(case))
+    params = {key: jnp.asarray(1.0 + 0.1 * rng.normal(size=shape),
+                               jnp.float32)
+              for key, shape in ((mod.key(n), s)
+                                 for n, s in mod.param_shapes().items())}
+    B, T = 2, 256
+    qkv = jnp.asarray(rng.normal(size=(B, T, (heads + 2 * kv_heads) * D))
+                      .astype(np.float32))
+    w = jnp.asarray(rng.normal(size=(B, T, heads * D)).astype(np.float32))
+    calls = []
+    _interpreted_kernels(monkeypatch, calls)
+
+    def run(qkv, stay):
+        monkeypatch.setattr(A, "stays_in_model_layout",
+                            lambda *a, **k: stay)
+        return mod.apply(qkv, M.Ctx(params, platform="tpu"))
+
+    new, old = run(qkv, True), run(qkv, False)
+    assert calls == ["btd", "bhtd"]
+    assert new.shape == (B, T, heads * D)
+    np.testing.assert_allclose(np.asarray(new), np.asarray(old), atol=2e-5)
+    g_new = jax.grad(lambda x: (run(x, True) * w).sum())(qkv)
+    g_old = jax.grad(lambda x: (run(x, False) * w).sum())(qkv)
+    _grad_close(_btd_parts(g_new, heads, kv_heads, D),
+                _btd_parts(g_old, heads, kv_heads, D))
+
+
+def test_attention_module_dropout_draws_the_same_mask_on_both_paths(
+        monkeypatch):
+    from penroz_tpu.ops import modules as M
+    mod = M.CausalSelfAttention(num_heads=4, dropout=0.25)
+    qkv = jnp.asarray(np.random.default_rng(0).normal(size=(2, 128, 3 * 256))
+                      .astype(np.float32))
+    calls = []
+    _interpreted_kernels(monkeypatch, calls)
+    outs = []
+    for stay in (True, False):
+        monkeypatch.setattr(A, "stays_in_model_layout",
+                            lambda *a, **k: stay)
+        outs.append(mod.apply(qkv, M.Ctx({}, platform="tpu", training=True,
+                                         rng=jax.random.key(7))))
+    assert calls == ["btd", "bhtd"]
+    np.testing.assert_allclose(np.asarray(outs[0]), np.asarray(outs[1]),
+                               atol=2e-5)
+    still = mod.apply(qkv, M.Ctx({}, platform="tpu"))
+    assert float(jnp.abs(outs[0] - still).max()) > 0.01
+
+
+@pytest.mark.parametrize("why", ["none", "cache", "sp_mesh", "sp_axis",
+                                 "softcap", "odd_heads", "gqa_d64",
+                                 "model_mesh", "data_mesh", "cpu",
+                                 "short"])
+def test_attention_module_falls_back_to_the_old_path(why, monkeypatch,
+                                                     cpu_devices):
+    """Each condition the module observes, one at a time: ``none`` and a
+    ``data``-only mesh stay in the model's layout, everything else takes
+    the path it took before — and a module the kernels would serve that
+    still falls back says why, once."""
+    import logging
+    from penroz_tpu.ops import kv_cache as KV
+    from penroz_tpu.ops import modules as M
+    from penroz_tpu.parallel import mesh as mesh_lib
+    heads, kv_heads, D, T = 4, 4, 64, 128
+    kwargs, ctx_kwargs, warns = {}, {"platform": "tpu"}, None
+    if why == "cache":
+        ctx_kwargs["kv"] = KV.KVState.create([(kv_heads, D)], batch=1,
+                                             max_len=T)
+    elif why == "sp_mesh":
+        ctx_kwargs["sp_mesh"] = mesh_lib.make_mesh(cpu_devices[:2],
+                                                   sequence=2)
+    elif why == "sp_axis":
+        ctx_kwargs["sp_manual_axis"] = "sequence"
+    elif why == "softcap":
+        kwargs["logit_softcap"] = 30.0    # causal_attention's own warning
+    elif why == "odd_heads":
+        heads = kv_heads = 3
+        warns = "whole 128-lane blocks"
+    elif why == "gqa_d64":
+        kv_heads, warns = 2, "grouped-query"
+    elif why == "model_mesh":
+        ctx_kwargs["platform"] = A.Placement("tpu", mesh_lib.make_mesh(
+            cpu_devices[:2], model=2))
+        warns = "model axis"
+    elif why == "data_mesh":
+        ctx_kwargs["platform"] = A.Placement("tpu", mesh_lib.make_mesh(
+            cpu_devices[:2], model=1))
+    elif why == "cpu":
+        ctx_kwargs["platform"] = "cpu"
+    elif why == "short":
+        T = 64
+    mod = M.CausalSelfAttention(num_heads=heads, num_kv_heads=kv_heads,
+                                **kwargs)
+    mod.bind("attn")
+    taken = []
+    monkeypatch.setattr(mod, "_apply_in_model_layout",
+                        lambda qkv, ctx, head_dim: taken.append(head_dim))
+    # the old path's branches are not run here, only chosen
+    for name in ("causal_attention", "cached_attention"):
+        monkeypatch.setattr(A, name, lambda q, *a, **k: q)
+    from penroz_tpu.parallel import ring_attention as ring
+    monkeypatch.setattr(ring, "ring_attention", lambda q, *a, **k: q)
+    monkeypatch.setattr(ring, "ring_attention_manual", lambda q, *a, **k: q)
+    monkeypatch.setattr(A, "_WARNED_ONCE", set())
+    warned = []
+    monkeypatch.setattr(logging.getLogger("penroz_tpu.ops.attention"),
+                        "warning", lambda msg, *a: warned.append(msg % a))
+    qkv = jnp.zeros((1, T, (heads + 2 * kv_heads) * D), jnp.float32)
+    apply = lambda x: mod.apply(x, M.Ctx({}, **ctx_kwargs))
+    if why == "sp_axis":    # the old path asks the bound axis for its size
+        from jax.sharding import PartitionSpec as P
+        apply = jax.shard_map(
+            apply, mesh=mesh_lib.make_mesh(cpu_devices[:2], sequence=2),
+            in_specs=P(), out_specs=P(), check_vma=False)
+    for _ in range(2):
+        apply(qkv)
+    assert taken == ([D, D] if why in ("none", "data_mesh") else [])
+    if warns is None:
+        assert warned == []
+    else:
+        assert len(warned) == 1 and warns in warned[0], warned
+        assert "leaves the (B, T, H·D) layout" in warned[0]
+
+
+def test_attention_module_stays_in_model_layout_under_a_data_mesh(
+        monkeypatch, cpu_devices):
+    """A ``data``-only mesh splits the batch of the fused projection and
+    each shard runs the ``btd`` kernels on its rows (``_on_shards``): the
+    same output and gradient as on one device."""
+    from penroz_tpu.ops import modules as M
+    from penroz_tpu.parallel import mesh as mesh_lib
+    mesh = mesh_lib.make_mesh(cpu_devices[:2], model=1)
+    mod = M.CausalSelfAttention(num_heads=2)
+    rng = np.random.default_rng(5)
+    qkv = jnp.asarray(rng.normal(size=(2, 128, 3 * 128)).astype(np.float32))
+    w = jnp.asarray(rng.normal(size=(2, 128, 128)).astype(np.float32))
+    calls = []
+    _interpreted_kernels(monkeypatch, calls)
+
+    def loss(platform):
+        return lambda x: (mod.apply(x, M.Ctx({}, platform=platform))
+                          * w).sum()
+
+    want = jax.value_and_grad(loss("tpu"))(qkv)
+    got = jax.jit(jax.value_and_grad(loss(A.Placement("tpu", mesh))))(qkv)
+    assert calls and set(calls) == {"btd"}
+    np.testing.assert_allclose(np.asarray(got[0]), np.asarray(want[0]),
+                               rtol=1e-5)
+    np.testing.assert_allclose(np.asarray(got[1]), np.asarray(want[1]),
+                               atol=2e-5)

@@ -82,6 +82,20 @@ def _flash_bwd(q=(ROWS, HEADS, BLOCK, HEAD_DIM), kv=None, **kwargs):
     return jax.grad(loss, argnums=(0, 1, 2)), shapes
 
 
+def _flash_btd(backward, shape, **kwargs):
+    """``flash_attention_btd`` on the fused ``(B, T, (Hq + 2·Hkv)·D)``
+    projection, or its gradient."""
+    from penroz_tpu.ops.pallas import flash_attention as fa
+
+    def attend(qkv):
+        return fa.flash_attention_btd(qkv, **kwargs)
+
+    def loss(qkv):
+        return attend(qkv).astype(jnp.float32).sum()
+
+    return (jax.grad(loss) if backward else attend), [(shape, jnp.bfloat16)]
+
+
 def _decode(quantized, dtype=jnp.bfloat16):
     from penroz_tpu.ops.pallas import decode_attention as da
     kv_dtype = jnp.int8 if quantized else dtype
@@ -162,6 +176,27 @@ CASES = {
     # the chunked kernels a long S falls to, with a window's clamped walks
     "flash_fwd_chunked": lambda: _flash_fwd(window=700, vmem_budget=2 ** 20),
     "flash_bwd_chunked": lambda: _flash_bwd(window=700, vmem_budget=2 ** 20),
+    # the (B, T, lanes) layout.  The cell's own call: the fused projection,
+    # two D = 64 heads a lane block
+    "flash_btd_fwd_cell": lambda: _flash_btd(
+        False, (CELL_ROWS, BLOCK, 3 * HEADS * HEAD_DIM), heads=HEADS),
+    "flash_btd_bwd_cell": lambda: _flash_btd(
+        True, (CELL_ROWS, BLOCK, 3 * HEADS * HEAD_DIM), heads=HEADS),
+    # D = 128, 4 query heads a K/V head: one head a block, any group
+    "flash_btd_fwd_d128_gqa": lambda: _flash_btd(
+        False, (2, BLOCK, 12 * 128), heads=8, kv_heads=2),
+    "flash_btd_bwd_d128_gqa": lambda: _flash_btd(
+        True, (2, BLOCK, 12 * 128), heads=8, kv_heads=2),
+    # … at T = 4096: resident forward on part of the queries, split backward
+    "flash_btd_bwd_t4096_gqa": lambda: _flash_btd(
+        True, (2, 4096, 12 * 128), heads=8, kv_heads=2),
+    # the chunked kernels with head pairs, a window's clamped walks
+    "flash_btd_fwd_chunked": lambda: _flash_btd(
+        False, (ROWS, BLOCK, 3 * HEADS * HEAD_DIM), heads=HEADS, window=700,
+        vmem_budget=2 ** 20),
+    "flash_btd_bwd_chunked": lambda: _flash_btd(
+        True, (ROWS, BLOCK, 3 * HEADS * HEAD_DIM), heads=HEADS, window=700,
+        vmem_budget=2 ** 20),
     "decode_bf16": lambda: _decode(False),
     "decode_int8": lambda: _decode(True),
     "paged_bf16_page128": lambda: _paged(128, False),
@@ -194,6 +229,21 @@ def test_kernel_compiles_for_v5e(chip, name):
         f"{name}: no Mosaic custom call in the compiled program"
 
 
+def test_btd_group_sum_adds_lane_ranges(chip):
+    """dK and dV of grouped heads in the ``(B, T, lanes)`` layout: the sum
+    over each K/V head's group is adds of 128-lane ranges inside fusions.
+    No array splits the lanes into ``(group, D)`` — that reshape relays the
+    whole per-query-head dK and dV out (0.29 of a 3.8 ms layer on the chip,
+    PERF.md §6, PR 32) — and nothing is reshaped, copied or transposed."""
+    fn, shapes = CASES["flash_btd_bwd_d128_gqa"]()
+    hlo = jax.jit(fn).lower(*(
+        jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+        for shape, dtype in shapes)).compile().as_text()
+    assert "[2,1024,2,4,128]" not in hlo
+    for op in (" reshape(", " copy(", " transpose("):
+        assert op not in hlo, op
+
+
 # -- the same kernels inside a program GSPMD partitions over four chips -------
 #
 # Mosaic refuses a kernel call under jit over more than one device ("cannot
@@ -223,6 +273,20 @@ def _mesh_train_cell(hint):
     return _mesh_train(hint, CELL_ROWS)
 
 
+def _mesh_train_btd(hint):
+    """The cell's attention in the model's own layout over ``data=4``: the
+    fused projection's batch split, 3 rows a chip, forward and backward."""
+    from penroz_tpu.ops import attention as A
+    qkv = ((CELL_ROWS, BLOCK, 3 * HEADS * HEAD_DIM), jnp.bfloat16, P("data"))
+
+    def loss(qkv):
+        return A.causal_attention_btd(
+            qkv, heads=HEADS, kv_heads=HEADS, platform=hint).astype(
+                jnp.float32).sum()
+
+    return jax.grad(loss), [qkv]
+
+
 def _mesh_ragged(hint):
     """The unified serving tick, heads split over ``model``."""
     from penroz_tpu.ops import attention as A
@@ -235,6 +299,7 @@ def _mesh_ragged(hint):
 
 @pytest.mark.parametrize("case,axes", [(_mesh_train, {"data": 4}),
                                        (_mesh_train_cell, {"data": 4}),
+                                       (_mesh_train_btd, {"data": 4}),
                                        (_mesh_train, {"model": 2}),
                                        (_mesh_ragged, {"model": 4})])
 def test_kernels_compile_partitioned_for_v5e(chips, case, axes):
@@ -296,13 +361,121 @@ def test_embedding_backward_under_a_mesh_gathers_rows_not_tables(chips):
     assert "all-reduce" not in hlo and "reduce-scatter" not in hlo
 
 
+def _custom_calls(hlo: str) -> list:
+    """(instruction name, result types) of every Mosaic custom call."""
+    calls = []
+    for line in hlo.splitlines():
+        head, call, _ = line.strip().partition(" custom-call(")
+        if call and "tpu_custom_call" in line:
+            calls.append(head.removeprefix("ROOT ").partition(" = ")[::2])
+    return calls
+
+
+@pytest.fixture(scope="module")
+def attention_block_hlo(chip):
+    """The gradient of one attention block of the cell —
+    ``CausalSelfAttention`` on the fused ``(12, 1024, 2304)`` projection,
+    then a projection matmul — compiled for a v5e."""
+    from penroz_tpu.ops import modules as M
+    width = HEADS * HEAD_DIM
+    attn = M.CausalSelfAttention(num_heads=HEADS)
+    attn.bind("attn")
+    qkv = jax.ShapeDtypeStruct((CELL_ROWS, BLOCK, 3 * width), jnp.bfloat16,
+                               sharding=chip)
+    w = jax.ShapeDtypeStruct((width, width), jnp.bfloat16, sharding=chip)
+
+    def loss(qkv, w):
+        out = attn.apply(qkv, M.Ctx({}, platform="tpu"))
+        return (out @ w).astype(jnp.float32).sum()
+
+    return jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        qkv, w).compile().as_text()
+
+
+def test_attention_block_gradient_stays_in_the_models_layout(
+        attention_block_hlo):
+    """The mechanism of PR 32, checked without a chip: the compiled
+    gradient of the cell's attention block holds the flash kernels under
+    their names (``penroz_flash_fwd``, ``penroz_flash_bwd_delta``,
+    ``penroz_flash_bwd``) with ``(B, T, H·D)`` results, the fused
+    projection's cotangent, and **no** head-major array: nothing
+    ``bf16[12,12,1024,64]`` (a transposed q, k, v, o or their cotangents,
+    stored padded to 128 lanes) and no ``f32[12,12,1024,1]`` logsumexp
+    anywhere in the program."""
+    import re
+    hlo = attention_block_hlo
+    calls = _custom_calls(hlo)
+    out = r"bf16\[12,1024,768\]"
+    fwd = [c for c in calls if "penroz_flash_fwd" in c[0]]
+    bwd = [c for c in calls if "penroz_flash_bwd" in c[0]]
+    assert len(fwd) == 1 and re.search(out + r".*f32\[12,6,2,1024\]",
+                                       fwd[0][1]), calls
+    # the backward: its δ rows, then one pass that returns dq, dk, dv
+    assert [len(re.findall(out, c[1])) for c in bwd] == [0, 3], calls
+    assert re.search(r"f32\[12,6,2,1024\]", bwd[0][1]), calls
+    assert len(calls) == 3, calls
+    assert re.search(r"bf16\[12,1024,2304\]", hlo)    # the fused cotangent
+    assert not re.search(r"bf16\[12,12,1024,64\]", hlo)
+    assert not re.search(r"f32\[12,12,1024,1\]", hlo)
+    assert " transpose(" not in hlo
+
+
+def test_cell_kernels_are_where_the_benchmark_looks_for_them(
+        attention_block_hlo):
+    """``benchmark/metrics/penroz_flash_roofline.py`` — the reader itself,
+    loaded from its file — finds the cell's kernels in a device trace made
+    of this program's instructions (a trace event carries its HLO
+    instruction's text): the forward and both backward calls, by name.
+    Renaming a call, or losing the name on the way to the HLO, blinds the
+    benchmark and fails here, on the CPU.  The old reader,
+    ``flash_roofline_pct``, matches head-major result shapes and finds
+    nothing in this program (the test below pins what it does find)."""
+    import importlib.util
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    sys.path.insert(0, root)
+    try:
+        readers = {}
+        for name in ("penroz_flash_roofline", "flash_roofline_pct"):
+            spec = importlib.util.spec_from_file_location(
+                "bench_metric_" + name,
+                os.path.join(root, "benchmark", "metrics", name + ".py"))
+            readers[name] = importlib.util.module_from_spec(spec)
+            spec.loader.exec_module(readers[name])
+        from benchmark.lib import kernel_costs, peaks
+    finally:
+        sys.path.remove(root)
+    lines = [line.strip().removeprefix("ROOT ")
+             for line in attention_block_hlo.splitlines()
+             if " custom-call(" in line and "tpu_custom_call" in line]
+    # one millisecond an event, back to back
+    ops = [(line, i * 1e-3, (i + 1) * 1e-3) for i, line in enumerate(lines)]
+    v5e = peaks.peaks_for("TPU v5 lite")
+    art = {"kind": "train", "peaks": v5e,
+           "dims": {"d": HEADS * HEAD_DIM, "heads": HEADS},
+           "job": {"batch_size": CELL_ROWS, "block_size": BLOCK},
+           "trace": {"planes": {"devices": {0: {"ops": ops}}, "spans": []},
+                     "w0": 0.0, "w1": 1.0}}
+    cost = kernel_costs.flash_attention(CELL_ROWS, HEADS, BLOCK, HEAD_DIM, 2)
+    least = sum(kernel_costs.roofline_seconds(cost[k], v5e)[0]
+                for k in ("fwd", "bwd"))
+    assert len(ops) == 3
+    assert readers["penroz_flash_roofline"].read(art) == pytest.approx(
+        100.0 * least / 3e-3)
+    assert readers["flash_roofline_pct"].read(art) is None
+
+
 def test_flash_kernels_are_where_the_benchmark_looks_for_them(chip):
-    """``benchmark/metrics/flash_roofline_pct.py`` finds the flash kernels in
-    a device trace by what their HLO instructions look like, not by a name:
-    a custom call under a ``jvp`` name stack whose results are the output
-    then the logsumexp, and custom call(s) under ``transpose(jvp`` with a
-    result of the output's shape.  A kernel change that would blind that
-    reader fails here, on the CPU, at the cell's own shape."""
+    """Pins the ``(B, H, T, D)`` entry, which sequence parallelism and
+    modules that fall back still call (the cell's own kernels are pinned by
+    ``test_cell_kernels_are_where_the_benchmark_looks_for_them``).
+    ``benchmark/metrics/flash_roofline_pct.py`` finds these in a device
+    trace by what their HLO instructions look like: a custom call under a
+    ``jvp`` name stack whose results are the output then the logsumexp,
+    and custom call(s) under ``transpose(jvp`` with a result of the
+    output's shape; the calls' own names (``penroz_flash_fwd`` /
+    ``penroz_flash_bwd``) follow that prefix.  A change to this entry that
+    would blind that reader fails here, on the CPU, at the cell's shape."""
     import re
     from penroz_tpu.ops import attention as A
     shape = (CELL_ROWS, HEADS, BLOCK, HEAD_DIM)
@@ -314,11 +487,7 @@ def test_flash_kernels_are_where_the_benchmark_looks_for_them(chip):
 
     hlo = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
         qkv, qkv, qkv).compile().as_text()
-    calls = []      # (instruction name, result types) of every custom call
-    for line in hlo.splitlines():
-        head, call, _ = line.strip().partition(" custom-call(")
-        if call and "tpu_custom_call" in line:
-            calls.append(head.removeprefix("ROOT ").partition(" = ")[::2])
+    calls = _custom_calls(hlo)
     # the reader's own patterns (kernel_time: search on name and result)
     out = r"bf16\[12,12,1024,64\]"
     lse = r"f32\[12,12,1024,1\]"
@@ -326,6 +495,7 @@ def test_flash_kernels_are_where_the_benchmark_looks_for_them(chip):
            and re.search(out + ".*" + lse, c[1])]
     bwd = [c for c in calls if re.search(r"^%transpose_jvp_", c[0])
            and re.search(out, c[1])]
-    assert len(fwd) == 1, calls
+    assert len(fwd) == 1 and "penroz_flash_fwd" in fwd[0][0], calls
     assert 1 <= len(bwd) <= 2, calls
+    assert all("penroz_flash_bwd" in c[0] for c in bwd), calls
     assert len(calls) == len(fwd) + len(bwd), calls

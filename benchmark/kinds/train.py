@@ -9,11 +9,18 @@ train start and the first save's cold costs are then behind); the window is
 the whole save cycles that complete within ``--seconds`` (``lib/cycles.py``).
 
 ``train_tokens_per_s`` is the tokens of those cycles over their wall time
-*less the time inside the periodic saves*: the whole-cycle rate, stall
-included, is printed beside it (``whole_cycle_tokens_per_s``) and is what a
-user feels, but one save in two takes 2.3 s longer than the other on the
-chip's host (PERF.md, PR 24), which two cycles to a window cannot average
-out; the stall is the per-layer ``ckpt_stall_pct`` until it repeats.
+*less the time inside the periodic saves*: every step of the whole cycles
+and all the time between their saves.  Two readers split it
+(``lib/cycles.py``): the median step that ran back to back
+(``train_step_ms``, which repeats to the fourth digit) and what a cycle's
+steps and bookkeeping took beyond that pace (``save_edge_ms``: mostly one
+step a cycle that waits for the flush thread of the save before it, 0 to
+0.74 s by the host of the run, which is the whole of the rate's spread;
+PERF.md sections 2 and 5).  The whole-cycle rate, stall included, is printed
+beside it (``whole_cycle_tokens_per_s``) and is what a user feels, but a
+step more or less to a cycle and a save 2.3 s longer than the other move it
+by 6 %, which two or three cycles to a window cannot average out; the stall
+is the per-layer ``ckpt_stall_pct`` until it repeats.
 """
 
 from __future__ import annotations
@@ -22,6 +29,7 @@ import math
 import os
 import shutil
 import time
+from statistics import median
 
 import numpy as np
 
@@ -170,19 +178,29 @@ def run(ctx) -> dict:
         saves = list(spy.saves)
         tokens = cycles.tokens_in(epochs, window.t0, window.t1)
         seconds = window.t1 - window.t0
-        stall = cycles.stall_seconds(
-            [(a, b) for a, b, periodic in saves if periodic],
-            window.t0, window.t1)
+        periodic = [(a, b) for a, b, is_periodic in saves if is_periodic]
+        stall = cycles.stall_seconds(periodic, window.t0, window.t1)
+        steady = cycles.steady_steps([t for t, _ in epochs], periodic,
+                                     window.t0, window.t1)
         say(phase="window", cycles=window.cycles, seconds=seconds,
             memory=memory[-1],
             tokens=tokens, overran=window.overran, stall_seconds=stall,
             whole_cycle_tokens_per_s=tokens / seconds,
+            steady_steps=len(steady),
+            steady_tokens_per_s=(epochs[0][1] / median(steady)
+                                 if steady else None),
             epochs_in_window=sum(window.t0 < t <= window.t1
                                  for t, _ in epochs),
-            save_seconds=[round(b - a, 3) for a, b, p in saves if p],
+            save_seconds=[round(b - a, 3) for a, b in periodic],
             progress_status=prog["status"].get("code"),
             progress_first_cost=recorded[0] if recorded else None,
             spy_first_cost=spy.costs[0])
+        # every event the arithmetic above read, seconds from the opening:
+        # tools/cycle_table.py recomputes a cycle's parts from a kept line
+        say(phase="timeline", opened_at_s=window.t0 - ctx["t_start"],
+            epoch_ends=[round(t - window.t0, 4) for t, _ in epochs],
+            saves=[[round(a - window.t0, 4), round(b - window.t0, 4),
+                    is_periodic] for a, b, is_periodic in saves])
         verdict = compare_with_reference(ctx, spy)
     finally:
         program.delete_model(svc, MODEL)
@@ -195,8 +213,7 @@ def run(ctx) -> dict:
         "attempted": len(epochs), "failed": 0,
         "end_to_end": {"train_tokens_per_s": tokens / (seconds - stall),
                        "setup_s": setup_s},
-        "window": window, "epochs": epochs,
-        "saves": [(a, b) for a, b, periodic in saves if periodic],
+        "window": window, "epochs": epochs, "saves": periodic,
         "flops_per_token": kernel_costs.model_flops_per_token(
             kernel_costs.gpt2_matmul_params(d["d"], d["depth"], d["vocab"]),
             d["depth"], d["d"], job["block_size"]),

@@ -13,9 +13,9 @@ from __future__ import annotations
 
 def model_flops_per_token(n_matmul_params: int, depth: int, d_model: int,
                           seq: int) -> float:
-    """Forward + backward FLOPs per trained token, nanoGPT/PaLM accounting
-    (as ``bench.py::_flops_per_token``): 6 per matmul parameter — embedding
-    look-ups do not count — plus 12·L·d·T for the attention scores."""
+    """Forward + backward FLOPs per trained token, nanoGPT/PaLM accounting:
+    6 per matmul parameter — embedding look-ups do not count — plus 12·L·d·T
+    for the attention scores."""
     return 6.0 * n_matmul_params + 12.0 * depth * d_model * seq
 
 

@@ -3,9 +3,16 @@ their roofline in the traced training epochs.  Least time the chip could
 take (``lib/kernel_costs.py::flash_attention`` at the shapes the kernels
 really get: micro-batch x heads x block x head size, bf16, causal; the
 larger of FLOPs / peak and bytes / peak bytes/s, here compute) over the
-device time of the kernels' events.  The program gives its kernels no names
-yet, so the events are told by the name stack they carry (``jvp`` forward,
-``transpose_jvp`` the two backward kernels) and their result shapes."""
+device time of the kernels' events, the events told by the name stack they
+carry (``jvp`` forward, ``transpose_jvp`` the backward kernels) and their
+head-major result shapes.
+
+Retired in PR 37: no entry of ``BENCHMARK.json`` names it and no run loads
+it.  Since PR 32 the cell's kernels work in ``(B, T, H·D)`` and it finds
+nothing there; ``penroz_flash_roofline`` reads them by name in either
+layout.  The file stays only because ``tests/test_tpu_compile.py`` loads it
+to assert just that, and a ``benchmark`` PR may not touch ``tests/``: it
+goes with that half of the test (PERF.md section 7)."""
 
 from benchmark.lib import kernel_costs, trace_reduce
 

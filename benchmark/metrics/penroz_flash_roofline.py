@@ -3,8 +3,9 @@ their roofline in the traced training epochs, with the kernels found by the
 names the program gives them (``pallas_call(name=)``: ``penroz_flash_fwd``;
 ``penroz_flash_bwd``, ``penroz_flash_bwd_dq`` / ``_dkv``, ``penroz_flash_
 bwd_delta``), whatever layout their operands and results have.  Least time
-as ``flash_roofline_pct`` counts it (``lib/kernel_costs.py::
-flash_attention``: micro-batch x heads x block x head size, bf16, causal;
+the chip could take (``lib/kernel_costs.py::flash_attention`` at the shapes
+the kernels really get: micro-batch x heads x block x head size, bf16,
+causal; the larger of FLOPs / peak and bytes / peak bytes/s, here compute;
 the backward's bytes include O and dO, so the δ kernel's time belongs to
 it) over the device time of every event so named.  A program that names no
 such kernel (before PR 32) gives nothing to read."""

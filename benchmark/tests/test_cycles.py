@@ -2,7 +2,8 @@
 
 import pytest
 
-from benchmark.lib import cycles
+from benchmark.lib import cycles, kernel_costs, peaks
+from benchmark.run import metric_reader
 
 
 def test_no_cycle_yet():
@@ -72,8 +73,8 @@ def test_steady_steps_leave_out_the_gap_a_save_fills():
 
 
 def test_rate_between_saves_ignores_how_long_a_save_took():
-    """What ``train_tokens_per_s`` is until the save repeats: tokens of the
-    whole cycles over their wall time less the time inside the saves."""
+    """``train_tokens_per_s``: tokens of the whole cycles over their wall
+    time less the time inside the saves."""
     def rates(slow_save):
         saves = [(90.0, 95.0), (107.0, 112.0),
                  (124.0, 129.0 + slow_save)]
@@ -89,3 +90,133 @@ def test_rate_between_saves_ignores_how_long_a_save_took():
     assert whole_slow < 0.95 * whole_fast            # the two-valued rate
     assert between_slow == pytest.approx(between_fast)
     assert between_fast == pytest.approx(1800 / 24.0)
+
+
+# -- the rate between saves, split into its two readers (PR 37) ---------------
+
+TOKENS = 147456          # one optimizer step of gpt2s-train-1chip
+STEP = 0.99              # its steady step, seconds
+FLOPS = kernel_costs.model_flops_per_token(
+    kernel_costs.gpt2_matmul_params(768, 12, 50304), 12, 768, 1024)
+V5E = peaks.peaks_for("TPU v5 lite")
+
+
+def artifact(cycle_specs):
+    """What ``kinds/train.py`` hands the readers, for a made-up run.  The
+    warm-up's save ends at 100 s; each cycle is ``steps`` optimizer steps of
+    ``STEP`` seconds (step ``i`` longer by ``slow[i]``), ``before`` seconds
+    of bookkeeping and a ``save``."""
+    t, ends, saves = 100.0, [], [(95.0, 100.0)]
+    for spec in cycle_specs:
+        for i in range(spec.get("steps", 11)):
+            t += STEP + spec.get("slow", {}).get(i, 0.0)
+            ends.append(t)
+        start = t + spec.get("before", 0.0)
+        t = start + spec.get("save", 5.0)
+        saves.append((start, t))
+    return {"kind": "train", "epochs": [(e, TOKENS) for e in ends],
+            "saves": saves, "peaks": V5E,
+            "device": {"count": 1}, "flops_per_token": FLOPS,
+            "window": cycles.Window(100.0, t, len(cycle_specs), False)}
+
+
+def readings(art):
+    w = art["window"]
+    tokens = cycles.tokens_in(art["epochs"], w.t0, w.t1)
+    stall = cycles.stall_seconds(art["saves"], w.t0, w.t1)
+    out = {name: metric_reader(name)(art) for name in (
+        "train_step_ms", "train_mfu_pct", "save_edge_ms", "ckpt_stall_pct")}
+    # ``train_tokens_per_s`` as kinds/train.py computes it
+    out["rate"] = tokens / (w.t1 - w.t0 - stall)
+    return out
+
+
+PLAIN = [{}, {}, {}]
+STEADY = ("train_step_ms", "train_mfu_pct")
+
+
+@pytest.mark.parametrize("case, specs, moved, unmoved", [
+    ("a step that waits for the save's flush (the second of a cycle, on "
+     "the chip) is in the rate and in the save's edge, not in the median "
+     "step",
+     [{"slow": {1: 0.36}}] * 3,
+     {"save_edge_ms": 360.0, "rate": TOKENS * 11 / (11 * STEP + 0.36)},
+     STEADY),
+    ("so is a slow first step after a save, which no back-to-back step "
+     "measures",
+     [{"slow": {0: 0.3}}] * 3,
+     {"save_edge_ms": 300.0, "rate": TOKENS * 11 / (11 * STEP + 0.3)},
+     STEADY),
+    ("and the bookkeeping before a save",
+     [{"before": 0.12}] * 3,
+     {"save_edge_ms": 120.0, "rate": TOKENS * 11 / (11 * STEP + 0.12)},
+     STEADY),
+    ("the edge is the median over the window's cycles; the rate holds "
+     "every cycle's",
+     [{"slow": {0: 0.1}}, {"slow": {1: 0.45}, "before": 0.05},
+      {"slow": {0: 0.2}}],
+     {"save_edge_ms": 200.0, "rate": TOKENS * 33 / (33 * STEP + 0.8)},
+     STEADY),
+    ("one stalled step in thirty-three: the rate counts all the time it "
+     "took; the median step and the median cycle's edge do not see it",
+     [{}, {"slow": {5: 1.8}}, {}],
+     {"rate": TOKENS * 33 / (33 * STEP + 1.8)},
+     STEADY + ("save_edge_ms",)),
+    ("a slower save is the stall's alone",
+     [{}, {"save": 7.3}, {}],
+     {"ckpt_stall_pct": 100 * 17.3 / (33 * STEP + 17.3)},
+     STEADY + ("rate", "save_edge_ms")),
+    ("a step more or less to a cycle changes the stall's share only",
+     [{"steps": 10}, {}, {"steps": 12}], {},
+     STEADY + ("rate", "save_edge_ms", "ckpt_stall_pct")),
+])
+def test_what_moves_the_rate_the_median_step_and_the_edge(case, specs, moved,
+                                                          unmoved):
+    plain, got = readings(artifact(PLAIN)), readings(artifact(specs))
+    assert plain["rate"] == pytest.approx(TOKENS / STEP, rel=1e-12)
+    assert plain["save_edge_ms"] == pytest.approx(0.0, abs=1e-6)
+    for name, value in moved.items():
+        assert got[name] == pytest.approx(value, rel=1e-9, abs=1e-6), case
+        assert got[name] != pytest.approx(plain[name], rel=1e-4), case
+    for name in unmoved:
+        assert got[name] == pytest.approx(plain[name], rel=1e-9,
+                                          abs=1e-6), (case, name)
+
+
+def test_mfu_is_the_median_step_in_another_unit():
+    """``train_mfu_pct`` = tokens of a step / ``train_step_ms`` x FLOPs a
+    token / peak, to the last digit: both read one sample
+    (``cycles.steady_steps``), whatever the other steps did."""
+    for specs in (PLAIN, [{}, {"slow": {1: 0.4, 5: 1.8}}, {"before": 0.1}]):
+        got = readings(artifact(specs))
+        assert got["train_mfu_pct"] == pytest.approx(
+            100.0 * TOKENS / (got["train_step_ms"] / 1e3) * FLOPS / 197e12,
+            rel=1e-12)
+        assert got["train_step_ms"] == pytest.approx(1e3 * STEP, rel=1e-12)
+        assert 64.5 < got["train_mfu_pct"] < 64.7     # the cell's, PR 32 on
+
+
+def test_the_median_step_and_the_edges_account_for_the_rate():
+    """Time between saves = each cycle's steps x the steady step + its
+    edge, exactly, whatever ``step`` is: the two per-layer readers split
+    ``train_tokens_per_s`` and leave nothing out."""
+    art = artifact([{"slow": {0: 0.25}, "before": 0.1, "save": 4.7},
+                    {"steps": 12, "slow": {1: 0.2, 3: 0.5}}])
+    w = art["window"]
+    ends = [t for t, _ in art["epochs"]]
+    one, two = cycles.anatomy(ends, art["saves"], w.t0, w.t1)
+    assert (len(one.steps), len(two.steps)) == (11, 12)
+    assert one.steps[0] == pytest.approx(STEP + 0.25)   # taken from the save
+    assert one.before_save == pytest.approx(0.1)
+    assert one.stall == pytest.approx(4.7)
+    assert two.t0 == one.t1 and max(two.steps) == pytest.approx(STEP + 0.5)
+    for c in (one, two):
+        assert sum(c.steps) + c.before_save + c.stall == pytest.approx(
+            c.t1 - c.t0)
+    step = metric_reader("train_step_ms")(art) / 1e3
+    between = sum(len(c.steps) * step + cycles.save_edge(c, step)
+                  for c in (one, two))
+    assert readings(art)["rate"] == pytest.approx(23 * TOKENS / between,
+                                                  rel=1e-12)
+    assert cycles.save_edge(one, step) == pytest.approx(0.35)
+    assert cycles.save_edge(two, step) == pytest.approx(0.7)

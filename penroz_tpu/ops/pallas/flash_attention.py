@@ -23,6 +23,20 @@ Either way only tiles the band's edge crosses build a mask; tiles wholly
 inside it skip the iotas, the compare and the select.  The softmax scale is
 folded into the (block_q, D) query tile, off the (block_q, block_k) scores.
 
+A tile the diagonal crosses is half dead, and at T = 1024 with 512-tiles two
+of a head's three tiles are such.  Where tiles are square that tile starts
+on the diagonal, so which of its sub-blocks are live is known when the
+kernel is traced, and its body does them alone: each band of ``diag_grain``
+keys against the queries from the diagonal down, static slices of the loaded
+tile (:func:`_diag_bands`).  The tile stays the unit of the walk and of the
+online softmax — no more grid steps or loop iterations, every row's (m, l,
+acc) rescaled once a tile — which is why this gains where smaller tiles
+lost (CHANGES.md PR 26, PR 38).  The forward (resident and chunked: one
+function) and the one-pass backward do it, each at its own grain
+(``FlashPlan.diag_grain``, ``.bwd_diag_grain``); the two-kernel backward
+does its tiles whole.  ``FlashPlan.computed_over_live`` says what a walk
+computes over what the band holds.
+
 The backward recomputes probabilities from the forward's saved logsumexp:
 
 - **fused** (a head's operands and its f32 dQ fit in VMEM): one kernel, key
@@ -108,6 +122,13 @@ VMEM_BUDGET = 12 * 2 ** 20
 _FWD_TILE = (512, 512)
 _BWD_TILE = (512, 512)
 _STEP_SCORES = 2 ** 19
+# Sub-blocks a tile on the diagonal is cut into, forward and one-pass
+# backward (FlashPlan.diag_grain, .bwd_diag_grain): from the sweep on one v5e
+# at (12, 12, 1024, 64) and (4, 8 on 2, 1024, 128) bf16 causal, CHANGES.md
+# PR 38 — the backward's time is its matmuls and follows the area down to 128,
+# the forward pays more for a band than 128 keys save it.
+_DIAG_GRAIN = 256
+_BWD_DIAG_GRAIN = 128
 
 
 def _dot_precision(dtype):
@@ -199,19 +220,16 @@ def _upper(a, b):
         else jnp.maximum(a, b)
 
 
-def _ordered(lo, full_lo, full_hi, hi):
-    lo = _lower(lo, hi)
-    full_lo = _lower(_upper(full_lo, lo), hi)
-    full_hi = _lower(_upper(full_hi, full_lo), hi)
-    return lo, full_lo, full_hi, hi
+def _clamp(x, lo, hi):
+    return _lower(_upper(x, lo), hi)
 
 
 def key_tile_ranges(qi, block_q: int, block_k: int, num_k: int, causal: bool,
                     window):
     """``(lo, full_lo, full_hi, hi)`` for query tile ``qi``: key tiles
     ``[lo, hi)`` meet the band; of those ``[full_lo, full_hi)`` lie wholly
-    inside it and need no mask; ``[lo, full_lo)`` straddle the window's
-    left edge and ``[full_hi, hi)`` the diagonal."""
+    inside it and need no mask; ``[full_hi, hi)`` straddle the diagonal and
+    ``[lo, full_lo)`` the window's left edge alone."""
     if not causal:
         return 0, 0, num_k, num_k
     q0 = qi * block_q
@@ -223,7 +241,9 @@ def key_tile_ranges(qi, block_q: int, block_k: int, num_k: int, causal: bool,
     lo = _div(_upper(q0 - (window - 1), 0), block_k)    # (kj+1)·bk − 1 > q0 − w
     full_lo = _div(_upper(q1 - (window - 1), 0) + (block_k - 1),
                    block_k)                             # kj·bk > q1 − w
-    return _ordered(lo, full_lo, full_hi, hi)
+    lo = _lower(lo, hi)
+    full_hi = _clamp(full_hi, lo, hi)
+    return lo, _clamp(full_lo, lo, full_hi), full_hi, hi
 
 
 def query_tile_ranges(kj, block_q: int, block_k: int, num_q: int,
@@ -231,7 +251,7 @@ def query_tile_ranges(kj, block_q: int, block_k: int, num_q: int,
     """:func:`key_tile_ranges` seen from key tile ``kj``: query tiles
     ``[lo, hi)`` meet the band, ``[full_lo, full_hi)`` need no mask,
     ``[lo, full_lo)`` straddle the diagonal and ``[full_hi, hi)`` the
-    window's left edge."""
+    window's left edge alone."""
     if not causal:
         return 0, 0, num_q, num_q
     k0 = kj * block_k
@@ -242,7 +262,9 @@ def query_tile_ranges(kj, block_q: int, block_k: int, num_q: int,
         return _lower(lo, num_q), _lower(full_lo, num_q), num_q, num_q
     hi = _lower(_div(k1 + (window - 1), block_q) + 1, num_q)
     full_hi = _div(k0 + window, block_q)            # qi·bq + bq − 1 < k0 + w
-    return _ordered(lo, full_lo, full_hi, hi)
+    lo = _lower(lo, hi)
+    full_lo = _clamp(full_lo, lo, hi)
+    return lo, full_lo, _clamp(full_hi, full_lo, hi), hi
 
 
 def _positions(q0, k0, block_q: int, block_k: int, transposed: bool = False):
@@ -263,6 +285,62 @@ def _band_mask(q_pos, k_pos, window):
     return mask
 
 
+# What a walk tells a tile's body about the band's edge (None: the tile lies
+# wholly inside).  _DIAG: the diagonal crosses it, the window's left edge
+# perhaps too; where tiles are square it is the one tile of its row and
+# column that starts on the diagonal (q0 == k0), and its body may leave out
+# the sub-blocks above the diagonal (:func:`_diag_bands`).  _EDGE: any other
+# tile an edge crosses, masked as a whole.
+_EDGE, _DIAG = "edge", "diag"
+
+
+def _cuts_diagonal(edge, grain: int, block_q: int, block_k: int) -> bool:
+    """Whether a tile's body does the tile by its live sub-blocks (a plan's
+    grain is below the tile only where tiles are square; checked again here
+    because only then does the _DIAG tile start on the diagonal)."""
+    return edge == _DIAG and block_q == block_k and grain < block_q
+
+
+def _diag_bands(block: int, grain: int, window):
+    """The live part of a square ``block`` × ``block`` tile that starts on
+    the diagonal, in bands of ``grain`` keys: ``(c, hi)`` for each — keys
+    ``[c·grain, (c+1)·grain)`` of the tile meet its queries ``[c·grain,
+    hi·grain)``, from the sub-block on the diagonal down to the last the
+    window lets see them; what lies above the diagonal or wholly left of
+    the window is in no band.  All static: the tile's place on the
+    diagonal and the window are Python ints."""
+    n = block // grain
+    # sub-block (rq, c) is wholly left of the window from
+    # (rq − c − 1)·grain + 1 ≥ window on
+    reach = n if window is None else 1 + -(-(window - 1) // grain)
+    return [(c, min(c + reach, n)) for c in range(n)]
+
+
+def _grains(lo: int, hi: int, grain: int):
+    return slice(lo * grain, hi * grain)
+
+
+def computed_over_live(T: int, S: int, block_q: int, block_k: int,
+                       grain: int, causal: bool, window) -> float:
+    """Score elements the walk of one head computes ÷ those its band holds
+    (``T·S/2`` causal, ``T·window`` windowed, as :func:`plan_flash` counts a
+    band): 1 for a walk that computes nothing dead."""
+    if not causal:
+        return 1.0
+    num_k = S // block_k
+    on_diagonal = block_q * block_k
+    if _cuts_diagonal(_DIAG, grain, block_q, block_k):
+        on_diagonal = sum((hi - c) * grain * grain
+                          for c, hi in _diag_bands(block_q, grain, window))
+    computed = 0
+    for qi in range(T // block_q):
+        lo, _, full_hi, hi = key_tile_ranges(qi, block_q, block_k, num_k,
+                                             causal, window)
+        computed += ((full_hi - lo) * block_q * block_k
+                     + (hi - full_hi) * on_diagonal)
+    return computed / (T * S / 2 if window is None else T * min(window, S))
+
+
 # ---------------------------------------------------------------------------
 # the plan
 # ---------------------------------------------------------------------------
@@ -279,6 +357,14 @@ class FlashPlan:
     q_rows: int             # query rows of one forward grid step
     heads_per_step: int     # resident forward and fused backward
     fused_bwd: bool
+    # a square tile on the diagonal is done in sub-blocks this wide, the
+    # dead ones left out (_diag_bands), by the forward and by the one-pass
+    # backward; = the tile's size: every tile is done whole
+    diag_grain: int
+    bwd_diag_grain: int
+    # score elements computed ÷ live (computed_over_live), likewise
+    computed_over_live: float
+    bwd_computed_over_live: float
     layout: str = "bhtd"    # "bhtd" | "btd" (module docstring)
     heads_per_block: int = 1    # btd: heads a lane block holds
     fused_qkv: bool = False     # btd: q, k, v are ranges of one array
@@ -286,6 +372,10 @@ class FlashPlan:
     def describe(self) -> str:
         text = (f"bq={self.block_q} bk={self.block_k} "
                 f"bwd_bq={self.bwd_block_q} bwd_bk={self.bwd_block_k} "
+                f"diag_grain={self.diag_grain} "
+                f"bwd_diag_grain={self.bwd_diag_grain} "
+                f"computed_over_live={self.computed_over_live:.3f} "
+                f"bwd_computed_over_live={self.bwd_computed_over_live:.3f} "
                 f"{'resident' if self.resident else 'chunked'} "
                 f"q_rows={self.q_rows} "
                 f"{'fused_bwd' if self.fused_bwd else 'split_bwd'} "
@@ -315,7 +405,7 @@ def _bwd_fused_bytes(T, S, D, itemsize, heads, kv_heads, bq, bk):
               + 2 * heads * _padded(S, D, itemsize)             # dk, dv
               + 2 * heads * 8 * T * 4)                          # lse, δ rows
     scratch = (_padded(T, D, 4) + _padded(T, D, itemsize)       # dq, scaled q
-               + 2 * _padded(bk, D, 4))
+               + 8 * T * 4 + 2 * _padded(bk, D, 4))     # lse and δ; dk, dv
     return 2 * blocks + scratch + 6 * bq * bk * 4
 
 
@@ -323,6 +413,17 @@ def _heads_per_block(D: int) -> int:
     """Heads of size ``D`` a ``btd`` lane block — ``max(D, 128)`` lanes —
     holds."""
     return max(_LANES // D, 1)
+
+
+def _diag_grain(preferred: int, block_q: int, block_k: int,
+                cuts: bool) -> int:
+    """``FlashPlan.diag_grain`` of one direction's tiles: ``preferred``, or
+    the largest below it the tile is whole sub-blocks of, where the kernel
+    ``cuts`` the tile on the diagonal and tiles are square, so that this
+    tile starts on the diagonal; else the tile itself."""
+    if cuts and block_q == block_k:
+        return _largest_dividing_block(block_q, preferred)
+    return block_q
 
 
 def _kv_heads_per_step(heads_per_step: int, group: int) -> int:
@@ -390,10 +491,19 @@ def plan_flash(T: int, S: int, D: int, itemsize: int, causal: bool = True,
             if not fwd_fits(cand) or (fused_bwd and not bwd_fits(cand)):
                 break
             hps = cand
+    # the two-kernel backward does its tiles whole
+    grain = _diag_grain(_DIAG_GRAIN, fq, fk, causal)
+    bwd_grain = _diag_grain(_BWD_DIAG_GRAIN, gq, gk, causal and fused_bwd)
     return FlashPlan(block_q=fq, block_k=fk, bwd_block_q=gq, bwd_block_k=gk,
                      resident=resident, q_rows=q_rows,
                      heads_per_step=hps * heads_per_block,
-                     fused_bwd=fused_bwd, layout=layout,
+                     fused_bwd=fused_bwd, diag_grain=grain,
+                     bwd_diag_grain=bwd_grain,
+                     computed_over_live=computed_over_live(
+                         T, S, fq, fk, grain, causal, window),
+                     bwd_computed_over_live=computed_over_live(
+                         T, S, gq, gk, bwd_grain, causal, window),
+                     layout=layout,
                      heads_per_block=heads_per_block, fused_qkv=fused_qkv)
 
 
@@ -765,36 +875,68 @@ def _scores(q, k, q0, k0, slope, *, masked: bool, window, positions: bool,
 
 
 def _fwd_tile(q, k, v, m_scr, l_scr, acc_scr, q0, k0, slope, seed, *,
-              masked: bool, window, dropout_rate: float):
+              edge, grain: int, window, dropout_rate: float):
     """Online-softmax update of (m, l, acc) with one (block_q, block_k)
-    tile.  ``masked``: the band's edge crosses this tile.  ``slope``/
-    ``seed``: None without ALiBi / dropout."""
-    block_k = k.shape[0]
-    s, mask, pos = _scores(q, k, q0, k0, slope, masked=masked, window=window,
-                           positions=dropout_rate > 0.0)
+    tile.  ``edge``: where the band's edge crosses it (None, _EDGE, _DIAG).
+    ``slope``/``seed``: None without ALiBi / dropout.
+
+    A tile on the diagonal is done by its live sub-blocks: each band of
+    ``grain`` keys against the queries from the diagonal down
+    (:func:`_diag_bands`: the keys are the matmuls' stationary operand, so
+    every band streams many rows through few of them — bands of query rows
+    gained nothing, CHANGES.md PR 38).  The bands share one update: the row
+    maxima of all are taken before any is exponentiated, so each row's
+    (m, l, acc) is rescaled once, as by a whole tile."""
+    block_q, block_k = q.shape[0], k.shape[0]
+    if _cuts_diagonal(edge, grain, block_q, block_k):
+        bands = [(_grains(c, hi, grain), _grains(c, c + 1, grain), True)
+                 for c, hi in _diag_bands(block_q, grain, window)]
+    else:
+        bands = [(slice(0, block_q), slice(0, block_k), edge is not None)]
     # (m, l) stay lane-replicated (block_q, 128) tiles from scratch to
     # scratch: a (block_q,) or (block_q, 1) value costs a relayout at every
     # broadcast against the scores, and that — not the matmuls — was the
     # forward's time (CHANGES.md PR 26)
-    m_prev = m_scr[...]
-    l_prev = l_scr[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    m_prev = m_new = m_scr[...]
+    scored = []
+    for rows, cols, masked in bands:
+        s, mask, pos = _scores(
+            q[rows], k[cols], q0 + rows.start, k0 + cols.start, slope,
+            masked=masked, window=window, positions=dropout_rate > 0.0)
+        scored.append((s, mask, pos))
+        m_new = _on_rows(jnp.maximum, m_new, rows,
+                         jnp.max(s, axis=-1, keepdims=True))
     alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(s - _lanes(m_new, block_k))
-    if masked and window is not None:
-        # _NEG_INF is finite (-1e30): a row whose window lies entirely
-        # outside this tile has s == m_new == -1e30 and exp(s - m_new)
-        # would be 1, not 0 — zero masked entries explicitly.
-        p = jnp.where(mask, p, 0.0)
-    l_scr[...] = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
+    l = l_scr[...] * alpha
+    acc = acc_scr[...] * _lanes(alpha, acc_scr.shape[-1])
+    for (rows, cols, _), (s, mask, pos) in zip(bands, scored):
+        p = jnp.exp(s - _lanes(m_new[rows], s.shape[1]))
+        if mask is not None and window is not None:
+            # _NEG_INF is finite (-1e30): a row whose window lies entirely
+            # outside this tile has s == m_new == -1e30 and exp(s - m_new)
+            # would be 1, not 0 — zero masked entries explicitly.
+            p = jnp.where(mask, p, 0.0)
+        l = _on_rows(jnp.add, l, rows, jnp.sum(p, axis=-1, keepdims=True))
+        if dropout_rate > 0.0:
+            # l accumulates the *undropped* probabilities (dropout applies
+            # after softmax normalization); only the V-contraction drops.
+            keep = _keep_mask(*pos, seed, dropout_rate)
+            p = jnp.where(keep, p, 0.0) * (1.0 / (1.0 - dropout_rate))
+        acc = _on_rows(jnp.add, acc, rows,
+                       _dot(p.astype(v.dtype), v[cols], (1, 0)))
     m_scr[...] = m_new
-    if dropout_rate > 0.0:
-        # l accumulates the *undropped* probabilities (dropout applies
-        # after softmax normalization); only the V-contraction drops.
-        keep = _keep_mask(*pos, seed, dropout_rate)
-        p = jnp.where(keep, p, 0.0) * (1.0 / (1.0 - dropout_rate))
-    acc_scr[...] = (acc_scr[...] * _lanes(alpha, acc_scr.shape[-1])
-                    + _dot(p.astype(v.dtype), v, (1, 0)))
+    l_scr[...] = l
+    acc_scr[...] = acc
+
+
+def _on_rows(op, x, rows: slice, part):
+    """``x`` with ``op(x[rows], part)`` in place of its ``rows`` (whole
+    sublane groups: the concatenation moves nothing)."""
+    if rows == slice(0, x.shape[0]):
+        return op(x, part)
+    return jnp.concatenate(
+        [piece for piece in (x[:rows.start], op(x[rows], part),
+                             x[rows.stop:]) if piece.shape[0]], axis=0)
 
 
 def _lanes(x, n: int):
@@ -836,30 +978,46 @@ def _loop(lo, hi, body):
     jax.lax.fori_loop(lo, hi, step, 0)
 
 
-def _walk(ranges, body):
-    """``body(tile, masked)`` over the live tiles of one band row or column
-    in ascending order; a walk whose bounds are statically empty (no window,
-    no mask at all) is not traced."""
+_KEYS_OF_A_QUERY_TILE = (_EDGE, _DIAG)   # key_tile_ranges' two masked groups
+_QUERIES_OF_A_KEY_TILE = (_DIAG, _EDGE)  # query_tile_ranges'
+
+
+def _walk(ranges, body, edges):
+    """``body(tile, edge)`` over the live tiles of one band row or column
+    in ascending order, ``edges`` naming the edge of the masked tiles before
+    and after the full ones; a walk whose bounds are statically empty (no
+    window, no mask at all) is not traced."""
     lo, full_lo, full_hi, hi = ranges
-    _loop(lo, full_lo, lambda i: body(i, True))
-    _loop(full_lo, full_hi, lambda i: body(i, False))
-    _loop(full_hi, hi, lambda i: body(i, True))
+    _loop(lo, full_lo, lambda i: body(i, edges[0]))
+    _loop(full_lo, full_hi, lambda i: body(i, None))
+    _loop(full_hi, hi, lambda i: body(i, edges[1]))
 
 
-def _when_live(ranges, tile, step, causal: bool):
+def _when_live(ranges, tile, step, causal: bool, edges=(_EDGE, _EDGE)):
     """The chunked kernels' form of :func:`_walk`: this grid step's ``tile``
-    runs ``step(masked)`` if it is live, and nothing if not."""
+    runs ``step(edge)`` if it is live, and nothing if not — one masked body
+    where ``edges`` names the two groups of masked tiles alike (the kernels
+    that do every masked tile whole)."""
     lo, full_lo, full_hi, hi = ranges
-    inside = (tile >= full_lo) & (tile < full_hi)
-    pl.when(inside)(lambda: step(False))
-    if causal:
-        pl.when((tile >= lo) & (tile < hi) & ~inside)(lambda: step(True))
+    within = lambda a, b: (tile >= a) & (tile < b)
+    inside = within(full_lo, full_hi)
+    pl.when(inside)(lambda: step(None))
+    if not causal:
+        return
+    if edges[0] == edges[1]:
+        pl.when(within(lo, hi) & ~inside)(lambda: step(edges[0]))
+        return
+    for (a, b), edge in zip(((lo, full_lo), (full_hi, hi)), edges):
+        if not (isinstance(a, int) and isinstance(b, int) and a == b):
+            pl.when(within(a, b))(functools.partial(step, edge))
 
 
-def _tile(i, block: int):
+def _tile(i, block: int, first: int = 0, count: int | None = None):
+    """The rows of tile ``i``: all, or ``count`` of them from the ``first``."""
+    count = block if count is None else count
     if isinstance(i, int):
-        return pl.ds(i * block, block)
-    return pl.ds(pl.multiple_of(i * block, block), block)
+        return pl.ds(i * block + first, count)
+    return pl.ds(pl.multiple_of(i * block, block) + first, count)
 
 
 _ALL = slice(None)
@@ -869,7 +1027,8 @@ def _fwd_resident_kernel(seed_ref, alibi_ref, q_ref, k_ref, v_ref, o_ref,
                          lse_ref, m_scr, l_scr, acc_scr, *, ix,
                          causal: bool, sm_scale: float, block_q: int,
                          block_k: int, num_k: int, heads_per_step: int,
-                         dropout_rate: float, window, use_alibi: bool):
+                         dropout_rate: float, window, use_alibi: bool,
+                         grain: int):
     b, hs, qr = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     tiles_per_step = ix.rows(q_ref) // block_q
 
@@ -885,15 +1044,15 @@ def _fwd_resident_kernel(seed_ref, alibi_ref, q_ref, k_ref, v_ref, o_ref,
             q = ix.scaled_q(q_ref, hh, rows, sm_scale)
             _fwd_init(m_scr, l_scr, acc_scr)
 
-            def key_tile(kj, masked):
+            def key_tile(kj, edge):
                 cols = _tile(kj, block_k)
                 _fwd_tile(q, ix.kv(k_ref, hkv, cols), ix.kv(v_ref, hkv, cols),
                           m_scr, l_scr, acc_scr, qi * block_q, kj * block_k,
-                          slope, seed, masked=masked, window=window,
+                          slope, seed, edge=edge, grain=grain, window=window,
                           dropout_rate=dropout_rate)
 
             _walk(key_tile_ranges(qi, block_q, block_k, num_k, causal,
-                                  window), key_tile)
+                                  window), key_tile, _KEYS_OF_A_QUERY_TILE)
             out, lse = _fwd_result(m_scr, l_scr, acc_scr, o_ref.dtype, ix)
             ix.put(o_ref, hh, rows, out)
             ix.put_lse(lse_ref, hh, rows, lse)
@@ -908,7 +1067,7 @@ def _fwd_chunked_kernel(seed_ref, alibi_ref, q_ref, k_ref, v_ref, o_ref,
                         ix, causal: bool, sm_scale: float,
                         block_q: int, block_k: int, num_k: int,
                         heads_per_step: int, dropout_rate: float, window,
-                        use_alibi: bool):
+                        use_alibi: bool, grain: int):
     b, hs, qi, kj = (pl.program_id(0), pl.program_id(1), pl.program_id(2),
                      pl.program_id(3))
     own = lambda ref, hh: _at(ref, hh, heads_per_step)
@@ -925,7 +1084,7 @@ def _fwd_chunked_kernel(seed_ref, alibi_ref, q_ref, k_ref, v_ref, o_ref,
 
         heads(scale)
 
-    def step(masked):
+    def step(edge):
         def head(hh):
             hkv = ix.kv_head(hh)
             _fwd_tile(own(qs_scr, hh)[...], ix.kv(k_ref, hkv, _ALL),
@@ -934,13 +1093,13 @@ def _fwd_chunked_kernel(seed_ref, alibi_ref, q_ref, k_ref, v_ref, o_ref,
                       *_head_operands(seed_ref, alibi_ref, b,
                                       _head_index(hs, heads_per_step, hh),
                                       ix.heads, use_alibi, dropout_rate),
-                      masked=masked, window=window,
+                      edge=edge, grain=grain, window=window,
                       dropout_rate=dropout_rate)
 
         heads(head)
 
     _when_live(key_tile_ranges(qi, block_q, block_k, num_k, causal, window),
-               kj, step, causal)
+               kj, step, causal, _KEYS_OF_A_QUERY_TILE)
 
     @pl.when(kj == num_k - 1)
     def _finish():
@@ -1003,7 +1162,7 @@ def _flash_forward(q, k, v, causal: bool = True,
     common = dict(ix=ix, causal=causal, sm_scale=sm_scale, block_q=block_q,
                   block_k=block_k, num_k=num_k, heads_per_step=hps,
                   dropout_rate=dropout_rate, window=window,
-                  use_alibi=alibi is not None)
+                  use_alibi=alibi is not None, grain=plan.diag_grain)
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     stats = [(block_q, _LANES), (block_q, _LANES), (block_q, ix.width)]
     if plan.resident:
@@ -1080,10 +1239,11 @@ def _recompute_probs(q, k, lse, q0, k0, slope, seed, *, masked: bool,
 
 def _bwd_fused_kernel(seed_ref, alibi_ref, q_ref, k_ref, v_ref, lse_ref,
                       delta_ref, do_ref, dq_ref, dk_ref, dv_ref,
-                      qs_scr, dq_scr, dk_scr, dv_scr, *, ix,
+                      qs_scr, dq_scr, stat_scr, dk_scr, dv_scr, *, ix,
                       causal: bool, sm_scale: float, block_q: int,
                       block_k: int, heads_per_step: int,
-                      dropout_rate: float, window, use_alibi: bool):
+                      dropout_rate: float, window, use_alibi: bool,
+                      grain: int):
     """One pass over the live tiles of ``heads_per_step`` heads, key tiles
     outermost, on transposed (block_k, block_q) tiles: dV and dK of a key
     tile accumulate in scratch over its query tiles, dQ of the whole head
@@ -1101,6 +1261,11 @@ def _bwd_fused_kernel(seed_ref, alibi_ref, q_ref, k_ref, v_ref, lse_ref,
                                      use_alibi, dropout_rate)
         qs_scr[...] = ix.scaled_q(q_ref, hh, _ALL, sm_scale)
         dq_scr[...] = jnp.zeros_like(dq_scr)
+        # the head's logsumexp and δ rows, side by side: a band reads a
+        # 128-lane share of each, which Mosaic loads from a static sublane
+        # only (``ix.row`` finds a head at a dynamic one)
+        stat_scr[0:1, :] = ix.row(lse_ref, hh, _ALL)
+        stat_scr[1:2, :] = ix.row(delta_ref, hh, _ALL)
 
         def key_tile(kj):
             cols = _tile(kj, block_k)
@@ -1109,27 +1274,46 @@ def _bwd_fused_kernel(seed_ref, alibi_ref, q_ref, k_ref, v_ref, lse_ref,
             dk_scr[...] = jnp.zeros_like(dk_scr)
             dv_scr[...] = jnp.zeros_like(dv_scr)
 
-            def query_tile(qi, masked):
-                rows = _tile(qi, block_q)
-                q = qs_scr[rows, :]
-                do = ix.get(do_ref, hh, rows)
-                p, drop_scale = _recompute_probs(
-                    q, k, ix.row(lse_ref, hh, rows), qi * block_q,
-                    kj * block_k, slope, seed, masked=masked, window=window,
-                    dropout_rate=dropout_rate, transposed=True)
-                dp = _dot(v, do, (1, 1))                  # (dO·Vᵀ)ᵀ
-                if drop_scale is not None:
-                    dp = dp * drop_scale
-                    p_drop = p * drop_scale
-                else:
-                    p_drop = p
-                dv_scr[...] += _dot(p_drop.astype(do.dtype), do, (1, 0))
-                ds = (p * (dp - ix.row(delta_ref, hh, rows))).astype(q.dtype)
-                dk_scr[...] += _dot(ds, q, (1, 0))        # q holds sm_scale
-                dq_scr[rows, :] += _dot(ds, k, (0, 0))    # scaled at the end
+            def query_tile(qi, edge):
+                def part(keys, queries, masked):
+                    """Rows ``keys`` of the tile's keys against ``queries``
+                    of its queries (slices of the tile)."""
+                    rows = _tile(qi, block_q, queries.start,
+                                 queries.stop - queries.start)
+                    q = qs_scr[rows, :]
+                    do = ix.get(do_ref, hh, rows)
+                    p, drop_scale = _recompute_probs(
+                        q, k[keys], stat_scr[0:1, rows],
+                        qi * block_q + queries.start,
+                        kj * block_k + keys.start, slope, seed,
+                        masked=masked, window=window,
+                        dropout_rate=dropout_rate, transposed=True)
+                    dp = _dot(v[keys], do, (1, 1))            # (dO·Vᵀ)ᵀ
+                    if drop_scale is not None:
+                        dp = dp * drop_scale
+                        p_drop = p * drop_scale
+                    else:
+                        p_drop = p
+                    dv_scr[keys, :] += _dot(p_drop.astype(do.dtype), do,
+                                            (1, 0))
+                    ds = (p * (dp - stat_scr[1:2, rows])).astype(q.dtype)
+                    dk_scr[keys, :] += _dot(ds, q, (1, 0))  # q holds sm_scale
+                    dq_scr[rows, :] += _dot(ds, k[keys], (0, 0))  # scaled last
+
+                if not _cuts_diagonal(edge, grain, block_q, block_k):
+                    return part(slice(0, block_k), slice(0, block_q),
+                                edge is not None)
+                # the tile on the diagonal: each band of its keys against
+                # the queries from the diagonal down (bands of queries, and
+                # a mask on the diagonal's sub-block alone, were slower:
+                # CHANGES.md PR 38)
+                for c, hi in _diag_bands(block_k, grain, window):
+                    part(_grains(c, c + 1, grain), _grains(c, hi, grain),
+                         True)
 
             _walk(query_tile_ranges(kj, block_q, block_k, num_q, causal,
-                                    window), query_tile)
+                                    window), query_tile,
+                  _QUERIES_OF_A_KEY_TILE)
             ix.put(dk_ref, hh, cols, dk_scr[...].astype(dk_ref.dtype))
             ix.put(dv_ref, hh, cols, dv_scr[...].astype(dv_ref.dtype))
 
@@ -1164,7 +1348,7 @@ def _dq_kernel(seed_ref, alibi_ref, q_ref, k_ref, v_ref, lse_ref, delta_ref,
 
         heads(scale)
 
-    def step(masked):
+    def step(edge):
         def head(hh):
             hkv = ix.kv_head(hh)
             k = ix.kv(k_ref, hkv, _ALL)
@@ -1174,8 +1358,8 @@ def _dq_kernel(seed_ref, alibi_ref, q_ref, k_ref, v_ref, lse_ref, delta_ref,
                 *_head_operands(seed_ref, alibi_ref, b,
                                 _head_index(hs, heads_per_step, hh),
                                 ix.heads, use_alibi, dropout_rate),
-                masked=masked, window=window, dropout_rate=dropout_rate,
-                transposed=t)
+                masked=edge is not None, window=window,
+                dropout_rate=dropout_rate, transposed=t)
             do = ix.get(do_ref, hh, _ALL)
             v = ix.own(ix.kv(v_ref, hkv, _ALL), hh)
             dp = _dot(v, do, (1, 1)) if t else _dot(do, v, (1, 1))
@@ -1216,7 +1400,7 @@ def _dkv_kernel(seed_ref, alibi_ref, q_ref, k_ref, v_ref, lse_ref,
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
-    def step(masked):
+    def step(edge):
         def head(hh):
             q = ix.scaled_q(q_ref, hh, _ALL, sm_scale)
             do = ix.get(do_ref, hh, _ALL)
@@ -1227,8 +1411,8 @@ def _dkv_kernel(seed_ref, alibi_ref, q_ref, k_ref, v_ref, lse_ref,
                 *_head_operands(seed_ref, alibi_ref, b,
                                 _head_index(hs, heads_per_step, hh),
                                 ix.heads, use_alibi, dropout_rate),
-                masked=masked, window=window, dropout_rate=dropout_rate,
-                transposed=t)
+                masked=edge is not None, window=window,
+                dropout_rate=dropout_rate, transposed=t)
             p_drop = p if drop_scale is None else p * drop_scale
             own(dv_scr, hh)[...] += _dot(p_drop.astype(do.dtype), do,
                                          (1, 0) if t else (0, 0))  # p̃ᵀ·dO
@@ -1312,7 +1496,8 @@ def _flash_backward(q, k, v, out, lse, g, causal: bool, plan: FlashPlan,
         row_spec = ix.stat_spec(hps, T, at, as_rows=True)
         dkv_spec = ix.spec(hps, S, at)
         dq, dk_ph, dv_ph = pl.pallas_call(
-            functools.partial(_bwd_fused_kernel, **common),
+            functools.partial(_bwd_fused_kernel, grain=plan.bwd_diag_grain,
+                              **common),
             grid=(B, Hq // hps),
             in_specs=[smem, smem, q_spec, ix.kv_spec(hps, S, at, 1),
                       ix.kv_spec(hps, S, at, 2), row_spec, row_spec,
@@ -1321,6 +1506,7 @@ def _flash_backward(q, k, v, out, lse, g, causal: bool, plan: FlashPlan,
             out_shape=[dq_shape] + dkv_shape,
             scratch_shapes=[pltpu.VMEM((T, width), q.dtype),
                             pltpu.VMEM((T, width), jnp.float32),
+                            pltpu.VMEM((2, T), jnp.float32),
                             pltpu.VMEM((block_k, width), jnp.float32),
                             pltpu.VMEM((block_k, width), jnp.float32)],
             compiler_params=pltpu.CompilerParams(

@@ -1015,17 +1015,37 @@ _FLASH_PLANS = {
     "resident256x128": (256, 128, None),
     "chunked128": (128, 128, 1),
     "chunked256x128": (256, 128, 1),
+    # square tiles a window's budget streams: the tile on the diagonal is
+    # cut in the chunked forward too
+    "chunked256": (256, 256, 1),
 }
+# (plan, the grain a tile on the diagonal is cut at): the plan's own for
+# every plan, and each other grain of the square tiles it can cut (a grain
+# of the tile's size: the tile is done whole)
+_FLASH_GRAINS = ([(plan, 128) for plan in _FLASH_PLANS]
+                 + [("resident512", 256), ("resident512", 512),
+                    ("resident256", 256), ("chunked256", 256)])
 
 
-@pytest.mark.parametrize("plan_name", list(_FLASH_PLANS))
+def _use_grain(monkeypatch, grain):
+    """The grains are the plan's to choose (no argument sets them): a test
+    moves the constants the plan reads, the forward's and the one-pass
+    backward's alike."""
+    from penroz_tpu.ops.pallas import flash_attention as FA
+    monkeypatch.setattr(FA, "_DIAG_GRAIN", grain)
+    monkeypatch.setattr(FA, "_BWD_DIAG_GRAIN", grain)
+
+
+@pytest.mark.parametrize("plan_name,grain", _FLASH_GRAINS)
 @pytest.mark.parametrize("feature", list(_FLASH_FEATURES))
-def test_flash_plans_match_oracle(feature, plan_name):
+def test_flash_plans_match_oracle(feature, plan_name, grain, monkeypatch):
     """Forward and dq/dk/dv (interpret) against the jnp oracle under every
     plan the function can return: K/V resident with in-kernel walks and the
     one-pass backward (several heads a grid step), and the chunked kernels
-    with the two-kernel backward a small budget forces."""
+    with the two-kernel backward a small budget forces; the tile on the
+    diagonal whole and cut at each grain."""
     from penroz_tpu.ops.pallas import flash_attention as FA
+    _use_grain(monkeypatch, grain)
     kwargs, oracle = _FLASH_FEATURES[feature]
     block_q, block_k, budget = _FLASH_PLANS[plan_name]
     q, k, v = _plan_inputs(len(feature) + len(plan_name))
@@ -1034,6 +1054,16 @@ def test_flash_plans_match_oracle(feature, plan_name):
                          block_q=block_q, block_k=block_k,
                          **({} if budget is None
                             else {"vmem_budget": budget}))
+    causal = kwargs.get("causal", True)
+    cut = block_q == block_k and grain < block_q and causal
+    assert plan.diag_grain == (grain if cut else block_q)
+    # the two-kernel backward does its tiles whole
+    assert plan.bwd_diag_grain == (grain if cut and budget is None
+                                   else block_q)
+    whole = FA.computed_over_live(512, 512, block_q, block_k, block_q,
+                                  causal, kwargs.get("window"))
+    assert (plan.computed_over_live < whole) == cut
+    assert (plan.bwd_computed_over_live < whole) == (cut and budget is None)
     if budget is None:
         assert plan.resident and plan.fused_bwd
         # several heads a grid step, but where 512-tiles leave no room
@@ -1062,7 +1092,21 @@ def test_flash_plans_match_oracle(feature, plan_name):
 @pytest.mark.parametrize("case,want", [
     # GPT-2 124M, the benchmark cell's attention: (12, 12, 1024, 64) bf16
     (dict(T=1024, S=1024, D=64, itemsize=2, heads=12),
-     dict(resident=True, q_rows=1024, fused_bwd=True)),
+     dict(resident=True, q_rows=1024, fused_bwd=True, block_q=512,
+          diag_grain=256, bwd_diag_grain=128, computed_over_live=1.25,
+          bwd_computed_over_live=1.125)),
+    # … not causal: no diagonal, nothing dead to compute
+    (dict(T=1024, S=1024, D=64, itemsize=2, heads=12, causal=False),
+     dict(block_q=512, diag_grain=512, bwd_diag_grain=512,
+          computed_over_live=1.0, bwd_computed_over_live=1.0)),
+    # … under a window inside one sub-block: of the two tiles on the
+    # diagonal the sub-blocks the band meets (3 of 256, 7 of 128), and the
+    # whole tile left of the second
+    (dict(T=1024, S=1024, D=64, itemsize=2, heads=12, window=64),
+     dict(diag_grain=256, bwd_diag_grain=128,
+          computed_over_live=(2 * 3 * 256 * 256 + 512 * 512) / (1024 * 64),
+          bwd_computed_over_live=(2 * 7 * 128 * 128 + 512 * 512)
+          / (1024 * 64))),
     # GPT-2-large: D = 64 × 20 heads
     (dict(T=1024, S=1024, D=64, itemsize=2, heads=20),
      dict(resident=True, q_rows=1024, fused_bwd=True)),
@@ -1073,10 +1117,14 @@ def test_flash_plans_match_oracle(feature, plan_name):
     # one tile
     (dict(T=128, S=128, D=64, itemsize=2, heads=4),
      dict(block_q=128, block_k=128, bwd_block_q=128, bwd_block_k=128,
-          resident=True, q_rows=128, fused_bwd=True, heads_per_step=4)),
+          resident=True, q_rows=128, fused_bwd=True, heads_per_step=4,
+          diag_grain=128, bwd_diag_grain=128, computed_over_live=2.0)),
     # K/V of a head past the budget: stream them
     (dict(T=32768, S=32768, D=128, itemsize=2, heads=8),
-     dict(resident=False, fused_bwd=False, heads_per_step=1)),
+     dict(resident=False, fused_bwd=False, heads_per_step=1, diag_grain=256,
+          bwd_diag_grain=512,
+          computed_over_live=(64 * 63 / 2 + 64 * 0.75) / (64 * 64 / 2),
+          bwd_computed_over_live=(64 * 63 / 2 + 64) / (64 * 64 / 2))),
     (dict(T=1024, S=1024, D=64, itemsize=2, heads=12, vmem_budget=2 ** 20),
      dict(resident=False, fused_bwd=False, heads_per_step=1)),
     # T = 4096, D = 64: resident forward in bf16 (on part of the queries a
@@ -1116,6 +1164,17 @@ def test_flash_plan_function(case, want):
     # pure: the same shapes give the same plan, and it names itself
     assert FA.plan_flash(**case) == plan
     assert f"heads_per_step={hps}" in plan.describe()
+    assert (f"diag_grain={plan.diag_grain} "
+            f"bwd_diag_grain={plan.bwd_diag_grain} "
+            f"computed_over_live={plan.computed_over_live:.3f} "
+            f"bwd_computed_over_live={plan.bwd_computed_over_live:.3f} "
+            in plan.describe())
+    # a grain cuts the tiles it is chosen for into whole sub-blocks
+    for grain, block in ((plan.diag_grain, plan.block_q),
+                         (plan.bwd_diag_grain, plan.bwd_block_q)):
+        assert block % grain == 0 and grain % 128 == 0
+    if not plan.fused_bwd:
+        assert plan.bwd_diag_grain == plan.bwd_block_q
 
 
 def test_flash_plan_honours_explicit_tiles_and_rejects_ragged_lengths():
@@ -1207,6 +1266,10 @@ def test_flash_plan_is_logged_once_and_spanned_per_trace(caplog):
     assert len(lines) == 1, lines
     assert lines[0].startswith("flash plan: T=384 S=384 D=64 bq=384 bk=384")
     assert "resident" in lines[0] and "fused_bwd" in lines[0]
+    # one 384-tile, which 256 does not divide: cut at 128 both ways, 6 of
+    # its 9 sub-blocks, over half the tile
+    assert (" diag_grain=128 bwd_diag_grain=128 computed_over_live=1.333 "
+            "bwd_computed_over_live=1.333 ") in lines[0]
     dispatch = trace.to_dict()["root"]["children"][0]
     spans = [c for c in dispatch["children"]
              if c["name"] == "penroz/flash_plan"]
@@ -1215,6 +1278,9 @@ def test_flash_plan_is_logged_once_and_spanned_per_trace(caplog):
     assert (meta["T"], meta["S"], meta["D"]) == (384, 384, 64)
     assert meta["block_q"] == 384 and meta["resident"] is True
     assert meta["fused_bwd"] is True and meta["heads_per_step"] == 2
+    assert meta["diag_grain"] == meta["bwd_diag_grain"] == 128
+    assert meta["computed_over_live"] == pytest.approx(4 / 3)
+    assert meta["bwd_computed_over_live"] == pytest.approx(4 / 3)
     trace.finish("completed")
 
 
@@ -1292,6 +1358,117 @@ def test_flash_btd_matches_oracle(shape, feature, form):
                 _btd_parts(want_g, Hq, Hkv, D))
 
 
+# tile → the grains its diagonal tiles are cut at (the tile's own: whole)
+_BTD_GRAINS = [(512, 128), (512, 256), (512, 512), (256, 128)]
+_BTD_GRAIN_FEATURES = dict(
+    _BTD_FEATURES, window_in_tile=lambda Hq: (
+        {"window": 64},
+        lambda q, k, v: A.causal_attention_reference(q, k, v, window=64)))
+
+
+@pytest.mark.parametrize("tile,grain", _BTD_GRAINS)
+@pytest.mark.parametrize("feature", list(_BTD_GRAIN_FEATURES))
+@pytest.mark.parametrize("shape", ["d64_pairs", "d128_gqa"])
+def test_flash_btd_diagonal_grain_matches_oracle(shape, feature, tile, grain,
+                                                 monkeypatch):
+    """The model's layout with tiles large enough to cut: the tile on the
+    diagonal at each grain and whole, head pairs and one head a lane block,
+    output and the gradient of the fused projection against the oracle."""
+    from penroz_tpu.ops.pallas import flash_attention as FA
+    _use_grain(monkeypatch, grain)
+    _, Hq, Hkv, _, D = _BTD_SHAPES[shape]
+    T = 512
+    kwargs, oracle = _BTD_GRAIN_FEATURES[feature](Hq)
+    plan = FA.plan_flash(T, T, D, 4, True, kwargs.get("window"), heads=Hq,
+                         group=Hq // Hkv, block_q=tile, block_k=tile,
+                         layout="btd")
+    assert plan.resident and plan.fused_bwd
+    assert plan.diag_grain == plan.bwd_diag_grain == grain
+    rng = np.random.default_rng(len(shape) + len(feature) + tile + grain)
+    qkv = jnp.asarray(rng.normal(size=(1, T, (Hq + 2 * Hkv) * D))
+                      .astype(np.float32))
+    w = jnp.asarray(rng.normal(size=(1, T, Hq * D)).astype(np.float32))
+    attend = lambda x: FA.flash_attention_btd(
+        x, heads=Hq, kv_heads=Hkv, block_q=tile, block_k=tile,
+        interpret=True, **kwargs)
+    want = _btd_oracle(oracle, Hq, Hkv, D)
+    np.testing.assert_allclose(np.asarray(attend(qkv)),
+                               np.asarray(want(qkv)), atol=2e-5)
+    got_g = jax.grad(lambda x: (attend(x) * w).sum())(qkv)
+    want_g = jax.grad(lambda x: (want(x) * w).sum())(qkv)
+    _grad_close(_btd_parts(got_g, Hq, Hkv, D),
+                _btd_parts(want_g, Hq, Hkv, D))
+
+
+def _cell_like_qkv(dtype, heads=2, T=1024, D=64):
+    rng = np.random.default_rng(38)
+    return (jnp.asarray(rng.normal(size=(1, T, 3 * heads * D)), dtype),
+            jnp.asarray(rng.normal(size=(1, T, heads * D)), jnp.float32))
+
+
+def test_flash_btd_cell_plan_matches_oracle():
+    """The benchmark cell's own plan — T = 1024, D = 64, bfloat16, the fused
+    projection, 512-tiles of which two of a head's three lie on the
+    diagonal and are cut at the plan's grain — on one head pair, against the
+    oracle in float32 on the same inputs."""
+    from penroz_tpu.ops.pallas import flash_attention as FA
+    qkv, w = _cell_like_qkv(jnp.bfloat16)
+    cell = FA.plan_flash(1024, 1024, 64, 2, heads=12, layout="btd",
+                         fused_qkv=True)
+    plan = FA.plan_flash(1024, 1024, 64, 2, heads=2, layout="btd",
+                         fused_qkv=True)
+    assert plan == cell and plan.describe() == (
+        "bq=512 bk=512 bwd_bq=512 bwd_bk=512 diag_grain=256 "
+        "bwd_diag_grain=128 computed_over_live=1.250 "
+        "bwd_computed_over_live=1.125 resident q_rows=1024 fused_bwd "
+        "heads_per_step=2 layout=btd heads_per_block=2 fused_qkv")
+    attend = lambda x: FA.flash_attention_btd(x, heads=2, interpret=True)
+    want = _btd_oracle(A.causal_attention_reference, 2, 2, 64)
+    exact = qkv.astype(jnp.float32)
+    pairs = [(attend(qkv), want(exact)),
+             (jax.grad(lambda x: (attend(x).astype(jnp.float32) * w).sum())(
+                 qkv),
+              jax.grad(lambda x: (want(x) * w).sum())(exact))]
+    for got, ref in pairs:
+        got = np.asarray(got.astype(jnp.float32))
+        # bfloat16's rounding of p, dS and the results: 4e-3 of the norm
+        assert np.linalg.norm(got - ref) <= 1e-2 * np.linalg.norm(ref)
+
+
+def _ulps_bf16(a, b):
+    """|a − b| in units of the last place of bfloat16 (8 significant bits)
+    at the size of the larger of the two — or of the array's mean entry,
+    for an entry whose terms cancelled to less: its last places are the
+    float32 accumulations', whatever their order."""
+    a, b = (np.asarray(x.astype(jnp.float32)) for x in (a, b))
+    top = np.maximum(np.maximum(np.abs(a), np.abs(b)), np.abs(b).mean())
+    return np.abs(a - b) / np.exp2(np.floor(np.log2(top)) - 7)
+
+
+@pytest.mark.parametrize("grain", [128, 256])
+def test_flash_cut_diagonal_tiles_equal_whole_ones(grain, monkeypatch):
+    """The same mathematics: a dead entry gave exp(−1e30 − m) = 0 to every
+    sum and a zero product to every accumulation, so on the same bfloat16
+    inputs the kernels that leave the dead sub-blocks out return what the
+    whole-tile kernels return, output and gradient, to one unit in
+    bfloat16's last place (the order of the float32 accumulations is all
+    that differs, and where it moves a bfloat16 rounding of p or dS)."""
+    from penroz_tpu.ops.pallas import flash_attention as FA
+    qkv, w = _cell_like_qkv(jnp.bfloat16)
+
+    def run(g):
+        _use_grain(monkeypatch, g)
+        plan = FA.plan_flash(1024, 1024, 64, 2, heads=2, layout="btd",
+                             fused_qkv=True)
+        assert plan.diag_grain == plan.bwd_diag_grain == g
+        attend = lambda x: FA.flash_attention_btd(x, heads=2, interpret=True)
+        return attend(qkv), jax.grad(
+            lambda x: (attend(x).astype(jnp.float32) * w).sum())(qkv)
+
+    for got, want in zip(run(grain), run(512)):
+        assert _ulps_bf16(got, want).max() <= 1.0
+
+
 @pytest.mark.parametrize("shape", ["d64_pairs", "d128_gqa"])
 def test_flash_btd_chunked_plan_matches_oracle(shape):
     """The streamed forward and the two-kernel backward a small budget
@@ -1352,6 +1529,8 @@ def test_flash_plan_names_its_layout(caplog):
     assert (plan.layout, plan.heads_per_block, plan.heads_per_step,
             plan.fused_qkv) == ("btd", 2, 2, True)
     assert plan.resident and plan.fused_bwd and plan.q_rows == 1024
+    assert (plan.diag_grain, plan.computed_over_live) == (256, 1.25)
+    assert (plan.bwd_diag_grain, plan.bwd_computed_over_live) == (128, 1.125)
     assert plan.describe().endswith("layout=btd heads_per_block=2 fused_qkv")
     assert FA.plan_flash(1024, 1024, 128, 2, heads=8, group=4,
                          layout="btd").heads_per_block == 1
@@ -1370,8 +1549,15 @@ def test_flash_plan_names_its_layout(caplog):
              if r.getMessage().startswith("flash plan:")]
     assert len(lines) == 1 and lines[0].endswith(
         "layout=btd heads_per_block=2 fused_qkv"), lines
+    # one 256-tile on the diagonal: whole forward, 3 of its 4 sub-blocks
+    # backward, over half of it
+    assert (" diag_grain=256 bwd_diag_grain=128 computed_over_live=2.000 "
+            "bwd_computed_over_live=1.500 ") in lines[0], lines
     span = trace.to_dict()["root"]["children"][0]["children"][0]
     assert span["name"] == "penroz/flash_plan"
+    assert (span["meta"]["diag_grain"], span["meta"]["bwd_diag_grain"],
+            span["meta"]["computed_over_live"],
+            span["meta"]["bwd_computed_over_live"]) == (256, 128, 2.0, 1.5)
     assert span["meta"]["layout"] == "btd"
     assert span["meta"]["heads_per_block"] == 2
     assert span["meta"]["fused_qkv"] is True

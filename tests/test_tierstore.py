@@ -518,10 +518,14 @@ def test_tier_fault_sites_crash_recover_with_parity(gpt_model, make_engine,
     if site == "tier.demote":
         # the generation succeeds; the async demotion tick crashes
         assert _submit(engine, prompt, 4, session_id="chaos").result() == out
+        # ... and has failed what was queued: the crash is counted before
+        # that, the reset after it, so a request submitted on the count
+        # alone can still be failed with the crash's error
         deadline = time.monotonic() + 60
-        while engine.stats()["crashes_total"] < 1:
+        while engine.stats()["engine_resets"] < 1:
             assert time.monotonic() < deadline, "demote fault never fired"
             time.sleep(0.02)
+        assert engine.stats()["crashes_total"] == 1
     else:
         # hibernate cleanly first, then the WAKE admission crashes: the
         # client gets the injected error, not a hang

@@ -182,6 +182,14 @@ CASES = {
         False, (CELL_ROWS, BLOCK, 3 * HEADS * HEAD_DIM), heads=HEADS),
     "flash_btd_bwd_cell": lambda: _flash_btd(
         True, (CELL_ROWS, BLOCK, 3 * HEADS * HEAD_DIM), heads=HEADS),
+    # … under a window narrower than a tile: the tiles on the diagonal cut
+    # into sub-blocks, those left of the window dropped, the rest masked
+    "flash_btd_fwd_cell_window": lambda: _flash_btd(
+        False, (CELL_ROWS, BLOCK, 3 * HEADS * HEAD_DIM), heads=HEADS,
+        window=200),
+    "flash_btd_bwd_cell_window": lambda: _flash_btd(
+        True, (CELL_ROWS, BLOCK, 3 * HEADS * HEAD_DIM), heads=HEADS,
+        window=200),
     # D = 128, 4 query heads a K/V head: one head a block, any group
     "flash_btd_fwd_d128_gqa": lambda: _flash_btd(
         False, (2, BLOCK, 12 * 128), heads=8, kv_heads=2),
@@ -227,6 +235,26 @@ def test_kernel_compiles_for_v5e(chip, name):
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text(), \
         f"{name}: no Mosaic custom call in the compiled program"
+
+
+@pytest.mark.parametrize("grain,want", [(512, 1.5), (256, 1.25),
+                                        (128, 1.125)])
+def test_cell_walk_computes_this_much_over_its_band(grain, want):
+    """``computed_over_live`` of the cell's attention (T = 1024, 512-tiles,
+    causal): three tiles for a band of two when the two on the diagonal are
+    done whole, 2.5 and 2.25 when they are cut at 256 and 128 — and the
+    plan the cell gets is one of the cut ones."""
+    from penroz_tpu.ops.pallas import flash_attention as fa
+    assert fa.computed_over_live(BLOCK, BLOCK, 512, 512, grain, True,
+                                 None) == want
+    plan = fa.plan_flash(BLOCK, BLOCK, HEAD_DIM, 2, heads=HEADS,
+                         layout="btd", fused_qkv=True)
+    assert (plan.block_q, plan.block_k) == (512, 512)
+    for g, ratio in ((plan.diag_grain, plan.computed_over_live),
+                     (plan.bwd_diag_grain, plan.bwd_computed_over_live)):
+        assert g < 512 and ratio <= 1.25
+        assert ratio == fa.computed_over_live(BLOCK, BLOCK, 512, 512, g, True,
+                                              None)
 
 
 def test_btd_group_sum_adds_lane_ranges(chip):

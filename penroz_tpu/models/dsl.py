@@ -94,6 +94,22 @@ def to_layer(entry: dict) -> M.Module:
             if name in args:
                 kwargs[name] = to_layer(args[name])
         mod = M.TransformerBlock(**kwargs)
+    elif algo == "looped":
+        # one stack of blocks run ``steps`` times with shared weights, an
+        # exit (final norm, head, gate) after every pass: ops/modules.Looped
+        unknown = set(args) - {"steps", "body", "exit", "entropy_weight"}
+        missing = {"steps", "body", "exit"} - set(args)
+        if unknown or missing or set(args["exit"]) != {"norm", "head",
+                                                        "gate"}:
+            raise ValueError(
+                "looped takes steps, body (a list of blocks), exit "
+                "({norm, head, gate}) and optionally entropy_weight; got "
+                f"{sorted(args)}")
+        mod = M.Looped(
+            steps=args["steps"], body=[to_layer(e) for e in args["body"]],
+            **{k: to_layer(args["exit"][k]) for k in ("norm", "head", "gate")},
+            **({"entropy_weight": args["entropy_weight"]}
+               if "entropy_weight" in args else {}))
     elif algo in _LEAF_ALGOS:
         mod = _LEAF_ALGOS[algo](**args)
     else:
@@ -278,6 +294,8 @@ class Mapper:
             return _bloom_dsl_from_config(config, n_layer_override)
         if model_type == "mpt":
             return _mpt_dsl_from_config(config, n_layer_override)
+        if model_type == "ouro":
+            return _ouro_dsl_from_config(config, n_layer_override)
         raise ValueError(f"Unsupported HuggingFace model type: {model_type}")
 
     # -- HF state-dict detection + remapping --------------------------------
@@ -1295,6 +1313,37 @@ def _llama_dsl_from_config(config, n_layer_override=None) -> list[dict]:
         {"softmaxlast": {"dim": -1}},
     ]
     return layers
+
+
+def _ouro_dsl_from_config(config, n_layer_override=None) -> list[dict]:
+    """Ouro (ByteDance, ``model_type`` ``ouro``) HF config → layer DSL: the
+    looped stack of ``presets.ouro_custom`` at the config's sizes.  What
+    the config does not say (sandwich norms, the gate's bias, the entropy
+    weight) is the preset's."""
+    from penroz_tpu.models import presets
+    if (getattr(config, "use_sliding_window", False)
+            and getattr(config, "sliding_window", None)):
+        raise ValueError("ouro with a sliding window is not supported")
+    if getattr(config, "rope_scaling", None):
+        raise ValueError("ouro with rope_scaling is not supported")
+    d = int(config.hidden_size)
+    heads = int(config.num_attention_heads)
+    kv = int(getattr(config, "num_key_value_heads", None) or heads)
+    if kv != heads:
+        raise ValueError("ouro with grouped K/V heads is not supported")
+    if getattr(config, "hidden_act", "silu") != "silu":
+        raise ValueError("ouro with an activation other than silu is not "
+                         "supported")
+    return presets.ouro_custom(
+        d=d, heads=heads,
+        head_dim=int(getattr(config, "head_dim", None) or d // heads),
+        intermediate=int(config.intermediate_size),
+        depth=int(n_layer_override if n_layer_override
+                  else config.num_hidden_layers),
+        steps=int(getattr(config, "total_ut_steps", 4)),
+        vocab=int(config.vocab_size),
+        rope_theta=float(getattr(config, "rope_theta", 1e6) or 1e6),
+        eps=float(getattr(config, "rms_norm_eps", 1e-6)))
 
 
 def _gelu_entry(act: str, family: str) -> dict:

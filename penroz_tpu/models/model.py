@@ -267,6 +267,15 @@ class CompiledArch:
         self.mods = dsl.build_modules(layers)
         self.algos = [dsl.layer_algo(entry) for entry in layers]
         self.classification = any(isinstance(m, M.Softmax) for m in self.mods)
+        # A stack with several exits (ops/modules.py::Looped): its loss is
+        # taken over the exit distribution, not from one pre-softmax
+        # activation.
+        looped = [m for top in self.mods for m in top.walk()
+                  if isinstance(m, M.Looped)]
+        if len(looped) > 1 or (looped and looped[0] not in self.mods):
+            raise ValueError("a model holds at most one looped stack, as a "
+                             "top-level layer")
+        self.looped: Optional[M.Looped] = looped[0] if looped else None
         self.param_order: list[str] = []
         for mod in self.mods:
             for sub in mod.walk():
@@ -287,6 +296,20 @@ class CompiledArch:
         pytree, indexed independently of the attention pools."""
 
         def visit(mod):
+            if isinstance(mod, M.Looped):
+                # every (pass, layer) has its own cache slot: the body's
+                # layers take the first pass's, and each later pass the
+                # same layers ``slots_per_pass`` further on
+                first = len(self.attn_layers)
+                for _, child in mod.children():
+                    visit(child)
+                if any(isinstance(m, M.GatedSSM) for m in mod.walk()):
+                    raise ValueError("ssm layers inside a looped stack are "
+                                     "not supported")
+                body = self.attn_layers[first:]
+                mod.slots_per_pass = len(body)
+                self.attn_layers.extend(body * (mod.steps - 1))
+                return
             if isinstance(mod, M.CausalSelfAttention):
                 mod.layer_idx = len(self.attn_layers)
                 self.attn_layers.append(mod)
@@ -312,7 +335,10 @@ class CompiledArch:
 
     @property
     def kv_specs(self) -> list[tuple[int, int]]:
-        """Per-attention-layer (num_kv_heads, head_dim) for KV allocation."""
+        """Per-attention-layer (num_kv_heads, head_dim) for KV allocation
+        (a looped stack: one entry per (pass, layer))."""
+        if self.looped is not None and KV.paged_enabled():
+            self.refuse_looped("the paged KV pool (PAGED_KV_CACHE=1)")
         specs = []
         for mod in self.attn_layers:
             if mod.head_dim is None:
@@ -321,6 +347,14 @@ class CompiledArch:
                                  "or pass head_dim explicitly")
             specs.append((mod.num_kv_heads, mod.head_dim))
         return specs
+
+    def refuse_looped(self, what: str):
+        """The one error for what a looped stack does not run."""
+        if self.looped is not None:
+            raise ValueError(
+                f"a looped model does not run with {what}: it serves "
+                "through the dense KV cache (one slot per pass and layer) "
+                "and trains and serves without pipeline stages")
 
     @property
     def ssm_specs(self) -> list[tuple[int, int, int]]:
@@ -346,12 +380,14 @@ class CompiledArch:
     def _apply(self, params, buffers, x, *, training=False, rng=None, kv=None,
                pos_offset=None, skip_softmax=False, compute_dtype=None,
                sp_mesh=None, platform=None, sp_mode="ring", ep_mesh=None,
-               lora=None, lora_idx=None, ragged_descs=None, ragged_rows=None):
+               lora=None, lora_idx=None, ragged_descs=None, ragged_rows=None,
+               targets=None):
         ctx = M.Ctx(params, buffers, training=training, rng=rng, kv=kv,
                     pos_offset=pos_offset, compute_dtype=compute_dtype,
                     sp_mesh=sp_mesh, platform=platform, sp_mode=sp_mode,
                     ep_mesh=ep_mesh, lora=lora, lora_idx=lora_idx,
-                    ragged_descs=ragged_descs, ragged_rows=ragged_rows)
+                    ragged_descs=ragged_descs, ragged_rows=ragged_rows,
+                    targets=targets if self.looped is not None else None)
         acts = []
         h = x
         logits = None
@@ -380,11 +416,7 @@ class CompiledArch:
         return jnp.mean((logits.astype(jnp.float32)
                          - targets.astype(jnp.float32)) ** 2)
 
-    def forward(self, params, buffers, tokens, targets=None, *,
-                training=False, rng=None, kv=None, pos_offset=None,
-                skip_softmax=False, compute_dtype=None, sp_mesh=None,
-                platform=None, sp_mode="ring", ep_mesh=None, lora=None,
-                lora_idx=None, ragged_descs=None, ragged_rows=None):
+    def forward(self, params, buffers, tokens, targets=None, **kw):
         """Full forward collecting every top-level activation.
 
         Returns ``(activations, cost, buffer_updates, new_kv)``; ``cost`` is
@@ -397,14 +429,35 @@ class CompiledArch:
         packed, ``pos_offset`` the (1, Tp) per-token positions, and
         ``new_kv`` advances per-descriptor instead of by ``T``.
         """
+        acts, cost, ctx, new_kv = self._forward(params, buffers, tokens,
+                                                targets, **kw)
+        return acts, cost, ctx.buffer_updates, new_kv
+
+    def _forward(self, params, buffers, tokens, targets=None, *,
+                 training=False, rng=None, kv=None, pos_offset=None,
+                 skip_softmax=False, compute_dtype=None, sp_mesh=None,
+                 platform=None, sp_mode="ring", ep_mesh=None, lora=None,
+                 lora_idx=None, ragged_descs=None, ragged_rows=None):
+        """:meth:`forward` with the module context in place of its buffer
+        updates: ``(activations, cost, ctx, new_kv)``.  The cost of a model
+        with several exits (a looped stack) is the expected loss over each
+        token's exit distribution (``ops/losses.py::expected_exit_loss``),
+        and ``ctx.exit_stats`` then holds each pass's mean loss and the
+        mean exit distribution."""
         acts, logits, ctx = self._apply(
             params, buffers, tokens, training=training, rng=rng, kv=kv,
             pos_offset=pos_offset, skip_softmax=skip_softmax,
             compute_dtype=compute_dtype, sp_mesh=sp_mesh, platform=platform,
             sp_mode=sp_mode, ep_mesh=ep_mesh, lora=lora, lora_idx=lora_idx,
-            ragged_descs=ragged_descs, ragged_rows=ragged_rows)
-        cost = (self._cost_from_logits(logits, targets, platform=platform)
-                if targets is not None else None)
+            ragged_descs=ragged_descs, ragged_rows=ragged_rows,
+            targets=targets)
+        if targets is None:
+            cost = None
+        elif ctx.exits is not None:
+            cost, ctx.exit_stats = losses.expected_exit_loss(
+                *ctx.exits, self.looped.entropy_weight)
+        else:
+            cost = self._cost_from_logits(logits, targets, platform=platform)
         if cost is not None and ctx.aux_losses:
             # Auxiliary training losses (MoE load balancing) ride the same
             # scalar so value_and_grad backpropagates them with the task loss.
@@ -416,7 +469,21 @@ class CompiledArch:
                 ctx.kv.lengths_after_packed(ragged_descs))
         else:
             new_kv = ctx.kv.advanced(tokens.shape[-1])
-        return acts, cost, ctx.buffer_updates, new_kv
+        return acts, cost, ctx, new_kv
+
+    def _train_loss_fn(self, compute_dtype, sp_mesh, platform, sp_mode,
+                       ep_mesh):
+        """``fn(params, buffers, x, y, rng) -> (cost, (buffer updates, exit
+        stats))`` of one training micro-step; the stats are ``None`` but
+        for a model with several exits."""
+        def loss_fn(params, buffers, x, y, rng):
+            _, cost, ctx, _ = self._forward(
+                params, buffers, x, y, training=True, rng=rng,
+                skip_softmax=True, compute_dtype=compute_dtype,
+                sp_mesh=sp_mesh, platform=platform, sp_mode=sp_mode,
+                ep_mesh=ep_mesh)
+            return cost, (ctx.buffer_updates, ctx.exit_stats)
+        return loss_fn
 
     def jit_forward(self, params, buffers, tokens, targets=None, *,
                     skip_softmax=False, compute_dtype=None, platform=None):
@@ -484,7 +551,11 @@ class CompiledArch:
 
         Returns ``fn(params, opt_state, buffers, xs, ys, rng) ->
         (params, opt_state, buffers, cost, weight_update_ratios)`` where
-        ``xs``/``ys`` are ``(num_steps, B, T)`` token batches.
+        ``xs``/``ys`` are ``(num_steps, B, T)`` token batches.  A model with
+        several exits (a looped stack) returns a sixth result after those
+        five, ``{"pass_loss": (steps,), "exit_mass": (steps,)}``: each
+        pass's mean cross-entropy and the mean exit distribution of the
+        epoch.
 
         ``with_ratios=False`` compiles a variant that skips the per-weight
         update-ratio stds (two full passes over the parameters) — the
@@ -526,18 +597,16 @@ class CompiledArch:
         optimizer = dsl.build_optimizer(optimizer_config)
 
         if pipe_cfg is None:
-            def loss_fn(params, buffers, x, y, rng):
-                _, cost, buf_upd, _ = self.forward(
-                    params, buffers, x, y, training=True, rng=rng,
-                    skip_softmax=True, compute_dtype=compute_dtype,
-                    sp_mesh=sp_mesh, platform=platform, sp_mode=sp_mode,
-                    ep_mesh=ep_mesh)
-                return cost, buf_upd
+            loss_fn = self._train_loss_fn(compute_dtype, sp_mesh, platform,
+                                          sp_mode, ep_mesh)
         else:
-            loss_fn = self._pipelined_loss_fn(pipe_cfg, compute_dtype,
-                                              platform,
-                                              pipe_remat=pipe_remat,
-                                              sp_mode=sp_mode)
+            piped = self._pipelined_loss_fn(pipe_cfg, compute_dtype,
+                                            platform, pipe_remat=pipe_remat,
+                                            sp_mode=sp_mode)
+
+            def loss_fn(*args):
+                cost, buf_upd = piped(*args)
+                return cost, (buf_upd, None)
 
         if remat:
             loss_fn = jax.checkpoint(loss_fn)
@@ -560,16 +629,18 @@ class CompiledArch:
             def micro(carry, batch):
                 grads_acc, bufs, cost_acc, i = carry
                 x, y = batch
-                (cost, upd), grads = grad_fn(params_c, bufs, x, y,
-                                             jax.random.fold_in(rng, i))
+                (cost, (upd, exits)), grads = grad_fn(
+                    params_c, bufs, x, y, jax.random.fold_in(rng, i))
                 bufs = {**bufs, **upd}
                 grads_acc = jax.tree.map(
                     lambda a, g: a + g.astype(jnp.float32), grads_acc, grads)
-                return (grads_acc, bufs, cost_acc + cost, i + 1), None
+                cost_acc = jax.tree.map(jnp.add, cost_acc,
+                                        {"cost": cost, **(exits or {})})
+                return (grads_acc, bufs, cost_acc, i + 1), None
 
             zeros = jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32),
                                  params)
-            init = (zeros, buffers, jnp.zeros((), jnp.float32), 0)
+            init = (zeros, buffers, self.zero_cost_sum(), 0)
             (grads, new_buffers, cost_sum, _), _ = jax.lax.scan(
                 micro, init, (xs, ys))
             return finalize(params, opt_state, grads, new_buffers, cost_sum)
@@ -590,7 +661,9 @@ class CompiledArch:
 
         def finalize(params, opt_state, grads, new_buffers, cost_sum):
             inv = 1.0 / num_steps
-            cost = cost_sum * inv
+            exits = jax.tree.map(lambda c: c * inv, cost_sum)
+            cost = exits.pop("cost")
+            exits = (exits,) if exits else ()   # a looped stack's only
             grads = jax.tree.map(
                 lambda g, p: (g * inv).astype(p.dtype), grads, params)
             updates, new_opt_state = optimizer.update(grads, opt_state, params)
@@ -601,7 +674,8 @@ class CompiledArch:
                 new_opt_state = jax.lax.with_sharding_constraint(
                     new_opt_state, out_shardings[1])
             if not with_ratios:
-                return new_params, new_opt_state, new_buffers, cost, None
+                return (new_params, new_opt_state, new_buffers, cost, None,
+                        *exits)
             # per-weight update ratio std(Δw)/std(w) (reference :686-700)
 
             def ratio(dw_src, w_src, stacked=False):
@@ -632,9 +706,22 @@ class CompiledArch:
                                              params[k])
             ratios = (jnp.stack([ratio_map[k] for k in self.param_order])
                       if self.param_order else jnp.zeros((0,)))
-            return new_params, new_opt_state, new_buffers, cost, ratios
+            return (new_params, new_opt_state, new_buffers, cost, ratios,
+                    *exits)
 
         return finalize
+
+    def zero_cost_sum(self) -> dict:
+        """What a training epoch accumulates over its micro-steps beside the
+        gradient: the cost and, for a model with several exits (a looped
+        stack), each pass's mean loss and the mean exit distribution,
+        which then leave the epoch program after its five results."""
+        zeros = lambda *shape: jnp.zeros(shape, jnp.float32)
+        if self.looped is None:
+            return {"cost": zeros()}
+        # an array each: the micro-stepped path donates the accumulator
+        return {"cost": zeros(), "pass_loss": zeros(self.looped.steps),
+                "exit_mass": zeros(self.looped.steps)}
 
     def train_micro_fns(self, optimizer_config: dict, num_steps: int,
                         remat: bool = False, compute_dtype=None,
@@ -655,7 +742,7 @@ class CompiledArch:
           ``(buffers, grads, cost)`` — one micro-step's grads accumulated
           in fp32.
         - ``finalize_fn(params, opt_state, grads, buffers, cost)`` → the
-          epoch fn's 5-tuple.
+          epoch fn's results.
 
         Numerics match the fused epoch to fp tolerance: same
         ``fold_in(rng, i)`` stream, same fp32 accumulation order, the
@@ -680,14 +767,8 @@ class CompiledArch:
 
         optimizer = dsl.build_optimizer(optimizer_config)
 
-        def loss_fn(params, buffers, x, y, rng):
-            _, cost, buf_upd, _ = self.forward(
-                params, buffers, x, y, training=True, rng=rng,
-                skip_softmax=True, compute_dtype=compute_dtype,
-                sp_mesh=sp_mesh, platform=platform, sp_mode=sp_mode,
-                ep_mesh=ep_mesh)
-            return cost, buf_upd
-
+        loss_fn = self._train_loss_fn(compute_dtype, sp_mesh, platform,
+                                      sp_mode, ep_mesh)
         if remat:
             loss_fn = jax.checkpoint(loss_fn)
         grad_fn = jax.value_and_grad(loss_fn, has_aux=True)
@@ -700,12 +781,13 @@ class CompiledArch:
                     for k, v in params.items()}
             else:
                 params_c = params
-            (cost, upd), grads = grad_fn(params_c, bufs, x, y,
-                                         jax.random.fold_in(rng, i))
+            (cost, (upd, exits)), grads = grad_fn(
+                params_c, bufs, x, y, jax.random.fold_in(rng, i))
             bufs = {**bufs, **upd}
             grads_acc = jax.tree.map(
                 lambda a, g: a + g.astype(jnp.float32), grads_acc, grads)
-            return bufs, grads_acc, cost_acc + cost
+            return bufs, grads_acc, jax.tree.map(
+                jnp.add, cost_acc, {"cost": cost, **(exits or {})})
 
         finalize = self._finalize_update_fn(optimizer, num_steps,
                                             out_shardings, with_ratios,
@@ -978,6 +1060,8 @@ class ServePipeline:
     """
 
     def __init__(self, arch: "CompiledArch", stages: int):
+        arch.refuse_looped("serving pipeline stages "
+                           "(PENROZ_SERVE_PIPE_STAGES)")
         from penroz_tpu.parallel import pipeline
         if arch.ssm_specs:
             raise ValueError(
@@ -1376,9 +1460,11 @@ class NeuralNetworkModel:
                             and not all(self._is_host_readable(v)
                                         for v in
                                         self._checkpoint_items().values()))
-            # PENROZ_REMAT=1 rematerializes the forward inside the backward
-            # (jax.checkpoint) — trades ~1/3 more FLOPs for activation memory,
-            # the lever for configs that would otherwise exceed HBM.
+            # PENROZ_REMAT=1 wraps the whole loss of a micro-step in one
+            # jax.checkpoint: the backward replays the whole forward first, so
+            # it costs ~1/3 more FLOPs and the replay holds every activation
+            # again; recomputation that does bound memory is per application,
+            # as ops/modules.py::Looped does for its blocks and exits.
             remat = os.environ.get("PENROZ_REMAT", "0") == "1"
             # PENROZ_PIPE_REMAT selects the pipelined path's activation
             # schedule: 'block' (default — backward recomputes each block
@@ -1502,11 +1588,11 @@ class NeuralNetworkModel:
                 # returns at dispatch, float(cost) waits for the device.
                 with tracing.span("penroz/train_epoch", epoch=epoch + 1,
                                   tokens=num_steps * buffer_size,
-                                  sampled=sampled, microstepped=use_micro):
+                                  sampled=sampled,
+                                  microstepped=use_micro) as epoch_span:
                     with tracing.span("penroz/train_dispatch"):
                         if use_micro:
-                            (self.params, self.opt_state, self.buffers,
-                             cost, ratios) = self._train_epoch_microstepped(
+                            out = self._train_epoch_microstepped(
                                 xs, ys, jax.random.fold_in(rng, epoch),
                                 num_steps, remat=remat,
                                 compute_dtype=compute_dtype,
@@ -1515,12 +1601,19 @@ class NeuralNetworkModel:
                                 sp_mode=sp_mode, ep_mesh=ep_mesh,
                                 with_ratios=sampled)
                         else:
-                            (self.params, self.opt_state, self.buffers,
-                             cost, ratios) = fn(
+                            out = fn(
                                 self.params, self.opt_state, self.buffers,
                                 xs, ys, jax.random.fold_in(rng, epoch))
+                        (self.params, self.opt_state, self.buffers,
+                         cost, ratios) = out[:5]
                     with tracing.span("penroz/train_wait"):
                         cost = float(cost)
+                        # a looped stack's exits: each pass's mean loss and
+                        # the mean exit distribution, one host read
+                        exits = ({k: np.asarray(v, np.float64).tolist()
+                                  for k, v in out[5].items()}
+                                 if len(out) > 5 else {})
+                    epoch_span.set(**tracing.exit_counters(exits))
                 duration = time.monotonic() - t0
                 if master:
                     if epoch % sample_every == 0:
@@ -1531,6 +1624,7 @@ class NeuralNetworkModel:
                             "speedPerSec": buffer_size / max(duration, 1e-9),
                             "weight_upd_ratio":
                                 np.asarray(ratios, np.float64).tolist(),
+                            **exits,
                         })
                     log.info("Epoch %d: cost=%.4f %.0f tokens/sec",
                              epoch + 1, cost,
@@ -1635,7 +1729,7 @@ class NeuralNetworkModel:
             platform=self._placement, with_ratios=with_ratios,
             out_shardings=out_shardings, sp_mode=sp_mode, ep_mesh=ep_mesh)
         grads = _sharded_zero_grads(self.params)
-        cost = jnp.zeros((), jnp.float32)
+        cost = self.arch.zero_cost_sum()
         bufs = self.buffers
         for i in range(num_steps):
             if i:
@@ -1837,6 +1931,7 @@ class NeuralNetworkModel:
         (mesh, start, count, num_microbatches)`` feeds
         :meth:`CompiledArch.train_epoch_fn`.
         """
+        self.arch.refuse_looped("pipeline stages (PENROZ_MESH_PIPE)")
         from penroz_tpu.parallel import pipeline
         pipe = mesh.shape[mesh_lib.PIPE_AXIS]
         data = mesh.shape[mesh_lib.DATA_AXIS]

@@ -121,6 +121,52 @@ def hybrid_custom(d: int, heads: int, depth: int, vocab: int = 50304,
     return base
 
 
+def ouro_custom(d: int, heads: int, head_dim: int, intermediate: int,
+                depth: int, steps: int, vocab: int, rope_theta: float = 1e6,
+                eps: float = 1e-6, entropy_weight: float = 0.1) -> list:
+    """Ouro-shaped looped language model (Zhu et al. 2025, arXiv:2510.25741;
+    ``ByteDance/Ouro-*`` ``config.json``) at arbitrary dimensions: ``depth``
+    sandwich-norm blocks (RMSNorm before and after each branch; full
+    attention with rotate-half RoPE, no bias; SwiGLU) run ``steps`` times
+    with shared weights, the final RMSNorm inside the loop, an untied head
+    and a one-scalar exit gate after every pass (``ops/modules.py::Looped``).
+    Trained with the expected loss over the exit distribution less
+    ``entropy_weight`` × its entropy.  N(0, 0.02) initialisation, the
+    residual projections scaled by 1/sqrt(2 · depth · steps): each is
+    applied ``steps`` times."""
+    std = 0.02
+    proj_std = std / (2 * depth * steps) ** 0.5
+    norm = {"rmsnorm": {"normalized_shape": d, "eps": eps}}
+
+    def linear(fan_in, fan_out, s=std, bias=False):
+        entry = {"linear": {"in_features": fan_in, "out_features": fan_out,
+                            "bias": bias},
+                 "normal": {"mean": 0.0, "std": s}}
+        return {**entry, "zeros": {}} if bias else entry
+
+    block = {"transformerblock": {
+        "attn_block": {"sequential": [
+            norm,
+            linear(d, 3 * heads * head_dim),
+            {"attention": {"num_heads": heads, "head_dim": head_dim,
+                           "rope_theta": rope_theta}},
+            linear(heads * head_dim, d, proj_std)]},
+        "mlp_block": {"sequential": [
+            norm,
+            {"gatedmlp": {"in_features": d, "intermediate_size": intermediate,
+                          "activation": "silu"}}]},
+        "post_attn_norm": norm, "post_mlp_norm": norm,
+        "post_norm_on_residual": False}}
+    return [
+        {"embedding": {"num_embeddings": vocab, "embedding_dim": d},
+         "normal": {"mean": 0.0, "std": std}},
+        {"looped": {"steps": steps, "body": [block for _ in range(depth)],
+                    "exit": {"norm": norm, "head": linear(d, vocab),
+                             "gate": linear(d, 1, bias=True)},
+                    "entropy_weight": entropy_weight}},
+        {"softmaxlast": {"dim": -1}}]
+
+
 def makemore_mlp(vocab: int = 27, d_embed: int = 10,
                  d_hidden: int = 200) -> list:
     """Char-level MLP in the makemore style (BASELINE.md CPU-parity config):

@@ -6,8 +6,13 @@ The reference computes CE through torch's fused ``F.cross_entropy`` path
 of the logits and saves fp32 residuals for the backward — ~1.6 GB at B=8,
 T=1024, V=50304, almost all of it HBM traffic rather than MXU work.
 
-``fused_cross_entropy_mean`` is a ``custom_vjp`` whose forward saves only the
-original (bf16) logits, the integer targets, and the per-row fp32 ``lse``:
+``fused_cross_entropy_rows`` (one loss a token) is a ``custom_vjp`` whose
+forward saves only the original (bf16) logits, the integer targets, and the
+per-row fp32 ``lse``; its backward takes one cotangent a row.
+``fused_cross_entropy_mean`` is the mean built on it, and
+``expected_exit_loss`` the loss of a model with several exits (a looped
+stack, ops/modules.py::Looped), which weighs each token's losses by that
+token's exit distribution:
 
 - On TPU it dispatches to streaming Pallas kernels
   (ops/pallas/cross_entropy.py) that read the logits exactly once per pass.
@@ -71,46 +76,52 @@ def _jnp_forward(x2d, t1d, chunk_rows: int):
 
 
 def _jnp_backward(x2d, t1d, lse, scale, chunk_rows: int):
-    """(softmax - onehot) · scale from saved lse, row-chunked."""
+    """(softmax - onehot) · scale from saved lse, row-chunked; ``scale`` is
+    fp32 ``(N, 1)``, one cotangent a row (a scalar serves every row)."""
     xp, tp, num_chunks = pad_rows(x2d, t1d, chunk_rows)
     v = xp.shape[-1]
     pad = xp.shape[0] - x2d.shape[0]
-    lp = jnp.pad(lse, ((0, pad), (0, 0))) if pad else lse
+    scale = jnp.broadcast_to(jnp.asarray(scale, jnp.float32).reshape(-1, 1),
+                             (x2d.shape[0], 1))
+    lp, sp = ((jnp.pad(a, ((0, pad), (0, 0))) for a in (lse, scale))
+              if pad else (lse, scale))
     xc = xp.reshape(num_chunks, chunk_rows, v)
     tc = tp.reshape(num_chunks, chunk_rows)
     lc = lp.reshape(num_chunks, chunk_rows, 1)
+    sc = sp.reshape(num_chunks, chunk_rows, 1)
 
     def step(_, chunk):
-        cx, ct, cl = chunk
+        cx, ct, cl, cs = chunk
         x = cx.astype(jnp.float32)
         p = jnp.exp(x - cl)
         safe_t = jnp.maximum(ct, 0)
         onehot = (jnp.arange(v, dtype=jnp.int32)[None, :] == safe_t[:, None])
         valid = (ct >= 0)[:, None]
-        return None, jnp.where(valid, (p - onehot) * scale, 0.0).astype(cx.dtype)
+        return None, jnp.where(valid, (p - onehot) * cs, 0.0).astype(cx.dtype)
 
-    _, grads = jax.lax.scan(step, None, (xc, tc, lc))
+    _, grads = jax.lax.scan(step, None, (xc, tc, lc, sc))
     return grads.reshape(-1, v)[: x2d.shape[0]]
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
-def fused_cross_entropy_mean(logits, targets, chunk_rows: int = _CHUNK_ROWS,
+def fused_cross_entropy_rows(logits, targets, chunk_rows: int = _CHUNK_ROWS,
                              platform=None):
-    """Mean integer-label CE over all leading dims without fp32 blowup.
+    """Integer-label CE of every token, fp32, in the shape of ``targets``,
+    without fp32 blowup: ``lse − label logit`` a row.
 
     ``logits``: ``(..., V)`` float (bf16 stays bf16 in HBM); ``targets``:
     ``(...,)`` int.  Numerically equivalent (fp32 accumulation) to
-    ``optax.softmax_cross_entropy_with_integer_labels(f32(logits), t).mean()``.
-    ``platform`` is the execution-placement hint forwarded to the Pallas gate
-    (see ops/attention.py:_tpu_platform).
+    ``optax.softmax_cross_entropy_with_integer_labels(f32(logits), t)``.
+    The backward takes one cotangent a row.  ``platform`` is the
+    execution-placement hint forwarded to the Pallas gate (see
+    ops/attention.py:_tpu_platform).
     """
-    loss, _ = _fce_fwd(logits, targets, chunk_rows, platform)
-    return loss
+    rows, _ = _fce_fwd(logits, targets, chunk_rows, platform)
+    return rows
 
 
 def _fce_fwd(logits, targets, chunk_rows: int, platform):
     v = logits.shape[-1]
-    n = int(np.prod(targets.shape)) if targets.shape else 1
     x2d = logits.reshape(-1, v)
     t1d = targets.reshape(-1).astype(jnp.int32)
     if _use_pallas(x2d, platform):
@@ -121,21 +132,19 @@ def _fce_fwd(logits, targets, chunk_rows: int, platform):
                              ("b.", "b."), x2d, t1d)
     else:
         lse, ll = _jnp_forward(x2d, t1d, chunk_rows)
-    loss = jnp.sum(lse - ll) / n
-    return loss, (logits, targets, lse)
+    return (lse - ll).reshape(targets.shape), (logits, targets, lse)
 
 
 def _fce_bwd(chunk_rows: int, platform, residuals, gbar):
     logits, targets, lse = residuals
     v = logits.shape[-1]
-    n = int(np.prod(targets.shape)) if targets.shape else 1
     x2d = logits.reshape(-1, v)
     t1d = targets.reshape(-1).astype(jnp.int32)
-    scale = gbar.astype(jnp.float32) / n
+    scale = gbar.astype(jnp.float32).reshape(-1, 1)
     if _use_pallas(x2d, platform):
         from penroz_tpu.ops.attention import _on_shards
         from penroz_tpu.ops.pallas import cross_entropy as ce
-        grad = _on_shards(ce.ce_backward, platform, ("b.", "b", "b.", ""),
+        grad = _on_shards(ce.ce_backward, platform, ("b.", "b", "b.", "b."),
                           "b.", x2d, t1d, lse, scale)
     else:
         grad = _jnp_backward(x2d, t1d, lse, scale, chunk_rows)
@@ -143,4 +152,44 @@ def _fce_bwd(chunk_rows: int, platform, residuals, gbar):
     return grad.reshape(logits.shape), t_tangent
 
 
-fused_cross_entropy_mean.defvjp(_fce_fwd, _fce_bwd)
+fused_cross_entropy_rows.defvjp(_fce_fwd, _fce_bwd)
+
+
+def fused_cross_entropy_mean(logits, targets, chunk_rows: int = _CHUNK_ROWS,
+                             platform=None):
+    """Mean of :func:`fused_cross_entropy_rows` over all leading dims (each
+    row's cotangent is then the same ``ḡ / N``)."""
+    n = int(np.prod(targets.shape)) if targets.shape else 1
+    return jnp.sum(fused_cross_entropy_rows(logits, targets, chunk_rows,
+                                            platform)) / n
+
+
+def exit_distribution(gate_logits):
+    """Per-token exit distribution of a stack with ``R`` exits from its
+    ``(R, ...)`` gate logits: ``λt = σ(g^t)``, ``p1 = λ1``, ``pt = λt ·
+    Π_{j<t}(1 − λj)``, the last exit taking what is left, ``pR = Π_{j<R}(1 −
+    λj)`` (its own gate is not read).  fp32; sums to 1 over axis 0."""
+    lam = jax.nn.sigmoid(gate_logits.astype(jnp.float32))
+    stay = jnp.cumprod(1.0 - lam[:-1], axis=0)      # Π_{j<=t}(1 − λj)
+    reach = jnp.concatenate([jnp.ones_like(lam[:1]), stay], axis=0)
+    return jnp.concatenate([lam[:-1] * reach[:-1], reach[-1:]], axis=0)
+
+
+def expected_exit_loss(ce_rows, gate_logits, entropy_weight: float):
+    """Mean over tokens of ``Σt pt · CE_t + β · Σt pt · log pt`` (the
+    expected task loss less ``β`` × the exit distribution's entropy; Zhu et
+    al. 2025, "Scaling Latent Reasoning via Looped Language Models", Stage
+    I), with ``ce_rows`` and ``gate_logits`` both ``(R, ...)``.
+
+    Returns ``(loss, {"pass_loss": (R,), "exit_mass": (R,)})``: the mean CE
+    of each exit and the mean exit distribution, for the counters.
+    """
+    p = exit_distribution(gate_logits)
+    tokens = tuple(range(1, p.ndim))
+    ce_rows = ce_rows.astype(jnp.float32)
+    neg_entropy = jnp.sum(p * jnp.log(jnp.maximum(p, 1e-30)), axis=0)
+    loss = jnp.mean(jnp.sum(p * ce_rows, axis=0)
+                    + entropy_weight * neg_entropy)
+    stats = {"pass_loss": jax.lax.stop_gradient(jnp.mean(ce_rows, tokens)),
+             "exit_mass": jax.lax.stop_gradient(jnp.mean(p, tokens))}
+    return loss, stats

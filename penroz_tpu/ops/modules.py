@@ -26,6 +26,7 @@ Design notes (TPU-first):
 
 from __future__ import annotations
 
+import copy
 import functools
 import logging
 import math
@@ -53,7 +54,7 @@ class Ctx:
                  kv=None, pos_offset=None, compute_dtype=None, sp_mesh=None,
                  platform=None, sp_mode="ring", sp_manual_axis=None,
                  ep_mesh=None, lora=None, lora_idx=None, ragged_descs=None,
-                 ragged_rows=None):
+                 ragged_rows=None, targets=None):
         self.params = params
         self.buffers = buffers or {}
         self.training = training
@@ -93,6 +94,16 @@ class Ctx:
         # per-token absolute positions.
         self.ragged_descs = ragged_descs
         self.ragged_rows = ragged_rows
+        # A stack with several exits (:class:`Looped`) takes its loss from
+        # each of them: given ``targets`` it leaves every exit's per-token
+        # cross-entropy and gate logit in ``exits`` for the model's cost.
+        # ``layer_offset`` is added to an attention layer's cache slot: the
+        # pass of the loop the layer is being applied in, times the slots
+        # a pass holds.
+        self.targets = targets
+        self.exits = None
+        self.exit_stats = None  # the model's, from ``exits``: for counters
+        self.layer_offset = 0
         self.buffer_updates = {}
         self.aux_losses = []  # auxiliary training losses (e.g. MoE balance)
         self._rng_counter = 0
@@ -696,6 +707,130 @@ class TransformerBlock(Module):
         return out
 
 
+@functools.lru_cache(maxsize=None)
+def _log_loop_plan(**plan) -> None:
+    """Once per distinct plan of the process."""
+    log.info("loop plan: %s", " ".join(f"{k}={v}" for k, v in plan.items()))
+
+
+class Looped(Module):
+    """One stack of blocks run ``steps`` times with the same weights, an
+    exit after every pass (Zhu et al. 2025, "Scaling Latent Reasoning via
+    Looped Language Models")::
+
+        u = x
+        for t in 1..steps:
+            for block in body: u = block(u)       # the SAME blocks each pass
+            h_t = norm(u);  u = h_t               # the final norm is inside
+            z_t = head(h_t);  g_t = gate(h_t)     # logits; one scalar a token
+
+    The container owns one set of parameters (``body.<i>…``, ``norm``,
+    ``head``, ``gate``); a shared weight's gradient is the sum over its
+    ``steps`` applications.  :meth:`apply` returns the last pass's logits
+    (generation never leaves early), so the container stands where a plain
+    stack has its blocks, final norm and head.  Given ``ctx.targets`` it
+    also leaves in ``ctx.exits`` every exit's per-token cross-entropy and
+    gate logit, ``(steps, …)`` each, from which the model takes
+    ``ops/losses.py::expected_exit_loss`` (weight ``entropy_weight``).
+
+    In training every application of a block runs under ``jax.checkpoint``
+    (a pass's last block together with the final norm and the exit: head,
+    gate, cross-entropy): the backward keeps each application's input and
+    recomputes its inside, so ``steps`` passes hold ``steps × len(body)``
+    block inputs and not ``steps`` times a plain stack's activations, nor
+    ``steps`` sets of logits.  That is a property of the container, not an
+    option.  With a KV cache each (pass, layer) has its own slot: pass ``t``
+    offsets its attention layers' slots by ``t · slots_per_pass``
+    (``Ctx.layer_offset``; the model builder counts the slots).
+    """
+
+    def __init__(self, steps: int, body: Sequence[Module], norm: Module,
+                 head: Module, gate: Module, entropy_weight: float = 0.1):
+        if int(steps) < 1:
+            raise ValueError(f"looped steps must be >= 1, got {steps}")
+        if not body:
+            raise ValueError("looped body must hold at least one block")
+        self.steps = int(steps)
+        self.body = list(body)
+        self.norm, self.head, self.gate = norm, head, gate
+        self.entropy_weight = float(entropy_weight)
+        self.slots_per_pass = 0  # attention layers a pass; the model builder
+
+    def children(self):
+        return ([(f"body.{i}", b) for i, b in enumerate(self.body)]
+                + [("norm", self.norm), ("head", self.head),
+                   ("gate", self.gate)])
+
+    def plan(self, training: bool) -> dict:
+        applications = self.steps * len(self.body)
+        return {"steps": self.steps, "layers": len(self.body),
+                "applications": applications,
+                "recomputed_applications": applications if training else 0,
+                "cache_slots": self.steps * self.slots_per_pass}
+
+    def _run(self, fn, ctx, mods, *args):
+        """``fn(ctx, *args)``; in training under ``jax.checkpoint``, as a
+        function of ``mods``' own parameters and ``args``: what the inside
+        leaves on its context (auxiliary losses, buffer updates, the dropout
+        counter) is handed on to ``ctx``."""
+        if not ctx.training:
+            return fn(ctx, *args)
+        prefixes = tuple(m.prefix + "." for m in mods)
+        own = {k: v for k, v in ctx.params.items() if k.startswith(prefixes)}
+        counter = [ctx._rng_counter]
+
+        def pure(params, rng, *inputs):
+            inner = copy.copy(ctx)
+            inner.params, inner.rng = params, rng
+            inner.aux_losses, inner.buffer_updates = [], {}
+            out = fn(inner, *inputs)
+            counter[0] = inner._rng_counter
+            return out, inner.aux_losses, inner.buffer_updates
+
+        out, aux, updates = jax.checkpoint(pure)(own, ctx.rng, *args)
+        ctx._rng_counter = counter[0]
+        ctx.aux_losses.extend(aux)
+        ctx.buffer_updates.update(updates)
+        return out
+
+    def _exit(self, ctx, h, targets):
+        """One exit: (per-token cross-entropy, gate logit), fp32."""
+        from penroz_tpu.ops import losses
+        rows = losses.fused_cross_entropy_rows(
+            self.head.apply(h, ctx), targets, platform=ctx.platform)
+        return rows, self.gate.apply(h, ctx)[..., 0].astype(jnp.float32)
+
+    def _pass_end(self, ctx, u, targets):
+        """The last block of a pass, the final norm and, given targets, the
+        exit: ``(h_t, exit or None)``.  One unit under recomputation, so
+        that exit ``t``'s backward waits for ``h_t``'s cotangent from pass
+        ``t + 1`` and the exits' logits are never held side by side."""
+        h = self.norm.apply(self.body[-1].apply(u, ctx), ctx)
+        return h, (self._exit(ctx, h, targets) if targets is not None
+                   else None)
+
+    def apply(self, x, ctx):
+        plan = self.plan(ctx.training)
+        _log_loop_plan(**plan)
+        with tracing.span("penroz/loop_plan", **plan):
+            pass
+        u, exits = x, []
+        for t in range(self.steps):
+            ctx.layer_offset = t * self.slots_per_pass
+            for block in self.body[:-1]:
+                u = self._run(lambda inner, h, b=block: b.apply(h, inner),
+                              ctx, [block], u)
+            u, out = self._run(
+                self._pass_end, ctx,
+                [self.body[-1], self.norm, self.head, self.gate], u,
+                ctx.targets)
+            exits.append(out)
+        ctx.layer_offset = 0
+        if ctx.targets is not None:
+            ctx.exits = tuple(jnp.stack(part) for part in zip(*exits))
+        return self.head.apply(u, ctx)
+
+
 class GatedMLP(Module):
     """SwiGLU/GeGLU gated MLP (Gemma/LLaMA style)."""
 
@@ -1292,28 +1427,26 @@ class CausalSelfAttention(Module):
 
         if ctx.kv is not None:
             from penroz_tpu.ops import kv_cache as KV
+            slot = self.layer_idx + ctx.layer_offset
             paged = isinstance(ctx.kv, KV.PagedKVState)
             ragged = paged and ctx.ragged_descs is not None
             if ragged:
                 store_k, store_v = ctx.kv.append_packed(
-                    self.layer_idx, k, v, ctx.ragged_rows)
+                    slot, k, v, ctx.ragged_rows)
                 length = None
             elif paged:
-                store_k, store_v, length = ctx.kv.append_rows(self.layer_idx,
-                                                              k, v)
+                store_k, store_v, length = ctx.kv.append_rows(slot, k, v)
             elif ctx.kv.quantized:
                 # int8 cache: store + attend on the raw buffers — the
                 # kernel dequantizes per VMEM tile, never materializing a
                 # full-precision cache.
-                store_k, store_v, length = ctx.kv.append_raw(self.layer_idx,
-                                                             k, v)
+                store_k, store_v, length = ctx.kv.append_raw(slot, k, v)
             else:
-                store_k, store_v, length = ctx.kv.append(self.layer_idx,
-                                                         k, v)
+                store_k, store_v, length = ctx.kv.append(slot, k, v)
             # int8 caches (paged pools and contiguous) carry per-token
             # scales; read AFTER the append so the new tokens' scales are in.
-            scales = ({"k_scale": ctx.kv.k_scale[self.layer_idx],
-                       "v_scale": ctx.kv.v_scale[self.layer_idx]}
+            scales = ({"k_scale": ctx.kv.k_scale[slot],
+                       "v_scale": ctx.kv.v_scale[slot]}
                       if ctx.kv.quantized else {})
             if ragged:
                 out = attn_ops.ragged_paged_cached_attention(

@@ -14,11 +14,16 @@ Two kernels, mirroring the flash-attention structure
   per-row ``lse`` and label logit.  The bf16 logits are read exactly once
   and no fp32 copy ever reaches HBM.
 - backward — fully parallel grid: ``(softmax - onehot) · scale`` per chunk
-  from the forward's saved ``lse``, written directly in the logits dtype.
+  from the forward's saved ``lse``, written directly in the logits dtype;
+  ``scale`` is one number a row (the cotangent of that row's loss: 1/N
+  for a mean, a token's exit probability over N for the exit loss).
 
-The public entry is :func:`fused_cross_entropy_mean` in ops/losses.py, which
-dispatches here on TPU and to the jnp chunk-scan elsewhere (the jnp path is
-the correctness oracle in tests/test_losses.py).
+The public entries are :func:`fused_cross_entropy_rows` and
+:func:`fused_cross_entropy_mean` in ops/losses.py, which dispatch here on
+TPU and to the jnp chunk-scan elsewhere (the jnp path is the correctness
+oracle in tests/test_losses.py).  The two calls are named
+(``penroz_ce_fwd`` / ``penroz_ce_bwd``) so that a trace and a reader find
+them by name.
 """
 
 from __future__ import annotations
@@ -87,7 +92,7 @@ def _bwd_kernel(x_ref, t_ref, lse_ref, scale_ref, dx_ref, *, block_n: int,
     onehot = cols == t[:, None]
     valid = (t >= 0)[:, None]  # padded rows contribute zero gradient
     g = jnp.where(valid & (cols < vocab),
-                  (p - onehot) * scale_ref[0], 0.0)
+                  (p - onehot) * scale_ref[...], 0.0)  # (block_n, 1) scale
     dx_ref[...] = g.astype(dx_ref.dtype)
 
 
@@ -139,6 +144,7 @@ def ce_forward(logits2d, targets1d, block_n: int = DEFAULT_BLOCK_N,
             bytes_accessed=int(x.size * x.dtype.itemsize),
             transcendentals=int(n * v)),
         interpret=interpret,
+        name="penroz_ce_fwd",
     )(x, t[:, None])
     real_n = logits2d.shape[0]
     return lse[:real_n], ll[:real_n]
@@ -147,12 +153,17 @@ def ce_forward(logits2d, targets1d, block_n: int = DEFAULT_BLOCK_N,
 def ce_backward(logits2d, targets1d, lse, scale,
                 block_n: int = DEFAULT_BLOCK_N,
                 block_v: int = DEFAULT_BLOCK_V, interpret: bool = False):
-    """``(softmax - onehot) * scale`` in the logits dtype; (N, V)."""
+    """``(softmax - onehot) * scale`` in the logits dtype; (N, V).
+    ``scale``: fp32 ``(N, 1)``, one cotangent a row (a scalar serves every
+    row)."""
     x, t = _pad_rows(logits2d, targets1d, block_n)
     n, v = x.shape
     pad = n - logits2d.shape[0]
+    scale = jnp.broadcast_to(jnp.asarray(scale, jnp.float32).reshape(-1, 1),
+                             (logits2d.shape[0], 1))
     if pad:
         lse = jnp.pad(lse, ((0, pad), (0, 0)))
+        scale = jnp.pad(scale, ((0, pad), (0, 0)))
     block_v = min(block_v, v)
     num_v = -(-v // block_v)
     grid = (n // block_n, num_v)
@@ -168,7 +179,8 @@ def ce_backward(logits2d, targets1d, lse, scale,
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((block_n, 1), lambda i, j: (i, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec((block_n, 1), lambda i, j: (i, 0),
+                         memory_space=pltpu.VMEM),
         ],
         out_specs=pl.BlockSpec((block_n, block_v), lambda i, j: (i, j),
                                memory_space=pltpu.VMEM),
@@ -180,5 +192,6 @@ def ce_backward(logits2d, targets1d, lse, scale,
             bytes_accessed=int(2 * x.size * x.dtype.itemsize),
             transcendentals=int(n * v)),
         interpret=interpret,
-    )(x, t[:, None], lse, jnp.asarray(scale, jnp.float32).reshape((1,)))
+        name="penroz_ce_bwd",
+    )(x, t[:, None], lse, scale)
     return dx[: logits2d.shape[0]]

@@ -38,7 +38,9 @@ gauges     engines, active_rows, queue_depth, batch_occupancy,
            (both monotonic in practice, exposed as gauges because the
            source counters live in ops/kv_cache.py),
            jit_programs{function} (live compiled-program count per jit
-           family — the ragged descriptor compile-churn guard)
+           family — the ragged descriptor compile-churn guard),
+           train_pass_loss{pass}, train_exit_mass{pass} (a looped model's
+           exits, newest /train/ epoch; utils/tracing.py)
 histograms ttft_ms, itl_ms, queue_wait_ms, chunk_stall_ms, tick_ms
            (fixed LATENCY_BUCKETS_MS buckets; cumulative ``_bucket``
            series sum to ``_count`` — asserted by the strict-format
@@ -257,6 +259,8 @@ SESSION_RESUME_TTFT_MS = REGISTRY.register(m.Histogram(
 # Every span of every /train/ job by name (train_epoch, ckpt_save and its
 # children, ...): the object lives with the code that observes it.
 TRAIN_SPAN_MS = REGISTRY.register(tracing.TRAIN_SPAN_MS)
+TRAIN_PASS_LOSS = REGISTRY.register(tracing.TRAIN_PASS_LOSS)
+TRAIN_EXIT_MASS = REGISTRY.register(tracing.TRAIN_EXIT_MASS)
 
 # -- gauges (scrape-time reads of live state) -------------------------------
 

@@ -100,6 +100,33 @@ TRAIN_SPAN_MS = metrics.Histogram(
     "(utils/tracing.py: train_epoch, ckpt_save and its children, ...), ms",
     labelnames=("span",))
 
+# The newest epoch's exits of a looped model's /train/ job, by pass (from 1):
+# each pass's mean cross-entropy and the mean exit distribution.  Counters
+# ``pass_loss_<t>`` / ``exit_mass_<t>`` of ``penroz/train_epoch``, and
+# ``penroz_train_pass_loss{pass}`` / ``penroz_train_exit_mass{pass}`` on
+# GET /metrics (registered by serve/metrics.py).
+_TRAIN_EXITS: dict = {"pass_loss": {}, "exit_mass": {}}
+TRAIN_PASS_LOSS = metrics.Gauge(
+    "penroz_train_pass_loss",
+    "Mean cross-entropy of each exit of a looped model, newest /train/ "
+    "epoch", fn=lambda: _TRAIN_EXITS["pass_loss"], labelnames=("pass",))
+TRAIN_EXIT_MASS = metrics.Gauge(
+    "penroz_train_exit_mass",
+    "Mean exit distribution of a looped model over its passes, newest "
+    "/train/ epoch", fn=lambda: _TRAIN_EXITS["exit_mass"],
+    labelnames=("pass",))
+
+
+def exit_counters(exits: dict) -> dict:
+    """``{"pass_loss": [...], "exit_mass": [...]}`` of one epoch as span
+    counters ``{"pass_loss_1": ..., "exit_mass_1": ..., ...}``; the same
+    values become the gauges' newest reading."""
+    for name, values in exits.items():
+        _TRAIN_EXITS[name] = {str(t + 1): v for t, v in enumerate(values)}
+    return {f"{name}_{t + 1}": v for name, values in exits.items()
+            for t, v in enumerate(values)}
+
+
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 _request_id_var: contextvars.ContextVar = contextvars.ContextVar(

@@ -402,18 +402,14 @@ def test_chunked_prefill_parity_and_stall_bound(gpt_model, make_engine,
     base_a = gpt_model.generate_tokens([pa], BLOCK, 8, temperature=0.0)
     base_b = gpt_model.generate_tokens([pb], BLOCK, 6, temperature=0.0)
     engine = make_engine("schedgpt", BLOCK, 0.0, None, capacity=2)
-    ca = _submit(engine, pa, 8)
-    deadline = time.monotonic() + 120
-    while ca.received < 2:  # A provably mid-decode before B arrives
-        assert time.monotonic() < deadline, "A never started decoding"
-        try:
-            kind, value = ca.q.get(timeout=1.0)
-        except queue.Empty:
-            continue
-        assert kind == "token", kind
-        ca.tokens.append(value)
-        ca.received += 1
+    # A provably mid-decode when B arrives: the worker is parked inside the
+    # delivery of A's second token until B is queued (waiting on the wall
+    # clock instead let A finish first under a loaded machine, and then no
+    # decode step ran between B's chunks)
+    ca = _submit(engine, pa, 8, hold_at=2)
+    assert ca.held.wait(timeout=120), "A never started decoding"
     cb = _submit(engine, pb, 6)
+    ca.release.set()
     assert cb.result() == base_b
     assert ca.result() == base_a
     stats = engine.stats()

@@ -103,3 +103,74 @@ def test_under_remat():
     grad = jax.grad(f)(logits)
     want = jax.grad(lambda x: _oracle(x, targets))(logits)
     np.testing.assert_allclose(np.asarray(grad), np.asarray(want), atol=1e-6)
+
+
+@pytest.mark.parametrize("shape,v,dtype", [
+    ((16,), 1024, jnp.float32),
+    ((3, 40), 640, jnp.bfloat16),    # padded rows, leading dims kept
+])
+def test_rows_and_per_row_cotangent_match_jnp(shape, v, dtype):
+    """The per-token form: ``lse − label logit`` a row out, one cotangent a
+    row in, against plain jnp."""
+    rng = np.random.default_rng(11)
+    logits = jnp.asarray(rng.normal(size=(*shape, v)) * 3, dtype)
+    targets = jnp.asarray(rng.integers(0, v, shape), jnp.int32)
+    weights = jnp.asarray(rng.uniform(0.1, 2.0, shape), jnp.float32)
+
+    def plain(x):
+        logp = jax.nn.log_softmax(x.astype(jnp.float32), axis=-1)
+        return -jnp.take_along_axis(logp, targets[..., None], -1)[..., 0]
+
+    rows = losses.fused_cross_entropy_rows(logits, targets, 8)
+    assert rows.shape == shape and rows.dtype == jnp.float32
+    np.testing.assert_allclose(rows, plain(logits), rtol=1e-5, atol=1e-5)
+    got = jax.grad(lambda x: jnp.sum(
+        weights * losses.fused_cross_entropy_rows(x, targets, 8)))(logits)
+    want = jax.grad(lambda x: jnp.sum(weights * plain(x)))(logits)
+    assert got.dtype == dtype
+    tol = 1e-5 if dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32), atol=tol)
+
+
+@pytest.mark.parametrize("n,v", [(40, 2048 + 512), (300, 1536)])
+def test_pallas_backward_takes_a_cotangent_a_row(n, v):
+    from penroz_tpu.ops.pallas import cross_entropy as ce
+    rng = np.random.default_rng(13)
+    logits = jnp.asarray(rng.normal(size=(n, v)) * 3, jnp.float32)
+    targets = jnp.asarray(rng.integers(0, v, (n,)), jnp.int32)
+    scale = jnp.asarray(rng.uniform(-1.0, 1.0, (n, 1)), jnp.float32)
+    lse, _ = losses._jnp_forward(logits, targets, 64)
+    got = ce.ce_backward(logits, targets, lse, scale, block_n=8, block_v=512,
+                         interpret=True)
+    want = losses._jnp_backward(logits, targets, lse, scale, 64)
+    np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-6)
+    soft = jax.nn.softmax(logits, axis=-1)
+    onehot = jax.nn.one_hot(targets, v)
+    np.testing.assert_allclose(want, (soft - onehot) * scale, atol=1e-5)
+
+
+def test_mean_is_unchanged_to_the_bit():
+    """The mean built on the per-token form computes what the fused mean
+    computed before it: ``Σ(lse − ll) / N`` forward, ``(softmax − onehot) ·
+    ḡ / N`` backward, from the same chunked scan."""
+    rng = np.random.default_rng(17)
+    n, v = 300, 515
+    logits = jnp.asarray(rng.normal(size=(n, v)) * 3, jnp.float32)
+    targets = jnp.asarray(rng.integers(0, v, (n,)), jnp.int32)
+    gbar = jnp.asarray(0.7, jnp.float32)
+
+    @jax.jit
+    def before(x):
+        lse, ll = losses._jnp_forward(x, targets, 64)
+        return (jnp.sum(lse - ll) / n,
+                losses._jnp_backward(x, targets, lse, gbar / n, 64))
+
+    @jax.jit
+    def now(x):
+        value, pull = jax.vjp(
+            lambda z: losses.fused_cross_entropy_mean(z, targets, 64), x)
+        return value, pull(gbar)[0]
+
+    for got, want in zip(now(logits), before(logits)):
+        assert np.array_equal(np.asarray(got), np.asarray(want))

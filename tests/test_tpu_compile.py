@@ -150,7 +150,7 @@ def _ce_bwd():
     return ce.ce_backward, [((ROWS * BLOCK, VOCAB), jnp.bfloat16),
                             ((ROWS * BLOCK,), jnp.int32),
                             ((ROWS * BLOCK, 1), jnp.float32),
-                            ((), jnp.float32)]
+                            ((ROWS * BLOCK, 1), jnp.float32)]   # a row each
 
 
 def _ssm_scan():
@@ -527,3 +527,79 @@ def test_flash_kernels_are_where_the_benchmark_looks_for_them(chip):
     assert 1 <= len(bwd) <= 2, calls
     assert all("penroz_flash_bwd" in c[0] for c in bwd), calls
     assert len(calls) == len(fwd) + len(bwd), calls
+
+
+def test_looped_stack_compiles_and_its_kernels_are_where_the_benchmark_looks(
+        chip):
+    """One layer run twice with shared weights at the looped cell's widths
+    (``ouro-train-4k-loop4``: micro-batch 2 x 4096, d 2048, 16 heads of 128,
+    SwiGLU 5632, vocabulary 49152, bf16), the exit loss and its gradient,
+    compiled for a v5e: every application's flash forward appears twice
+    (once recomputed), its split backward once, each exit's cross-entropy
+    forward twice and backward once, all under their names — and the
+    benchmark's two readers, loaded from their files, find them in a trace
+    made of this program's instructions."""
+    import importlib.util
+    import sys
+    from penroz_tpu.models import dsl, presets
+    from penroz_tpu.models.model import CompiledArch
+    steps, rows, block = 2, 2, 4096
+    arch = CompiledArch.get(presets.ouro_custom(
+        d=2048, heads=16, head_dim=128, intermediate=5632, depth=1,
+        steps=steps, vocab=49152))
+    shapes, _ = jax.eval_shape(
+        lambda: dsl.init_module_params(arch.mods, seed=0))
+    params = {k: jax.ShapeDtypeStruct(v.shape, jnp.bfloat16, sharding=chip)
+              for k, v in shapes.items()}
+    x = jax.ShapeDtypeStruct((rows, block), jnp.int32, sharding=chip)
+
+    def loss(p, x, y):
+        _, cost, _, _ = arch.forward(p, {}, x, y, training=True,
+                                     skip_softmax=True,
+                                     compute_dtype=jnp.bfloat16,
+                                     platform="tpu")
+        return cost
+
+    hlo = jax.jit(jax.grad(loss)).lower(params, x, x).compile().as_text()
+    calls = _custom_calls(hlo)
+    count = lambda needle: sum(needle in name for name, _ in calls)
+    assert count("penroz_flash_fwd") == 2 * steps, calls
+    assert count("penroz_flash_bwd_dq") == count("penroz_flash_bwd_dkv") \
+        == count("penroz_flash_bwd_delta") == steps, calls
+    assert count("penroz_ce_fwd") == 2 * steps, calls
+    assert count("penroz_ce_bwd") == steps, calls
+    assert len(calls) == 8 * steps, calls
+    # attention stays in the model's layout at D = 128 after RoPE
+    assert "bf16[2,16,4096,128]" not in hlo
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    sys.path.insert(0, root)
+    try:
+        readers = {}
+        for name in ("penroz_flash_roofline.useful", "penroz_ce_roofline"):
+            spec = importlib.util.spec_from_file_location(
+                "bench_metric_" + name.replace(".", "_"),
+                os.path.join(root, "benchmark", "metrics", name + ".py"))
+            readers[name] = importlib.util.module_from_spec(spec)
+            spec.loader.exec_module(readers[name])
+        from benchmark.lib import kernel_costs, looped_costs, peaks
+    finally:
+        sys.path.remove(root)
+    lines = [line.strip().removeprefix("ROOT ") for line in hlo.splitlines()
+             if " custom-call(" in line and "tpu_custom_call" in line]
+    ops = [(line, i * 1e-3, (i + 1) * 1e-3) for i, line in enumerate(lines)]
+    v5e = peaks.peaks_for("TPU v5 lite")
+    art = {"kind": "train", "peaks": v5e,
+           "dims": {"d": 2048, "heads": 16, "head_dim": 128, "vocab": 49152},
+           "job": {"batch_size": rows, "block_size": block},
+           "trace": {"planes": {"devices": {0: {"ops": ops}}, "spans": []},
+                     "w0": 0.0, "w1": 1.0}}
+    least = lambda cost: kernel_costs.roofline_seconds(cost, v5e)[0]
+    flash = kernel_costs.flash_attention(rows, 16, block, 128, 2)
+    assert readers["penroz_flash_roofline.useful"].read(art) == pytest.approx(
+        100.0 * steps * (least(flash["fwd"]) + least(flash["bwd"]))
+        / (5 * steps * 1e-3))
+    ce = looped_costs.cross_entropy(rows * block, 49152, 2)
+    assert readers["penroz_ce_roofline"].read(art) == pytest.approx(
+        100.0 * steps * (2 * least(ce["fwd"]) + least(ce["bwd"]))
+        / (3 * steps * 1e-3))

@@ -65,16 +65,16 @@ class App:
         except ValueError:
             return resp, body.decode()
 
-    def create(self, model_id):
+    def create(self, model_id, layers=LAYERS):
         resp, body = self.call("POST", "/model/", json={
-            "model_id": model_id, "layers": LAYERS,
+            "model_id": model_id, "layers": layers,
             "optimizer": {"sgd": {"lr": 0.1}}})
         assert resp.status == 200, body
 
-    def train(self, model_id, epochs=EPOCHS, dataset="ds"):
+    def train(self, model_id, epochs=EPOCHS, dataset="ds", batch=BATCH):
         resp, body = self.call("PUT", "/train/", json={
             "model_id": model_id, "device": "cpu", "dataset_id": dataset,
-            "shard": 0, "epochs": epochs, "batch_size": BATCH,
+            "shard": 0, "epochs": epochs, "batch_size": batch,
             "block_size": BLOCK, "step_size": 1})
         assert resp.status == 202, body
         return resp.headers["X-Request-Id"]
@@ -277,6 +277,58 @@ CASES = [_resolves_live_and_after, _children_lie_inside_parents,
 @pytest.mark.parametrize("case", CASES, ids=lambda f: f.__name__.lstrip("_"))
 def test_train_trace(job, case):
     case(job)
+
+
+def test_a_looped_models_job_carries_its_plan_and_its_exits(app):
+    """``POST /model/`` → ``PUT /train/`` → ``/progress/`` → ``POST
+    /generate/`` of a small looped model on the normal path: the job's
+    trace has ``penroz/loop_plan`` under the compiling epoch's dispatch and
+    the eight exit counters on every epoch; ``/progress/`` rows and
+    ``/metrics`` carry the same."""
+    from penroz_tpu.models import presets
+    steps, depth, epochs = 4, 2, 3
+    app.create("loop", presets.ouro_custom(
+        d=16, heads=2, head_dim=8, intermediate=24, depth=depth, steps=steps,
+        vocab=32))
+    rid = app.train("loop", epochs=epochs, batch=2)
+    progress = app.wait("loop")
+    assert progress["status"]["code"] == "Trained", progress["status"]
+    _, tree = app.call("GET", f"/trace/{rid}")
+    first, *later = named(tree, "penroz/train_epoch")
+    (dispatch,) = [c for c in first["children"]
+                   if c["name"] == "penroz/train_dispatch"]
+    plans = [n for n, _ in walk(dispatch) if n["name"] == "penroz/loop_plan"]
+    assert plans and all(p["meta"] == {
+        "steps": steps, "layers": depth, "applications": steps * depth,
+        "recomputed_applications": steps * depth,
+        "cache_slots": steps * depth} for p in plans)
+    assert not any(n["name"] == "penroz/loop_plan"
+                   for e in later for n, _ in walk(e))
+    # the stats passes at the job's end run the loop too, recomputing nothing
+    (stats,) = named(tree, "penroz/train_stats")
+    assert {n["meta"]["recomputed_applications"] for n, _ in walk(stats)
+            if n["name"] == "penroz/loop_plan"} == {0}
+    counters = [f"{name}_{t}" for name in ("pass_loss", "exit_mass")
+                for t in range(1, steps + 1)]
+    for epoch, row in zip([first, *later], progress["progress"]):
+        meta = epoch["meta"]
+        assert set(counters) <= set(meta)
+        assert sum(meta[f"exit_mass_{t}"] for t in range(1, steps + 1)) \
+            == pytest.approx(1.0, abs=1e-5)
+        assert all(0.0 < meta[f"pass_loss_{t}"] < 10.0
+                   for t in range(1, steps + 1))
+        assert row["pass_loss"] == [meta[f"pass_loss_{t}"]
+                                    for t in range(1, steps + 1)]
+        assert row["exit_mass"] == [meta[f"exit_mass_{t}"]
+                                    for t in range(1, steps + 1)]
+    _, scrape = app.call("GET", "/metrics")
+    for t in range(1, steps + 1):
+        assert f'penroz_train_pass_loss{{pass="{t}"}} ' in scrape
+        assert f'penroz_train_exit_mass{{pass="{t}"}} ' in scrape
+    resp, body = app.call("POST", "/generate/", json={
+        "model_id": "loop", "input": [[1, 2, 3]], "block_size": BLOCK,
+        "max_new_tokens": 4, "temperature": 0.0})
+    assert resp.status == 200, body
 
 
 def test_ring_keeps_the_newest_subtrees_and_counts_the_dropped(

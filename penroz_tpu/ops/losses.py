@@ -27,7 +27,12 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 
+# What ``_fce_fwd`` calls its per-row logsumexp: kept by a ``jax.checkpoint``
+# whose policy saves the name (ops/modules.py::Looped), whose backward then
+# recomputes the logits and not the cross-entropy forward over them.
+LSE_NAME = "penroz_ce_lse"
 # Rows per jnp scan step: big enough to keep the VPU busy, small enough that
 # the fp32 temporaries stay cache-sized.
 _CHUNK_ROWS = 512
@@ -132,6 +137,7 @@ def _fce_fwd(logits, targets, chunk_rows: int, platform):
                              ("b.", "b."), x2d, t1d)
     else:
         lse, ll = _jnp_forward(x2d, t1d, chunk_rows)
+    lse = checkpoint_name(lse, LSE_NAME)
     return (lse - ll).reshape(targets.shape), (logits, targets, lse)
 
 

@@ -713,6 +713,14 @@ def _log_loop_plan(**plan) -> None:
     log.info("loop plan: %s", " ".join(f"{k}={v}" for k, v in plan.items()))
 
 
+def _kept_names() -> tuple:
+    """What :class:`Looped`'s recomputation keeps: the outputs its kernels'
+    callers name (``checkpoint_name``)."""
+    from penroz_tpu.ops import losses
+    from penroz_tpu.ops.pallas import flash_attention as fa
+    return fa.OUT_NAME, fa.LSE_NAME, losses.LSE_NAME
+
+
 class Looped(Module):
     """One stack of blocks run ``steps`` times with the same weights, an
     exit after every pass (Zhu et al. 2025, "Scaling Latent Reasoning via
@@ -738,10 +746,14 @@ class Looped(Module):
     gate, cross-entropy): the backward keeps each application's input and
     recomputes its inside, so ``steps`` passes hold ``steps × len(body)``
     block inputs and not ``steps`` times a plain stack's activations, nor
-    ``steps`` sets of logits.  That is a property of the container, not an
-    option.  With a KV cache each (pass, layer) has its own slot: pass ``t``
-    offsets its attention layers' slots by ``t · slots_per_pass``
-    (``Ctx.layer_offset``; the model builder counts the slots).
+    ``steps`` sets of logits.  Of the inside it keeps what the kernels
+    wrote and name (:func:`_kept_names`: the flash forward's ``o`` and
+    logsumexp, the cross-entropy forward's logsumexp), so the recomputation
+    runs the matmuls, norms and RoPE again and neither kernel's forward.
+    That is a property of the container, not an option.  With a KV cache
+    each (pass, layer) has its own slot: pass ``t`` offsets its attention
+    layers' slots by ``t · slots_per_pass`` (``Ctx.layer_offset``; the
+    model builder counts the slots).
     """
 
     def __init__(self, steps: int, body: Sequence[Module], norm: Module,
@@ -762,11 +774,16 @@ class Looped(Module):
                    ("gate", self.gate)])
 
     def plan(self, training: bool) -> dict:
+        """The loop's counters.  ``kept_outputs``: the names the
+        recomputation's policy saves, kept wherever a kernel's caller gave
+        them (a path that names nothing, the jnp attention, keeps nothing:
+        what that costs in HBM is the device's to say, ``hbm_peak_gb``)."""
         applications = self.steps * len(self.body)
         return {"steps": self.steps, "layers": len(self.body),
                 "applications": applications,
                 "recomputed_applications": applications if training else 0,
-                "cache_slots": self.steps * self.slots_per_pass}
+                "cache_slots": self.steps * self.slots_per_pass,
+                "kept_outputs": ",".join(_kept_names()) if training else ""}
 
     def _run(self, fn, ctx, mods, *args):
         """``fn(ctx, *args)``; in training under ``jax.checkpoint``, as a
@@ -787,7 +804,9 @@ class Looped(Module):
             counter[0] = inner._rng_counter
             return out, inner.aux_losses, inner.buffer_updates
 
-        out, aux, updates = jax.checkpoint(pure)(own, ctx.rng, *args)
+        keep = jax.checkpoint_policies.save_only_these_names(*_kept_names())
+        out, aux, updates = jax.checkpoint(pure, policy=keep)(
+            own, ctx.rng, *args)
         ctx._rng_counter = counter[0]
         ctx.aux_losses.extend(aux)
         ctx.buffer_updates.update(updates)

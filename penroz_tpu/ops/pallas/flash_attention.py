@@ -101,6 +101,7 @@ import logging
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -129,6 +130,12 @@ _STEP_SCORES = 2 ** 19
 # the forward pays more for a band than 128 keys save it.
 _DIAG_GRAIN = 256
 _BWD_DIAG_GRAIN = 128
+# What the forward rule calls its two results (``checkpoint_name``): the
+# identity everywhere but under a ``jax.checkpoint`` whose policy saves these
+# names (ops/modules.py::Looped), whose backward then finds ``o`` and the
+# logsumexp kept and does not run ``penroz_flash_fwd`` a second time.
+OUT_NAME = "penroz_flash_out"
+LSE_NAME = "penroz_flash_lse"
 
 
 def _dot_precision(dtype):
@@ -1611,6 +1618,7 @@ def _flash_fwd_rule(arrays, seed, ix, causal, plan, dropout_rate, interpret,
                               interpret=interpret, return_lse=True,
                               window=window, alibi=alibi, scale=scale,
                               plan=plan, ix=ix)
+    out, lse = checkpoint_name(out, OUT_NAME), checkpoint_name(lse, LSE_NAME)
     return out, (arrays, seed, out, lse)
 
 

@@ -274,8 +274,9 @@ def test_dsl_round_trip_and_hf_config(arch):
     loop = arch.looped
     assert (loop.steps, len(loop.body), loop.slots_per_pass) == (STEPS,
                                                                  DEPTH, DEPTH)
-    assert loop.plan(True) == {"steps": 4, "layers": 2, "applications": 8,
-                               "recomputed_applications": 8, "cache_slots": 8}
+    assert loop.plan(False) == {"steps": 4, "layers": 2, "applications": 8,
+                                "recomputed_applications": 0,
+                                "cache_slots": 8, "kept_outputs": ""}
     with pytest.raises(ValueError, match="looped takes steps, body"):
         bad = json.loads(json.dumps(layers))
         del bad[1]["looped"]["exit"]["gate"]
@@ -327,3 +328,197 @@ def test_epoch_program_returns_the_exits_after_its_five_results(arch,
                                rtol=1e-5)
     plain = CompiledArch.get(presets.makemore_mlp())
     assert plain.looped is None and set(plain.zero_cost_sum()) == {"cost"}
+
+
+def _checkpoint_as_the_parent_did(monkeypatch, recompute=True):
+    """``Looped`` as it was before it kept anything: every application under
+    a bare ``jax.checkpoint`` (or, ``recompute=False``, under none), rebuilt
+    here by wrapping what the container calls."""
+    real = jax.checkpoint
+    monkeypatch.setattr(
+        jax, "checkpoint",
+        lambda fn, policy=None: real(fn) if recompute else fn)
+
+
+@pytest.mark.parametrize("jitted", [False, True])
+def test_keeping_the_exits_logsumexp_changes_no_bit(arch, weights,
+                                                    monkeypatch, jitted):
+    """On the CPU attention takes the jnp path, which names nothing, so of
+    the three names only the cross-entropy's logsumexp is kept here (the
+    flash forward's two: ``test_keeping_the_flash_forwards_results_...``).
+    The policy keeps what the recomputation would have made again, the same
+    arithmetic: loss and gradient equal, bit for bit, those of a bare
+    ``jax.checkpoint``, and to tolerance those with nothing recomputed."""
+    _, params = weights
+    x, y = _tokens((2, T)), _tokens((2, T), 1)
+
+    def loss_and_grad():
+        fn = jax.value_and_grad(lambda p: _program_loss(arch, p, x, y)[0])
+        return (jax.jit(fn) if jitted else fn)(params)
+
+    loss, grad = loss_and_grad()
+    with monkeypatch.context() as patch:
+        _checkpoint_as_the_parent_did(patch)
+        bare_loss, bare_grad = loss_and_grad()
+    with monkeypatch.context() as patch:
+        _checkpoint_as_the_parent_did(patch, recompute=False)
+        plain_loss, plain_grad = loss_and_grad()
+    assert float(loss) == float(bare_loss)
+    assert float(loss) == pytest.approx(float(plain_loss), rel=1e-6)
+    for name in params:
+        np.testing.assert_array_equal(grad[name], bare_grad[name],
+                                      err_msg=name)
+        np.testing.assert_allclose(grad[name], plain_grad[name], rtol=1e-4,
+                                   atol=1e-7, err_msg=name)
+
+
+def test_in_bfloat16_no_bit_changes_where_xla_rounds_as_written(arch, weights,
+                                                               monkeypatch):
+    """bfloat16 compute, compiled with ``xla_allow_excess_precision`` off:
+    the gradient with the policy is the bare checkpoint's bit for bit.
+    (With XLA's default it need not be: two programs of another structure
+    keep excess precision in other places.  On the chip not even without
+    it: the kept ``o`` comes from the forward's compilation of the matmuls
+    before it, and a second compilation of one matmul need not round
+    alike; the looped cell's ``grad_rel_err`` moved in its fourth digit,
+    either way by seed, PR 40.)"""
+    _, params = weights
+    x, y = _tokens((2, T)), _tokens((2, T), 1)
+
+    def loss(p):
+        _, cost, _, _ = arch.forward(p, {}, x, y, training=True,
+                                     skip_softmax=True,
+                                     compute_dtype=jnp.bfloat16)
+        return cost
+
+    def compiled_grad():
+        return jax.jit(jax.value_and_grad(loss)).lower(params).compile(
+            compiler_options={"xla_allow_excess_precision": False})(params)
+
+    loss_kept, grad = compiled_grad()
+    _checkpoint_as_the_parent_did(monkeypatch)
+    loss_bare, bare_grad = compiled_grad()
+    assert float(loss_kept) == float(loss_bare)
+    for name in params:
+        np.testing.assert_array_equal(grad[name], bare_grad[name],
+                                      err_msg=name)
+
+
+@pytest.mark.parametrize("training", [True, False])
+def test_plan_names_what_the_recomputation_keeps(arch, training):
+    """``kept_outputs``: the three names of the policy where the loop
+    recomputes, none where it does not."""
+    names = "penroz_flash_out,penroz_flash_lse,penroz_ce_lse"
+    assert arch.looped.plan(training) == {
+        "steps": STEPS, "layers": DEPTH, "applications": STEPS * DEPTH,
+        "recomputed_applications": STEPS * DEPTH if training else 0,
+        "cache_slots": STEPS * DEPTH,
+        "kept_outputs": names if training else ""}
+    assert names.split(",") == list(M._kept_names())
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_keeping_the_flash_forwards_results_changes_no_bit(dtype):
+    """The flash kernels (interpret mode, the CPU) between two matmuls under
+    ``jax.checkpoint``: with the loop's policy the backward finds ``o`` and
+    the logsumexp kept and its jaxpr holds the forward kernel once, with a
+    bare checkpoint twice, and the gradients are equal bit for bit."""
+    from penroz_tpu.ops.pallas import flash_attention as fa
+    heads, dim, rows = 2, 64, 128
+    rng = np.random.default_rng(3)
+    x = jnp.asarray(rng.standard_normal((1, rows, heads * dim)), dtype)
+    w_in = jnp.asarray(0.1 * rng.standard_normal((heads * dim,
+                                                  3 * heads * dim)), dtype)
+    w_out = jnp.asarray(0.1 * rng.standard_normal((heads * dim, heads * dim)),
+                        dtype)
+
+    def block(x, w_in, w_out):
+        return fa.flash_attention_btd(x @ w_in, heads=heads,
+                                      interpret=True) @ w_out
+
+    def grad(policy):
+        return jax.grad(lambda *a: jnp.square(jax.checkpoint(
+            block, policy=policy)(*a).astype(jnp.float32)).sum(),
+            argnums=(0, 1, 2))
+
+    keep = jax.checkpoint_policies.save_only_these_names(*M._kept_names())
+    forwards = lambda policy: _kernel_calls(jax.make_jaxpr(grad(policy))(
+        x, w_in, w_out).jaxpr)["penroz_flash_fwd"]
+    assert (forwards(None), forwards(keep)) == (2, 1)
+    for kept, bare in zip(jax.jit(grad(keep))(x, w_in, w_out),
+                          jax.jit(grad(None))(x, w_in, w_out)):
+        np.testing.assert_array_equal(kept, bare)
+
+
+def test_a_looped_body_whose_attention_gives_no_head_dim_trains():
+    """``head_dim`` is optional in the DSL: after a clamp of the projection
+    (OLMo's ``clip_qkv``) the model builder cannot infer it either, and
+    the attention derives it from the projection's width when applied.
+    The loop's plan reckons from no attribute of its layers, so such a
+    body trains."""
+    layers = json.loads(json.dumps(
+        presets.ouro_custom(**ouro.preset_args(CFG))))
+    for block in layers[1]["looped"]["body"]:
+        items = block["transformerblock"]["attn_block"]["sequential"]
+        del items[2]["attention"]["head_dim"]
+        items.insert(2, {"clamp": {"min": -1e4, "max": 1e4}})
+    bare = CompiledArch.get(layers)
+    assert all(m.head_dim is None for block in bare.looped.body
+               for m in block.walk()
+               if isinstance(m, M.CausalSelfAttention))
+    params, _ = Mapper(layers, ADAMW).init_params(bare.mods, seed=SEED)
+    x, y = _tokens((2, T)), _tokens((2, T), 1)
+    loss, grad = jax.value_and_grad(
+        lambda p: _program_loss(bare, p, x, y)[0])(params)
+    assert np.isfinite(float(loss))
+    assert all(np.isfinite(np.asarray(g)).all() for g in grad.values())
+    assert any(float(jnp.abs(g).max()) > 0 for g in grad.values())
+
+
+def _kernel_calls(jaxpr, found=None) -> dict:
+    """How often each named Pallas call stands in ``jaxpr``, nested ones
+    (a ``jax.checkpoint``'s, a ``custom_vjp``'s) included."""
+    found = {} if found is None else found
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            name = eqn.params["name"]
+            found[name] = found.get(name, 0) + 1
+        for inner in jax.core.jaxprs_in_params(eqn.params):
+            _kernel_calls(inner, found)
+    return found
+
+
+@pytest.mark.parametrize("parent", [False, True])
+def test_recomputation_runs_neither_kernels_forward_again(monkeypatch,
+                                                          parent):
+    """The gradient of a looped stack traced with the kernels in it (the
+    TPU's path, bf16; nothing is lowered): the flash forward stands once an
+    application and the cross-entropy forward once an exit, where the
+    parent's bare ``jax.checkpoint`` had each twice."""
+    from penroz_tpu.models import dsl
+    steps, depth = 3, 2
+    small = CompiledArch.get(presets.ouro_custom(
+        d=128, heads=2, head_dim=64, intermediate=256, depth=depth,
+        steps=steps, vocab=1024))
+    shapes, _ = jax.eval_shape(
+        lambda: dsl.init_module_params(small.mods, seed=0))
+    params = {k: jax.ShapeDtypeStruct(v.shape, jnp.bfloat16)
+              for k, v in shapes.items()}
+    x = jax.ShapeDtypeStruct((2, 128), jnp.int32)
+
+    def loss(p, x, y):
+        _, cost, _, _ = small.forward(p, {}, x, y, training=True,
+                                      skip_softmax=True,
+                                      compute_dtype=jnp.bfloat16,
+                                      platform="tpu")
+        return cost
+
+    if parent:
+        _checkpoint_as_the_parent_did(monkeypatch)
+    calls = _kernel_calls(jax.make_jaxpr(jax.grad(loss))(params, x, x).jaxpr)
+    again = 2 if parent else 1
+    assert calls == {"penroz_flash_fwd": again * steps * depth,
+                     "penroz_flash_bwd_delta": steps * depth,
+                     "penroz_flash_bwd": steps * depth,
+                     "penroz_ce_fwd": again * steps,
+                     "penroz_ce_bwd": steps}

@@ -399,11 +399,10 @@ def _custom_calls(hlo: str) -> list:
     return calls
 
 
-@pytest.fixture(scope="module")
-def attention_block_hlo(chip):
-    """The gradient of one attention block of the cell —
-    ``CausalSelfAttention`` on the fused ``(12, 1024, 2304)`` projection,
-    then a projection matmul — compiled for a v5e."""
+def _attention_block(chip):
+    """(attend, qkv, w): the cell's ``CausalSelfAttention`` as a function of
+    the fused ``(12, 1024, 2304)`` projection, and the shapes of that
+    projection and of a projection matrix after it, on ``chip``."""
     from penroz_tpu.ops import modules as M
     width = HEADS * HEAD_DIM
     attn = M.CausalSelfAttention(num_heads=HEADS)
@@ -411,10 +410,18 @@ def attention_block_hlo(chip):
     qkv = jax.ShapeDtypeStruct((CELL_ROWS, BLOCK, 3 * width), jnp.bfloat16,
                                sharding=chip)
     w = jax.ShapeDtypeStruct((width, width), jnp.bfloat16, sharding=chip)
+    return (lambda qkv: attn.apply(qkv, M.Ctx({}, platform="tpu"))), qkv, w
+
+
+@pytest.fixture(scope="module")
+def attention_block_hlo(chip):
+    """The gradient of one attention block of the cell —
+    ``CausalSelfAttention`` on the fused ``(12, 1024, 2304)`` projection,
+    then a projection matmul — compiled for a v5e."""
+    attend, qkv, w = _attention_block(chip)
 
     def loss(qkv, w):
-        out = attn.apply(qkv, M.Ctx({}, platform="tpu"))
-        return (out @ w).astype(jnp.float32).sum()
+        return (attend(qkv) @ w).astype(jnp.float32).sum()
 
     return jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
         qkv, w).compile().as_text()
@@ -534,11 +541,13 @@ def test_looped_stack_compiles_and_its_kernels_are_where_the_benchmark_looks(
     """One layer run twice with shared weights at the looped cell's widths
     (``ouro-train-4k-loop4``: micro-batch 2 x 4096, d 2048, 16 heads of 128,
     SwiGLU 5632, vocabulary 49152, bf16), the exit loss and its gradient,
-    compiled for a v5e: every application's flash forward appears twice
-    (once recomputed), its split backward once, each exit's cross-entropy
-    forward twice and backward once, all under their names — and the
-    benchmark's two readers, loaded from their files, find them in a trace
-    made of this program's instructions."""
+    compiled for a v5e: every application's flash forward appears once
+    (the loop's recomputation keeps ``o`` and the logsumexp by name and runs
+    the matmuls around them again, not the kernel: twice before PR 40), its
+    split backward once, each exit's cross-entropy forward once and
+    backward once, all under their names — and the benchmark's two readers,
+    loaded from their files, find them in a trace made of this program's
+    instructions."""
     import importlib.util
     import sys
     from penroz_tpu.models import dsl, presets
@@ -563,12 +572,12 @@ def test_looped_stack_compiles_and_its_kernels_are_where_the_benchmark_looks(
     hlo = jax.jit(jax.grad(loss)).lower(params, x, x).compile().as_text()
     calls = _custom_calls(hlo)
     count = lambda needle: sum(needle in name for name, _ in calls)
-    assert count("penroz_flash_fwd") == 2 * steps, calls
+    assert count("penroz_flash_fwd") == steps, calls
     assert count("penroz_flash_bwd_dq") == count("penroz_flash_bwd_dkv") \
         == count("penroz_flash_bwd_delta") == steps, calls
-    assert count("penroz_ce_fwd") == 2 * steps, calls
+    assert count("penroz_ce_fwd") == steps, calls
     assert count("penroz_ce_bwd") == steps, calls
-    assert len(calls) == 8 * steps, calls
+    assert len(calls) == 6 * steps, calls
     # attention stays in the model's layout at D = 128 after RoPE
     assert "bf16[2,16,4096,128]" not in hlo
 
@@ -598,8 +607,38 @@ def test_looped_stack_compiles_and_its_kernels_are_where_the_benchmark_looks(
     flash = kernel_costs.flash_attention(rows, 16, block, 128, 2)
     assert readers["penroz_flash_roofline.useful"].read(art) == pytest.approx(
         100.0 * steps * (least(flash["fwd"]) + least(flash["bwd"]))
-        / (5 * steps * 1e-3))
+        / (4 * steps * 1e-3))
     ce = looped_costs.cross_entropy(rows * block, 49152, 2)
     assert readers["penroz_ce_roofline"].read(art) == pytest.approx(
-        100.0 * steps * (2 * least(ce["fwd"]) + least(ce["bwd"]))
-        / (3 * steps * 1e-3))
+        100.0 * steps * (least(ce["fwd"]) + least(ce["bwd"]))
+        / (2 * steps * 1e-3))
+
+
+def test_a_bare_checkpoint_still_runs_the_flash_forward_twice(chip):
+    """The names ``_flash_fwd_rule`` gives its results are inert outside a
+    policy that asks for them: the cell's attention block (not looped) under
+    a ``jax.checkpoint`` with no policy, as ``models/model.py`` and
+    ``parallel/pipeline.py`` wrap theirs, compiles to the forward kernel
+    twice (once recomputed) and the backward as ever; with the loop's
+    policy, once."""
+    from penroz_tpu.ops import modules as M
+    attend, qkv, w = _attention_block(chip)
+
+    def block(qkv, w):
+        # tanh: something for the checkpoint to recompute around the kernel
+        return jnp.tanh(attend(qkv)) @ w
+
+    def forwards(policy):
+        # squared: the cotangent reads the primal, so its forward stays
+        loss = lambda qkv, w: jnp.square(jax.checkpoint(
+            block, policy=policy)(qkv, w).astype(jnp.float32)).sum()
+        calls = _custom_calls(jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+            qkv, w).compile().as_text())
+        count = lambda needle: sum(needle in name for name, _ in calls)
+        assert count("penroz_flash_bwd") == 2, calls    # δ and the one pass
+        assert len(calls) == 2 + count("penroz_flash_fwd"), calls
+        return count("penroz_flash_fwd")
+
+    assert forwards(None) == 2
+    assert forwards(jax.checkpoint_policies.save_only_these_names(
+        *M._kept_names())) == 1

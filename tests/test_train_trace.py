@@ -301,13 +301,16 @@ def test_a_looped_models_job_carries_its_plan_and_its_exits(app):
     assert plans and all(p["meta"] == {
         "steps": steps, "layers": depth, "applications": steps * depth,
         "recomputed_applications": steps * depth,
-        "cache_slots": steps * depth} for p in plans)
+        "cache_slots": steps * depth,
+        "kept_outputs": "penroz_flash_out,penroz_flash_lse,penroz_ce_lse"}
+        for p in plans)
     assert not any(n["name"] == "penroz/loop_plan"
                    for e in later for n, _ in walk(e))
     # the stats passes at the job's end run the loop too, recomputing nothing
     (stats,) = named(tree, "penroz/train_stats")
-    assert {n["meta"]["recomputed_applications"] for n, _ in walk(stats)
-            if n["name"] == "penroz/loop_plan"} == {0}
+    assert {(n["meta"]["recomputed_applications"], n["meta"]["kept_outputs"])
+            for n, _ in walk(stats)
+            if n["name"] == "penroz/loop_plan"} == {(0, "")}
     counters = [f"{name}_{t}" for name in ("pass_loss", "exit_mass")
                 for t in range(1, steps + 1)]
     for epoch, row in zip([first, *later], progress["progress"]):

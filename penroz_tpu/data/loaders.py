@@ -19,6 +19,7 @@ from __future__ import annotations
 import glob
 import logging
 import os
+import time
 from concurrent.futures import ThreadPoolExecutor
 
 import numpy as np
@@ -62,6 +63,13 @@ class Loader:
         self._stream = None          # native mmap stream (penroz_loader)
         self._stream_sig: list[tuple] = []   # (name, size, mtime_ns) per shard
         self._prefix: list[int] = []
+        # Where ``next_batch``'s time went, running totals in seconds
+        # (``time.perf_counter``): learning what there is to read (the glob,
+        # the ``stat``s, a shard's ``np.load``) and bringing the tokens
+        # (gather, copy, prefetch; slicing and ``astype``).  A caller reads
+        # the differences (``penroz/load_batch``: scan_ms, gather_ms).
+        self.scan_seconds = 0.0
+        self.gather_seconds = 0.0
 
     def _files(self) -> list[str]:
         pattern = os.path.join(DATA_FOLDER, f"{self.dataset_id}_*.npy")
@@ -85,7 +93,9 @@ class Loader:
             # keep at most two shards resident (current + wraparound peek)
             if len(self._cache) > 1:
                 self._cache.clear()
+            t0 = time.perf_counter()
             data = np.load(os.path.join(DATA_FOLDER, files[shard_idx]))
+            self.scan_seconds += time.perf_counter() - t0
             self._cache[shard_idx] = data
         return data
 
@@ -127,11 +137,14 @@ class Loader:
     def next_batch(self, target_offset: int = 1):
         """(input, target) flat int32 arrays of ``buffer_size`` tokens;
         target is input shifted by ``target_offset`` (None when 0)."""
+        t0 = time.perf_counter()
         files = self._files()
         if not files:
             raise ValueError(f"Dataset {self.dataset_id} has no shards")
         need = self.buffer_size + target_offset
         stream = self._native_stream(files)
+        t1 = time.perf_counter()
+        self.scan_seconds += t1 - t0
         if stream is not None:
             # (shard, idx) → linear stream position, then fold the state
             # back to normalized (shard, idx) exactly as the fallback's
@@ -152,7 +165,9 @@ class Loader:
                  if target_offset else None)
             self.idx += self.idx_offset
             stream.prefetch(pos + self.idx_offset, need)
+            self.gather_seconds += time.perf_counter() - t1
             return x, y
+        loaded = self.scan_seconds      # ``_shard_data`` adds its np.load
         self.shard %= len(files)
         data = self._shard_data(files, self.shard)
         while self.idx >= len(data):
@@ -169,6 +184,8 @@ class Loader:
         y = (buf[target_offset:target_offset + self.buffer_size]
              .astype(np.int32) if target_offset else None)
         self.idx += self.idx_offset
+        self.gather_seconds += (time.perf_counter() - t1
+                                - (self.scan_seconds - loaded))
         return x, y
 
 

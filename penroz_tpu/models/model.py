@@ -1554,7 +1554,10 @@ class NeuralNetworkModel:
                     long_training = dist.all_reduce_mean(
                         1.0 if long_training else 0.0) >= 0.5
                 with tracing.span("penroz/load_batch",
-                                  tokens=num_steps * buffer_size):
+                                  tokens=num_steps * buffer_size
+                                  ) as batch_span:
+                    scanned = loader.scan_seconds
+                    gathered = loader.gather_seconds
                     xs, ys = [], []
                     for _ in range(num_steps):
                         x, y = loader.next_batch()
@@ -1563,6 +1566,13 @@ class NeuralNetworkModel:
                     # stay on host: global_batch/jit place them exactly once
                     xs = np.stack(xs)
                     ys = np.stack(ys)
+                    # the loader's own account of the span: what it spent
+                    # learning what there is to read / bringing the tokens
+                    batch_span.set(
+                        scan_ms=round(
+                            1e3 * (loader.scan_seconds - scanned), 3),
+                        gather_ms=round(
+                            1e3 * (loader.gather_seconds - gathered), 3))
                 last_batch = (xs[-1], ys[-1])
                 if mesh is not None:
                     xs = sharding_lib.global_batch(

@@ -207,3 +207,31 @@ def test_native_stream_not_stale_after_delete(workdir):
     fresh, _ = loader.next_batch()
     assert (np.asarray(fresh) == 7).all()
     assert not np.array_equal(first, fresh)
+
+
+@pytest.mark.parametrize("native", [True, False], ids=["native", "numpy"])
+def test_next_batch_says_where_its_time_went(workdir, monkeypatch, native):
+    """Two running totals on the loader: learning what there is to read
+    (glob, stat, a shard's np.load) and bringing the tokens; both grow on
+    either path, and together they stay inside the wall time of the calls
+    (``penroz/load_batch`` carries their differences as scan_ms /
+    gather_ms)."""
+    import time
+    from penroz_tpu.data.loaders import Loader, _native_loader_module
+    if native and _native_loader_module() is None:
+        pytest.skip("native loader unavailable")
+    if not native:
+        monkeypatch.setenv("PENROZ_NATIVE_LOADER", "0")
+    dataset = _make_shards(workdir, [100, 70, 30])
+    loader = Loader(dataset, buffer_size=64)
+    assert loader.scan_seconds == 0.0 and loader.gather_seconds == 0.0
+    seen = []
+    t0 = time.perf_counter()
+    for _ in range(8):          # wraps the stream: every shard is loaded
+        loader.next_batch()
+        seen.append((loader.scan_seconds, loader.gather_seconds))
+    wall = time.perf_counter() - t0
+    assert (loader._stream is not None) is native
+    assert all(b[0] > a[0] and b[1] > a[1]
+               for a, b in zip([(0.0, 0.0)] + seen, seen))
+    assert loader.scan_seconds + loader.gather_seconds <= wall

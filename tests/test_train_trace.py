@@ -267,11 +267,66 @@ def _chrome_export_is_valid_trace_event_json(job):
     json.dumps(chrome)
 
 
+HOST_KEYS = {"cpu_ms", "sys_ms", "major_faults", "minor_faults", "waits",
+             "preempted"}
+
+
+def _every_span_carries_its_threads_account(job):
+    """``host`` beside ``meta`` on every span the job opened through
+    ``tracing.span``; the root and the after-the-fact ``penroz/compile``
+    have none.  The account is the thread's own, so it fits in the span."""
+    seen = set()
+    for node, parent in walk(job["done"]["root"]):
+        if parent is None or node["name"] == "penroz/compile":
+            assert "host" not in node, node["name"]
+            continue
+        host = node["host"]
+        assert set(host) == HOST_KEYS, node["name"]
+        assert host["sys_ms"] >= 0, node
+        assert 0 <= host["cpu_ms"] <= node["duration_ms"] + 1, node
+        assert all(isinstance(host[k], int) and host[k] >= 0
+                   for k in HOST_KEYS - {"cpu_ms", "sys_ms"}), node
+        assert not HOST_KEYS & set(node.get("meta", {})), node["name"]
+        seen.add(node["name"])
+    assert {"penroz/train_setup", "penroz/load_batch", "penroz/train_epoch",
+            "penroz/train_dispatch", "penroz/train_wait",
+            "penroz/train_stats", "penroz/ckpt_save", "penroz/ckpt_d2h",
+            "penroz/ckpt_encode", "penroz/ckpt_write",
+            "penroz/ckpt_flush"} <= seen
+
+
+def _load_batch_says_where_its_time_went(job):
+    batches = named(job["done"], "penroz/load_batch")
+    assert len(batches) == EPOCHS
+    for b in batches:
+        scan, gather = b["meta"]["scan_ms"], b["meta"]["gather_ms"]
+        assert scan > 0 and gather > 0
+        assert scan + gather <= b["duration_ms"] + 0.002   # three roundings
+
+
+def _chrome_export_carries_host_under_args(job):
+    by_name = {}
+    for e in job["chrome"]["traceEvents"]:
+        by_name.setdefault(e["name"], []).append(e)
+    for name in ("penroz/load_batch", "penroz/train_wait",
+                 "penroz/ckpt_write", "penroz/ckpt_flush"):
+        assert all(set(e["args"]["host"]) == HOST_KEYS
+                   for e in by_name[name]), name
+    assert all("host" not in e.get("args", {})
+               for e in by_name["penroz/compile"] + by_name["request"])
+    (batch,) = by_name["penroz/load_batch"][:1]
+    assert batch["args"]["tokens"] == MICRO_STEPS * BATCH * BLOCK
+    assert json.loads(json.dumps(job["chrome"])) == job["chrome"]
+
+
 CASES = [_resolves_live_and_after, _children_lie_inside_parents,
          _top_level_is_setup_then_epochs_then_the_last_save,
          _wait_lies_inside_its_epoch_after_the_dispatch, _epoch_counters,
          _save_anatomy_and_bytes, _compile_in_the_first_epoch_only,
-         _totals_and_metrics, _chrome_export_is_valid_trace_event_json]
+         _totals_and_metrics, _chrome_export_is_valid_trace_event_json,
+         _every_span_carries_its_threads_account,
+         _load_batch_says_where_its_time_went,
+         _chrome_export_carries_host_under_args]
 
 
 @pytest.mark.parametrize("case", CASES, ids=lambda f: f.__name__.lstrip("_"))
@@ -307,7 +362,9 @@ def test_a_looped_models_job_carries_its_plan_and_its_exits(app):
     assert not any(n["name"] == "penroz/loop_plan"
                    for e in later for n, _ in walk(e))
     # the stats passes at the job's end run the loop too, recomputing nothing
-    (stats,) = named(tree, "penroz/train_stats")
+    # (the last such span: where the first epoch's compile outlasts the save
+    # cadence's 10 s, a periodic save and its unrefreshed stats come first)
+    *_, stats = named(tree, "penroz/train_stats")
     assert {(n["meta"]["recomputed_applications"], n["meta"]["kept_outputs"])
             for n, _ in walk(stats)
             if n["name"] == "penroz/loop_plan"} == {(0, "")}
@@ -491,3 +548,112 @@ def test_threads_share_one_job_trace_without_losing_a_span(monkeypatch):
     assert held + tree["dropped_spans"] == 2 * workers * rounds
     assert tree["totals"]["outer"]["count"] == workers * rounds
     assert tree["totals"]["inner"]["count"] == workers * rounds
+
+
+def _burn(seconds):
+    """Spin until the calling thread has used ``seconds`` of CPU."""
+    end = time.thread_time() + seconds
+    while time.thread_time() < end:
+        pass
+
+
+def _one_span(body, name="penroz/x"):
+    trace = tracing.Trace("t", job=True)
+    with tracing.use(trace), tracing.span(name):
+        body()
+    (sp,) = trace.to_dict()["root"]["children"]
+    return sp
+
+
+@pytest.mark.parametrize("body, low, high", [
+    (lambda: time.sleep(0.05), 0.0, 10.0),
+    (lambda: _burn(0.05), 40.0, None),
+], ids=["a_span_that_sleeps_waited", "a_span_that_spins_ran"])
+def test_the_account_tells_running_from_waiting(body, low, high):
+    """Two spans of 50 ms and more on the wall clock: ``cpu_ms`` says which
+    computed; ``duration_ms - cpu_ms`` is what the other waited."""
+    sp = _one_span(body)
+    assert sp["duration_ms"] >= 50.0
+    cpu = sp["host"]["cpu_ms"]
+    assert low <= cpu <= (high if high is not None
+                          else sp["duration_ms"] + 1), sp
+    if high is not None:
+        assert sp["host"]["waits"] >= 1     # the sleep gave the CPU up
+
+
+def test_a_thread_beside_a_span_keeps_an_account_of_its_own():
+    """The flush thread's ``penroz/ckpt_flush`` burns a core while the
+    thread that spawned it waits inside its own span: each span reads its
+    own thread (were the account the process's, the waiting span would
+    read the other's 50 ms)."""
+    trace = tracing.Trace("t", job=True)
+
+    def flush(binding):
+        with tracing.use(binding), tracing.span("penroz/ckpt_flush"):
+            _burn(0.05)
+
+    with tracing.use(trace), tracing.span("penroz/ckpt_save"):
+        thread = threading.Thread(target=flush, args=(tracing.capture(),))
+        with tracing.span("penroz/ckpt_write"):
+            thread.start()
+            thread.join(60)
+            assert not thread.is_alive()
+    (save,) = trace.to_dict()["root"]["children"]
+    write, flushed = save["children"]
+    assert (write["name"], flushed["name"]) == ("penroz/ckpt_write",
+                                                "penroz/ckpt_flush")
+    assert flushed["host"]["cpu_ms"] > 40.0
+    assert write["duration_ms"] >= 50.0 > 10.0 > write["host"]["cpu_ms"]
+    assert save["host"]["cpu_ms"] < 10.0
+
+
+class _CountedUsage:
+    """``resource.getrusage`` with its calls counted."""
+
+    def __init__(self, monkeypatch):
+        self.calls = 0
+        real = tracing.resource.getrusage
+
+        def getrusage(who):
+            self.calls += 1
+            return real(who)
+
+        monkeypatch.setattr(tracing.resource, "getrusage", getrusage)
+
+
+@pytest.mark.parametrize("trace", [
+    lambda: None, lambda: tracing.Trace("r", route="/generate/")],
+    ids=["no_trace_current", "a_requests_trace"])
+def test_outside_a_job_trace_no_getrusage_call_is_made(monkeypatch, trace):
+    """Serving spans, request traces and a job sampled out pay nothing for
+    the account: not one read of it.  A job's span pays two."""
+    counted = _CountedUsage(monkeypatch)
+    current = trace()
+    with tracing.use(current), tracing.span("penroz/decode_step_batched",
+                                            rows=3) as sp:
+        with tracing.span("penroz/prefill"):
+            sp.set(more=1)
+    assert counted.calls == 0
+    if current is not None:
+        (step,) = current.to_dict()["root"]["children"]
+        assert step["meta"] == {"rows": 3, "more": 1}
+        assert "host" not in step and "host" not in step["children"][0]
+    assert "host" in _one_span(lambda: None) and counted.calls == 2
+
+
+def test_without_rusage_thread_the_job_runs_and_carries_no_account(
+        app, monkeypatch):
+    """A platform that keeps no per-thread account: nothing raises, no read
+    is attempted, and the spans are what they were before the field."""
+    monkeypatch.delattr(tracing.resource, "RUSAGE_THREAD")
+    counted = _CountedUsage(monkeypatch)
+    app.create("plain")
+    rid = app.train("plain")
+    assert app.wait("plain")["status"]["code"] == "Trained"
+    _, tree = app.call("GET", f"/trace/{rid}")
+    nodes = [n for n, _ in walk(tree["root"])]
+    assert len(nodes) > 4 * EPOCHS and not any("host" in n for n in nodes)
+    assert counted.calls == 0
+    _, chrome = app.call("GET", f"/trace/{rid}?format=chrome")
+    assert not any("host" in e.get("args", {})
+                   for e in chrome["traceEvents"])

@@ -590,6 +590,19 @@ def phase_kernels(sz: dict, on_tpu: bool):
                 lambda *a: A.causal_attention(*a, platform=hint),
                 hq, hkv))), qg, kg, vg), 4)
 
+        # the looped cell's attention (ouro-train-4k-loop4: micro-batch 2,
+        # 16 heads of 128, T = 4096, three arrays after RoPE): the one-pass
+        # backward under the VMEM limit its plan asks the compiler for
+        hl, tl = 16, 4096
+        ql, kl, vl = (rand(2, tl, hl * d) for _ in range(3))
+        flash_loop = value_and_grads(lambda q, k, v: A.causal_attention_btd(
+            q, k, v, heads=hl, kv_heads=hl, platform=hint))
+        compare("flash_btd_loop4k_fwd_bwd", flash_loop,
+                value_and_grads(in_btd(A.causal_attention_reference,
+                                       hl, hl)), (ql, kl, vl), BF16)
+        timings["flash_btd_loop4k_fwd_bwd_ms"] = round(
+            median_ms(jax.jit(flash_loop), ql, kl, vl), 4)
+
     # contiguous decode, ragged lengths
     lengths = jnp.asarray(rng.integers(1, T + 1, B), jnp.int32)
     q1 = rand(B, H, 1, D)

@@ -39,12 +39,19 @@ computes over what the band holds.
 
 The backward recomputes probabilities from the forward's saved logsumexp:
 
-- **fused** (a head's operands and its f32 dQ fit in VMEM): one kernel, key
-  tiles outermost; each live tile's s, p, dp, ds are computed once (on the
-  transposed tile, so logsumexp and δ arrive as lane-dense rows) and feed
-  dV += p̃ᵀ·dO, dK += dSᵀ·Q and dQ += dS·K — five matmuls and one ``exp``.
-- otherwise the two-kernel split: ``_dq_kernel`` (query tiles resident, K/V
-  streaming) and ``_dkv_kernel`` (key tiles resident, Q/dO streaming).
+- **fused** (a head's operands and its f32 dQ, with Mosaic's share on top,
+  fit in the VMEM one call may ask the compiler for, ``VMEM_LIMIT``: to
+  T = 8192 at D = 128 in bf16, T = 4096 at D = 256 or in f32): one kernel, key tiles outermost; each live tile's s, p, dp,
+  ds are computed once (on the transposed tile, so logsumexp and δ arrive as
+  lane-dense rows) and feed dV += p̃ᵀ·dO, dK += dSᵀ·Q and dQ += dS·K — five
+  matmuls and one ``exp``.  A plan whose estimate is past the compiler's
+  default share (``VMEM_BUDGET``: T ≥ 2048 at these head sizes) says so on
+  its call (``vmem_limit_bytes``, ``FlashPlan.bwd_vmem_bytes``); one that
+  fits compiles to the program it always was.
+- otherwise (T ≥ 16384, or a caller's narrow budget) the two-kernel split:
+  ``_dq_kernel`` (query tiles resident, K/V streaming) and ``_dkv_kernel``
+  (key tiles resident, Q/dO streaming) — seven matmuls and two ``exp`` a
+  tile, every tile on the diagonal whole.
 
 GQA: per-query-head dK/dV, summed over the group outside the kernels.
 
@@ -114,8 +121,21 @@ _LANES = 128  # f32 scratch lane width for the (m, l) carries
 _HEAD_SEED_PRIME = np.int32(0x632BE5A7)
 
 # What a kernel's blocks, scratch and tile temporaries may take by the
-# estimates below: v5e's scoped-VMEM default is 16 MiB, the rest is Mosaic's.
+# estimates below without asking: v5e's scoped-VMEM default is 16 MiB, the
+# rest is Mosaic's.  The forward's residency and the heads a step owns are
+# judged against it, and a backward that fits it passes no limit.
+_SCOPED_DEFAULT = 16 * 2 ** 20
 VMEM_BUDGET = 12 * 2 ** 20
+# The most the one-pass backward's call may ask the compiler for
+# (``vmem_limit_bytes``): a v5e core has 128 MiB of VMEM, the 16 MiB only the
+# default share of one call; half of it leaves the rest to whatever XLA keeps
+# resident around the call.  A plan asks for its estimate and Mosaic's share
+# on top, in the proportion the budget has of the default (:func:`_asked`);
+# past the limit the backward falls to the two kernels.  Mosaic's own need
+# read 0.33–0.83 of the estimate in bf16 (17.5 MiB of 24.1 at T = 4096,
+# D = 128) and up to 1.12 of it in f32 (T = 1024 with dropout, ALiBi and a
+# window: 16.4 MiB, which the default does not hold either; CHANGES.md PR 42).
+VMEM_LIMIT = 64 * 2 ** 20
 # Tiles (block_q, block_k) by direction and score elements a grid step should
 # cover (a step costs ~0.35 µs; a head of T = S = 1024, D = 64 takes ~5 µs
 # forward): from the sweep on one v5e at (12, 12, 1024, 64) bf16 causal,
@@ -364,6 +384,11 @@ class FlashPlan:
     q_rows: int             # query rows of one forward grid step
     heads_per_step: int     # resident forward and fused backward
     fused_bwd: bool
+    # bytes the one-pass backward counts on (_bwd_fused_bytes of the heads a
+    # step owns); past VMEM_BUDGET its call asks the compiler for them and
+    # Mosaic's share (_asked).  0: the two-kernel backward, whose tiles fit
+    # the default at any length
+    bwd_vmem_bytes: int
     # a square tile on the diagonal is done in sub-blocks this wide, the
     # dead ones left out (_diag_bands), by the forward and by the one-pass
     # backward; = the tile's size: every tile is done whole
@@ -386,6 +411,7 @@ class FlashPlan:
                 f"{'resident' if self.resident else 'chunked'} "
                 f"q_rows={self.q_rows} "
                 f"{'fused_bwd' if self.fused_bwd else 'split_bwd'} "
+                f"bwd_vmem_mib={self.bwd_vmem_bytes / 2 ** 20:.1f} "
                 f"heads_per_step={self.heads_per_step} "
                 f"layout={self.layout}")
         if self.layout == "btd":
@@ -414,6 +440,12 @@ def _bwd_fused_bytes(T, S, D, itemsize, heads, kv_heads, bq, bk):
     scratch = (_padded(T, D, 4) + _padded(T, D, itemsize)       # dq, scaled q
                + 8 * T * 4 + 2 * _padded(bk, D, 4))     # lse and δ; dk, dv
     return 2 * blocks + scratch + 6 * bq * bk * 4
+
+
+def _asked(estimate: int) -> int:
+    """What a call whose blocks, scratch and temporaries are estimated at
+    ``estimate`` bytes asks the compiler for: that and Mosaic's share."""
+    return estimate * _SCOPED_DEFAULT // VMEM_BUDGET
 
 
 def _heads_per_block(D: int) -> int:
@@ -447,7 +479,13 @@ def plan_flash(T: int, S: int, D: int, itemsize: int, causal: bool = True,
     K/V head bound the heads a grid step may own.  ``block_q``/``block_k``
     override the tile sizes of both directions (tests).  ``layout="btd"``:
     a step owns one lane block — ``max(D, 128)`` lanes, ``heads_per_block``
-    heads — whose VMEM is that of one head as wide as the block."""
+    heads — whose VMEM is that of one head as wide as the block.
+
+    Whether the backward runs in one pass is judged against ``VMEM_LIMIT``,
+    the most its call may ask for (:func:`_asked`); everything else against
+    the budget.  A caller
+    that narrows the budget below the default (tests, to reach the chunked
+    and split kernels at small shapes) narrows that with it."""
     heads_per_block = 1
     if layout == "btd":
         heads_per_block = _heads_per_block(D)
@@ -472,12 +510,13 @@ def plan_flash(T: int, S: int, D: int, itemsize: int, causal: bool = True,
             q_rows, S, D, itemsize, hps, _kv_heads_per_step(hps, group),
             fq, fk) <= vmem_budget
 
-    def bwd_fits(hps):
+    def bwd_bytes(hps):
         return _bwd_fused_bytes(
-            T, S, D, itemsize, hps, _kv_heads_per_step(hps, group),
-            gq, gk) <= vmem_budget
+            T, S, D, itemsize, hps, _kv_heads_per_step(hps, group), gq, gk)
 
-    fused_bwd = bwd_fits(1)
+    fused_bwd = (_asked(bwd_bytes(1)) <= VMEM_LIMIT
+                 if vmem_budget >= VMEM_BUDGET
+                 else bwd_bytes(1) <= vmem_budget)
     # forward: the whole query length a step if that fits, else halved down
     # to one tile; chunked if a head's K/V do not fit beside even that
     q_rows = T
@@ -495,7 +534,8 @@ def plan_flash(T: int, S: int, D: int, itemsize: int, causal: bool = True,
                 break
             if heads % cand or (cand % group and group % cand):
                 continue
-            if not fwd_fits(cand) or (fused_bwd and not bwd_fits(cand)):
+            if not fwd_fits(cand) or (fused_bwd
+                                      and bwd_bytes(cand) > vmem_budget):
                 break
             hps = cand
     # the two-kernel backward does its tiles whole
@@ -504,7 +544,9 @@ def plan_flash(T: int, S: int, D: int, itemsize: int, causal: bool = True,
     return FlashPlan(block_q=fq, block_k=fk, bwd_block_q=gq, bwd_block_k=gk,
                      resident=resident, q_rows=q_rows,
                      heads_per_step=hps * heads_per_block,
-                     fused_bwd=fused_bwd, diag_grain=grain,
+                     fused_bwd=fused_bwd,
+                     bwd_vmem_bytes=bwd_bytes(hps) if fused_bwd else 0,
+                     diag_grain=grain,
                      bwd_diag_grain=bwd_grain,
                      computed_over_live=computed_over_live(
                          T, S, fq, fk, grain, causal, window),
@@ -1517,7 +1559,12 @@ def _flash_backward(q, k, v, out, lse, g, causal: bool, plan: FlashPlan,
                             pltpu.VMEM((block_k, width), jnp.float32),
                             pltpu.VMEM((block_k, width), jnp.float32)],
             compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "parallel")),
+                dimension_semantics=("parallel", "parallel"),
+                # a plan past the compiler's default share says so; one
+                # within it compiles to the program it always was
+                vmem_limit_bytes=(_asked(plan.bwd_vmem_bytes)
+                                  if plan.bwd_vmem_bytes > VMEM_BUDGET
+                                  else None)),
             cost_estimate=pl.CostEstimate(
                 flops=flops, transcendentals=exps,
                 bytes_accessed=3 * q_bytes + 2 * kv_bytes + 2 * dkv_bytes),
@@ -1670,7 +1717,7 @@ def flash_attention(q, k, v, causal: bool = True,
     Tile sizes, K/V residency, the backward's form and the heads a grid
     step owns come from :func:`plan_flash` on the shapes; ``block_q`` /
     ``block_k`` override the tile sizes and ``vmem_budget`` the bytes the
-    plan may count on (a small one forces the chunked kernels).
+    plan may count on (a small one forces the chunked and split kernels).
     """
     B, Hq, T, D = q.shape
     Hkv, S = k.shape[1], k.shape[2]

@@ -1,6 +1,8 @@
 """Attention math tests: RoPE, GQA grouping, cached-vs-causal equivalence,
 and the Pallas flash kernel (interpret mode) against the jnp oracle."""
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -1110,10 +1112,31 @@ def test_flash_plans_match_oracle(feature, plan_name, grain, monkeypatch):
     # GPT-2-large: D = 64 × 20 heads
     (dict(T=1024, S=1024, D=64, itemsize=2, heads=20),
      dict(resident=True, q_rows=1024, fused_bwd=True)),
-    # long context, GQA: K/V of a head still fit, seven (T, D) operands of
-    # the one-pass backward do not
+    # long context, GQA: K/V of a head still fit the default share, the
+    # seven (T, D) operands of the one-pass backward only what its call asks
+    # the compiler for (the looped cell's shape; 36 tiles, 8 of them cut)
     (dict(T=4096, S=4096, D=128, itemsize=2, heads=32, group=4),
-     dict(resident=True, fused_bwd=False, heads_per_step=1)),
+     dict(resident=True, q_rows=1024, fused_bwd=True, heads_per_step=1,
+          bwd_diag_grain=128, bwd_computed_over_live=1.03125,
+          bwd_vmem_bytes=int(24.125 * 2 ** 20))),
+    # … the sides of the lengths around it at D = 128: one pass with a
+    # limit from T = 2048 to 8192, the two kernels from 16384
+    (dict(T=2048, S=2048, D=128, itemsize=2, heads=32, group=4),
+     dict(resident=True, q_rows=2048, fused_bwd=True, heads_per_step=1,
+          bwd_diag_grain=128, bwd_computed_over_live=1.0625,
+          bwd_vmem_bytes=int(15.3125 * 2 ** 20))),
+    (dict(T=8192, S=8192, D=128, itemsize=2, heads=32, group=4),
+     dict(resident=False, fused_bwd=True, heads_per_step=1,
+          bwd_diag_grain=128, bwd_computed_over_live=1.015625,
+          bwd_vmem_bytes=int(41.75 * 2 ** 20))),
+    (dict(T=16384, S=16384, D=128, itemsize=2, heads=32, group=4),
+     dict(resident=False, fused_bwd=False, heads_per_step=1,
+          bwd_diag_grain=512, bwd_computed_over_live=1.03125,
+          bwd_vmem_bytes=0)),
+    # … and in the model's layout, where D = 256 is a block as wide
+    (dict(T=4096, S=4096, D=256, itemsize=2, heads=8, group=4,
+          layout="btd"),
+     dict(fused_bwd=True, bwd_diag_grain=128, heads_per_block=1)),
     # one tile
     (dict(T=128, S=128, D=64, itemsize=2, heads=4),
      dict(block_q=128, block_k=128, bwd_block_q=128, bwd_block_k=128,
@@ -1125,14 +1148,19 @@ def test_flash_plans_match_oracle(feature, plan_name, grain, monkeypatch):
           bwd_diag_grain=512,
           computed_over_live=(64 * 63 / 2 + 64 * 0.75) / (64 * 64 / 2),
           bwd_computed_over_live=(64 * 63 / 2 + 64) / (64 * 64 / 2))),
+    # a budget narrowed below the default narrows what the backward may ask
+    # for with it
     (dict(T=1024, S=1024, D=64, itemsize=2, heads=12, vmem_budget=2 ** 20),
-     dict(resident=False, fused_bwd=False, heads_per_step=1)),
+     dict(resident=False, fused_bwd=False, heads_per_step=1,
+          bwd_vmem_bytes=0)),
     # T = 4096, D = 64: resident forward in bf16 (on part of the queries a
-    # step), streamed in f32
+    # step), streamed in f32; the backward in one pass in both
     (dict(T=4096, S=4096, D=64, itemsize=2, heads=1),
-     dict(resident=True, fused_bwd=False, heads_per_step=1)),
+     dict(resident=True, fused_bwd=True, heads_per_step=1,
+          bwd_diag_grain=128, bwd_computed_over_live=1.03125,
+          bwd_vmem_bytes=int(24.125 * 2 ** 20))),
     (dict(T=4096, S=4096, D=64, itemsize=4, heads=1),
-     dict(resident=False, fused_bwd=False, heads_per_step=1)),
+     dict(resident=False, fused_bwd=True, heads_per_step=1)),
 ])
 def test_flash_plan_function(case, want):
     from penroz_tpu.ops.pallas import flash_attention as FA
@@ -1150,20 +1178,31 @@ def test_flash_plan_function(case, want):
     assert case["T"] % plan.q_rows == 0 and plan.q_rows % plan.block_q == 0
     if not plan.resident:
         assert plan.q_rows == plan.block_q
-    # the estimates the plan was chosen by stay inside the budget
+    # the estimates the plan was chosen by stay inside the budget, the
+    # one-pass backward's inside what its call may ask for — and the plan
+    # carries that one (a lane block is one head as wide)
     budget = case.get("vmem_budget", FA.VMEM_BUDGET)
     kvh = FA._kv_heads_per_step(hps, group)
     if plan.resident:
         assert FA._fwd_resident_bytes(
             plan.q_rows, case["S"], case["D"], case["itemsize"], hps, kvh,
             plan.block_q, plan.block_k) <= budget
-    if plan.fused_bwd:
-        assert FA._bwd_fused_bytes(
+    if plan.fused_bwd and case.get("layout") != "btd":
+        assert plan.bwd_vmem_bytes == FA._bwd_fused_bytes(
             case["T"], case["S"], case["D"], case["itemsize"], hps, kvh,
-            plan.bwd_block_q, plan.bwd_block_k) <= budget
+            plan.bwd_block_q, plan.bwd_block_k)
+    # … with Mosaic's share on top of it
+    assert FA._asked(FA.VMEM_BUDGET) == 16 * 2 ** 20    # the default
+    assert (FA._asked(plan.bwd_vmem_bytes) <= FA.VMEM_LIMIT
+            if budget >= FA.VMEM_BUDGET else plan.bwd_vmem_bytes <= budget)
+    assert (plan.bwd_vmem_bytes > 0) == plan.fused_bwd
+    # several heads a step only where the backward asks for nothing
+    assert hps == plan.heads_per_block or plan.bwd_vmem_bytes <= budget
     # pure: the same shapes give the same plan, and it names itself
     assert FA.plan_flash(**case) == plan
-    assert f"heads_per_step={hps}" in plan.describe()
+    assert (f"{'fused_bwd' if plan.fused_bwd else 'split_bwd'} "
+            f"bwd_vmem_mib={plan.bwd_vmem_bytes / 2 ** 20:.1f} "
+            f"heads_per_step={hps} ") in plan.describe()
     assert (f"diag_grain={plan.diag_grain} "
             f"bwd_diag_grain={plan.bwd_diag_grain} "
             f"computed_over_live={plan.computed_over_live:.3f} "
@@ -1265,7 +1304,7 @@ def test_flash_plan_is_logged_once_and_spanned_per_trace(caplog):
              if r.getMessage().startswith("flash plan:")]
     assert len(lines) == 1, lines
     assert lines[0].startswith("flash plan: T=384 S=384 D=64 bq=384 bk=384")
-    assert "resident" in lines[0] and "fused_bwd" in lines[0]
+    assert " resident q_rows=384 fused_bwd bwd_vmem_mib=" in lines[0]
     # one 384-tile, which 256 does not divide: cut at 128 both ways, 6 of
     # its 9 sub-blocks, over half the tile
     assert (" diag_grain=128 bwd_diag_grain=128 computed_over_live=1.333 "
@@ -1278,6 +1317,10 @@ def test_flash_plan_is_logged_once_and_spanned_per_trace(caplog):
     assert (meta["T"], meta["S"], meta["D"]) == (384, 384, 64)
     assert meta["block_q"] == 384 and meta["resident"] is True
     assert meta["fused_bwd"] is True and meta["heads_per_step"] == 2
+    # what the one-pass backward counts on, under the default share: its
+    # call asks the compiler for nothing
+    assert 0 < meta["bwd_vmem_bytes"] <= FA.VMEM_BUDGET
+    assert f"bwd_vmem_mib={meta['bwd_vmem_bytes'] / 2 ** 20:.1f} " in lines[0]
     assert meta["diag_grain"] == meta["bwd_diag_grain"] == 128
     assert meta["computed_over_live"] == pytest.approx(4 / 3)
     assert meta["bwd_computed_over_live"] == pytest.approx(4 / 3)
@@ -1421,7 +1464,9 @@ def test_flash_btd_cell_plan_matches_oracle():
         "bq=512 bk=512 bwd_bq=512 bwd_bk=512 diag_grain=256 "
         "bwd_diag_grain=128 computed_over_live=1.250 "
         "bwd_computed_over_live=1.125 resident q_rows=1024 fused_bwd "
-        "heads_per_step=2 layout=btd heads_per_block=2 fused_qkv")
+        "bwd_vmem_mib=10.9 heads_per_step=2 layout=btd heads_per_block=2 "
+        "fused_qkv")
+    assert plan.bwd_vmem_bytes <= FA.VMEM_BUDGET      # no limit asked for
     attend = lambda x: FA.flash_attention_btd(x, heads=2, interpret=True)
     want = _btd_oracle(A.causal_attention_reference, 2, 2, 64)
     exact = qkv.astype(jnp.float32)
@@ -1467,6 +1512,68 @@ def test_flash_cut_diagonal_tiles_equal_whole_ones(grain, monkeypatch):
 
     for got, want in zip(run(grain), run(512)):
         assert _ulps_bf16(got, want).max() <= 1.0
+
+
+def _pallas_vmem_limits(jaxpr, found=None) -> dict:
+    """``vmem_limit_bytes`` of every named Pallas call in ``jaxpr``, the
+    nested ones (a ``custom_vjp``'s) included."""
+    found = {} if found is None else found
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found[eqn.params["name"]] = (
+                eqn.params["compiler_params"]["mosaic_tpu"].vmem_limit_bytes)
+        for inner in jax.core.jaxprs_in_params(eqn.params):
+            _pallas_vmem_limits(inner, found)
+    return found
+
+
+def test_flash_one_pass_past_the_default_share_equals_the_split(monkeypatch):
+    """The same shapes — one D = 128 head of the looped cell's kind at
+    T = 2048, three bfloat16 arrays — under today's budget (``VMEM_LIMIT``
+    held to ``VMEM_BUDGET``: the two kernels) and under what a call may ask
+    for (one pass, its call carrying the plan's bytes as the limit): the
+    same output, and gradients equal to one unit in bfloat16's last place
+    (the split does its diagonal tiles whole and rounds dS once a kernel;
+    nothing else differs)."""
+    from penroz_tpu.ops.pallas import flash_attention as FA
+    T, D = 2048, 128
+    rng = np.random.default_rng(42)
+    q, k, v = (jnp.asarray(rng.normal(size=(1, T, D)), jnp.bfloat16)
+               for _ in range(3))
+    w = jnp.asarray(rng.normal(size=(1, T, D)), jnp.float32)
+    attend = lambda q, k, v: FA.flash_attention_btd(q, k, v, heads=1,
+                                                    interpret=True)
+    loss = lambda q, k, v: (attend(q, k, v).astype(jnp.float32) * w).sum()
+
+    def run(limit):
+        monkeypatch.setattr(FA, "VMEM_LIMIT", limit)
+        plan = FA.plan_flash(T, T, D, 2, heads=1, layout="btd")
+        limits = _pallas_vmem_limits(
+            jax.make_jaxpr(jax.grad(loss, (0, 1, 2)))(q, k, v).jaxpr)
+        return plan, limits, (attend(q, k, v),
+                              *jax.grad(loss, (0, 1, 2))(q, k, v))
+
+    limit = FA.VMEM_LIMIT
+    split, split_limits, want = run(FA.VMEM_BUDGET)
+    fused, fused_limits, got = run(limit)
+    assert not split.fused_bwd and split.bwd_vmem_bytes == 0
+    assert set(split_limits) == {
+        "penroz_flash_fwd", "penroz_flash_bwd_delta", "penroz_flash_bwd_dq",
+        "penroz_flash_bwd_dkv"} and not any(split_limits.values())
+    assert fused.fused_bwd and (fused.bwd_diag_grain, split.bwd_diag_grain,
+                                fused.bwd_computed_over_live) == (
+        128, 512, 1.0625)
+    assert FA.VMEM_BUDGET < fused.bwd_vmem_bytes <= 16 * 2 ** 20
+    # asked for: the estimate and Mosaic's share, over the default's 16 MiB
+    assert fused_limits == {"penroz_flash_fwd": None,
+                            "penroz_flash_bwd_delta": None,
+                            "penroz_flash_bwd": fused.bwd_vmem_bytes * 4 // 3}
+    # the forward is not the limit's: the same plan, the same call
+    assert dataclasses.replace(
+        fused, fused_bwd=False, bwd_vmem_bytes=0, bwd_diag_grain=512,
+        bwd_computed_over_live=split.bwd_computed_over_live) == split
+    for a, b in zip(got, want):
+        assert _ulps_bf16(a, b).max() <= 1.0
 
 
 @pytest.mark.parametrize("shape", ["d64_pairs", "d128_gqa"])

@@ -57,6 +57,35 @@ def test_roundtrip_noncontiguous_and_empty_arrays():
     assert out["empty"].dtype == np.int8
 
 
+def test_host_arrays_die_with_the_save_or_load_that_made_them():
+    """What a save copied to the host is freed when the save returns, by
+    reference counting alone: no reference cycle of the encoder holds the
+    arrays for the next full garbage collection to find (a nested ``enc``
+    that called itself did — 6 GB a save in the looped cell, three saves
+    deep before a collection, which a 40 GiB host does not hold: PR 42)."""
+    import gc
+    import weakref
+    a = np.arange(12.0).reshape(3, 4)       # contiguous: kept as it is
+    b = np.arange(6, dtype=np.int32)[::2]   # not: the encoder's own copy
+    alive = weakref.ref(a)
+    gc.collect()
+    gc.disable()
+    try:
+        header, arrays, meta = checkpoint._encode_parts(
+            {"params": {"w": a}, "opt": [1, (2.0, b)], "again": a})
+        assert arrays[0] is a and len(arrays) == len(meta) == 3
+        copied = weakref.ref(arrays[1])
+        del arrays, a
+        assert alive() is None and copied() is None
+        # … and what a load read dies with the loaded tree
+        loaded = checkpoint._decode(checkpoint._encode({"w": np.ones(3)}))
+        read = weakref.ref(loaded["w"])
+        del loaded
+        assert read() is None
+    finally:
+        gc.enable()
+
+
 def test_shard_pieces_shape_survives():
     """The shard-file payload shape: pieces are (ranges, array) pairs whose
     tuples become lists — reassembly unpacks them positionally."""

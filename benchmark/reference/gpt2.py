@@ -141,6 +141,10 @@ def _fp8(x):
 PRECISIONS = {
     # name: (storage/compute dtype, operand rounding before each matmul)
     "float32": (jnp.float32, None),
+    # float32 kept, multiplied at the backend's default precision: on a TPU
+    # one bfloat16 pass with float32 accumulation, what the program's own
+    # matmuls do.  No control: a witness of where the program's rounding is
+    "float32_default": (jnp.float32, None),
     "bfloat16": (jnp.bfloat16, None),
     "fp8": (jnp.bfloat16, _fp8),
 }
@@ -253,22 +257,30 @@ def greedy_continue(params, prompt, new_tokens: int, *, heads: int,
     return seq[len(prompt):]
 
 
-def greedy_regret(params, prompt, generated, *, heads: int, block: int):
+def greedy_regret(params, prompt, generated, *, heads: int, block: int,
+                  chosen_by: str | None = None):
     """How far each generated token is from the float32 reference's own
     greedy choice, teacher-forced on the sequence as generated: for every
     generated position, (largest reference logit − reference logit of the
     token that was emitted) ÷ the standard deviation of that position's
     logits.  0 where the emitted token is the reference's argmax.  Returns a
-    float array, one entry per generated token."""
+    float array, one entry per generated token.
+
+    ``chosen_by`` names a precision: then the token judged at each position
+    is not the emitted one but the one that precision puts first on the same
+    prefix — the control's reading at the program's own positions, without
+    decoding."""
     seq = list(prompt) + list(generated)
     if len(seq) > block:
         raise ValueError(f"{len(seq)} tokens exceed the block of {block}")
     buf = np.zeros((1, block), np.int32)
     buf[0, :len(seq)] = seq
-    z = logits(params, jnp.asarray(buf), heads=heads)[0]
-    rows = z[len(prompt) - 1:len(seq) - 1]
-    chosen = jnp.take_along_axis(
-        rows, jnp.asarray(generated, jnp.int32)[:, None], -1)[:, 0]
+    span = slice(len(prompt) - 1, len(seq) - 1)
+    rows = logits(params, jnp.asarray(buf), heads=heads)[0][span]
+    tokens = (jnp.asarray(generated, jnp.int32) if chosen_by is None
+              else jnp.argmax(logits(params, jnp.asarray(buf), heads=heads,
+                                     precision=chosen_by)[0][span], -1))
+    chosen = jnp.take_along_axis(rows, tokens[:, None], -1)[:, 0]
     return np.asarray((rows.max(-1) - chosen) / rows.std(-1), np.float64)
 
 

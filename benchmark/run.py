@@ -208,7 +208,15 @@ def main(argv=None) -> int:
         result["breakdown"] = {
             "device_ops": art["trace"]["device_ops"][:10],
             "idle_gaps": art["trace"]["idle_gaps"][:10]}
+    # every number compared beside its limit: last in the result line, and
+    # as the last lines of stderr
+    result["checks"] = art.get("checks", {})
     print(json.dumps(result), flush=True)
+    for name, check in result["checks"].items():
+        bound = ("limit", "<=") if "limit" in check else ("at_least", ">=")
+        print(f"run.py: correct={result['correct']} {name} "
+              f"{check['value']!r} {bound[1]} {check[bound[0]]!r}",
+              file=sys.stderr, flush=True)
     return 0
 
 

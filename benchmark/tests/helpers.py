@@ -40,3 +40,30 @@ def leftovers(root):
     found += glob.glob(os.path.join(root, "models", "model_bench*"))
     found += glob.glob(os.path.join(root, "data", "benchtoks*"))
     return found
+
+
+def tree_with_a_serving_cell(tmp_path) -> str:
+    """A copy of the benchmark in ``tmp_path`` whose manifest also holds a
+    serving cell, made of files that are there (``gpt2-large-hf`` under
+    ``chat_steady``) plus entries: none is in ``BENCHMARK.json`` yet
+    (PERF.md, Open questions).  Returns the cell's name; run it with
+    ``PYTHONPATH=ROOT``, since the copy lacks the program."""
+    import shutil
+    shutil.copytree(os.path.join(ROOT, "benchmark"), tmp_path / "benchmark",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    manifest = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
+    cfg = json.load(open(os.path.join(ROOT, "benchmark", "configs",
+                                      "gpt2-large-hf.json")))
+    manifest["configs"].append({
+        "name": "gpt2-large-hf", "source": cfg["source"],
+        "file": "benchmark/configs/gpt2-large-hf.json", "reduced": [],
+        "why": "the serving path"})
+    manifest["workloads"].append({
+        "name": "gpt2l-chat-steady", "config": "gpt2-large-hf",
+        "traffic": "chat_steady", "chips": 1, "why": "a serving cell"})
+    manifest["end_to_end"].append({
+        "name": "serve_tokens_per_s", "unit": "tokens/s", "better": "higher",
+        "bound": 0.1, "source": "host_clock",
+        "workloads": ["gpt2l-chat-steady"]})
+    json.dump(manifest, open(tmp_path / "BENCHMARK.json", "w"))
+    return "gpt2l-chat-steady"

@@ -108,3 +108,56 @@ def test_greedy_regret_is_zero_for_the_reference_and_not_for_noise():
                              block=64).mean() > 0.5
     with pytest.raises(ValueError):
         ref.greedy_regret(weights, prompt, own * 6, heads=4, block=64)
+
+
+def test_the_serving_controls_read_above_the_reference_itself():
+    """``tools/control.py``'s serving control at a size a test can hold: the
+    reference decoding greedily in a lower precision, scored as a run scores
+    the served tokens (``kinds/serve_open.py``), and each precision's own
+    choice read without decoding (``chosen_by``).  Float32 reads 0, scaled
+    fp8 over the limit, bfloat16 (the control of a float32 configuration)
+    between.  On the chip bfloat16 reads under every limit the program
+    passes, which is why no serving cell stands yet (PERF.md, Open
+    questions, first)."""
+    import json
+    import os
+    from benchmark.kinds.serve_open import regret_numbers, sample_requests
+    from benchmark.lib import traffic
+    from helpers import ROOT
+    cfg = json.load(open(os.path.join(
+        ROOT, "benchmark", "configs", "gpt2-large-hf.json")))
+    limits = cfg["rehearse"]["correct"]
+    mix = {"rate_per_s": 1.0,
+           "prompt_tokens": {"median": 12, "sigma": .6, "min": 4, "max": 24},
+           "output_tokens": {"median": 12, "sigma": .6, "min": 4, "max": 24}}
+    reqs = traffic.schedule(mix, SEED, 12, 512, 64)
+    sample = sample_requests(reqs, SEED, 6)
+    assert len(sample) == 6 and len({id(r) for r in sample}) == 6
+    assert sample[0].max_new == max(r.max_new for r in reqs)   # the longest
+    assert sample_requests(reqs, SEED, 6) == sample             # from the seed
+    assert sample_requests(reqs, SEED + 1, 6)[1:] != sample[1:]
+    weights = ref.init_params(CFG, SEED)
+    kw = dict(heads=4, block=64)
+    got, forced = {}, {}
+    for precision in ("float32", "bfloat16", "fp8"):
+        regrets, choices = [], []
+        for r in sample:
+            tokens = ref.greedy_continue(weights, r.prompt, r.max_new,
+                                         precision=precision, **kw)
+            regrets.append(ref.greedy_regret(weights, r.prompt, tokens, **kw))
+            # without decoding: the precision's own choice at each position
+            # of the sequence the float32 reference continues the prompt with
+            own = ref.greedy_continue(weights, r.prompt, r.max_new,
+                                      precision="float32", **kw)
+            choices.append(ref.greedy_regret(weights, r.prompt, own,
+                                             chosen_by=precision, **kw))
+        got[precision] = regret_numbers(regrets)
+        forced[precision] = regret_numbers(choices)
+    for reading in (got, forced):
+        assert reading["float32"]["greedy_regret_mean"] == 0.0
+        assert reading["float32"]["greedy_regret_max"] == 0.0
+        assert reading["fp8"]["greedy_regret_mean"] \
+            > limits["greedy_regret_mean"]
+        assert reading["fp8"]["greedy_regret_mean"] \
+            >= reading["bfloat16"]["greedy_regret_mean"]
+    assert regret_numbers([])["greedy_regret_mean"] == float("inf")

@@ -19,7 +19,15 @@ def test_rehearsal_runs_checks_and_cleans_up(cell, trace):
     rc, lines, err = run_cell(ROOT, cell, "--rehearse", trace=trace)
     assert rc == 0, err[-3000:]
     result = last_json(lines)
-    assert KEYS <= set(result) <= KEYS | {"breakdown"}
+    assert KEYS <= set(result) <= KEYS | {"breakdown", "checks"}
+    # every number compared beside its limit, last in the line and as the
+    # last lines of stderr
+    assert list(result)[-1] == "checks" and result["checks"]
+    assert all({"value"} < set(c) <= {"value", "limit", "at_least"}
+               for c in result["checks"].values())
+    tail = err.strip().splitlines()[-len(result["checks"]):]
+    assert all(line.startswith("run.py: correct=True ") for line in tail)
+    assert {line.split()[2] for line in tail} == set(result["checks"])
     assert result["correct"] is True and result["failed"] == 0
     assert result["attempted"] > 0
     # a rehearsal names its platform and prints no number under a metric

@@ -82,3 +82,18 @@ def test_histogram_window_delta_and_quantile():
     assert stats.hist_quantile(d, 0.99) <= 40.0
     assert stats.hist_quantile({"buckets": [1], "counts": [0], "sum": 0,
                                 "count": 0, "max": None}, 0.5) is None
+
+
+def test_nothing_is_offered_at_or_after_the_windows_close():
+    import json
+    import os
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    for name in sorted(os.listdir(os.path.join(here, "traffic"))):
+        mix = json.load(open(os.path.join(here, "traffic", name)))
+        if mix["kind"] != "serve_open":
+            continue
+        for seed in (3, 2**31 + 99):
+            reqs = traffic.schedule(mix, seed, 51, 50257, 1024)
+            assert all(r.due < 51 for r in reqs), name
+            assert all(0 <= r.due for r in reqs if r.counted), name
+            assert all(r.due < 0 for r in reqs if not r.counted), name

@@ -21,6 +21,7 @@ import logging
 import os
 import time
 from concurrent.futures import ThreadPoolExecutor
+from concurrent.futures import TimeoutError as ScanTimeout
 
 import numpy as np
 
@@ -30,6 +31,14 @@ log = logging.getLogger(__name__)
 
 DATA_FOLDER = "data"
 NATIVE_LOADER_ENV = "PENROZ_NATIVE_LOADER"
+
+# ``Loader.next_batch`` looks at its directory on every batch (a shard a
+# concurrent Downloader adds is read on the next one), on a helper thread:
+# a look that the file system answers in time is the batch's own, as if made
+# inline; one that it does not (a checkpoint's flush renaming 6 GB held
+# ``glob`` and ``stat`` beside it for 0.1 to 3.4 s, inside a training step)
+# costs the batch this much, and is read by the first batch after it ends.
+SCAN_WAIT_SECONDS = 0.1
 
 
 def _native_loader_module():
@@ -63,6 +72,9 @@ class Loader:
         self._stream = None          # native mmap stream (penroz_loader)
         self._stream_sig: list[tuple] = []   # (name, size, mtime_ns) per shard
         self._prefix: list[int] = []
+        self._seen = None            # newest finished look: (files, sig)
+        self._looking = None         # a look the file system has yet to answer
+        self._looker = None          # the helper thread, made at first need
         # Where ``next_batch``'s time went, running totals in seconds
         # (``time.perf_counter``): learning what there is to read (the glob,
         # the ``stat``s, a shard's ``np.load``) and bringing the tokens
@@ -85,6 +97,38 @@ class Loader:
         # Drop the mmap stream too: a re-download reusing the same shard
         # filenames must not serve the deleted files' pages.
         self._stream, self._stream_sig, self._prefix = None, [], []
+        self._seen = self._looking = None
+
+    def _scan(self) -> tuple[list[str], list[tuple] | None]:
+        """The shards' names and each one's (name, size, mtime_ns); no
+        second where a shard vanished between the glob and its stat."""
+        files = self._files()
+        try:
+            return files, [(name, st.st_size, st.st_mtime_ns) for name, st in
+                           ((n, os.stat(os.path.join(DATA_FOLDER, n)))
+                            for n in files)]
+        except OSError:
+            return files, None
+
+    def _look(self) -> tuple[list[str], list[tuple] | None]:
+        """What there is to read, by a look made now where the file system
+        answers within ``SCAN_WAIT_SECONDS`` (always, for the first), else
+        by the newest one that finished.  While a look is unanswered no
+        batch waits for it or starts another."""
+        if self._looking is not None:
+            if not self._looking.done():
+                return self._seen
+            self._seen, self._looking = self._looking.result(), None
+        if self._looker is None:
+            self._looker = ThreadPoolExecutor(
+                max_workers=1, thread_name_prefix="penroz-loader-scan")
+        look = self._looker.submit(self._scan)
+        try:
+            self._seen = look.result(
+                None if self._seen is None else SCAN_WAIT_SECONDS)
+        except ScanTimeout:
+            self._looking = look
+        return self._seen
 
     def _shard_data(self, files: list[str], shard_idx: int) -> np.ndarray:
         shard_idx %= len(files)
@@ -99,17 +143,13 @@ class Loader:
             self._cache[shard_idx] = data
         return data
 
-    def _native_stream(self, files: list[str]):
+    def _native_stream(self, files: list[str], sig: list[tuple] | None):
         """mmap-backed token stream over ``files``; None → numpy fallback.
 
-        Rebuilt whenever any shard's (name, size, mtime) changes — new
-        shards from a concurrent Downloader, or same-name rewrites after a
-        delete + re-download."""
-        try:
-            sig = [(name, st.st_size, st.st_mtime_ns) for name, st in
-                   ((n, os.stat(os.path.join(DATA_FOLDER, n)))
-                    for n in files)]
-        except OSError:
+        Rebuilt whenever any shard's (name, size, mtime) changes (``sig``,
+        from ``_scan``) — new shards from a concurrent Downloader, or
+        same-name rewrites after a delete + re-download."""
+        if sig is None:
             return None
         if sig == self._stream_sig:
             return self._stream
@@ -138,11 +178,11 @@ class Loader:
         """(input, target) flat int32 arrays of ``buffer_size`` tokens;
         target is input shifted by ``target_offset`` (None when 0)."""
         t0 = time.perf_counter()
-        files = self._files()
+        files, sig = self._look()
         if not files:
             raise ValueError(f"Dataset {self.dataset_id} has no shards")
         need = self.buffer_size + target_offset
-        stream = self._native_stream(files)
+        stream = self._native_stream(files, sig)
         t1 = time.perf_counter()
         self.scan_seconds += t1 - t0
         if stream is not None:

@@ -276,6 +276,12 @@ class CompiledArch:
             raise ValueError("a model holds at most one looped stack, as a "
                              "top-level layer")
         self.looped: Optional[M.Looped] = looped[0] if looped else None
+        # Dropless expert layers count what they route
+        # (ops/modules.py::MOE_COUNTERS); a training epoch sums the counts
+        # and returns them after its other results.
+        self.counts_routing = any(
+            isinstance(m, M.MixtureOfExperts) and m.dispatch == "dropless"
+            for top in self.mods for m in top.walk())
         self.param_order: list[str] = []
         for mod in self.mods:
             for sub in mod.walk():
@@ -473,16 +479,18 @@ class CompiledArch:
 
     def _train_loss_fn(self, compute_dtype, sp_mesh, platform, sp_mode,
                        ep_mesh):
-        """``fn(params, buffers, x, y, rng) -> (cost, (buffer updates, exit
+        """``fn(params, buffers, x, y, rng) -> (cost, (buffer updates,
         stats))`` of one training micro-step; the stats are ``None`` but
-        for a model with several exits."""
+        for a model with several exits (each pass's loss and exit mass) or
+        with dropless expert layers (their routing counters)."""
         def loss_fn(params, buffers, x, y, rng):
             _, cost, ctx, _ = self._forward(
                 params, buffers, x, y, training=True, rng=rng,
                 skip_softmax=True, compute_dtype=compute_dtype,
                 sp_mesh=sp_mesh, platform=platform, sp_mode=sp_mode,
                 ep_mesh=ep_mesh)
-            return cost, (ctx.buffer_updates, ctx.exit_stats)
+            stats = {**(ctx.exit_stats or {}), **(ctx.moe_stats or {})}
+            return cost, (ctx.buffer_updates, stats or None)
         return loss_fn
 
     def jit_forward(self, params, buffers, tokens, targets=None, *,
@@ -555,7 +563,10 @@ class CompiledArch:
         several exits (a looped stack) returns a sixth result after those
         five, ``{"pass_loss": (steps,), "exit_mass": (steps,)}``: each
         pass's mean cross-entropy and the mean exit distribution of the
-        epoch.
+        epoch.  A model with dropless expert layers returns that sixth
+        result too, holding the epoch's routing counters
+        (``ops/modules.py::MOE_COUNTERS``, summed over layers and
+        micro-steps).
 
         ``with_ratios=False`` compiles a variant that skips the per-weight
         update-ratio stds (two full passes over the parameters) — the
@@ -661,9 +672,11 @@ class CompiledArch:
 
         def finalize(params, opt_state, grads, new_buffers, cost_sum):
             inv = 1.0 / num_steps
-            exits = jax.tree.map(lambda c: c * inv, cost_sum)
+            # means over the micro-steps; the routing counters stay sums
+            exits = {k: c if k in M.MOE_COUNTERS else c * inv
+                     for k, c in cost_sum.items()}
             cost = exits.pop("cost")
-            exits = (exits,) if exits else ()   # a looped stack's only
+            exits = (exits,) if exits else ()   # looped or dropless only
             grads = jax.tree.map(
                 lambda g, p: (g * inv).astype(p.dtype), grads, params)
             updates, new_opt_state = optimizer.update(grads, opt_state, params)
@@ -714,14 +727,18 @@ class CompiledArch:
     def zero_cost_sum(self) -> dict:
         """What a training epoch accumulates over its micro-steps beside the
         gradient: the cost and, for a model with several exits (a looped
-        stack), each pass's mean loss and the mean exit distribution,
-        which then leave the epoch program after its five results."""
+        stack), each pass's mean loss and the mean exit distribution, and
+        for one with dropless expert layers their routing counters, which
+        then leave the epoch program after its five results."""
         zeros = lambda *shape: jnp.zeros(shape, jnp.float32)
-        if self.looped is None:
-            return {"cost": zeros()}
         # an array each: the micro-stepped path donates the accumulator
-        return {"cost": zeros(), "pass_loss": zeros(self.looped.steps),
-                "exit_mass": zeros(self.looped.steps)}
+        sums = {"cost": zeros()}
+        if self.looped is not None:
+            sums.update(pass_loss=zeros(self.looped.steps),
+                        exit_mass=zeros(self.looped.steps))
+        if self.counts_routing:
+            sums.update({name: zeros() for name in M.MOE_COUNTERS})
+        return sums
 
     def train_micro_fns(self, optimizer_config: dict, num_steps: int,
                         remat: bool = False, compute_dtype=None,
@@ -1623,7 +1640,11 @@ class NeuralNetworkModel:
                         exits = ({k: np.asarray(v, np.float64).tolist()
                                   for k, v in out[5].items()}
                                  if len(out) > 5 else {})
-                    epoch_span.set(**tracing.exit_counters(exits))
+                        # dropless expert layers: what the epoch routed
+                        routed = {k: int(exits.pop(k))
+                                  for k in M.MOE_COUNTERS if k in exits}
+                    epoch_span.set(**tracing.exit_counters(exits),
+                                   **tracing.routing_counters(routed))
                 duration = time.monotonic() - t0
                 if master:
                     if epoch % sample_every == 0:
@@ -1634,7 +1655,7 @@ class NeuralNetworkModel:
                             "speedPerSec": buffer_size / max(duration, 1e-9),
                             "weight_upd_ratio":
                                 np.asarray(ratios, np.float64).tolist(),
-                            **exits,
+                            **exits, **routed,
                         })
                     log.info("Epoch %d: cost=%.4f %.0f tokens/sec",
                              epoch + 1, cost,

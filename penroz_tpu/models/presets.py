@@ -167,6 +167,105 @@ def ouro_custom(d: int, heads: int, head_dim: int, intermediate: int,
         {"softmaxlast": {"dim": -1}}]
 
 
+def laguna_custom(d: int, head_dim: int, layer_types: list,
+                  heads_per_layer: list, kv_heads: int, mlp_layer_types: list,
+                  intermediate: int, num_experts: int, top_k: int,
+                  moe_intermediate: int, shared_intermediate: int, vocab: int,
+                  window: int, rope: dict, experts_held: int | None = None,
+                  first_expert: int = 0, routed_scale: float = 1.0,
+                  norm_topk: bool = True, eps: float = 1e-6,
+                  published_layers: int | None = None) -> list:
+    """Laguna-shaped sparse-expert language model (poolside ``Laguna-*``
+    ``config.json``) at arbitrary dimensions, whole or as one rank's share
+    of an expert- and tensor-parallel layer.
+
+    One pre-norm block a layer (RMSNorm before attention and before the MLP,
+    no bias anywhere), a final RMSNorm, an untied head.  Layer ``i``:
+
+    - attention of kind ``layer_types[i]`` with ``heads_per_layer[i]`` query
+      heads on ``kv_heads`` K/V heads of size ``head_dim`` and a per-head
+      sigmoid gate on its output (``gate: per_head``; the gate's weight is
+      the last ``heads`` rows of the fused projection).  ``full_attention``
+      and ``sliding_attention`` (a window of ``window`` keys) take their
+      rotation from ``rope[kind]``: ``rope_theta``, ``partial_rotary_factor``
+      and, for ``rope_type: yarn``, its parameters.
+    - ``mlp_layer_types[i]``: ``dense`` is a SwiGLU of ``intermediate``;
+      ``sparse`` a router over ``num_experts`` (softmax, ``top_k`` a token,
+      renormalised if ``norm_topk``, times ``routed_scale``) over SwiGLU
+      experts of ``moe_intermediate`` beside one ungated shared expert of
+      ``shared_intermediate``, dispatched dropless.
+
+    The share: ``experts_held`` experts from ``first_expert`` (default all),
+    and the caller's ``heads_per_layer`` / ``kv_heads`` / ``vocab`` already
+    cut to what this rank holds.  What the absent experts and heads would
+    add is left out; nothing stands in for the other ranks.
+
+    N(0, 0.02) initialisation of the linear layers, the attention output
+    projections scaled by 1/sqrt(2 · ``published_layers``) (default: the
+    layers built)."""
+    depth = len(layer_types)
+    if not (len(heads_per_layer) == len(mlp_layer_types) == depth):
+        raise ValueError("layer_types, heads_per_layer and mlp_layer_types "
+                         "name one entry a layer")
+    std = 0.02
+    proj_std = std / (2 * (published_layers or depth)) ** 0.5
+    norm = {"rmsnorm": {"normalized_shape": d, "eps": eps}}
+
+    def linear(fan_in, fan_out, s=std):
+        return {"linear": {"in_features": fan_in, "out_features": fan_out,
+                           "bias": False},
+                "normal": {"mean": 0.0, "std": s}}
+
+    def attention(kind, heads):
+        if kind not in ("full_attention", "sliding_attention"):
+            raise ValueError(f"unknown layer type {kind!r}")
+        spec = rope[kind]
+        args = {"num_heads": heads, "num_kv_heads": kv_heads,
+                "head_dim": head_dim, "rope_theta": spec["rope_theta"],
+                "gate": "per_head"}
+        if float(spec.get("partial_rotary_factor", 1)) < 1:
+            args["rope_pct"] = spec["partial_rotary_factor"]
+        if spec.get("rope_type", "default") != "default":
+            args["rope_scaling"] = {
+                k: v for k, v in spec.items()
+                if k not in ("rope_theta", "partial_rotary_factor")}
+        if kind == "sliding_attention":
+            args["sliding_window"] = window
+        return {"attention": args}
+
+    def mlp(kind):
+        if kind == "dense":
+            return {"gatedmlp": {"in_features": d,
+                                 "intermediate_size": intermediate,
+                                 "activation": "silu"}}
+        if kind != "sparse":
+            raise ValueError(f"unknown MLP layer type {kind!r}")
+        return {"moe": {
+            "in_features": d, "intermediate_size": moe_intermediate,
+            "num_experts": num_experts, "top_k": top_k,
+            "activation": "silu", "norm_topk": norm_topk,
+            "routed_scale": routed_scale,
+            "shared_expert_size": shared_intermediate,
+            "shared_expert_gate": False, "dispatch": "dropless",
+            "experts_held": experts_held or num_experts,
+            "first_expert": first_expert}}
+
+    blocks = []
+    for kind, heads, mlp_kind in zip(layer_types, heads_per_layer,
+                                     mlp_layer_types):
+        fused = (heads + 2 * kv_heads) * head_dim + heads   # [q | k | v | g]
+        blocks.append({"transformerblock": {
+            "attn_block": {"sequential": [
+                norm, linear(d, fused), attention(kind, heads),
+                linear(heads * head_dim, d, proj_std)]},
+            "mlp_block": {"sequential": [norm, mlp(mlp_kind)]},
+            "post_norm_on_residual": False}})
+    return ([{"embedding": {"num_embeddings": vocab, "embedding_dim": d},
+              "normal": {"mean": 0.0, "std": std}}]
+            + blocks
+            + [norm, linear(d, vocab), {"softmaxlast": {"dim": -1}}])
+
+
 def makemore_mlp(vocab: int = 27, d_embed: int = 10,
                  d_hidden: int = 200) -> list:
     """Char-level MLP in the makemore style (BASELINE.md CPU-parity config):

@@ -14,6 +14,7 @@ from __future__ import annotations
 
 import functools
 import logging
+import math
 from typing import NamedTuple, Optional
 
 import jax
@@ -117,6 +118,53 @@ def _llama3_scale_inv_freq(inv_freq, scaling: dict):
     return jnp.where(is_medium, smoothed, scaled)
 
 
+def yarn_scaling(scaling: dict) -> dict:
+    """A ``rope_scaling`` dict of type ``yarn`` checked and completed: the
+    published defaults for what it leaves out (``beta_fast`` 32,
+    ``beta_slow`` 1, ``attention_factor`` 0.1·ln(factor) + 1)."""
+    missing = [k for k in ("factor", "original_max_position_embeddings")
+               if k not in scaling]
+    if missing:
+        raise ValueError(f"rope_scaling type 'yarn' is not supported "
+                         f"without {missing} (missing keys)")
+    factor = float(scaling["factor"])
+    if factor < 1.0:
+        raise ValueError("rope_scaling factor must be >= 1")
+    attention_factor = scaling.get("attention_factor")
+    if attention_factor is None:
+        attention_factor = 0.1 * math.log(factor) + 1.0
+    return {"rope_type": "yarn", "factor": factor,
+            "original_max_position_embeddings":
+                float(scaling["original_max_position_embeddings"]),
+            "beta_fast": float(scaling.get("beta_fast") or 32.0),
+            "beta_slow": float(scaling.get("beta_slow") or 1.0),
+            "attention_factor": float(attention_factor)}
+
+
+def _yarn_inv_freq(dim: int, theta: float, scaling: dict):
+    """YaRN (Peng et al. 2023, arXiv:2309.00071) inverse frequencies over
+    ``dim`` rotated dims: a dim whose wavelength makes more than
+    ``beta_fast`` turns within the original context keeps its frequency
+    (extrapolation), one that makes fewer than ``beta_slow`` has it divided
+    by ``factor`` (interpolation), a linear ramp over the dims between (the
+    two bounds taken to whole dims, floor and ceiling)."""
+    def correction_dim(rotations):
+        return (dim * math.log(scaling["original_max_position_embeddings"]
+                               / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(correction_dim(scaling["beta_fast"])), 0)
+    high = min(math.ceil(correction_dim(scaling["beta_slow"])), dim - 1)
+    if low == high:
+        high += 0.001
+    extrapolated = 1.0 / (theta ** (
+        jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    return (extrapolated / scaling["factor"]) * ramp \
+        + extrapolated * (1.0 - ramp)
+
+
 def rope_cos_sin(head_dim: int, theta: float, offset, length: int, dtype,
                  scaling: Optional[dict] = None):
     """cos/sin tables of shape (length, head_dim) starting at ``offset`` —
@@ -127,8 +175,11 @@ def rope_cos_sin(head_dim: int, theta: float, offset, length: int, dtype,
     sequences at unrelated positions).
 
     ``scaling``: an HF ``rope_scaling`` dict with ``rope_type='llama3'``
-    rescales the inverse frequencies (Llama 3.1+ long-context models)."""
+    rescales the inverse frequencies (Llama 3.1+ long-context models);
+    ``'yarn'`` (:func:`yarn_scaling`) blends them per dim and multiplies
+    cos and sin by its ``attention_factor``."""
     inv_freq = 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
+    amplitude = 1.0
     if scaling:
         rope_type = (scaling.get("rope_type") or scaling.get("type")
                      or "default")
@@ -137,6 +188,9 @@ def rope_cos_sin(head_dim: int, theta: float, offset, length: int, dtype,
             # factor, equivalently inv_freq /= factor (Gemma-3 global
             # layers ship {'rope_type': 'linear', 'factor': 8.0}).
             inv_freq = inv_freq / float(scaling["factor"])
+        elif rope_type == "yarn":
+            inv_freq = _yarn_inv_freq(head_dim, theta, scaling)
+            amplitude = scaling["attention_factor"]
         else:
             inv_freq = _llama3_scale_inv_freq(inv_freq, scaling)
     steps = jnp.arange(length, dtype=jnp.float32)
@@ -152,7 +206,8 @@ def rope_cos_sin(head_dim: int, theta: float, offset, length: int, dtype,
         t = offset.astype(jnp.float32) + steps
     freqs = t[..., None] * inv_freq
     emb = jnp.concatenate([freqs, freqs], axis=-1)
-    return jnp.cos(emb).astype(dtype), jnp.sin(emb).astype(dtype)
+    return ((amplitude * jnp.cos(emb)).astype(dtype),
+            (amplitude * jnp.sin(emb)).astype(dtype))
 
 
 def _rotate_half(x):

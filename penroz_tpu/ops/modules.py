@@ -30,7 +30,7 @@ import copy
 import functools
 import logging
 import math
-from typing import Any, Optional, Sequence
+from typing import Any, NamedTuple, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -103,6 +103,9 @@ class Ctx:
         self.targets = targets
         self.exits = None
         self.exit_stats = None  # the model's, from ``exits``: for counters
+        # What the dropless expert layers routed in this call, summed over
+        # them (:data:`MOE_COUNTERS`); ``None`` in a model with none.
+        self.moe_stats = None
         self.layer_offset = 0
         self.buffer_updates = {}
         self.aux_losses = []  # auxiliary training losses (e.g. MoE balance)
@@ -879,6 +882,142 @@ def _gated_activation(name: str, x):
     return jax.nn.gelu(x, approximate=(name == "gelu_pytorch_tanh"))
 
 
+# What a dropless expert layer counts of a call, summed over the layers of a
+# model and the micro-steps of an epoch (``penroz/train_epoch`` counters,
+# ``/progress/`` rows, ``/metrics``): pairs routed to held experts, rows the
+# grouped products computed (the groups padded to whole tiles), the fullest
+# held expert's rows (the per-layer maxima summed), pairs that found no row.
+MOE_COUNTERS = ("moe_rows", "moe_rows_padded", "moe_load_max", "moe_dropped")
+
+
+@functools.lru_cache(maxsize=None)
+def _log_moe_plan(**plan) -> None:
+    """Once per distinct plan of the process."""
+    log.info("moe plan: %s", " ".join(f"{k}={v}" for k, v in plan.items()))
+
+
+def _record_moe_plan(**plan) -> None:
+    """As ``penroz/flash_plan``: an INFO line per distinct plan and, each
+    time a program traces a dropless layer, a ``penroz/moe_plan`` span under
+    whatever span is compiling."""
+    _log_moe_plan(**plan)
+    with tracing.span("penroz/moe_plan", **plan):
+        pass
+
+
+class _DroplessConfig(NamedTuple):
+    row_tile: int
+    rows: int           # rows a round is handed
+    held: int           # experts held: a tile of expert ``held`` is empty
+    activation: str
+    on_tpu: bool
+
+
+def _dropless_round(cfg, xs, weight, w_gate, w_up, w_down, tile_group):
+    """One round's rows through their experts, weighted: float32
+    ``(rows, d)``.  A padding row has weight 0."""
+    from penroz_tpu.ops.pallas import moe_gmm
+    product = functools.partial(moe_gmm.grouped_matmul, tile_group=tile_group,
+                                row_tile=cfg.row_tile, on_tpu=cfg.on_tpu)
+    hidden = (_gated_activation(cfg.activation, product(xs, w_gate))
+              * product(xs, w_up))
+    return product(hidden, w_down).astype(jnp.float32) * weight[:, None]
+
+
+def _round_slices(cfg, j, row_token, row_weight, tile_group):
+    """Round ``j``'s rows: the token each reads (0 for a padding row, which
+    has weight 0), its weight, its tiles' experts, and which of its rows are
+    real *and* lie in a tile the products compute."""
+    tiles = cfg.rows // cfg.row_tile
+    cut = jax.lax.dynamic_slice_in_dim
+    tok = cut(row_token, j * cfg.rows, cfg.rows)
+    groups = cut(tile_group, j * tiles, tiles)
+    live = (tok >= 0) & jnp.repeat(groups < cfg.held, cfg.row_tile)
+    return (jnp.maximum(tok, 0), cut(row_weight, j * cfg.rows, cfg.rows),
+            groups, live)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _dropless_rows(cfg, x, row_weight, w_gate, w_up, w_down, row_token,
+                   tile_group, rounds):
+    """``(y, placed)``: ``y[n] = Σ_{rows r of token n} weight[r] ·
+    expert_{g(r)}(x[n])`` over the first ``rounds`` rounds of the sorted rows
+    (a traced count: the loop runs as long as there are rows, not as long as
+    the bound), and how many real rows (``row_token`` ≥ 0) those rounds
+    handed to a live tile of the products, counted as the rounds run.
+
+    Its derivative is taken a round at a time too, each round recomputed
+    from ``x``, the indices and the weights: nothing of a round outlives it
+    in either direction, so what the layer keeps for the backward is what
+    it was given."""
+    def one(j, carry):
+        y, placed = carry
+        tok, weight, groups, live = _round_slices(cfg, j, row_token,
+                                                  row_weight, tile_group)
+        y = y.at[tok].add(_dropless_round(
+            cfg, x[tok], weight, w_gate, w_up, w_down, groups))
+        return y, placed + jnp.sum(live, dtype=jnp.int32)
+
+    y, placed = jax.lax.fori_loop(
+        0, rounds, one, (jnp.zeros(x.shape, jnp.float32), jnp.int32(0)))
+    return y.astype(x.dtype), placed.astype(jnp.float32)
+
+
+def _dropless_rows_fwd(cfg, x, row_weight, w_gate, w_up, w_down, row_token,
+                       tile_group, rounds):
+    kept = (x, row_weight, w_gate, w_up, w_down, row_token, tile_group,
+            rounds)
+    return _dropless_rows(cfg, *kept), kept
+
+
+def _dropless_rows_bwd(cfg, kept, cotangents):
+    x, row_weight, w_gate, w_up, w_down, row_token, tile_group, rounds = kept
+    dy, _ = cotangents                   # the count has no derivative
+    stacks = (w_gate, w_up, w_down)
+
+    def round_grads(j, dx, dweight):
+        """Round ``j`` recomputed and pulled back: its rows' gradients added
+        into ``dx`` and ``dweight``, and the stacks' gradients it made."""
+        tok, weight, groups, _ = _round_slices(cfg, j, row_token, row_weight,
+                                               tile_group)
+        _, pull = jax.vjp(
+            lambda *args: _dropless_round(cfg, *args, groups),
+            x[tok], weight, *stacks)
+        dxs, dw, *ds = pull(dy[tok].astype(jnp.float32))
+        return (dx.at[tok].add(dxs.astype(jnp.float32)),
+                jax.lax.dynamic_update_slice_in_dim(
+                    dweight, dw, j * cfg.rows, 0), ds)
+
+    zeros = (jnp.zeros(x.shape, jnp.float32), jnp.zeros_like(row_weight))
+
+    def one_round():
+        # the usual case (the buffer is a row a token): the stacks'
+        # gradients leave as the products made them
+        dx, dweight, ds = round_grads(0, *zeros)
+        return dx, dweight, tuple(ds)
+
+    def many_rounds():
+        # summed over the rounds in float32, as one product over all the
+        # rows would accumulate them, and cast once
+        def one(j, grads):
+            dx, dweight, ds = round_grads(j, *grads[:2])
+            return dx, dweight, tuple(a + g.astype(jnp.float32)
+                                      for a, g in zip(grads[2], ds))
+
+        sums = tuple(jnp.zeros(w.shape, jnp.float32) for w in stacks)
+        dx, dweight, sums = jax.lax.fori_loop(0, rounds, one, (*zeros, sums))
+        return dx, dweight, tuple(g.astype(w.dtype)
+                                  for g, w in zip(sums, stacks))
+
+    # no round at all (nothing routed here) takes the first branch too: its
+    # one round finds padding rows and empty tiles only
+    dx, dweight, dstacks = jax.lax.cond(rounds <= 1, one_round, many_rounds)
+    return (dx.astype(x.dtype), dweight, *dstacks, None, None, None)
+
+
+_dropless_rows.defvjp(_dropless_rows_fwd, _dropless_rows_bwd)
+
+
 class MixtureOfExperts(Module):
     """Top-k routed mixture of gated-MLP experts (Mixtral/Switch style).
 
@@ -918,14 +1057,36 @@ class MixtureOfExperts(Module):
                  num_experts: int, top_k: int = 2, bias: bool = False,
                  activation: str = "silu", aux_loss_coef: float = 0.0,
                  dispatch: str = "dense", capacity_factor: float = 1.25,
-                 norm_topk: bool = True, shared_expert_size: int = 0):
+                 norm_topk: bool = True, shared_expert_size: int = 0,
+                 experts_held: Optional[int] = None, first_expert: int = 0,
+                 routed_scale: float = 1.0, shared_expert_gate: bool = True):
         if top_k < 1 or top_k > num_experts:
             raise ValueError(f"top_k={top_k} outside [1, {num_experts}]")
         if bias:
             raise ValueError("MixtureOfExperts does not support bias yet")
-        if dispatch not in ("dense", "capacity"):
-            raise ValueError(f"dispatch must be 'dense' or 'capacity', "
-                             f"got {dispatch!r}")
+        if dispatch not in ("dense", "capacity", "dropless"):
+            raise ValueError(f"dispatch must be 'dense', 'capacity' or "
+                             f"'dropless', got {dispatch!r}")
+        # One rank's share of an expert-parallel layer: the router scores
+        # all ``num_experts``, this module holds (and computes) experts
+        # ``first_expert .. first_expert + experts_held - 1`` only; what the
+        # others would have added is left out (the partial sum an
+        # expert-parallel rank holds before the exchange).
+        held = num_experts if experts_held is None else int(experts_held)
+        if not (1 <= held <= num_experts
+                and 0 <= int(first_expert) <= num_experts - held):
+            raise ValueError(
+                f"experts_held={experts_held} from first_expert="
+                f"{first_expert} does not lie within {num_experts} experts")
+        if dispatch == "capacity" and held != num_experts:
+            raise ValueError("dispatch 'capacity' holds every expert; a "
+                             "share takes 'dense' or 'dropless'")
+        self.experts_held, self.first_expert = held, int(first_expert)
+        # DeepSeek-style ``routed_scaling_factor``: the (renormalised)
+        # top-k weights times a constant.
+        self.routed_scale = float(routed_scale)
+        # ``False``: the shared expert is added as it is (no sigmoid gate).
+        self.shared_expert_gate = bool(shared_expert_gate)
         if float(capacity_factor) <= 0.0:
             raise ValueError(f"capacity_factor must be > 0, "
                              f"got {capacity_factor}")
@@ -952,11 +1113,12 @@ class MixtureOfExperts(Module):
 
     def param_shapes(self):
         d, h, e = self.in_features, self.intermediate_size, self.num_experts
+        held = self.experts_held
         shapes = {
             "router.weight": (e, d),
-            "experts.gate_proj.weight": (e, h, d),
-            "experts.up_proj.weight": (e, h, d),
-            "experts.down_proj.weight": (e, d, h),
+            "experts.gate_proj.weight": (held, h, d),
+            "experts.up_proj.weight": (held, h, d),
+            "experts.down_proj.weight": (held, d, h),
         }
         if self.shared_expert_size:
             hs = self.shared_expert_size
@@ -964,8 +1126,9 @@ class MixtureOfExperts(Module):
                 "shared_expert.gate_proj.weight": (hs, d),
                 "shared_expert.up_proj.weight": (hs, d),
                 "shared_expert.down_proj.weight": (d, hs),
-                "shared_expert_gate.weight": (1, d),
             })
+            if self.shared_expert_gate:
+                shapes["shared_expert_gate.weight"] = (1, d)
         return shapes
 
     def init(self, rng):
@@ -987,7 +1150,19 @@ class MixtureOfExperts(Module):
                 jnp.zeros((self.num_experts,), jnp.float32)}
 
     def router_weights(self, x, ctx):
-        """(B, T, E) combine weights: softmax → top-k → renormalize.
+        """(B, T, held) combine weights of the experts held: softmax over
+        all → top-k → renormalize → scale."""
+        top_vals, top_idx = self.route(x, ctx)
+        one_hot = jax.nn.one_hot(top_idx, self.num_experts,
+                                 dtype=jnp.float32)  # (B, T, k, E)
+        weights = jnp.einsum("btk,btke->bte", top_vals, one_hot)
+        first = self.first_expert
+        return weights[..., first:first + self.experts_held]
+
+    def route(self, x, ctx):
+        """``(weights, experts)``, both (B, T, top_k): softmax over all
+        ``num_experts`` → top-k → renormalize (``norm_topk``) → times
+        ``routed_scale``.
 
         Routing runs entirely in fp32 — logits einsum included: bf16
         rounding before the (monotonic) softmax still flips expert choices
@@ -999,9 +1174,9 @@ class MixtureOfExperts(Module):
         top_vals, top_idx = jax.lax.top_k(probs, self.top_k)
         if self.norm_topk:
             top_vals = top_vals / jnp.sum(top_vals, axis=-1, keepdims=True)
-        one_hot = jax.nn.one_hot(top_idx, self.num_experts,
-                                 dtype=jnp.float32)  # (B, T, k, E)
         if ctx.training:
+            one_hot = jax.nn.one_hot(top_idx, self.num_experts,
+                                     dtype=jnp.float32)  # (B, T, k, E)
             # f_e: fraction of routing slots assigned to expert e;
             # P_e: mean router probability.  Switch aux = E · Σ f_e P_e is
             # minimized (=1) by uniform routing.
@@ -1013,12 +1188,18 @@ class MixtureOfExperts(Module):
                 aux = self.num_experts * jnp.sum(
                     (fractions / self.top_k) * mean_probs)
                 ctx.aux_losses.append(self.aux_loss_coef * aux)
-        return jnp.einsum("btk,btke->bte", top_vals, one_hot)
+        if self.routed_scale != 1.0:
+            top_vals = top_vals * self.routed_scale
+        return top_vals, top_idx
 
     def apply(self, x, ctx):
         w_gate = self._p(ctx, "experts.gate_proj.weight")
         w_up = self._p(ctx, "experts.up_proj.weight")
         w_down = self._p(ctx, "experts.down_proj.weight")
+        if self.dispatch == "dropless":
+            routed = self._apply_dropless(x, *self.route(x, ctx), w_gate,
+                                          w_up, w_down, ctx)
+            return routed + self._shared(x, ctx)
         weights = self.router_weights(x, ctx).astype(x.dtype)
         if self.dispatch == "capacity":
             from penroz_tpu.parallel.mesh import EXPERT_AXIS
@@ -1038,22 +1219,113 @@ class MixtureOfExperts(Module):
             hidden = self._act(g) * u
             y = jnp.einsum("bteh,edh->bted", hidden, w_down)
             routed = jnp.einsum("bted,bte->btd", y, weights)
-        if self.shared_expert_size:
-            # Always-on shared expert (Qwen2-MoE): ordinary gated MLP
-            # scaled by a per-token sigmoid gate, summed with the routed
-            # output.
-            sg = jnp.einsum("btd,hd->bth", x,
-                            self._p(ctx, "shared_expert.gate_proj.weight"))
-            su = jnp.einsum("btd,hd->bth", x,
-                            self._p(ctx, "shared_expert.up_proj.weight"))
-            shared = jnp.einsum(
-                "bth,dh->btd", self._act(sg) * su,
-                self._p(ctx, "shared_expert.down_proj.weight"))
-            gate = jax.nn.sigmoid(jnp.einsum(
-                "btd,od->bto", x,
-                self._p(ctx, "shared_expert_gate.weight")))
-            routed = routed + gate * shared
-        return routed
+        return routed + self._shared(x, ctx)
+
+    def _shared(self, x, ctx):
+        """The always-on shared expert (Qwen2-MoE): an ordinary gated MLP,
+        scaled by a per-token sigmoid gate unless ``shared_expert_gate`` is
+        off; summed with the routed output.  0 without one."""
+        if not self.shared_expert_size:
+            return jnp.zeros((), x.dtype)
+        sg = jnp.einsum("btd,hd->bth", x,
+                        self._p(ctx, "shared_expert.gate_proj.weight"))
+        su = jnp.einsum("btd,hd->bth", x,
+                        self._p(ctx, "shared_expert.up_proj.weight"))
+        shared = jnp.einsum(
+            "bth,dh->btd", self._act(sg) * su,
+            self._p(ctx, "shared_expert.down_proj.weight"))
+        if not self.shared_expert_gate:
+            return shared
+        gate = jax.nn.sigmoid(jnp.einsum(
+            "btd,od->bto", x, self._p(ctx, "shared_expert_gate.weight")))
+        return gate * shared
+
+    # -- dropless: every routed pair computed, by grouped products ----------
+
+    # Rows to a tile of the grouped products (``ops/pallas/moe_gmm.py``): a
+    # tile belongs to one expert, so each expert's group is padded to it.
+    ROW_TILE = 128
+
+    def dropless_plan(self, tokens: int) -> dict:
+        """The static sizes of the dropless path for ``tokens`` tokens.
+
+        The (token, choice) pairs whose expert is held are laid out sorted
+        by expert, each expert's group padded to ``ROW_TILE`` rows.  The one
+        length that can never overflow, ``rows_bound`` (every token choosing
+        as many held experts as it can, every group a tile short of full),
+        is the length of two index vectors only.  The activations live in a
+        buffer of ``rows`` rows, a row a token (in whole tiles, at most the
+        bound): the layout is walked ``rows`` at a time for as many rounds
+        as the rows really routed need, at most ``rounds_bound``; a layer
+        whose tokens meet one held expert each on average takes one."""
+        tile = self.ROW_TILE
+        bound = (tokens * min(self.top_k, self.experts_held)
+                 + self.experts_held * (tile - 1))
+        bound = -(-bound // tile) * tile
+        rows = min(-(-tokens // tile) * tile, bound)
+        rounds = -(-bound // rows)
+        return {"experts": self.num_experts, "held": self.experts_held,
+                "first": self.first_expert, "top_k": self.top_k,
+                "rows": rows, "row_tile": tile, "dispatch": self.dispatch,
+                "rows_bound": rounds * rows, "rounds_bound": rounds}
+
+    def _apply_dropless(self, x, top_vals, top_idx, w_gate, w_up, w_down,
+                        ctx):
+        """Σ over a token's chosen experts *that are held* of weight ·
+        expert(x), no pair lost whatever the imbalance.
+
+        A counting sort gives every such pair its row in the layout of
+        :meth:`dropless_plan` (its expert's offset + how many pairs of that
+        expert precede it), the rows' tokens and weights are scattered into
+        two vectors of the bound's length (a row no pair took reads token
+        -1, weight 0), and :func:`_dropless_rows` walks the rows really
+        routed a round at a time: gather the tokens' activations, three
+        grouped products (``ops/pallas/moe_gmm.py``), scatter-add back with
+        the weights.  Time and memory follow the rows routed; the bound
+        costs two vectors.  ``moe_dropped`` is the pairs the router sent to
+        held experts less the real rows the rounds handed to the products,
+        counted as they ran."""
+        B, T, d = x.shape
+        tokens, k, held = B * T, self.top_k, self.experts_held
+        plan = self.dropless_plan(tokens)
+        _record_moe_plan(**plan)
+        tile, rows, bound = plan["row_tile"], plan["rows"], plan["rows_bound"]
+        local = top_idx.reshape(tokens * k) - self.first_expert
+        valid = (local >= 0) & (local < held)
+        group = jnp.clip(local, 0, held - 1)
+        member = valid[:, None] & (
+            group[:, None] == jnp.arange(held, dtype=local.dtype))
+        seen = jnp.cumsum(member.astype(jnp.int32), axis=0)  # (pairs, held)
+        sizes = seen[-1]
+        rank = jnp.take_along_axis(seen, group[:, None], axis=1)[:, 0] - 1
+        ends = jnp.cumsum(-(-sizes // tile) * tile)
+        starts = ends - (-(-sizes // tile) * tile)
+        dest = jnp.where(valid, starts[group] + rank, bound)  # bound: nowhere
+        pair_token = jnp.arange(tokens * k, dtype=jnp.int32) // k
+        row_token = jnp.full((bound,), -1, jnp.int32).at[dest].set(
+            pair_token, mode="drop")
+        row_weight = jnp.zeros((bound,), jnp.float32).at[dest].set(
+            top_vals.reshape(tokens * k).astype(jnp.float32), mode="drop")
+        tile_start = jnp.arange(bound // tile, dtype=jnp.int32) * tile
+        # the expert a tile belongs to; ``held`` past the last real row
+        tile_group = jnp.searchsorted(ends, tile_start, side="right").astype(
+            jnp.int32)
+        routed_rows, padded_rows = jnp.sum(valid), ends[-1]
+        rounds = -(-padded_rows // rows)
+        cfg = _DroplessConfig(
+            row_tile=tile, rows=rows, held=held, activation=self.activation,
+            on_tpu=attn_ops._tpu_platform(x, ctx.platform))
+        y, placed = _dropless_rows(
+            cfg, x.reshape(tokens, d), row_weight, w_gate, w_up, w_down,
+            row_token, tile_group, rounds)
+        stats = {"moe_rows": routed_rows, "moe_rows_padded": padded_rows,
+                 "moe_load_max": jnp.max(sizes),
+                 "moe_dropped": routed_rows - placed}
+        ctx.moe_stats = {
+            name: jax.lax.stop_gradient(value.astype(jnp.float32))
+            + (ctx.moe_stats or {}).get(name, 0.0)
+            for name, value in stats.items()}
+        return y.reshape(B, T, d)
 
     # Tokens per dispatch group.  One-hot dispatch costs
     # O(group_size · E · C) with C ∝ group_size/E, i.e. quadratic in the
@@ -1222,7 +1494,19 @@ class CausalSelfAttention(Module):
                  qk_norm: bool = False, qk_norm_eps: float = 1e-6,
                  qk_norm_scope: str = "head", rope_dim=None,
                  qk_norm_fp32_weight: bool = False, alibi: bool = False,
-                 logit_softcap=None, attn_scale=None):
+                 logit_softcap=None, attn_scale=None,
+                 gate: Optional[str] = None):
+        # ``gate="per_head"`` (headwise gating of the attention output,
+        # arXiv:2505.06708): the fused projection carries ``num_heads``
+        # more columns after [q | k | v], one gate logit a head, from the
+        # same normed input (the last ``num_heads`` rows of that linear's
+        # weight are W_g); the attention output of head h is multiplied by
+        # sigmoid(logit_h) over its head_dim lanes, before the output
+        # projection.
+        if gate not in (None, "per_head"):
+            raise ValueError(f"gate must be 'per_head' or absent, "
+                             f"got {gate!r}")
+        self.gate = gate
         if sliding_window is not None and int(sliding_window) < 1:
             raise ValueError(f"sliding_window must be >= 1, "
                              f"got {sliding_window}")
@@ -1287,20 +1571,23 @@ class CausalSelfAttention(Module):
         # the DSL reaches this module directly, so the HF importer's guard
         # alone would let a yarn dict silently run the llama3 formula or a
         # missing key crash opaquely at first jit trace.
-        if rope_scaling and (rope_scaling.get("rope_type")
-                             or rope_scaling.get("type")) == "linear":
+        rope_type = rope_scaling and (rope_scaling.get("rope_type")
+                                      or rope_scaling.get("type")
+                                      or "default")
+        if rope_type == "linear":
             # HF linear scaling: positions divide by the factor (Gemma-3
             # global layers); no band parameters to validate.
             if float(rope_scaling.get("factor", 0.0)) < 1.0:
                 raise ValueError("linear rope_scaling needs factor >= 1")
             self.rope_scaling = {"rope_type": "linear",
                                  "factor": float(rope_scaling["factor"])}
+        elif rope_type == "yarn":
+            self.rope_scaling = attn_ops.yarn_scaling(rope_scaling)
         elif rope_scaling:
-            rope_type = (rope_scaling.get("rope_type")
-                         or rope_scaling.get("type") or "default")
             if rope_type != "llama3":
                 raise ValueError(f"rope_scaling type {rope_type!r} is not "
-                                 "supported (only 'llama3' and 'linear')")
+                                 "supported (only 'llama3', 'linear' and "
+                                 "'yarn')")
             missing = [k for k in ("factor",
                                    "original_max_position_embeddings")
                        if k not in rope_scaling]
@@ -1410,7 +1697,17 @@ class CausalSelfAttention(Module):
             alibi=attn_ops.alibi_slopes(heads) if self.alibi else None,
             scale=self.attn_scale)
 
-    def apply(self, qkv, ctx):
+    def apply(self, x, ctx):
+        if self.gate is None:
+            return self._attend(x, ctx)
+        B, T, _ = x.shape
+        heads = self.num_heads
+        out = self._attend(x[..., :-heads], ctx)
+        gate = jax.nn.sigmoid(x[..., -heads:].astype(jnp.float32))
+        out = out.reshape(B, T, heads, -1) * gate.astype(out.dtype)[..., None]
+        return out.reshape(B, T, -1)
+
+    def _attend(self, qkv, ctx):
         B, T, total_dim = qkv.shape
         head_dim = total_dim // (self.num_heads + 2 * self.num_kv_heads)
         q_dim = self.num_heads * head_dim

@@ -40,7 +40,9 @@ gauges     engines, active_rows, queue_depth, batch_occupancy,
            jit_programs{function} (live compiled-program count per jit
            family — the ragged descriptor compile-churn guard),
            train_pass_loss{pass}, train_exit_mass{pass} (a looped model's
-           exits, newest /train/ epoch; utils/tracing.py)
+           exits, newest /train/ epoch; utils/tracing.py),
+           train_moe{counter} (the dropless expert layers' routing
+           counters, newest /train/ epoch; utils/tracing.py)
 histograms ttft_ms, itl_ms, queue_wait_ms, chunk_stall_ms, tick_ms
            (fixed LATENCY_BUCKETS_MS buckets; cumulative ``_bucket``
            series sum to ``_count`` — asserted by the strict-format
@@ -261,6 +263,7 @@ SESSION_RESUME_TTFT_MS = REGISTRY.register(m.Histogram(
 TRAIN_SPAN_MS = REGISTRY.register(tracing.TRAIN_SPAN_MS)
 TRAIN_PASS_LOSS = REGISTRY.register(tracing.TRAIN_PASS_LOSS)
 TRAIN_EXIT_MASS = REGISTRY.register(tracing.TRAIN_EXIT_MASS)
+TRAIN_MOE = REGISTRY.register(tracing.TRAIN_MOE)
 
 # -- gauges (scrape-time reads of live state) -------------------------------
 

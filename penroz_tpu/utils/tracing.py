@@ -166,6 +166,28 @@ def exit_counters(exits: dict) -> dict:
             for t, v in enumerate(values)}
 
 
+# The newest epoch's routing counters of a model with dropless expert layers
+# (``ops/modules.py::MOE_COUNTERS``): counters of ``penroz/train_epoch`` under
+# their own names, and ``penroz_train_moe{counter}`` on GET /metrics.
+_TRAIN_ROUTING: dict = {}
+TRAIN_MOE = metrics.Gauge(
+    "penroz_train_moe",
+    "Routing counters of the dropless expert layers, newest /train/ epoch, "
+    "summed over layers and micro-steps: moe_rows (pairs routed to held "
+    "experts), moe_rows_padded (rows the grouped products computed), "
+    "moe_load_max (the fullest held expert's rows), moe_dropped",
+    fn=lambda: _TRAIN_ROUTING, labelnames=("counter",))
+
+
+def routing_counters(routed: dict) -> dict:
+    """One epoch's routing counters as span counters (as they are); the
+    same values become the gauge's newest reading."""
+    if routed:
+        _TRAIN_ROUTING.clear()
+        _TRAIN_ROUTING.update(routed)
+    return routed
+
+
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 _request_id_var: contextvars.ContextVar = contextvars.ContextVar(

@@ -1864,3 +1864,130 @@ def test_attention_module_stays_in_model_layout_under_a_data_mesh(
                                rtol=1e-5)
     np.testing.assert_allclose(np.asarray(got[1]), np.asarray(want[1]),
                                atol=2e-5)
+
+
+# ---------------------------------------------------------------------------
+# YaRN frequencies, the per-head output gate, heads held as a share
+# ---------------------------------------------------------------------------
+
+LAGUNA_FULL = {"rope_type": "yarn", "factor": 128, "beta_fast": 32,
+               "beta_slow": 1, "original_max_position_embeddings": 8192,
+               "attention_factor": 1.4852030263919618}
+
+
+def test_yarn_cos_sin_match_the_published_formula_for_lagunas_parameters():
+    """``rope_cos_sin`` with ``rope_type: yarn`` against the formula written
+    out here (Peng et al. 2023 as published implementations compute it) for
+    Laguna-S-2.1's full-attention layers: 64 rotated dims of a 128-wide head
+    (``partial_rotary_factor`` 0.5), θ 500 000, factor 128 over 8192, ramp
+    between the dims that make 32 and 1 turns, cos and sin × 1.4852."""
+    import math
+    from penroz_tpu.ops import attention as attn_ops
+    dim, theta, length = 64, 500000.0, 40
+    s = LAGUNA_FULL
+    pair_of = lambda turns: dim * math.log(
+        s["original_max_position_embeddings"] / (turns * 2 * math.pi)) \
+        / (2 * math.log(theta))
+    low = max(math.floor(pair_of(s["beta_fast"])), 0)
+    high = min(math.ceil(pair_of(s["beta_slow"])), dim - 1)
+    inv = []
+    for i in range(dim // 2):
+        plain = theta ** (-2.0 * i / dim)
+        ramp = min(1.0, max(0.0, (i - low) / (high - low)))
+        inv.append(plain / s["factor"] * ramp + plain * (1.0 - ramp))
+    assert 0 < low < high < dim // 2    # all three regimes among the 32 pairs
+    assert inv[0] == 1.0 and inv[-1] == pytest.approx(
+        theta ** (-62 / 64) / 128)
+    positions = np.arange(7, 7 + length)[:, None] * np.asarray(inv)[None, :]
+    want_cos = s["attention_factor"] * np.cos(
+        np.concatenate([positions, positions], -1))
+    want_sin = s["attention_factor"] * np.sin(
+        np.concatenate([positions, positions], -1))
+    cos, sin = attn_ops.rope_cos_sin(dim, theta, 7, length, jnp.float32,
+                                     scaling=attn_ops.yarn_scaling(s))
+    np.testing.assert_allclose(cos, want_cos, atol=2e-5)
+    np.testing.assert_allclose(sin, want_sin, atol=2e-5)
+    # the default attention factor is the published 0.1 ln(factor) + 1
+    bare = {k: v for k, v in s.items() if k != "attention_factor"}
+    assert attn_ops.yarn_scaling(bare)["attention_factor"] == pytest.approx(
+        s["attention_factor"], rel=1e-12)
+    # and the benchmark's reference computes the same frequencies
+    from benchmark.reference import laguna
+    np.testing.assert_allclose(
+        laguna.yarn_inv_freq(dim, theta, 128.0, 8192.0, 32.0, 1.0), inv,
+        rtol=1e-6)
+
+
+def _attention_block(heads, kv_heads, head_dim, **kw):
+    from penroz_tpu.ops.modules import CausalSelfAttention, Ctx
+    mod = CausalSelfAttention(num_heads=heads, num_kv_heads=kv_heads,
+                              head_dim=head_dim, **kw)
+    return lambda fused: mod.apply(fused, Ctx({}))
+
+
+def test_per_head_gate_scales_each_heads_output_by_its_sigmoid():
+    heads, kv, hd, T = 4, 2, 8, 12
+    rng = np.random.default_rng(0)
+    qkv = jnp.asarray(rng.normal(size=(2, T, (heads + 2 * kv) * hd)),
+                      jnp.float32)
+    logits = jnp.asarray(rng.normal(size=(2, T, heads)), jnp.float32)
+    plain = _attention_block(heads, kv, hd, rope_theta=1e4)(qkv)
+    gated = _attention_block(heads, kv, hd, rope_theta=1e4,
+                             gate="per_head")(
+        jnp.concatenate([qkv, logits], -1))
+    want = (plain.reshape(2, T, heads, hd)
+            * jax.nn.sigmoid(logits)[..., None]).reshape(2, T, heads * hd)
+    np.testing.assert_allclose(gated, want, atol=1e-6)
+    with pytest.raises(ValueError, match="gate"):
+        _attention_block(heads, kv, hd, gate="per_token")
+
+
+@pytest.mark.parametrize("kind", ["full_attention", "sliding_attention"])
+def test_head_shares_add_up_to_the_uncut_reference_attention_block(kind):
+    """Four ranks, each holding one K/V head with its three query heads
+    (the fused projection's columns, gate logits included, and the output
+    projection's rows cut to them): the program's attention block on each
+    share, summed, is the uncut attention block of
+    ``benchmark/reference/laguna.py`` (12 query heads on 4 K/V heads; YaRN
+    on half a head in the full layer, a window in the sliding one)."""
+    from benchmark.reference import laguna
+    d, hd, kv, group, T, window = 24, 16, 4, 3, 20, 6
+    heads = kv * group
+    rope = {"full_attention": {"rope_theta": 500000.0,
+                               "partial_rotary_factor": 0.5, **LAGUNA_FULL},
+            "sliding_attention": {"rope_theta": 10000.0, "rope_type":
+                                  "default", "partial_rotary_factor": 1}}
+    keys = jax.random.split(jax.random.key(2), 3)
+    fused_w = 0.3 * jax.random.normal(keys[0],
+                                      (d, (heads + 2 * kv) * hd + heads))
+    o_w = 0.3 * jax.random.normal(keys[1], (heads * hd, d))
+    a = jax.random.normal(keys[2], (2, T, d))
+    hyper = {"head_dim": hd, "kv_heads": kv, "eps": 1e-6, "window": window,
+             "rope": tuple((k, tuple(sorted(v.items())))
+                           for k, v in rope.items())}
+    with jax.default_matmul_precision("highest"):
+        # the reference's layer with the norms' gains at 1 on an input
+        # already normed, less its residual and its MLP: run it for the
+        # attention branch alone by handing it a zero dense MLP
+        ones, zero = jnp.ones((d,)), jnp.zeros((d, 4))
+        layer = {"n1": ones, "n2": ones, "qkvg_w": fused_w, "o_w": o_w,
+                 "gate_proj": zero, "up_proj": zero, "down_proj": zero.T}
+        normed = a * jax.lax.rsqrt(jnp.mean(a * a, -1, keepdims=True) + 1e-6)
+        want = laguna._layer(layer, a, heads=heads, kind=kind, hyper=hyper,
+                             mm=jnp.matmul) - a
+        spec = rope[kind]
+        args = {"rope_theta": spec["rope_theta"], "gate": "per_head"}
+        if kind == "full_attention":
+            args.update(rope_pct=0.5, rope_scaling=LAGUNA_FULL)
+        else:
+            args.update(sliding_window=window)
+        q0, k0, v0, g0 = 0, heads * hd, (heads + kv) * hd, (heads + 2 * kv) * hd
+        total = 0.0
+        for rank in range(kv):
+            cols = np.r_[q0 + rank * group * hd:q0 + (rank + 1) * group * hd,
+                         k0 + rank * hd:k0 + (rank + 1) * hd,
+                         v0 + rank * hd:v0 + (rank + 1) * hd,
+                         g0 + rank * group:g0 + (rank + 1) * group]
+            out = _attention_block(group, 1, hd, **args)(normed @ fused_w[:, cols])
+            total = total + out @ o_w[rank * group * hd:(rank + 1) * group * hd]
+    np.testing.assert_allclose(total, want, atol=2e-5)

@@ -235,3 +235,49 @@ def test_next_batch_says_where_its_time_went(workdir, monkeypatch, native):
     assert all(b[0] > a[0] and b[1] > a[1]
                for a, b in zip([(0.0, 0.0)] + seen, seen))
     assert loader.scan_seconds + loader.gather_seconds <= wall
+
+
+@pytest.mark.parametrize("native", [True, False], ids=["native", "numpy"])
+def test_a_look_the_file_system_does_not_answer_holds_one_batch(
+        workdir, monkeypatch, native):
+    """A look at the directory that hangs (a checkpoint's flush beside it)
+    costs the batch that made it ``SCAN_WAIT_SECONDS`` and later batches
+    nothing: they go on over the shards last seen, in order, and the batch
+    after the look ends reads what it found."""
+    import threading
+    import time
+    from penroz_tpu.data.loaders import Loader, _native_loader_module
+    if native and _native_loader_module() is None:
+        pytest.skip("native loader unavailable")
+    if not native:
+        monkeypatch.setenv("PENROZ_NATIVE_LOADER", "0")
+    monkeypatch.setattr(loaders, "SCAN_WAIT_SECONDS", 0.05)
+    dataset = _make_shards(workdir, [64])
+    loader = Loader(dataset, buffer_size=16)
+    first, _ = loader.next_batch()
+    answered, files = threading.Event(), loader._files
+
+    def hung():
+        assert answered.wait(30)
+        return files()
+
+    monkeypatch.setattr(loader, "_files", hung)
+    _make_shards(workdir, [64, 64], dataset=dataset)    # adds shard 1
+    t0 = time.perf_counter()
+    held, _ = loader.next_batch()
+    t1 = time.perf_counter()
+    later = [loader.next_batch()[0] for _ in range(5)]
+    t2 = time.perf_counter()
+    assert 0.05 <= t1 - t0 < 5
+    assert t2 - t1 < 0.2          # five waits would be 0.25
+    # the known shard, wrapping: 16..31, then 32..63, 0..15, ...
+    np.testing.assert_array_equal(first, np.arange(16))
+    np.testing.assert_array_equal(held, np.arange(16, 32))
+    np.testing.assert_array_equal(later[2], np.arange(16))
+    answered.set()
+    loader._looking.result(30)
+    monkeypatch.setattr(loader, "_files", files)
+    loader.shard, loader.idx = 0, 56
+    crossing, _ = loader.next_batch()       # runs on into the new shard
+    np.testing.assert_array_equal(crossing, np.arange(56, 72))
+    assert loader._looking is None

@@ -417,3 +417,266 @@ def test_moe_capacity_ep_alltoall_composes_with_sp(cpu_devices):
     out = jax.jit(lambda p, xb: mod.apply(xb, M.Ctx(p, ep_mesh=mesh)))(
         sharded, xs)
     np.testing.assert_allclose(np.asarray(out), expected, atol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# dropless dispatch, and a layer held as one rank's share
+# ---------------------------------------------------------------------------
+
+def _skewed(dispatch, **kw):
+    """A layer whose router sends most rows to expert 2 and none to expert
+    5 (the input's first feature is positive; the router leans on it)."""
+    args = dict(in_features=16, intermediate_size=24, num_experts=8, top_k=3,
+                shared_expert_size=8, shared_expert_gate=False,
+                routed_scale=2.5)
+    mod = M.MixtureOfExperts(dispatch=dispatch, **{**args, **kw})
+    mod.bind("moe")
+    params = mod.init(jax.random.key(0))
+    router = 0.3 * np.array(params["moe.router.weight"])
+    router[:, 0] = 0.0
+    router[2, 0], router[5, 0] = 4.0, -60.0
+    params["moe.router.weight"] = jnp.asarray(router)
+    x = np.random.default_rng(0).normal(size=(2, 12, 16))
+    x[..., 0] = np.abs(x[..., 0]) + 1.0
+    return mod, params, jnp.asarray(x, jnp.float32)
+
+
+def _loop_over_experts(mod, params, x):
+    """Plain loop: every held expert on every token, weighted by what the
+    router gave it; the ungated shared expert added."""
+    p = lambda name: params[mod.key(name)]
+    silu = lambda t: t * jax.nn.sigmoid(t)
+    probs = jax.nn.softmax(x @ p("router.weight").T, axis=-1)
+    w, e = jax.lax.top_k(probs, mod.top_k)
+    w = mod.routed_scale * w / jnp.sum(w, -1, keepdims=True)
+    out = (silu(x @ p("shared_expert.gate_proj.weight").T)
+           * (x @ p("shared_expert.up_proj.weight").T)
+           ) @ p("shared_expert.down_proj.weight").T
+    for j in range(mod.experts_held):
+        share = jnp.sum(jnp.where(e == mod.first_expert + j, w, 0.0), -1)
+        y = (silu(x @ p("experts.gate_proj.weight")[j].T)
+             * (x @ p("experts.up_proj.weight")[j].T)
+             ) @ p("experts.down_proj.weight")[j].T
+        out = out + share[..., None] * y
+    return out
+
+
+@pytest.fixture
+def tile_of_4(monkeypatch):
+    """Groups padded to 4 rows, so that a toy layer has whole tiles, ragged
+    groups and several rounds of its row buffer (a row a token)."""
+    monkeypatch.setattr(M.MixtureOfExperts, "ROW_TILE", 4)
+
+
+@pytest.mark.parametrize("rounds", ["one_round", "many_rounds", "no_round"])
+def test_moe_dropless_equals_dense_and_a_loop_under_a_skewed_router(
+        rounds, tile_of_4):
+    """Every expert held: the dropless dispatch (a counting sort, grouped
+    products over the rows each expert really got, scatter back) computes
+    what ``dense`` and a plain loop over the experts compute, forward and
+    gradient, with one expert taking most rows and one none, in the four
+    rounds of the row buffer that three choices a token need; as a share
+    of two experts (one of them the empty one) in one round; and as a
+    share of the empty expert alone, which takes none."""
+    share, sizes, took = {
+        "many_rounds": ({}, (24, 96, 4), 4),
+        "one_round": (dict(experts_held=2, first_expert=4), (24, 72, 3), 1),
+        "no_round": (dict(experts_held=1, first_expert=5), (24, 48, 2), 0),
+    }[rounds]
+    dense, params, x = _skewed("dense", **share)
+    dropless, _, _ = _skewed("dropless", **share)
+    plan = dropless.dropless_plan(24)
+    assert (plan["rows"], plan["rows_bound"], plan["rounds_bound"]) == sizes
+    ctx = M.Ctx(params)
+    dropless.apply(x, ctx)
+    assert -(-float(ctx.moe_stats["moe_rows_padded"]) // plan["rows"]) == took
+    weight = jnp.cos(jnp.arange(x.size, dtype=jnp.float32)).reshape(x.shape)
+
+    def loss(apply):
+        return lambda p, x: jnp.sum(apply(p, x) * weight)
+
+    by = {"dense": lambda p, x: dense.apply(x, M.Ctx(p)),
+          "dropless": lambda p, x: dropless.apply(x, M.Ctx(p)),
+          "loop": lambda p, x: _loop_over_experts(dense, p, x)}
+    got = {name: jax.jit(jax.value_and_grad(loss(fn), (0, 1)))(params, x)
+           for name, fn in by.items()}
+    routed = np.asarray(dense.route(x, M.Ctx(params))[1]).ravel()
+    loads = np.bincount(routed, minlength=8)
+    assert loads[2] == x.shape[0] * x.shape[1] and loads[5] == 0
+    for other in ("dense", "loop"):
+        value, (dparams, dx) = got[other]
+        assert float(got["dropless"][0]) == pytest.approx(float(value),
+                                                          rel=1e-5)
+        np.testing.assert_allclose(got["dropless"][1][1], dx, atol=2e-5)
+        for name, want in dparams.items():
+            np.testing.assert_allclose(got["dropless"][1][0][name], want,
+                                       atol=2e-5, err_msg=name)
+
+
+def test_moe_dropless_counts_what_the_router_chose_and_drops_nothing(
+        tile_of_4):
+    """``moe_rows`` is the count of (token, choice) pairs whose expert is
+    held, by the router's own choices; ``moe_rows_padded`` every group
+    padded to whole tiles; ``moe_load_max`` the fullest held expert;
+    ``moe_dropped`` 0 — also when every token goes to held experts only
+    (held = the router's whole width: no pair may be lost to the bound)."""
+    for held, first in ((8, 0), (3, 2)):
+        mod, params, x = _skewed("dropless", experts_held=held,
+                                 first_expert=first)
+        ctx = M.Ctx(params)
+        mod.apply(x, ctx)
+        chosen = np.asarray(mod.route(x, M.Ctx(params))[1]).ravel() - first
+        loads = np.bincount(chosen[(chosen >= 0) & (chosen < held)],
+                            minlength=held)
+        stats = {k: float(v) for k, v in ctx.moe_stats.items()}
+        assert set(stats) == set(M.MOE_COUNTERS)
+        assert stats["moe_rows"] == loads.sum() > 0
+        assert stats["moe_rows_padded"] == (-(-loads // 4) * 4).sum()
+        assert stats["moe_load_max"] == loads.max()
+        assert stats["moe_dropped"] == 0
+    assert loads.sum() < 3 * x.shape[0] * x.shape[1]   # a share: some absent
+
+
+def _sorted_rows(rounds_of: int, dtype, each: int = 64):
+    """A layout as ``_apply_dropless`` makes it, written out: two experts
+    of ``each`` rows in tiles of 8, every row real, cut into rounds of
+    ``rounds_of`` rows; inputs and weights positive, so that every row adds
+    to a weight's gradient with the same sign."""
+    experts, tile, tokens, d, h = 2, 8, 32, 16, 24
+    keys = jax.random.split(jax.random.key(3), 6)
+    x = (0.5 + jnp.abs(jax.random.normal(keys[0], (tokens, d)))).astype(dtype)
+    stacks = tuple(
+        (0.2 + 0.1 * jax.random.uniform(k, (experts, *shape))).astype(dtype)
+        for k, shape in zip(keys[1:4], ((h, d), (h, d), (d, h))))
+    row_token = jax.random.randint(keys[4], (experts * each,), 0, tokens)
+    row_weight = jax.random.uniform(keys[5], (experts * each,), jnp.float32,
+                                    0.5, 1.5)
+    tile_group = jnp.repeat(jnp.arange(experts, dtype=jnp.int32),
+                            each // tile)
+    cfg = M._DroplessConfig(row_tile=tile, rows=rounds_of, held=experts,
+                            activation="silu", on_tpu=False)
+    return cfg, x, row_weight, stacks, row_token, tile_group
+
+
+def test_moe_dropless_sums_weight_gradients_over_rounds_in_float32():
+    """Under bfloat16 compute an expert's weight gradient summed over the
+    64 rounds its 512 rows take is the gradient of one round over all the
+    rows to within the last cast: every row's products are the same in
+    both, the running sum over the rounds is float32 and is cast once
+    (summed in bfloat16, a running sum 64 times a round's share rounds
+    most of the share away: it reads 0.8 % off where this reads 0.1)."""
+    def grads(rounds_of):
+        cfg, x, w, stacks, tok, groups = _sorted_rows(
+            rounds_of, jnp.bfloat16, each=512)
+        rounds = jnp.int32(tok.size // rounds_of)
+        fn = lambda *s: jnp.sum(M._dropless_rows(
+            cfg, x, w, *s, tok, groups, rounds)[0].astype(jnp.float32))
+        return jax.jit(jax.grad(fn, (0, 1, 2)))(*stacks)
+
+    for one, many in zip(grads(1024), grads(8)):
+        assert many.dtype == jnp.bfloat16
+        one, many = one.astype(jnp.float32), many.astype(jnp.float32)
+        assert float(jnp.linalg.norm(many - one)
+                     / jnp.linalg.norm(one)) < 0.003
+
+
+def test_moe_dropped_counts_rows_the_rounds_really_handed_to_the_products():
+    """``_dropless_rows`` counts its second result as its rounds run: a
+    real row (token >= 0) in a tile the products compute.  A loop that
+    stops a round short, a real row under a tile marked empty, and a
+    padding row are each not counted, so ``moe_dropped`` (pairs routed less
+    rows placed) would read them."""
+    cfg, x, w, stacks, tok, groups = _sorted_rows(32, jnp.float32)
+    placed = lambda tok, groups, rounds: float(M._dropless_rows(
+        cfg, x, w, *stacks, tok, groups, jnp.int32(rounds))[1])
+    assert placed(tok, groups, 4) == 128
+    assert placed(tok, groups, 3) == 96                  # a round skipped
+    assert placed(tok, groups.at[-1].set(2), 4) == 120   # a tile skipped
+    assert placed(tok.at[:5].set(-1), groups, 4) == 123  # padding rows
+
+
+def test_moe_expert_shares_add_up_to_the_uncut_reference_block(tile_of_4):
+    """Four ranks of four experts each, the program's dropless layer told
+    its share (``experts_held``, ``first_expert``): their routed parts, with
+    the shared expert counted once, are the uncut block of
+    ``benchmark/reference/laguna.py`` (all 16 experts held)."""
+    from benchmark.reference import laguna
+    d, h, experts, ranks, k = 16, 24, 16, 4, 5
+    keys = jax.random.split(jax.random.key(1), 8)
+    normal = lambda i, *shape: 0.3 * jax.random.normal(keys[i], shape)
+    ref = {"router": normal(0, d, experts), "e_gate": normal(1, experts, d, h),
+           "e_up": normal(2, experts, d, h), "e_down": normal(3, experts, h, d),
+           "s_gate": normal(4, d, 8), "s_up": normal(5, d, 8),
+           "s_down": normal(6, 8, d)}
+    x = jax.random.normal(keys[7], (2, 9, d))
+    with jax.default_matmul_precision("highest"):
+        want = laguna._sparse(ref, x, first=0, top_k=k, scale=2.5,
+                              norm_topk=True, mm=jnp.matmul)
+        shared = laguna._swiglu(x, ref["s_gate"], ref["s_up"], ref["s_down"],
+                                jnp.matmul)
+        total = shared
+        for rank in range(ranks):
+            held = experts // ranks
+            mod = M.MixtureOfExperts(
+                in_features=d, intermediate_size=h, num_experts=experts,
+                top_k=k, dispatch="dropless", routed_scale=2.5,
+                experts_held=held, first_expert=rank * held)
+            mod.bind("moe")
+            mine = slice(rank * held, (rank + 1) * held)
+            swap = lambda a: jnp.swapaxes(a[mine], 1, 2)
+            total = total + mod.apply(x, M.Ctx({
+                "moe.router.weight": ref["router"].T,
+                "moe.experts.gate_proj.weight": swap(ref["e_gate"]),
+                "moe.experts.up_proj.weight": swap(ref["e_up"]),
+                "moe.experts.down_proj.weight": swap(ref["e_down"])}))
+    np.testing.assert_allclose(total, want, atol=1e-5)
+
+
+TILE = 8
+
+
+@pytest.mark.parametrize("tile_group", [
+    [0, 0, 2, 3, 3, 3],         # ragged, expert 1 empty, all tiles live
+    [1, 1, 1, 4, 4, 4],         # one expert, then tiles past the last row
+    [4, 4, 4, 4],               # nothing routed at all
+    [0, 1, 2, 3],               # a tile each
+], ids=["ragged", "tail_empty", "all_empty", "one_tile_each"])
+def test_moe_grouped_kernels_match_ragged_dot_in_interpret_mode(tile_group):
+    """``penroz_moe_gmm_fwd`` / ``_bwd_dx`` / ``_bwd_dw`` run as jnp
+    (interpret mode) against ``jax.lax.ragged_dot`` over the same buffer:
+    groups of unlike length crossing tiles, an expert with no row, tiles
+    past the last routed row (their rows zero, their gradient nothing)."""
+    from penroz_tpu.ops.pallas import moe_gmm
+    groups, k, n = 4, 32, 16
+    tiles = jnp.asarray(tile_group, jnp.int32)
+    keys = jax.random.split(jax.random.key(0), 3)
+    lhs = jax.random.normal(keys[0], (TILE * len(tile_group), k))
+    rhs = jax.random.normal(keys[1], (groups, n, k))
+    cotangent = jax.random.normal(keys[2], (TILE * len(tile_group), n))
+    kernel = lambda l, r: moe_gmm.grouped_matmul_kernel(
+        l, r, tiles, row_tile=TILE, interpret=True)
+    ragged = lambda l, r: moe_gmm.grouped_matmul_ragged(
+        l, r, tiles, row_tile=TILE)
+    np.testing.assert_allclose(kernel(lhs, rhs), ragged(lhs, rhs), atol=1e-4)
+    grads = [jax.grad(lambda l, r: jnp.sum(fn(l, r) * cotangent), (0, 1))(
+        lhs, rhs) for fn in (kernel, ragged)]
+    for got, want in zip(*grads):
+        np.testing.assert_allclose(got, want, atol=1e-4)
+    empty = np.repeat(np.asarray(tile_group) >= groups, TILE)
+    assert not np.asarray(kernel(lhs, rhs))[empty].any()
+
+
+def test_moe_share_and_dropless_dsl_validation():
+    moe = lambda **kw: M.MixtureOfExperts(
+        in_features=8, intermediate_size=8, num_experts=8, top_k=2, **kw)
+    assert moe(experts_held=2, first_expert=6,
+               dispatch="dropless").param_shapes()[
+                   "experts.gate_proj.weight"] == (2, 8, 8)
+    assert moe(experts_held=2).param_shapes()["router.weight"] == (8, 8)
+    assert "shared_expert_gate.weight" not in moe(
+        shared_expert_size=4, shared_expert_gate=False).param_shapes()
+    for bad in (dict(experts_held=0), dict(experts_held=3, first_expert=6),
+                dict(experts_held=2, dispatch="capacity"),
+                dict(dispatch="droples")):
+        with pytest.raises(ValueError):
+            moe(**bad)

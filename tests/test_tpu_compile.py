@@ -672,3 +672,95 @@ def test_a_bare_checkpoint_still_runs_the_flash_forward_twice(chip):
     assert forwards(None) == 2
     assert forwards(jax.checkpoint_policies.save_only_these_names(
         *M._kept_names())) == 1
+
+
+# ---------------------------------------------------------------------------
+# the share cell (``laguna-train-8k-ep32share``): the grouped products and
+# one block of each attention kind, at the cell's shapes
+# ---------------------------------------------------------------------------
+
+MOE_ROWS, MOE_D, MOE_H, MOE_HELD, MOE_TILE = 8192, 3072, 1024, 8, 128
+
+
+@pytest.mark.parametrize("which", ["fwd", "bwd"])
+def test_moe_grouped_products_compile_for_v5e(chip, which):
+    """The dropless layer's grouped products over a round's row buffer of
+    the cell (8192 rows of 3072, 8 held experts of width 1024, bf16, tiles
+    of 128 rows): ``penroz_moe_gmm_fwd`` for gate/up and for down (the
+    contraction 3072 and 1024 long), and in the gradient ``_bwd_dx`` and
+    ``_bwd_dw`` for both, all under the names the benchmark's reader looks
+    for."""
+    from penroz_tpu.ops.pallas import moe_gmm
+    shapes = [((MOE_ROWS, MOE_D), jnp.bfloat16),
+              ((MOE_HELD, MOE_H, MOE_D), jnp.bfloat16),
+              ((MOE_HELD, MOE_D, MOE_H), jnp.bfloat16),
+              ((MOE_ROWS // MOE_TILE,), jnp.int32)]
+
+    def forward(x, up, down, tiles):
+        product = lambda a, w: moe_gmm.grouped_matmul_kernel(
+            a, w, tiles, row_tile=MOE_TILE)
+        return product(product(x, up), down)
+
+    fn = forward if which == "fwd" else jax.grad(
+        lambda *a: forward(*a).astype(jnp.float32).sum(), (0, 1, 2))
+    args = [jax.ShapeDtypeStruct(s, d, sharding=chip) for s, d in shapes]
+    calls = _custom_calls(jax.jit(fn).lower(*args).compile().as_text())
+    count = lambda needle: sum(needle in name for name, _ in calls)
+    # the gradient of a sum needs the first product's result only
+    assert count("penroz_moe_gmm_fwd") == (2 if which == "fwd" else 1), calls
+    assert count("penroz_moe_gmm_bwd_dx") == (2 if which == "bwd" else 0)
+    assert count("penroz_moe_gmm_bwd_dw") == (2 if which == "bwd" else 0)
+
+
+@pytest.mark.parametrize("kind,heads", [
+    ("sliding_attention", 9), ("full_attention", 6)])
+def test_laguna_block_compiles_for_v5e_at_the_cells_shapes(chip, kind, heads):
+    """One sparse block of ``presets.laguna_custom`` as the cell holds it
+    (1 x 8192 tokens, d 3072, 9 or 6 query heads of 128 on one K/V head, a
+    window of 512 or YaRN on half a head, per-head gate, 8 of 256 experts
+    top-10 beside a shared one, bf16), loss and gradient, compiled for a
+    v5e: attention stays in the model's layout with grouped queries
+    (``penroz_flash_fwd`` once, the one-pass ``penroz_flash_bwd``), and the
+    dropless layer's grouped calls are there: the forward's three, and in
+    each of the backward's two branches (one round, as the products made
+    its gradients; several, summed in float32; one of them runs) those
+    three again by the routed path's recomputation with three of each
+    gradient."""
+    from penroz_tpu.models import dsl, presets
+    from penroz_tpu.models.model import CompiledArch
+    rope = {"full_attention": {
+                "rope_theta": 500000, "rope_type": "yarn", "factor": 128,
+                "original_max_position_embeddings": 8192, "beta_slow": 1,
+                "beta_fast": 32, "attention_factor": 1.4852030263919618,
+                "partial_rotary_factor": 0.5},
+            "sliding_attention": {"rope_type": "default", "rope_theta": 10000,
+                                  "partial_rotary_factor": 1}}
+    arch = CompiledArch.get(presets.laguna_custom(
+        d=3072, head_dim=128, layer_types=[kind], heads_per_layer=[heads],
+        kv_heads=1, mlp_layer_types=["sparse"], intermediate=12288,
+        num_experts=256, experts_held=8, top_k=10, moe_intermediate=1024,
+        shared_intermediate=1024, vocab=12544, window=512, rope=rope,
+        routed_scale=2.5, published_layers=48))
+    shapes, _ = jax.eval_shape(
+        lambda: dsl.init_module_params(arch.mods, seed=0))
+    params = {k: jax.ShapeDtypeStruct(v.shape, jnp.bfloat16, sharding=chip)
+              for k, v in shapes.items()}
+    x = jax.ShapeDtypeStruct((1, 8192), jnp.int32, sharding=chip)
+
+    def loss(p, x, y):
+        _, cost, _, _ = arch.forward(p, {}, x, y, training=True,
+                                     skip_softmax=True,
+                                     compute_dtype=jnp.bfloat16,
+                                     platform="tpu")
+        return cost
+
+    hlo = jax.jit(jax.grad(loss)).lower(params, x, x).compile().as_text()
+    calls = _custom_calls(hlo)
+    count = lambda needle: sum(needle in name for name, _ in calls)
+    assert count("penroz_flash_fwd") == 1, calls
+    assert count("penroz_flash_bwd_dq") == 0, calls     # one pass at 8192
+    assert count("penroz_moe_gmm_fwd") == 3 + 2 * 3, calls
+    assert count("penroz_moe_gmm_bwd_dx") == 2 * 3, calls
+    assert count("penroz_moe_gmm_bwd_dw") == 2 * 3, calls
+    # attention never leaves (B, T, H·D)
+    assert f"bf16[1,{heads},8192,128]" not in hlo

@@ -657,3 +657,63 @@ def test_without_rusage_thread_the_job_runs_and_carries_no_account(
     _, chrome = app.call("GET", f"/trace/{rid}?format=chrome")
     assert not any("host" in e.get("args", {})
                    for e in chrome["traceEvents"])
+
+
+def test_a_laguna_share_trains_on_the_normal_path_and_counts_its_routing(app):
+    """``presets.laguna_custom`` told a share (4 of 16 experts, 2 or 3 query
+    heads on one K/V head) → ``POST /model/`` → ``PUT /train/``: the job's
+    trace has ``penroz/moe_plan`` under the compiling epoch's dispatch, every
+    ``penroz/train_epoch`` the four routing counters summed over the sparse
+    layers and the micro-steps, ``/progress/`` rows and ``/metrics`` the
+    same; nothing dropped; ``/generate/`` serves it."""
+    from penroz_tpu.models import presets
+    epochs, batch, top_k, sparse = 3, 2, 4, 2
+    app.create("share", presets.laguna_custom(
+        d=16, head_dim=8, layer_types=["full_attention", "sliding_attention",
+                                       "full_attention"],
+        heads_per_layer=[2, 3, 2], kv_heads=1,
+        mlp_layer_types=["dense", "sparse", "sparse"], intermediate=32,
+        num_experts=16, experts_held=4, first_expert=4, top_k=top_k,
+        moe_intermediate=8, shared_intermediate=8, vocab=32, window=4,
+        rope={"full_attention": {
+                  "rope_theta": 500000, "rope_type": "yarn", "factor": 128,
+                  "original_max_position_embeddings": 8192, "beta_slow": 1,
+                  "beta_fast": 32, "partial_rotary_factor": 0.5},
+              "sliding_attention": {"rope_type": "default",
+                                    "rope_theta": 10000,
+                                    "partial_rotary_factor": 1}},
+        routed_scale=2.5))
+    rid = app.train("share", epochs=epochs, batch=batch)
+    progress = app.wait("share")
+    assert progress["status"]["code"] == "Trained", progress["status"]
+    _, tree = app.call("GET", f"/trace/{rid}")
+    first, *later = named(tree, "penroz/train_epoch")
+    (dispatch,) = [c for c in first["children"]
+                   if c["name"] == "penroz/train_dispatch"]
+    plans = [n["meta"] for n, _ in walk(dispatch)
+             if n["name"] == "penroz/moe_plan"]
+    # a micro-step is batch x BLOCK = 16 tokens: a buffer of one tile, and
+    # 16 · min(4, 4) + 4 · 127 rows in whole tiles at the bound
+    assert plans and all(p == {
+        "experts": 16, "held": 4, "first": 4, "top_k": top_k, "rows": 128,
+        "row_tile": 128, "dispatch": "dropless", "rows_bound": 640,
+        "rounds_bound": 5} for p in plans), plans[0]
+    # an epoch's pairs, held or not: ``batch`` micro-steps of batch x BLOCK
+    pairs = batch * batch * BLOCK * top_k * sparse
+    for epoch, row in zip([first, *later], progress["progress"]):
+        meta = epoch["meta"]
+        assert 0 < meta["moe_rows"] < pairs
+        assert meta["moe_rows"] <= meta["moe_rows_padded"] \
+            < meta["moe_rows"] + 128 * 4 * sparse * batch
+        assert 0 < meta["moe_load_max"] <= meta["moe_rows"]
+        assert meta["moe_dropped"] == 0
+        assert all(row[k] == meta[k] for k in (
+            "moe_rows", "moe_rows_padded", "moe_load_max", "moe_dropped"))
+    _, scrape = app.call("GET", "/metrics")
+    for name in ("moe_rows", "moe_rows_padded", "moe_load_max"):
+        assert (f'penroz_train_moe{{counter="{name}"}} '
+                f'{later[-1]["meta"][name]}\n') in scrape, name
+    resp, body = app.call("POST", "/generate/", json={
+        "model_id": "share", "input": [[1, 2, 3]], "block_size": BLOCK,
+        "max_new_tokens": 4, "temperature": 0.0})
+    assert resp.status == 200, body

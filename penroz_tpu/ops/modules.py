@@ -882,6 +882,29 @@ def _gated_activation(name: str, x):
     return jax.nn.gelu(x, approximate=(name == "gelu_pytorch_tanh"))
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
+def _top_k(probs, k: int):
+    """``jax.lax.top_k`` whose gradient is a compare: ``dprobs[..., e] = Σ_j
+    [e = index[..., j]] · dvalues[..., j]``, a fused compare-select-sum in
+    place of the scatter XLA makes of it (an element at a time: 1.0 ms for
+    8 192 x 10 into 256 on a v5e against 0.16, PERF.md §6)."""
+    return tuple(jax.lax.top_k(probs, k))
+
+
+def _top_k_fwd(probs, k):
+    values, index = jax.lax.top_k(probs, k)
+    return (values, index), (index, probs.shape[-1])
+
+
+def _top_k_bwd(k, kept, cotangents):
+    (index, width), (dvalues, _) = kept, cotangents  # indices: no derivative
+    hit = index[..., None] == jnp.arange(width, dtype=index.dtype)
+    return (jnp.sum(jnp.where(hit, dvalues[..., None], 0), axis=-2),)
+
+
+_top_k.defvjp(_top_k_fwd, _top_k_bwd)
+
+
 # What a dropless expert layer counts of a call, summed over the layers of a
 # model and the micro-steps of an epoch (``penroz/train_epoch`` counters,
 # ``/progress/`` rows, ``/metrics``): pairs routed to held experts, rows the
@@ -911,84 +934,195 @@ class _DroplessConfig(NamedTuple):
     held: int           # experts held: a tile of expert ``held`` is empty
     activation: str
     on_tpu: bool
+    combine: str        # how rows come back to their tokens: runs | take
 
 
-def _dropless_round(cfg, xs, weight, w_gate, w_up, w_down, tile_group):
-    """One round's rows through their experts, weighted: float32
-    ``(rows, d)``.  A padding row has weight 0."""
+class _DroplessLayout(NamedTuple):
+    """Where the (token, choice) pairs of the held experts lie: sorted by
+    expert, each expert's group padded to whole tiles, and inside a group
+    ascending in the token (a token meets an expert at most once), so token
+    ``n``'s row in expert ``e`` is ``starts[e] + pos[e, n] - 1``.  Integers
+    only: nothing here has a derivative."""
+    chosen: jax.Array       # (held, tokens) bool: the router sent n to e
+    pos: jax.Array          # (held, tokens): e's chosen tokens up to n's
+    sizes: jax.Array        # (held,) rows of each group
+    starts: jax.Array       # (held,) a group's first row
+    padded: jax.Array       # () rows of all groups, each in whole tiles
+    tile_group: jax.Array   # (rows_bound // row_tile,) a tile's expert
+    place_row: jax.Array    # (places, tokens) a token's rows, -1 for none
+
+
+class _RoundRows(NamedTuple):
+    """One round's rows (``cfg.rows`` of the layout)."""
+    tok: jax.Array          # the token a row reads; 0 where it is not live
+    weight: jax.Array       # float32; 0 where it is not live
+    groups: jax.Array       # (rows // row_tile,) its tiles' experts
+    live: jax.Array         # a real row in a tile the products compute
+    first: jax.Array        # the round's first row in the layout
+    rank: jax.Array         # (tiles, row_tile) a row's place in its group
+
+
+def _held_choices(top_vals, top_idx, first: int, held: int):
+    """The ``(tokens, top_k)`` choices reduced to the held experts by dense
+    compares, tokens on the lanes: ``chosen[e, n]`` and ``weight[e, n] =
+    Σ_k top_vals[n, k] · [top_idx[n, k] = first + e]`` (float32), and the
+    compare itself ``(top_k, held, tokens)``.  The weight's derivative in
+    ``top_vals`` is a broadcast and a mask."""
+    hit = (top_idx.T[:, None, :] - first
+           == jnp.arange(held, dtype=top_idx.dtype)[None, :, None])
+    weight = jnp.sum(jnp.where(
+        hit, top_vals.T[:, None, :].astype(jnp.float32), 0.0), axis=0)
+    return hit, jnp.any(hit, axis=0), weight
+
+
+def _dropless_layout(hit, chosen, tile: int, bound: int) -> _DroplessLayout:
+    """The stable counting sort as one cumulative sum along the tokens."""
+    held = chosen.shape[0]
+    pos = jnp.cumsum(chosen.astype(jnp.int32), axis=1)
+    sizes = pos[:, -1]
+    padded = -(-sizes // tile) * tile
+    ends = jnp.cumsum(padded)
+    starts = ends - padded
+    tile_start = jnp.arange(bound // tile, dtype=jnp.int32) * tile
+    # the expert a tile belongs to; ``held`` past the last real row
+    tile_group = jnp.searchsorted(ends, tile_start, side="right").astype(
+        jnp.int32)
+    row = starts[:, None] + pos - 1
+    if held <= hit.shape[0]:        # a place an expert held
+        place_row = jnp.where(chosen, row, -1)
+    else:                           # a place a choice
+        place_row = jnp.sum(jnp.where(hit, row[None] + 1, 0), axis=1) - 1
+    return _DroplessLayout(chosen, pos, sizes, starts, ends[-1], tile_group,
+                           place_row)
+
+
+def _round_rows(cfg, j, layout, weight) -> _RoundRows:
+    """Round ``j``'s rows.  The one inverse of the layout is made here, for
+    the round's rows only and without an indexed write: a row of rank ``i``
+    in its group reads the token at which the group's running count first
+    passes ``i``, which is how many tokens' counts do not (the counts
+    ascend), a dense compare and a sum along the lanes."""
+    tiles = cfg.rows // cfg.row_tile
+    first = j * cfg.rows
+    groups = jax.lax.dynamic_slice_in_dim(layout.tile_group, j * tiles, tiles)
+    held = jnp.minimum(groups, cfg.held - 1)
+    rank = (first + jnp.arange(cfg.rows, dtype=jnp.int32).reshape(
+        tiles, cfg.row_tile)) - layout.starts[held][:, None]
+    live = ((groups < cfg.held)[:, None]
+            & (rank < layout.sizes[held][:, None])).reshape(cfg.rows)
+    tok = jnp.sum(layout.pos[held][:, None, :] <= rank[:, :, None], axis=-1,
+                  dtype=jnp.int32).reshape(cfg.rows)
+    tok = jnp.where(live, tok, 0)
+    row_weight = jnp.where(
+        live, weight[jnp.repeat(held, cfg.row_tile), tok], 0.0)
+    return _RoundRows(tok, row_weight, groups, live, first, rank)
+
+
+def _round_out(cfg, xs, w_gate, w_up, w_down, tile_group):
+    """One round's rows through their experts: ``(rows, d)``."""
     from penroz_tpu.ops.pallas import moe_gmm
     product = functools.partial(moe_gmm.grouped_matmul, tile_group=tile_group,
                                 row_tile=cfg.row_tile, on_tpu=cfg.on_tpu)
     hidden = (_gated_activation(cfg.activation, product(xs, w_gate))
               * product(xs, w_up))
-    return product(hidden, w_down).astype(jnp.float32) * weight[:, None]
+    return product(hidden, w_down)
 
 
-def _round_slices(cfg, j, row_token, row_weight, tile_group):
-    """Round ``j``'s rows: the token each reads (0 for a padding row, which
-    has weight 0), its weight, its tiles' experts, and which of its rows are
-    real *and* lie in a tile the products compute."""
+def _token_runs(cfg, layout, rnd, tokens: int):
+    """``(first, one past the last)`` row of the round that each held expert
+    has for each tile of tokens, ``(held, token tiles)`` each: inside a
+    group the rows ascend in their token, so a tile's are one run, from the
+    group's count before the tile to its count at the tile's end."""
+    from penroz_tpu.ops.pallas import moe_combine
+    tile = moe_combine.token_tile(tokens)
+    ahead = jnp.pad(layout.pos[:, tile - 1::tile], ((0, 0), (1, 0)))
+    run = jnp.clip(layout.starts[:, None] + ahead - rnd.first, 0, cfg.rows)
+    return run[:, :-1], run[:, 1:]
+
+
+def _rows_to_tokens(cfg, layout, rnd, rows, scale, y):
+    """``y[n] + Σ_{live rows r of token n} scale[r] · rows[r]``, float32, by
+    reads: on the TPU each token tile reads every group's contiguous run of
+    its rows once (``ops/pallas/moe_combine.py``); elsewhere a token reads
+    the row it has in each of its places, a row of zeros where it has none
+    there or the row lies in another round."""
+    if cfg.combine == "runs":
+        from penroz_tpu.ops.pallas import moe_combine
+        return moe_combine.rows_to_tokens(
+            rows, scale, rnd.tok, *_token_runs(cfg, layout, rnd, y.shape[0]),
+            y, places=layout.place_row.shape[0])
+    local = layout.place_row - rnd.first
+    here = (layout.place_row >= 0) & (local >= 0) & (local < cfg.rows)
+    local = jnp.where(here, local, 0)
+    got = (jnp.take(rows, local, axis=0).astype(jnp.float32)
+           * jnp.where(here, jnp.take(scale, local), 0.0)[:, :, None])
+    return y + jnp.sum(got, axis=0)
+
+
+def _rows_to_choices(cfg, layout, rnd, values):
+    """A scalar a row, back at its ``(expert, token)``: ``(held, tokens)``,
+    0 where the round holds no row.  Dense again: a tile's rows against the
+    running count of the tile's expert, then the tiles of an expert
+    summed."""
     tiles = cfg.rows // cfg.row_tile
-    cut = jax.lax.dynamic_slice_in_dim
-    tok = cut(row_token, j * cfg.rows, cfg.rows)
-    groups = cut(tile_group, j * tiles, tiles)
-    live = (tok >= 0) & jnp.repeat(groups < cfg.held, cfg.row_tile)
-    return (jnp.maximum(tok, 0), cut(row_weight, j * cfg.rows, cfg.rows),
-            groups, live)
+    held = jnp.minimum(rnd.groups, cfg.held - 1)
+    values = jnp.where(rnd.live, values, 0.0).reshape(tiles, cfg.row_tile)
+    hit = layout.pos[held][:, None, :] == rnd.rank[:, :, None] + 1
+    per_tile = jnp.where(
+        layout.chosen[held],
+        jnp.sum(jnp.where(hit, values[:, :, None], 0.0), axis=1), 0.0)
+    mine = held[None, :] == jnp.arange(cfg.held, dtype=held.dtype)[:, None]
+    return jnp.sum(jnp.where(mine[:, :, None], per_tile[None], 0.0), axis=1)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _dropless_rows(cfg, x, row_weight, w_gate, w_up, w_down, row_token,
-                   tile_group, rounds):
-    """``(y, placed)``: ``y[n] = Σ_{rows r of token n} weight[r] ·
-    expert_{g(r)}(x[n])`` over the first ``rounds`` rounds of the sorted rows
-    (a traced count: the loop runs as long as there are rows, not as long as
-    the bound), and how many real rows (``row_token`` ≥ 0) those rounds
-    handed to a live tile of the products, counted as the rounds run.
+def _dropless_rows(cfg, x, weight, w_gate, w_up, w_down, layout, rounds):
+    """``(y, placed)``: ``y[n] = Σ_{held experts e of token n} weight[e, n] ·
+    expert_e(x[n])`` over the first ``rounds`` rounds of the sorted rows (a
+    traced count: the loop runs as long as there are rows, not as long as
+    the bound), and how many real rows those rounds handed to a live tile
+    of the products, counted as the rounds run.
 
     Its derivative is taken a round at a time too, each round recomputed
-    from ``x``, the indices and the weights: nothing of a round outlives it
+    from ``x``, the layout and the weights: nothing of a round outlives it
     in either direction, so what the layer keeps for the backward is what
     it was given."""
     def one(j, carry):
         y, placed = carry
-        tok, weight, groups, live = _round_slices(cfg, j, row_token,
-                                                  row_weight, tile_group)
-        y = y.at[tok].add(_dropless_round(
-            cfg, x[tok], weight, w_gate, w_up, w_down, groups))
-        return y, placed + jnp.sum(live, dtype=jnp.int32)
+        rnd = _round_rows(cfg, j, layout, weight)
+        out = _round_out(cfg, x[rnd.tok], w_gate, w_up, w_down, rnd.groups)
+        return (_rows_to_tokens(cfg, layout, rnd, out, rnd.weight, y),
+                placed + jnp.sum(rnd.live, dtype=jnp.int32))
 
     y, placed = jax.lax.fori_loop(
         0, rounds, one, (jnp.zeros(x.shape, jnp.float32), jnp.int32(0)))
     return y.astype(x.dtype), placed.astype(jnp.float32)
 
 
-def _dropless_rows_fwd(cfg, x, row_weight, w_gate, w_up, w_down, row_token,
-                       tile_group, rounds):
-    kept = (x, row_weight, w_gate, w_up, w_down, row_token, tile_group,
-            rounds)
+def _dropless_rows_fwd(cfg, x, weight, w_gate, w_up, w_down, layout, rounds):
+    kept = (x, weight, w_gate, w_up, w_down, layout, rounds)
     return _dropless_rows(cfg, *kept), kept
 
 
 def _dropless_rows_bwd(cfg, kept, cotangents):
-    x, row_weight, w_gate, w_up, w_down, row_token, tile_group, rounds = kept
+    x, weight, w_gate, w_up, w_down, layout, rounds = kept
     dy, _ = cotangents                   # the count has no derivative
     stacks = (w_gate, w_up, w_down)
 
     def round_grads(j, dx, dweight):
         """Round ``j`` recomputed and pulled back: its rows' gradients added
         into ``dx`` and ``dweight``, and the stacks' gradients it made."""
-        tok, weight, groups, _ = _round_slices(cfg, j, row_token, row_weight,
-                                               tile_group)
+        rnd = _round_rows(cfg, j, layout, weight)
         _, pull = jax.vjp(
-            lambda *args: _dropless_round(cfg, *args, groups),
-            x[tok], weight, *stacks)
-        dxs, dw, *ds = pull(dy[tok].astype(jnp.float32))
-        return (dx.at[tok].add(dxs.astype(jnp.float32)),
-                jax.lax.dynamic_update_slice_in_dim(
-                    dweight, dw, j * cfg.rows, 0), ds)
+            lambda xs, w, *s: (_round_out(cfg, xs, *s, rnd.groups).astype(
+                jnp.float32) * w[:, None]),
+            x[rnd.tok], rnd.weight, *stacks)
+        dxs, dw, *ds = pull(dy[rnd.tok].astype(jnp.float32))
+        return (_rows_to_tokens(cfg, layout, rnd, dxs,
+                                rnd.live.astype(jnp.float32), dx),
+                dweight + _rows_to_choices(cfg, layout, rnd, dw), ds)
 
-    zeros = (jnp.zeros(x.shape, jnp.float32), jnp.zeros_like(row_weight))
+    zeros = (jnp.zeros(x.shape, jnp.float32), jnp.zeros_like(weight))
 
     def one_round():
         # the usual case (the buffer is a row a token): the stacks'
@@ -1012,7 +1146,7 @@ def _dropless_rows_bwd(cfg, kept, cotangents):
     # no round at all (nothing routed here) takes the first branch too: its
     # one round finds padding rows and empty tiles only
     dx, dweight, dstacks = jax.lax.cond(rounds <= 1, one_round, many_rounds)
-    return (dx.astype(x.dtype), dweight, *dstacks, None, None, None)
+    return (dx.astype(x.dtype), dweight, *dstacks, None, None)
 
 
 _dropless_rows.defvjp(_dropless_rows_fwd, _dropless_rows_bwd)
@@ -1171,7 +1305,7 @@ class MixtureOfExperts(Module):
         logits = jnp.einsum("btd,ed->bte", x.astype(jnp.float32),
                             router.astype(jnp.float32))
         probs = jax.nn.softmax(logits, axis=-1)
-        top_vals, top_idx = jax.lax.top_k(probs, self.top_k)
+        top_vals, top_idx = _top_k(probs, self.top_k)
         if self.norm_topk:
             top_vals = top_vals / jnp.sum(top_vals, axis=-1, keepdims=True)
         if ctx.training:
@@ -1246,80 +1380,80 @@ class MixtureOfExperts(Module):
     # tile belongs to one expert, so each expert's group is padded to it.
     ROW_TILE = 128
 
-    def dropless_plan(self, tokens: int) -> dict:
+    def dropless_plan(self, tokens: int, on_tpu: bool = False) -> dict:
         """The static sizes of the dropless path for ``tokens`` tokens.
 
         The (token, choice) pairs whose expert is held are laid out sorted
         by expert, each expert's group padded to ``ROW_TILE`` rows.  The one
         length that can never overflow, ``rows_bound`` (every token choosing
         as many held experts as it can, every group a tile short of full),
-        is the length of two index vectors only.  The activations live in a
+        is the length of the tile table only.  The activations live in a
         buffer of ``rows`` rows, a row a token (in whole tiles, at most the
         bound): the layout is walked ``rows`` at a time for as many rounds
         as the rows really routed need, at most ``rounds_bound``; a layer
-        whose tokens meet one held expert each on average takes one."""
+        whose tokens meet one held expert each on average takes one.
+
+        ``places`` is the most rows one token can have, ``combine`` how the
+        rows come back to their tokens: ``runs`` (the TPU's kernel, a token
+        tile reading each group's run of its rows once, wherever its
+        scalars and copies fit the core: ``moe_combine.fits``) or ``take``
+        (a token reads a row a place)."""
+        from penroz_tpu.ops.pallas import moe_combine
         tile = self.ROW_TILE
-        bound = (tokens * min(self.top_k, self.experts_held)
-                 + self.experts_held * (tile - 1))
+        places = min(self.top_k, self.experts_held)
+        bound = tokens * places + self.experts_held * (tile - 1)
         bound = -(-bound // tile) * tile
         rows = min(-(-tokens // tile) * tile, bound)
         rounds = -(-bound // rows)
+        runs = on_tpu and moe_combine.fits(
+            rows=rows, tokens=tokens, width=self.in_features,
+            groups=self.experts_held, places=places)
         return {"experts": self.num_experts, "held": self.experts_held,
                 "first": self.first_expert, "top_k": self.top_k,
                 "rows": rows, "row_tile": tile, "dispatch": self.dispatch,
-                "rows_bound": rounds * rows, "rounds_bound": rounds}
+                "rows_bound": rounds * rows, "rounds_bound": rounds,
+                "places": places, "combine": "runs" if runs else "take"}
 
     def _apply_dropless(self, x, top_vals, top_idx, w_gate, w_up, w_down,
                         ctx):
         """Σ over a token's chosen experts *that are held* of weight ·
-        expert(x), no pair lost whatever the imbalance.
+        expert(x), no pair lost whatever the imbalance, and no row moved by
+        an indexed write.
 
-        A counting sort gives every such pair its row in the layout of
-        :meth:`dropless_plan` (its expert's offset + how many pairs of that
-        expert precede it), the rows' tokens and weights are scattered into
-        two vectors of the bound's length (a row no pair took reads token
-        -1, weight 0), and :func:`_dropless_rows` walks the rows really
-        routed a round at a time: gather the tokens' activations, three
-        grouped products (``ops/pallas/moe_gmm.py``), scatter-add back with
-        the weights.  Time and memory follow the rows routed; the bound
-        costs two vectors.  ``moe_dropped`` is the pairs the router sent to
-        held experts less the real rows the rounds handed to the products,
-        counted as they ran."""
+        Dense compares reduce the ``(tokens, top_k)`` choices to the held
+        experts (:func:`_held_choices`: who chose whom and with what weight,
+        tokens on the lanes), and one cumulative sum along the tokens is the
+        stable counting sort (:func:`_dropless_layout`): a token's row in an
+        expert's group is the group's offset plus how many tokens chose the
+        expert up to it, in the layout of :meth:`dropless_plan`.
+        :func:`_dropless_rows` walks the rows really routed a round at a
+        time: find each row's token (a compare against the running count),
+        read the tokens' activations, three grouped products
+        (``ops/pallas/moe_gmm.py``), and bring the rows back to their tokens
+        by reads (``combine``).  Time and memory follow the rows routed; the
+        bound costs the tile table.  ``moe_dropped`` is the pairs the router
+        sent to held experts less the real rows the rounds handed to the
+        products, counted as they ran."""
         B, T, d = x.shape
-        tokens, k, held = B * T, self.top_k, self.experts_held
-        plan = self.dropless_plan(tokens)
+        tokens, held = B * T, self.experts_held
+        on_tpu = attn_ops._tpu_platform(x, ctx.platform)
+        plan = self.dropless_plan(tokens, on_tpu)
         _record_moe_plan(**plan)
-        tile, rows, bound = plan["row_tile"], plan["rows"], plan["rows_bound"]
-        local = top_idx.reshape(tokens * k) - self.first_expert
-        valid = (local >= 0) & (local < held)
-        group = jnp.clip(local, 0, held - 1)
-        member = valid[:, None] & (
-            group[:, None] == jnp.arange(held, dtype=local.dtype))
-        seen = jnp.cumsum(member.astype(jnp.int32), axis=0)  # (pairs, held)
-        sizes = seen[-1]
-        rank = jnp.take_along_axis(seen, group[:, None], axis=1)[:, 0] - 1
-        ends = jnp.cumsum(-(-sizes // tile) * tile)
-        starts = ends - (-(-sizes // tile) * tile)
-        dest = jnp.where(valid, starts[group] + rank, bound)  # bound: nowhere
-        pair_token = jnp.arange(tokens * k, dtype=jnp.int32) // k
-        row_token = jnp.full((bound,), -1, jnp.int32).at[dest].set(
-            pair_token, mode="drop")
-        row_weight = jnp.zeros((bound,), jnp.float32).at[dest].set(
-            top_vals.reshape(tokens * k).astype(jnp.float32), mode="drop")
-        tile_start = jnp.arange(bound // tile, dtype=jnp.int32) * tile
-        # the expert a tile belongs to; ``held`` past the last real row
-        tile_group = jnp.searchsorted(ends, tile_start, side="right").astype(
-            jnp.int32)
-        routed_rows, padded_rows = jnp.sum(valid), ends[-1]
-        rounds = -(-padded_rows // rows)
+        hit, chosen, weight = _held_choices(
+            top_vals.reshape(tokens, self.top_k),
+            top_idx.reshape(tokens, self.top_k), self.first_expert, held)
+        layout = _dropless_layout(hit, chosen, plan["row_tile"],
+                                  plan["rows_bound"])
+        routed_rows = jnp.sum(layout.sizes)
         cfg = _DroplessConfig(
-            row_tile=tile, rows=rows, held=held, activation=self.activation,
-            on_tpu=attn_ops._tpu_platform(x, ctx.platform))
+            row_tile=plan["row_tile"], rows=plan["rows"], held=held,
+            activation=self.activation, on_tpu=on_tpu,
+            combine=plan["combine"])
         y, placed = _dropless_rows(
-            cfg, x.reshape(tokens, d), row_weight, w_gate, w_up, w_down,
-            row_token, tile_group, rounds)
-        stats = {"moe_rows": routed_rows, "moe_rows_padded": padded_rows,
-                 "moe_load_max": jnp.max(sizes),
+            cfg, x.reshape(tokens, d), weight, w_gate, w_up, w_down, layout,
+            -(-layout.padded // plan["rows"]))
+        stats = {"moe_rows": routed_rows, "moe_rows_padded": layout.padded,
+                 "moe_load_max": jnp.max(layout.sizes),
                  "moe_dropped": routed_rows - placed}
         ctx.moe_stats = {
             name: jax.lax.stop_gradient(value.astype(jnp.float32))

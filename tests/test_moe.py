@@ -468,25 +468,37 @@ def tile_of_4(monkeypatch):
     monkeypatch.setattr(M.MixtureOfExperts, "ROW_TILE", 4)
 
 
-@pytest.mark.parametrize("rounds", ["one_round", "many_rounds", "no_round"])
+# a share's arguments, ``dropless_plan(24)``'s (rows, rows_bound,
+# rounds_bound, places) and the rounds the skewed router's rows take: every
+# expert held (a token meets three of them: three rows a token, four
+# rounds); a share of two experts, one of them the empty one, from expert 4
+# on (held < top_k: a place an expert); the empty expert alone; five of the
+# eight from expert 1 on (held > top_k: a place a choice)
+SHARES = {
+    "many_rounds": ({}, (24, 96, 4, 3), 4),
+    "one_round": (dict(experts_held=2, first_expert=4), (24, 72, 3, 2), 1),
+    "no_round": (dict(experts_held=1, first_expert=5), (24, 48, 2, 1), 0),
+    "a_place_a_choice": (dict(experts_held=5, first_expert=1),
+                         (24, 96, 4, 3), 3),
+}
+
+
+@pytest.mark.parametrize("share", list(SHARES))
 def test_moe_dropless_equals_dense_and_a_loop_under_a_skewed_router(
-        rounds, tile_of_4):
-    """Every expert held: the dropless dispatch (a counting sort, grouped
-    products over the rows each expert really got, scatter back) computes
-    what ``dense`` and a plain loop over the experts compute, forward and
-    gradient, with one expert taking most rows and one none, in the four
-    rounds of the row buffer that three choices a token need; as a share
-    of two experts (one of them the empty one) in one round; and as a
-    share of the empty expert alone, which takes none."""
-    share, sizes, took = {
-        "many_rounds": ({}, (24, 96, 4), 4),
-        "one_round": (dict(experts_held=2, first_expert=4), (24, 72, 3), 1),
-        "no_round": (dict(experts_held=1, first_expert=5), (24, 48, 2), 0),
-    }[rounds]
+        share, tile_of_4):
+    """The dropless dispatch (the layout from compares and a cumulative sum,
+    grouped products over the rows each expert really got, the rows read
+    back by their tokens) computes what ``dense`` and a plain loop over the
+    experts compute, forward and gradient (in ``x``, the router's weights
+    and the three stacks), with one expert taking most rows and one none,
+    for every share of ``SHARES``."""
+    share, sizes, took = SHARES[share]
     dense, params, x = _skewed("dense", **share)
     dropless, _, _ = _skewed("dropless", **share)
     plan = dropless.dropless_plan(24)
-    assert (plan["rows"], plan["rows_bound"], plan["rounds_bound"]) == sizes
+    assert (plan["rows"], plan["rows_bound"], plan["rounds_bound"],
+            plan["places"]) == sizes
+    assert plan["combine"] == "take"                # the kernel is the TPU's
     ctx = M.Ctx(params)
     dropless.apply(x, ctx)
     assert -(-float(ctx.moe_stats["moe_rows_padded"]) // plan["rows"]) == took
@@ -511,6 +523,97 @@ def test_moe_dropless_equals_dense_and_a_loop_under_a_skewed_router(
         for name, want in dparams.items():
             np.testing.assert_allclose(got["dropless"][1][0][name], want,
                                        atol=2e-5, err_msg=name)
+
+
+def _layout_of(mod, params, x):
+    """The layer's own layout for ``x``, the weights beside it, and the
+    round configuration ``_apply_dropless`` would walk it with."""
+    tokens = x.shape[0] * x.shape[1]
+    plan = mod.dropless_plan(tokens)
+    top_vals, top_idx = mod.route(x, M.Ctx(params))
+    hit, chosen, weight = M._held_choices(
+        top_vals.reshape(tokens, -1), top_idx.reshape(tokens, -1),
+        mod.first_expert, mod.experts_held)
+    layout = M._dropless_layout(hit, chosen, plan["row_tile"],
+                                plan["rows_bound"])
+    cfg = M._DroplessConfig(
+        row_tile=plan["row_tile"], rows=plan["rows"], held=mod.experts_held,
+        activation=mod.activation, on_tpu=False, combine=plan["combine"])
+    return cfg, plan, layout, weight, (top_vals, top_idx)
+
+
+@pytest.mark.parametrize("share", list(SHARES))
+def test_moe_dropless_layout_is_a_stable_counting_sort(share, tile_of_4):
+    """The layout made of dense compares and one cumulative sum, and the
+    rows each round finds in it, against a counting sort written out in
+    NumPy over the (token, choice) pairs in their order: row -> token,
+    row -> weight, the tile table, the groups' sizes, a token's rows place
+    by place, and the four counters."""
+    share, _, took = SHARES[share]
+    mod, params, x = _skewed("dropless", **share)
+    cfg, plan, layout, weight, (top_vals, top_idx) = _layout_of(mod, params, x)
+    tile, bound, held = plan["row_tile"], plan["rows_bound"], mod.experts_held
+    vals = np.asarray(top_vals).reshape(-1, mod.top_k)
+    local = np.asarray(top_idx).reshape(-1, mod.top_k) - mod.first_expert
+    sizes = np.array([(local == e).sum() for e in range(held)])
+    ends = np.cumsum(-(-sizes // tile) * tile)
+    row_token = np.full(bound, -1)
+    row_weight = np.zeros(bound, np.float32)
+    filled = ends - (-(-sizes // tile) * tile)      # a group's next free row
+    rows_of = [[] for _ in range(local.shape[0])]
+    for n, choices in enumerate(local):
+        for k, e in enumerate(choices):
+            if 0 <= e < held:
+                row_token[filled[e]], row_weight[filled[e]] = n, vals[n, k]
+                rows_of[n].append(filled[e])
+                filled[e] += 1
+    tile_group = np.searchsorted(ends, np.arange(bound // tile) * tile,
+                                 side="right")
+    np.testing.assert_array_equal(layout.sizes, sizes)
+    np.testing.assert_array_equal(layout.tile_group, tile_group)
+    places = np.asarray(layout.place_row)
+    assert places.shape == (plan["places"], local.shape[0])
+    for n, rows in enumerate(rows_of):
+        assert sorted(places[:, n][places[:, n] >= 0]) == sorted(rows)
+    got_token = np.full(bound, -1)
+    got_weight = np.zeros(bound, np.float32)
+    for j in range(plan["rounds_bound"]):
+        rnd = M._round_rows(cfg, j, layout, weight)
+        live, at = np.asarray(rnd.live), slice(j * cfg.rows, (j + 1) * cfg.rows)
+        got_token[at] = np.where(live, rnd.tok, -1)
+        got_weight[at] = rnd.weight
+        assert not np.asarray(rnd.tok)[~live].any()
+    np.testing.assert_array_equal(got_token, row_token)
+    np.testing.assert_allclose(got_weight, row_weight, rtol=1e-6)
+    assert -(-ends[-1] // cfg.rows) == took
+    ctx = M.Ctx(params)
+    mod.apply(x, ctx)
+    assert {k: float(v) for k, v in ctx.moe_stats.items()} == {
+        "moe_rows": sizes.sum(), "moe_rows_padded": ends[-1],
+        "moe_load_max": sizes.max(), "moe_dropped": 0}
+
+
+@pytest.mark.parametrize("share", list(SHARES))
+def test_moe_combine_kernel_reads_what_the_take_reads(share, tile_of_4):
+    """``penroz_moe_combine`` (interpret mode) against the ``take`` form the
+    CPU runs, round by round over the skewed layout: the same float32 sums
+    on top of the same running ``y``, from rows of either dtype."""
+    from penroz_tpu.ops.pallas import moe_combine
+    mod, params, x = _skewed("dropless", **SHARES[share][0])
+    cfg, plan, layout, weight, _ = _layout_of(mod, params, x)
+    keys = jax.random.split(jax.random.key(5), 2)
+    y = jax.random.normal(keys[0], (24, 16))
+    for j in range(plan["rounds_bound"]):
+        rnd = M._round_rows(cfg, j, layout, weight)
+        for dtype in (jnp.float32, jnp.bfloat16):
+            rows = jax.random.normal(keys[1], (cfg.rows, 16)).astype(dtype)
+            want = M._rows_to_tokens(cfg, layout, rnd, rows, rnd.weight, y)
+            lo, hi = M._token_runs(cfg, layout, rnd, 24)
+            got = moe_combine.rows_to_tokens(
+                rows, rnd.weight, rnd.tok, lo, hi, y,
+                places=plan["places"], interpret=True)
+            np.testing.assert_allclose(got, want, atol=1e-5)
+        y = want
 
 
 def test_moe_dropless_counts_what_the_router_chose_and_drops_nothing(
@@ -538,24 +641,24 @@ def test_moe_dropless_counts_what_the_router_chose_and_drops_nothing(
 
 
 def _sorted_rows(rounds_of: int, dtype, each: int = 64):
-    """A layout as ``_apply_dropless`` makes it, written out: two experts
-    of ``each`` rows in tiles of 8, every row real, cut into rounds of
-    ``rounds_of`` rows; inputs and weights positive, so that every row adds
-    to a weight's gradient with the same sign."""
-    experts, tile, tokens, d, h = 2, 8, 32, 16, 24
-    keys = jax.random.split(jax.random.key(3), 6)
-    x = (0.5 + jnp.abs(jax.random.normal(keys[0], (tokens, d)))).astype(dtype)
+    """A layout as ``_apply_dropless`` makes it: two experts that every one
+    of ``each`` tokens chose, so ``each`` rows an expert in tiles of 8,
+    every row real, cut into rounds of ``rounds_of`` rows; inputs and
+    weights positive, so that every row adds to a weight's gradient with
+    the same sign."""
+    experts, tile, d, h = 2, 8, 16, 24
+    keys = jax.random.split(jax.random.key(3), 5)
+    x = (0.5 + jnp.abs(jax.random.normal(keys[0], (each, d)))).astype(dtype)
     stacks = tuple(
         (0.2 + 0.1 * jax.random.uniform(k, (experts, *shape))).astype(dtype)
         for k, shape in zip(keys[1:4], ((h, d), (h, d), (d, h))))
-    row_token = jax.random.randint(keys[4], (experts * each,), 0, tokens)
-    row_weight = jax.random.uniform(keys[5], (experts * each,), jnp.float32,
-                                    0.5, 1.5)
-    tile_group = jnp.repeat(jnp.arange(experts, dtype=jnp.int32),
-                            each // tile)
+    weight = jax.random.uniform(keys[4], (experts, each), jnp.float32,
+                                0.5, 1.5)
+    chosen = jnp.ones((experts, each), jnp.bool_)
+    layout = M._dropless_layout(chosen[None], chosen, tile, experts * each)
     cfg = M._DroplessConfig(row_tile=tile, rows=rounds_of, held=experts,
-                            activation="silu", on_tpu=False)
-    return cfg, x, row_weight, stacks, row_token, tile_group
+                            activation="silu", on_tpu=False, combine="take")
+    return cfg, x, weight, stacks, layout
 
 
 def test_moe_dropless_sums_weight_gradients_over_rounds_in_float32():
@@ -566,11 +669,11 @@ def test_moe_dropless_sums_weight_gradients_over_rounds_in_float32():
     (summed in bfloat16, a running sum 64 times a round's share rounds
     most of the share away: it reads 0.8 % off where this reads 0.1)."""
     def grads(rounds_of):
-        cfg, x, w, stacks, tok, groups = _sorted_rows(
+        cfg, x, w, stacks, layout = _sorted_rows(
             rounds_of, jnp.bfloat16, each=512)
-        rounds = jnp.int32(tok.size // rounds_of)
+        rounds = jnp.int32(1024 // rounds_of)
         fn = lambda *s: jnp.sum(M._dropless_rows(
-            cfg, x, w, *s, tok, groups, rounds)[0].astype(jnp.float32))
+            cfg, x, w, *s, layout, rounds)[0].astype(jnp.float32))
         return jax.jit(jax.grad(fn, (0, 1, 2)))(*stacks)
 
     for one, many in zip(grads(1024), grads(8)):
@@ -582,17 +685,19 @@ def test_moe_dropless_sums_weight_gradients_over_rounds_in_float32():
 
 def test_moe_dropped_counts_rows_the_rounds_really_handed_to_the_products():
     """``_dropless_rows`` counts its second result as its rounds run: a
-    real row (token >= 0) in a tile the products compute.  A loop that
-    stops a round short, a real row under a tile marked empty, and a
-    padding row are each not counted, so ``moe_dropped`` (pairs routed less
-    rows placed) would read them."""
-    cfg, x, w, stacks, tok, groups = _sorted_rows(32, jnp.float32)
-    placed = lambda tok, groups, rounds: float(M._dropless_rows(
-        cfg, x, w, *stacks, tok, groups, jnp.int32(rounds))[1])
-    assert placed(tok, groups, 4) == 128
-    assert placed(tok, groups, 3) == 96                  # a round skipped
-    assert placed(tok, groups.at[-1].set(2), 4) == 120   # a tile skipped
-    assert placed(tok.at[:5].set(-1), groups, 4) == 123  # padding rows
+    real row (within its group's size) in a tile the products compute.  A
+    loop that stops a round short, a real row under a tile marked empty,
+    and a padding row are each not counted, so ``moe_dropped`` (pairs routed
+    less rows placed) would read them."""
+    cfg, x, w, stacks, layout = _sorted_rows(32, jnp.float32)
+    placed = lambda layout, rounds: float(M._dropless_rows(
+        cfg, x, w, *stacks, layout, jnp.int32(rounds))[1])
+    assert placed(layout, 4) == 128
+    assert placed(layout, 3) == 96                       # a round skipped
+    assert placed(layout._replace(
+        tile_group=layout.tile_group.at[-1].set(2)), 4) == 120   # a tile
+    assert placed(layout._replace(
+        sizes=layout.sizes.at[1].add(-5)), 4) == 123     # padding rows
 
 
 def test_moe_expert_shares_add_up_to_the_uncut_reference_block(tile_of_4):
@@ -664,6 +769,24 @@ def test_moe_grouped_kernels_match_ragged_dot_in_interpret_mode(tile_group):
         np.testing.assert_allclose(got, want, atol=1e-4)
     empty = np.repeat(np.asarray(tile_group) >= groups, TILE)
     assert not np.asarray(kernel(lhs, rhs))[empty].any()
+
+
+def test_moe_dropless_plan_says_how_the_rows_come_back():
+    """``combine`` is read from the shapes and the platform alone: the
+    share cell's layer (8 of 256 experts, 8 192 tokens of 3 072) takes the
+    kernel on a TPU and ``take`` elsewhere; a layer that holds all 256
+    experts, a width off the lanes and a token count off the tile take
+    ``take`` on a TPU too (``moe_combine.fits``: the kernel's scalars and
+    copies would not fit the core)."""
+    plan = lambda tokens=8192, on_tpu=True, d=3072, **kw: M.MixtureOfExperts(
+        in_features=d, intermediate_size=1024, top_k=10, dispatch="dropless",
+        **{**dict(num_experts=256, experts_held=8), **kw}).dropless_plan(
+            tokens, on_tpu)
+    assert (plan()["places"], plan()["combine"]) == (8, "runs")
+    assert plan(on_tpu=False)["combine"] == "take"
+    assert (plan(experts_held=256)["places"],
+            plan(experts_held=256)["combine"]) == (10, "take")
+    assert plan(d=3000)["combine"] == plan(tokens=8200)["combine"] == "take"
 
 
 def test_moe_share_and_dropless_dsl_validation():

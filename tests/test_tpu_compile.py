@@ -725,7 +725,13 @@ def test_laguna_block_compiles_for_v5e_at_the_cells_shapes(chip, kind, heads):
     each of the backward's two branches (one round, as the products made
     its gradients; several, summed in float32; one of them runs) those
     three again by the routed path's recomputation with three of each
-    gradient."""
+    gradient.  The layer moves no row and no index by a scatter: its rows
+    come back to their tokens through ``penroz_moe_combine`` (``y`` in the
+    forward, ``dx`` in either branch of the backward), and what scatters
+    the program keeps are the embedding's gradient into the vocabulary's
+    table and the grouped products' one flag a tile."""
+    import math
+    import re
     from penroz_tpu.models import dsl, presets
     from penroz_tpu.models.model import CompiledArch
     rope = {"full_attention": {
@@ -762,5 +768,14 @@ def test_laguna_block_compiles_for_v5e_at_the_cells_shapes(chip, kind, heads):
     assert count("penroz_moe_gmm_fwd") == 3 + 2 * 3, calls
     assert count("penroz_moe_gmm_bwd_dx") == 2 * 3, calls
     assert count("penroz_moe_gmm_bwd_dw") == 2 * 3, calls
+    assert count("penroz_moe_combine") == 3, calls
+    # no scatter of rows of the model's width but the embedding's own, and
+    # none as long as the (token, choice) pairs or the rows' bound
+    scatters = [tuple(int(n) for n in dims.split(","))
+                for dims in re.findall(r" = \w+\[([\d,]+)\]\S* scatter\(", hlo)]
+    assert scatters, hlo[:2000]
+    assert [s for s in scatters if s[-1] == 3072] == [(12544, 3072)], scatters
+    assert all(math.prod(s) < 8192 for s in scatters if s[-1] != 3072), \
+        scatters
     # attention never leaves (B, T, H·D)
     assert f"bf16[1,{heads},8192,128]" not in hlo

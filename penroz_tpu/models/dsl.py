@@ -57,6 +57,7 @@ _LEAF_ALGOS = {
     "softmaxlast": M.SoftmaxOnLast,
     "dropout": M.Dropout,
     "attention": M.CausalSelfAttention,
+    "latentattention": M.LatentAttention,
     "ssm": M.GatedSSM,
     "gatedmlp": M.GatedMLP,
     "moe": M.MixtureOfExperts,
@@ -110,6 +111,16 @@ def to_layer(entry: dict) -> M.Module:
             **{k: to_layer(args["exit"][k]) for k in ("norm", "head", "gate")},
             **({"entropy_weight": args["entropy_weight"]}
                if "entropy_weight" in args else {}))
+    elif algo == "hyperconnected":
+        # one sub-block on a residual path of several mixed streams:
+        # ops/modules.HyperConnected
+        if "body" not in args or "features" not in args:
+            raise ValueError("hyperconnected takes features, body (one "
+                             f"layer entry) and its options; got "
+                             f"{sorted(args)}")
+        mod = M.HyperConnected(
+            body=to_layer(args["body"]),
+            **{k: v for k, v in args.items() if k != "body"})
     elif algo in _LEAF_ALGOS:
         mod = _LEAF_ALGOS[algo](**args)
     else:

@@ -262,13 +262,14 @@ def train_adapter(model, adapter_id: str, config: dict, dataset_id: str,
     at completion is adapter-only, loadable straight into the serving
     registry.  Reference loader semantics match the base trainer: every
     micro-step consumes a full ``(batch_size, block_size)`` buffer and
-    ``num_steps = buffer // (step_size · block)`` micro-steps accumulate
+    ``models/model.py::accumulation_steps`` micro-steps accumulate
     into one update.  An existing adapter checkpoint with the same config
     resumes from its params (continued fine-tuning); a config mismatch is
     a ValueError.
     """
     from penroz_tpu.data.loaders import Loader
     from penroz_tpu.models import dsl
+    from penroz_tpu.models.model import accumulation_steps
     import optax
 
     config = validate_config(config)
@@ -306,7 +307,7 @@ def train_adapter(model, adapter_id: str, config: dict, dataset_id: str,
              "message": f"Training adapter on {dataset_id}"})
     try:
         buffer_size = batch_size * block_size
-        num_steps = max(1, buffer_size // (step_size * block_size))
+        num_steps = accumulation_steps(batch_size, step_size)
         loader = Loader(dataset_id, begin_shard=shard, begin_idx=0,
                         buffer_size=buffer_size, idx_offset=buffer_size)
         optimizer = dsl.build_optimizer(model.optimizer_config)

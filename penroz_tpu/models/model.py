@@ -28,6 +28,7 @@ import atexit
 import contextlib
 import json
 import logging
+import math
 import os
 import threading
 import time
@@ -113,6 +114,21 @@ def _yield_to_decodes():
     deadline = time.monotonic() + cap_ms / 1000.0
     while decode_pending() > 0 and time.monotonic() < deadline:
         time.sleep(0.005)
+
+
+def accumulation_steps(batch_size: int, step_size, world: int = 1) -> int:
+    """Micro-steps an optimizer step accumulates: ``batch_size // (step_size
+    · world)``, at least 1 (reference: neural_net_model.py:581-586).  Every
+    micro-step is a full ``(batch_size, block_size)`` buffer, so a whole
+    ``step_size`` can only ask for as many micro-steps as the batch has
+    rows; a fraction asks for more (``batch_size`` 1, ``step_size`` 0.25:
+    four micro-steps of one row), which is how a job whose chip holds one
+    sequence accumulates several."""
+    if not step_size > 0:
+        raise ValueError(f"step_size must be positive, got {step_size}")
+    # floored as the reference floors; the 1e-9 is for fractions such as
+    # 0.1, whose quotient falls a rounding short of the whole number
+    return max(1, int(batch_size / (step_size * world) + 1e-9))
 
 
 def _sharded_zero_grads(params: dict) -> dict:
@@ -282,6 +298,18 @@ class CompiledArch:
         self.counts_routing = any(
             isinstance(m, M.MixtureOfExperts) and m.dispatch == "dropless"
             for top in self.mods for m in top.walk())
+        # A multi-stream residual's sub-blocks and latent-attention layers
+        # (ops/modules.py::HyperConnected, LatentAttention); what they and
+        # a router's selection bias report as a largest value
+        # (ops/modules.py::MAX_COUNTERS) leaves the epoch with the counts.
+        walked = [m for top in self.mods for m in top.walk()]
+        self.hyper = [m for m in walked if isinstance(m, M.HyperConnected)]
+        self.latent = [m for m in walked if isinstance(m, M.LatentAttention)]
+        self.max_counters = tuple(name for name, present in (
+            ("hc_sinkhorn_err", bool(self.hyper)),
+            ("moe_bias_absmax", any(
+                isinstance(m, M.MixtureOfExperts) and m.selection_bias
+                for m in walked))) if present)
         self.param_order: list[str] = []
         for mod in self.mods:
             for sub in mod.walk():
@@ -345,6 +373,8 @@ class CompiledArch:
         (a looped stack: one entry per (pass, layer))."""
         if self.looped is not None and KV.paged_enabled():
             self.refuse_looped("the paged KV pool (PAGED_KV_CACHE=1)")
+        self.refuse_latent("a KV cache (/generate/, the paged pool, the "
+                           "decode scheduler)")
         specs = []
         for mod in self.attn_layers:
             if mod.head_dim is None:
@@ -361,6 +391,24 @@ class CompiledArch:
                 f"a looped model does not run with {what}: it serves "
                 "through the dense KV cache (one slot per pass and layer) "
                 "and trains and serves without pipeline stages")
+
+    def refuse_latent(self, what: str):
+        """The one error for what latent attention does not run."""
+        if self.latent:
+            raise ValueError(
+                f"a model with latent attention does not run with {what}: "
+                "only the expanded form is written (training, /evaluate/, "
+                "/output/); the latent cache and the absorbed decode path "
+                "are not")
+
+    def end_step(self, buffers: dict) -> dict:
+        """``buffers`` after what the modules do once an optimizer step
+        (``Module.end_step``: a router's selection bias moves by its
+        balance rule)."""
+        for top in self.mods:
+            for mod in top.walk():
+                buffers = {**buffers, **mod.end_step(buffers)}
+        return buffers
 
     @property
     def ssm_specs(self) -> list[tuple[int, int, int]]:
@@ -394,6 +442,8 @@ class CompiledArch:
                     ep_mesh=ep_mesh, lora=lora, lora_idx=lora_idx,
                     ragged_descs=ragged_descs, ragged_rows=ragged_rows,
                     targets=targets if self.looped is not None else None)
+        if self.hyper:
+            M.record_hc_plan(self.hyper, math.prod(x.shape[:2]), training)
         acts = []
         h = x
         logits = None
@@ -489,7 +539,8 @@ class CompiledArch:
                 skip_softmax=True, compute_dtype=compute_dtype,
                 sp_mesh=sp_mesh, platform=platform, sp_mode=sp_mode,
                 ep_mesh=ep_mesh)
-            stats = {**(ctx.exit_stats or {}), **(ctx.moe_stats or {})}
+            stats = {**(ctx.exit_stats or {}), **(ctx.moe_stats or {}),
+                     **ctx.max_stats}
             return cost, (ctx.buffer_updates, stats or None)
         return loss_fn
 
@@ -645,8 +696,7 @@ class CompiledArch:
                 bufs = {**bufs, **upd}
                 grads_acc = jax.tree.map(
                     lambda a, g: a + g.astype(jnp.float32), grads_acc, grads)
-                cost_acc = jax.tree.map(jnp.add, cost_acc,
-                                        {"cost": cost, **(exits or {})})
+                cost_acc = self.add_costs(cost_acc, cost, exits)
                 return (grads_acc, bufs, cost_acc, i + 1), None
 
             zeros = jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32),
@@ -673,8 +723,10 @@ class CompiledArch:
         def finalize(params, opt_state, grads, new_buffers, cost_sum):
             inv = 1.0 / num_steps
             # means over the micro-steps; the routing counters stay sums
-            exits = {k: c if k in M.MOE_COUNTERS else c * inv
-                     for k, c in cost_sum.items()}
+            # and what is a largest value stays one
+            exits = {k: c if k in M.MOE_COUNTERS + M.MAX_COUNTERS
+                     else c * inv for k, c in cost_sum.items()}
+            new_buffers = self.end_step(new_buffers)
             cost = exits.pop("cost")
             exits = (exits,) if exits else ()   # looped or dropless only
             grads = jax.tree.map(
@@ -738,7 +790,16 @@ class CompiledArch:
                         exit_mass=zeros(self.looped.steps))
         if self.counts_routing:
             sums.update({name: zeros() for name in M.MOE_COUNTERS})
+        sums.update({name: zeros() for name in self.max_counters})
         return sums
+
+    @staticmethod
+    def add_costs(cost_acc: dict, cost, stats) -> dict:
+        """:meth:`zero_cost_sum`'s accumulator after one more micro-step:
+        sums, but for what is a largest value (``M.MAX_COUNTERS``)."""
+        new = {"cost": cost, **(stats or {})}
+        return {k: jnp.maximum(v, new[k]) if k in M.MAX_COUNTERS
+                else v + new[k] for k, v in cost_acc.items()}
 
     def train_micro_fns(self, optimizer_config: dict, num_steps: int,
                         remat: bool = False, compute_dtype=None,
@@ -803,8 +864,7 @@ class CompiledArch:
             bufs = {**bufs, **upd}
             grads_acc = jax.tree.map(
                 lambda a, g: a + g.astype(jnp.float32), grads_acc, grads)
-            return bufs, grads_acc, jax.tree.map(
-                jnp.add, cost_acc, {"cost": cost, **(exits or {})})
+            return bufs, grads_acc, self.add_costs(cost_acc, cost, exits)
 
         finalize = self._finalize_update_fn(optimizer, num_steps,
                                             out_shardings, with_ratios,
@@ -1362,7 +1422,8 @@ class NeuralNetworkModel:
         micro-step consumes a full ``(batch_size, block_size)`` buffer from
         the loader; ``step_size`` only sets how many such micro-steps
         accumulate into one optimizer step
-        (``num_steps = buffer_size // (step_size * block_size * world)``).
+        (:func:`accumulation_steps`: ``batch_size // (step_size * world)``;
+        a fraction asks for more micro-steps than the batch has rows).
         Progress/stats reset at train start (:597-601); ``speedPerSec``
         counts ``buffer_size`` tokens per epoch exactly as the reference
         does (:684-703), although an epoch consumes ``num_steps`` buffers.
@@ -1413,8 +1474,7 @@ class NeuralNetworkModel:
                                and mesh.shape[mesh_lib.PIPE_AXIS] > 1)
             dp_world = 1 if pipe_over_hosts else world
             dp_rank = 0 if pipe_over_hosts else rank
-            num_steps = max(1, buffer_size
-                            // (step_size * block_size * dp_world))
+            num_steps = accumulation_steps(batch_size, step_size, dp_world)
             loader = Loader(dataset_id, begin_shard=shard,
                             begin_idx=buffer_size * dp_rank,
                             buffer_size=buffer_size,
@@ -1643,8 +1703,13 @@ class NeuralNetworkModel:
                         # dropless expert layers: what the epoch routed
                         routed = {k: int(exits.pop(k))
                                   for k in M.MOE_COUNTERS if k in exits}
+                        # a multi-stream residual's Sinkhorn error, a
+                        # router's largest selection bias
+                        peaks = {k: float(exits.pop(k))
+                                 for k in M.MAX_COUNTERS if k in exits}
                     epoch_span.set(**tracing.exit_counters(exits),
-                                   **tracing.routing_counters(routed))
+                                   **tracing.routing_counters(routed),
+                                   **tracing.peak_counters(peaks))
                 duration = time.monotonic() - t0
                 if master:
                     if epoch % sample_every == 0:
@@ -1655,7 +1720,7 @@ class NeuralNetworkModel:
                             "speedPerSec": buffer_size / max(duration, 1e-9),
                             "weight_upd_ratio":
                                 np.asarray(ratios, np.float64).tolist(),
-                            **exits, **routed,
+                            **exits, **routed, **peaks,
                         })
                     log.info("Epoch %d: cost=%.4f %.0f tokens/sec",
                              epoch + 1, cost,

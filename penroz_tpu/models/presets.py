@@ -266,6 +266,110 @@ def laguna_custom(d: int, head_dim: int, layer_types: list,
             + [norm, linear(d, vocab), {"softmaxlast": {"dim": -1}}])
 
 
+def xing_custom(d: int, heads: int, q_rank: int, kv_rank: int, d_nope: int,
+                d_rope: int, d_v: int, mlp_layer_types: list,
+                intermediate: int, num_experts: int, top_k: int,
+                moe_intermediate: int, shared_intermediate: int, vocab: int,
+                rope_theta: float = 10000.0, rope_scaling: dict | None = None,
+                experts_held: int | None = None, first_expert: int = 0,
+                routed_scale: float = 1.0, norm_topk: bool = True,
+                streams: int = 4, sinkhorn_iters: int = 20,
+                hc_eps: float = 1e-6, res_clamp: list | None = None,
+                bias_update_rate: float = 0.001,
+                router_bias: list | None = None, eps: float = 1e-6,
+                published_layers: int | None = None) -> list:
+    """Xing4.0-shaped sparse-expert language model (XingChen-AGI
+    ``Xing4.0-*`` ``config.json``) at arbitrary dimensions, whole or as one
+    rank's share of an expert-parallel layer.
+
+    The residual path carries ``streams`` streams mixed a token at a time
+    (``hyperconnected``: one around each sub-block, the first of the model
+    starting every stream as the embedding, the last summing them), a final
+    RMSNorm, an untied head.  Layer ``i``:
+
+    - latent attention (``latentattention``): queries through a rank
+      ``q_rank`` bottleneck, keys and values from one normed rank
+      ``kv_rank`` vector a token, a ``d_rope``-wide rotary key shared by
+      ``heads`` heads that are ``d_nope + d_rope`` wide for the scores and
+      ``d_v`` for the values; ``rope_scaling`` of type ``yarn`` as the
+      module takes it.
+    - ``mlp_layer_types[i]``: ``dense`` is a SwiGLU of ``intermediate``;
+      ``sparse`` a router over ``num_experts`` (sigmoid scores, ``top_k`` a
+      token chosen by score + selection bias, weights renormalised if
+      ``norm_topk``, times ``routed_scale``; the bias moves by
+      ``bias_update_rate`` an optimizer step) over SwiGLU experts of
+      ``moe_intermediate`` beside one ungated shared expert of
+      ``shared_intermediate``, dispatched dropless.  ``router_bias``: one
+      list of ``num_experts`` values a sparse layer, the selection bias's
+      first value (default zeros).
+
+    The share: ``experts_held`` experts from ``first_expert`` (default all)
+    and the caller's ``heads`` and ``vocab`` already cut to what this rank
+    holds.
+
+    N(0, 0.02) initialisation of the linear layers, the attention output
+    projections scaled by 1/sqrt(2 · ``published_layers``) (default: the
+    layers built)."""
+    depth = len(mlp_layer_types)
+    sparse = [i for i, kind in enumerate(mlp_layer_types) if kind == "sparse"]
+    if router_bias is not None and len(router_bias) != len(sparse):
+        raise ValueError("router_bias names one list a sparse layer")
+    std = 0.02
+    proj_std = std / (2 * (published_layers or depth)) ** 0.5
+    norm = {"rmsnorm": {"normalized_shape": d, "eps": eps}}
+
+    def linear(fan_in, fan_out, s=std):
+        return {"linear": {"in_features": fan_in, "out_features": fan_out,
+                           "bias": False},
+                "normal": {"mean": 0.0, "std": s}}
+
+    attention = {"latentattention": {
+        "in_features": d, "num_heads": heads, "q_rank": q_rank,
+        "kv_rank": kv_rank, "d_nope": d_nope, "d_rope": d_rope, "d_v": d_v,
+        "rope_theta": rope_theta, "eps": eps, "init_std": std,
+        "out_init_std": proj_std,
+        **({"rope_scaling": rope_scaling} if rope_scaling else {})}}
+
+    def mlp(i):
+        kind = mlp_layer_types[i]
+        if kind == "dense":
+            return {"gatedmlp": {"in_features": d,
+                                 "intermediate_size": intermediate,
+                                 "activation": "silu"}}
+        if kind != "sparse":
+            raise ValueError(f"unknown MLP layer type {kind!r}")
+        args = {
+            "in_features": d, "intermediate_size": moe_intermediate,
+            "num_experts": num_experts, "top_k": top_k,
+            "activation": "silu", "norm_topk": norm_topk,
+            "routed_scale": routed_scale,
+            "shared_expert_size": shared_intermediate,
+            "shared_expert_gate": False, "dispatch": "dropless",
+            "experts_held": experts_held or num_experts,
+            "first_expert": first_expert, "scoring": "sigmoid",
+            "selection_bias": True, "bias_update_rate": bias_update_rate}
+        if router_bias is not None:
+            args["selection_bias_init"] = list(router_bias[sparse.index(i)])
+        return {"moe": args}
+
+    def mixed(body, **ends):
+        return {"hyperconnected": {
+            "features": d, "streams": streams,
+            "sinkhorn_iters": sinkhorn_iters, "hc_eps": hc_eps,
+            "res_clamp": list(res_clamp or (-30.0, 30.0)), "eps": eps,
+            **ends,
+            "body": {"sequential": [norm, body]}}}
+
+    blocks = [{"sequential": [
+        mixed(attention, **({"expand": True} if i == 0 else {})),
+        mixed(mlp(i), **({"reduce": True} if i == depth - 1 else {}))]}
+        for i in range(depth)]
+    return ([{"embedding": {"num_embeddings": vocab, "embedding_dim": d},
+              "normal": {"mean": 0.0, "std": std}}]
+            + blocks
+            + [norm, linear(d, vocab), {"softmaxlast": {"dim": -1}}])
+
+
 def makemore_mlp(vocab: int = 27, d_embed: int = 10,
                  d_hidden: int = 200) -> list:
     """Char-level MLP in the makemore style (BASELINE.md CPU-parity config):

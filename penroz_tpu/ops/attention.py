@@ -329,7 +329,8 @@ def causal_attention_reference(q, k, v, dropout_rate=0.0, dropout_rng=None,
                                alibi: Optional[np.ndarray] = None,
                                scale: Optional[float] = None,
                                softcap: Optional[float] = None):
-    """Pure-jnp causal attention. q: (B, Hq, T, D); k, v: (B, Hkv, T, D).
+    """Pure-jnp causal attention. q: (B, Hq, T, D); k: (B, Hkv, T, D); v:
+    (B, Hkv, T, Dv), ``Dv`` = ``D`` but under latent attention.
 
     ``window``: sliding-window width — query t attends keys in
     ``(t - window, t]`` (HF Mistral/Gemma-2 semantics: the window *includes*
@@ -348,7 +349,7 @@ def causal_attention_reference(q, k, v, dropout_rate=0.0, dropout_rng=None,
             else _alibi_bias(alibi, q_pos, k_pos, num_kv_heads))
     out = _attend(qg, k, v, mask, dropout_rate, dropout_rng, bias=bias,
                   scale=scale, softcap=softcap)
-    return out.reshape(B, Hq, T, D)
+    return out.reshape(B, Hq, T, v.shape[-1])
 
 
 def causal_attention(q, k, v, dropout_rate=0.0, dropout_rng=None,
@@ -382,7 +383,7 @@ def causal_attention(q, k, v, dropout_rate=0.0, dropout_rng=None,
                                           dropout_rng, window=window,
                                           alibi=alibi, scale=scale,
                                           softcap=softcap)
-    if _use_flash(q, k, platform):
+    if _use_flash(q, k, platform, v):
         from penroz_tpu.ops.pallas import flash_attention as fa
         rate, seed = _flash_dropout(dropout_rate, dropout_rng)
 
@@ -803,18 +804,36 @@ def _tpu_platform(x, platform=None) -> bool:
     return platform == "tpu"
 
 
-def _use_flash(q, k, platform=None) -> bool:
-    """Whether the Pallas flash kernel applies to these shapes/platform."""
+def _use_flash(q, k, platform=None, v=None) -> bool:
+    """Whether the Pallas flash kernel applies to these shapes/platform.  A
+    shape the kernels would serve on this TPU but for its sizes says so once
+    (a model that silently left the kernels is seen in the job's log)."""
     if _flash_disabled() or not _tpu_platform(q, platform):
         return False
     B, Hq, T, D = q.shape
-    return _flash_shapes(T, D, Hq, k.shape[1])
+    Dv = D if v is None else v.shape[-1]
+    fits = _flash_shapes(T, D, Hq, k.shape[1], Dv)
+    if not fits:
+        _warn_once(f"off_flash: {T} {D} {Dv} {Hq} {k.shape[1]}",
+                   "attention leaves the flash kernels for the O(T^2) jnp "
+                   "path: T=%d (D, Dv)=(%d, %d) heads=%d on %d (they take T "
+                   "a multiple of 128 and (D, Dv) of (64, 64), (128, 128), "
+                   "(256, 256) or (192, 128))", T, D, Dv, Hq, k.shape[1])
+    return fits
 
 
-def _flash_shapes(T: int, D: int, Hq: int, Hkv: int) -> bool:
+# (score width, value width) of a head the flash kernels take: one width, or
+# latent attention's 192-wide scores over 128-wide values
+_FLASH_WIDTHS = ((64, 64), (128, 128), (256, 256), (192, 128))
+
+
+def _flash_shapes(T: int, D: int, Hq: int, Hkv: int,
+                  Dv: Optional[int] = None) -> bool:
     # MXU-friendly: head dim multiple of 128 lane requirement handled by the
     # kernel via padding; sequence must be long enough to tile.
-    return T >= 128 and T % 128 == 0 and D in (64, 128, 256) and Hq % Hkv == 0
+    return (T >= 128 and T % 128 == 0
+            and (D, D if Dv is None else Dv) in _FLASH_WIDTHS
+            and Hq % Hkv == 0)
 
 
 def _use_flash_decode(q, k_full, platform=None) -> bool:

@@ -106,6 +106,9 @@ class Ctx:
         # What the dropless expert layers routed in this call, summed over
         # them (:data:`MOE_COUNTERS`); ``None`` in a model with none.
         self.moe_stats = None
+        # What a call's modules report as a largest value, over them
+        # (:data:`MAX_COUNTERS`); empty in a model with none.
+        self.max_stats = {}
         self.layer_offset = 0
         self.buffer_updates = {}
         self.aux_losses = []  # auxiliary training losses (e.g. MoE balance)
@@ -116,6 +119,13 @@ class Ctx:
             raise ValueError("PRNG key required (dropout in training mode)")
         self._rng_counter += 1
         return jax.random.fold_in(self.rng, self._rng_counter)
+
+    def note_max(self, name: str, value):
+        """Keep the larger of ``value`` and what ``name`` read so far."""
+        value = jax.lax.stop_gradient(value.astype(jnp.float32))
+        seen = self.max_stats.get(name)
+        self.max_stats[name] = (value if seen is None
+                                else jnp.maximum(seen, value))
 
     def offset(self):
         """Current sequence position offset (0 when no cache attached)."""
@@ -162,6 +172,12 @@ class Module:
         """Shapes of own (non-child) trainable parameters."""
         return {}
 
+    def end_step(self, buffers: dict) -> dict[str, jax.Array]:
+        """Updates of own buffers made once an optimizer step, after its
+        last micro-step (``CompiledArch.end_step``), from the buffers as the
+        micro-steps left them."""
+        return {}
+
     # -- application --------------------------------------------------------
     def apply(self, x, ctx: Ctx):
         raise NotImplementedError
@@ -171,6 +187,22 @@ class Module:
         if ctx.compute_dtype is not None and jnp.issubdtype(p.dtype, jnp.floating):
             p = p.astype(ctx.compute_dtype)
         return p
+
+
+@functools.lru_cache(maxsize=None)
+def _log_plan(kind: str, **plan) -> None:
+    """Once per distinct plan of the process."""
+    log.info("%s plan: %s", kind,
+             " ".join(f"{k}={v}" for k, v in plan.items()))
+
+
+def _record_plan(kind: str, **plan) -> None:
+    """As ``penroz/flash_plan``: an INFO line ``<kind> plan: …`` per distinct
+    plan and, each time a program traces the module, a
+    ``penroz/<kind>_plan`` span under whatever span is compiling."""
+    _log_plan(kind, **plan)
+    with tracing.span(f"penroz/{kind}_plan", **plan):
+        pass
 
 
 def _uniform(rng, shape, bound, dtype=jnp.float32):
@@ -710,18 +742,198 @@ class TransformerBlock(Module):
         return out
 
 
-@functools.lru_cache(maxsize=None)
-def _log_loop_plan(**plan) -> None:
-    """Once per distinct plan of the process."""
-    log.info("loop plan: %s", " ".join(f"{k}={v}" for k, v in plan.items()))
+def record_hc_plan(sub_blocks: Sequence["HyperConnected"], tokens: int,
+                   training: bool):
+    """One ``hc`` plan (:func:`_record_plan`) a traced program of a model
+    with a multi-stream residual, for all of its sub-blocks;
+    ``recomputed_sub_blocks``: those whose inside the backward runs again
+    (all of them in training, :func:`_recomputed`)."""
+    first = sub_blocks[0]
+    _record_plan("hc", streams=first.streams,
+                 sinkhorn_iters=first.sinkhorn_iters,
+                 sub_blocks=len(sub_blocks), tokens=int(tokens),
+                 recomputed_sub_blocks=len(sub_blocks) if training else 0)
+
+
+def sinkhorn(logits, iters: int, eps: float):
+    """``exp(logits)`` made doubly stochastic over its first two axes
+    ``(n, n, ...)``: ``iters`` times its columns, then its rows, each divided
+    by its sum + ``eps``.  Differentiated through every iteration."""
+    m = jnp.exp(logits)
+    for _ in range(iters):
+        m = m / (jnp.sum(m, axis=0, keepdims=True) + eps)
+        m = m / (jnp.sum(m, axis=1, keepdims=True) + eps)
+    return m
+
+
+class HyperConnected(Module):
+    """One sub-block ``body`` on a residual path of ``streams`` streams that
+    are mixed a token at a time (manifold-constrained hyper-connections,
+    arXiv:2512.24880, on hyper-connections, arXiv:2409.19606), in place of
+    ``x + body(x)``.  State ``X`` ``(B, T, n, d)``::
+
+        u = (vec(X) / rms(vec(X))) Phi                 Phi (n·d, 2n + n²)
+        H_pre  = sigmoid(a_pre · u[:n] + b_pre)                        (n,)
+        H_post = 2 sigmoid(a_post · u[n:2n] + b_post)                  (n,)
+        H_res  = Sinkhorn(clip(a_res · mat(u[2n:]) + b_res))         (n, n)
+        y = body(Σ_i H_pre[i] X[i])
+        X'[i] = Σ_j H_res[i, j] X[j] + H_post[i] y
+
+    ``body`` brings its own pre-norm.  Sinkhorn is ``sinkhorn_iters`` times
+    columns then rows of ``exp(·)`` divided by their sums + ``hc_eps``, so
+    H_res's rows sum to 1 and its columns nearly (``hc_sinkhorn_err``, the
+    largest |column sum − 1| of a call, :data:`MAX_COUNTERS`); the gradient
+    goes through every iteration.  ``expand``: the input is ``(B, T, d)``
+    and every stream starts as a copy of it (a model's first sub-block);
+    ``reduce``: the result is Σ_i X'[i] ``(B, T, d)`` (its last).
+
+    The statistics (rms, u's scaling, the three maps, Sinkhorn) are float32
+    whatever the compute dtype, with the tokens on the minor axis: an
+    ``(n, n)`` matrix a token would pad 64-fold in the TPU's tiles.  The
+    mixing itself is ``n²`` multiply-adds of ``(B, T, d)`` slices, which XLA
+    fuses; written as a contraction over ``n`` it would be a batch of
+    4 × 4 matmuls.
+
+    In training a sub-block runs under ``jax.checkpoint``
+    (:func:`_recomputed`, as :class:`Looped`'s applications do): ``n``
+    streams hold ``n`` times a plain stack's residual, and the maps, the
+    mixed input, ``y`` and the body's inside beside them for every
+    sub-block; the backward keeps a sub-block's input ``X`` and what the
+    kernels wrote and name, and runs the rest again.  A property of the
+    container, not an option."""
+
+    def __init__(self, features: int, body: Module, streams: int = 4,
+                 sinkhorn_iters: int = 20, hc_eps: float = 1e-6,
+                 res_clamp: Sequence[float] = (-30.0, 30.0),
+                 eps: float = 1e-6, expand: bool = False,
+                 reduce: bool = False):
+        if int(streams) < 1 or int(sinkhorn_iters) < 0:
+            raise ValueError("hyperconnected needs streams >= 1 and "
+                             "sinkhorn_iters >= 0")
+        self.features, self.body = int(features), body
+        self.streams, self.sinkhorn_iters = int(streams), int(sinkhorn_iters)
+        self.hc_eps, self.eps = float(hc_eps), float(eps)
+        self.res_clamp = (float(res_clamp[0]), float(res_clamp[1]))
+        self.expand, self.reduce = bool(expand), bool(reduce)
+
+    def children(self):
+        return [("body", self.body)]
+
+    @property
+    def maps(self) -> int:
+        return 2 * self.streams + self.streams ** 2
+
+    def param_shapes(self):
+        return {"phi.weight": (self.maps, self.streams * self.features),
+                "alpha": (3,), "bias": (self.maps,)}
+
+    def init(self, rng):
+        """Phi N(0, 0.02); the dynamic part enters at ``alpha`` 0.01, so
+        that at the start the maps are their static parts: H_pre 1/2,
+        H_post 1, H_res Sinkhorn of 4·I (near the identity)."""
+        n = self.streams
+        bias = jnp.concatenate([jnp.zeros((2 * n,), jnp.float32),
+                                4.0 * jnp.eye(n).reshape(-1)])
+        return {self.key("phi.weight"): 0.02 * jax.random.normal(
+                    rng, self.param_shapes()["phi.weight"], jnp.float32),
+                self.key("alpha"): jnp.full((3,), 0.01, jnp.float32),
+                self.key("bias"): bias}
+
+    def maps_of(self, X, ctx):
+        """``(H_pre (n, tokens), H_post (n, tokens), H_res (n, n, tokens))``
+        in float32 of ``X`` ``(B, T, n, d)``."""
+        B, T, n, d = X.shape
+        flat = X.reshape(B * T, n * d)
+        # the token's 1/rms scales the product: x̃ itself is never written
+        r = jax.lax.rsqrt(jnp.mean(jnp.square(flat.astype(jnp.float32)),
+                                   axis=-1) + self.eps)
+        u = jnp.matmul(flat, self._p(ctx, "phi.weight").T,
+                       preferred_element_type=jnp.float32)
+        u = (u * r[:, None]).T                              # (maps, tokens)
+        alpha = ctx.params[self.key("alpha")].astype(jnp.float32)
+        bias = ctx.params[self.key("bias")].astype(jnp.float32)[:, None]
+        pre = jax.nn.sigmoid(alpha[0] * u[:n] + bias[:n])
+        post = 2.0 * jax.nn.sigmoid(alpha[1] * u[n:2 * n] + bias[n:2 * n])
+        res = jnp.clip(alpha[2] * u[2 * n:] + bias[2 * n:], *self.res_clamp)
+        res = sinkhorn(res.reshape(n, n, B * T), self.sinkhorn_iters,
+                       self.hc_eps)
+        return pre, post, res
+
+    def _mix(self, X, ctx):
+        B, T, n, d = X.shape
+        pre, post, res = self.maps_of(X, ctx)
+        ctx.note_max("hc_sinkhorn_err",
+                     jnp.max(jnp.abs(jnp.sum(res, axis=0) - 1.0)))
+        # a token's scalar against its d lanes: (B, T, 1) columns
+        col = lambda t: t.reshape(B, T, 1)
+        streams = [X[:, :, j, :].astype(jnp.float32) for j in range(n)]
+        x_in = sum(col(pre[i]) * streams[i] for i in range(n))
+        y = self.body.apply(x_in.astype(X.dtype), ctx).astype(jnp.float32)
+        new = [sum(col(res[i, j]) * streams[j] for j in range(n))
+               + col(post[i]) * y for i in range(n)]
+        if self.reduce:
+            return sum(new).astype(X.dtype)
+        return jnp.stack(new, axis=2).astype(X.dtype)
+
+    def _apply(self, x, ctx):
+        n = self.streams
+        if self.expand:
+            x = jnp.broadcast_to(x[:, :, None, :],
+                                 x.shape[:2] + (n, x.shape[-1]))
+        if x.ndim != 4 or x.shape[2] != n or x.shape[3] != self.features:
+            raise ValueError(
+                f"hyperconnected takes (B, T, {n}, {self.features}) streams "
+                f"(or (B, T, {self.features}) with expand), got {x.shape}")
+        return self._mix(x, ctx)
+
+    def apply(self, x, ctx):
+        return _recomputed(lambda inner, h: self._apply(h, inner), ctx,
+                           [self], x)
 
 
 def _kept_names() -> tuple:
-    """What :class:`Looped`'s recomputation keeps: the outputs its kernels'
-    callers name (``checkpoint_name``)."""
+    """What a recomputation (:func:`_recomputed`) keeps: the outputs the
+    kernels' callers name (``checkpoint_name``)."""
     from penroz_tpu.ops import losses
     from penroz_tpu.ops.pallas import flash_attention as fa
     return fa.OUT_NAME, fa.LSE_NAME, losses.LSE_NAME
+
+
+def _recomputed(fn, ctx, mods, *args):
+    """``fn(ctx, *args)``; in training under ``jax.checkpoint``, as a
+    function of ``mods``' own parameters and ``args``: the backward keeps
+    ``args`` and what the kernels' callers name (:func:`_kept_names`) and
+    runs the rest of the inside again.  What the inside leaves on its
+    context (auxiliary losses, buffer updates, the routing counters, the
+    largest values, the dropout counter) is handed on to ``ctx``."""
+    if not ctx.training:
+        return fn(ctx, *args)
+    prefixes = tuple(m.prefix + "." for m in mods)
+    own = {k: v for k, v in ctx.params.items() if k.startswith(prefixes)}
+    counter = [ctx._rng_counter]
+
+    def pure(params, rng, *inputs):
+        inner = copy.copy(ctx)
+        inner.params, inner.rng = params, rng
+        inner.aux_losses, inner.buffer_updates = [], {}
+        inner.moe_stats, inner.max_stats = None, {}
+        out = fn(inner, *inputs)
+        counter[0] = inner._rng_counter
+        return (out, inner.aux_losses, inner.buffer_updates,
+                inner.moe_stats or {}, inner.max_stats)
+
+    keep = jax.checkpoint_policies.save_only_these_names(*_kept_names())
+    out, aux, updates, routed, largest = jax.checkpoint(pure, policy=keep)(
+        own, ctx.rng, *args)
+    ctx._rng_counter = counter[0]
+    ctx.aux_losses.extend(aux)
+    ctx.buffer_updates.update(updates)
+    if routed:
+        ctx.moe_stats = {name: value + (ctx.moe_stats or {}).get(name, 0.0)
+                         for name, value in routed.items()}
+    for name, value in largest.items():
+        ctx.note_max(name, value)
+    return out
 
 
 class Looped(Module):
@@ -788,33 +1000,6 @@ class Looped(Module):
                 "cache_slots": self.steps * self.slots_per_pass,
                 "kept_outputs": ",".join(_kept_names()) if training else ""}
 
-    def _run(self, fn, ctx, mods, *args):
-        """``fn(ctx, *args)``; in training under ``jax.checkpoint``, as a
-        function of ``mods``' own parameters and ``args``: what the inside
-        leaves on its context (auxiliary losses, buffer updates, the dropout
-        counter) is handed on to ``ctx``."""
-        if not ctx.training:
-            return fn(ctx, *args)
-        prefixes = tuple(m.prefix + "." for m in mods)
-        own = {k: v for k, v in ctx.params.items() if k.startswith(prefixes)}
-        counter = [ctx._rng_counter]
-
-        def pure(params, rng, *inputs):
-            inner = copy.copy(ctx)
-            inner.params, inner.rng = params, rng
-            inner.aux_losses, inner.buffer_updates = [], {}
-            out = fn(inner, *inputs)
-            counter[0] = inner._rng_counter
-            return out, inner.aux_losses, inner.buffer_updates
-
-        keep = jax.checkpoint_policies.save_only_these_names(*_kept_names())
-        out, aux, updates = jax.checkpoint(pure, policy=keep)(
-            own, ctx.rng, *args)
-        ctx._rng_counter = counter[0]
-        ctx.aux_losses.extend(aux)
-        ctx.buffer_updates.update(updates)
-        return out
-
     def _exit(self, ctx, h, targets):
         """One exit: (per-token cross-entropy, gate logit), fp32."""
         from penroz_tpu.ops import losses
@@ -832,17 +1017,14 @@ class Looped(Module):
                    else None)
 
     def apply(self, x, ctx):
-        plan = self.plan(ctx.training)
-        _log_loop_plan(**plan)
-        with tracing.span("penroz/loop_plan", **plan):
-            pass
+        _record_plan("loop", **self.plan(ctx.training))
         u, exits = x, []
         for t in range(self.steps):
             ctx.layer_offset = t * self.slots_per_pass
             for block in self.body[:-1]:
-                u = self._run(lambda inner, h, b=block: b.apply(h, inner),
-                              ctx, [block], u)
-            u, out = self._run(
+                u = _recomputed(lambda inner, h, b=block: b.apply(h, inner),
+                                ctx, [block], u)
+            u, out = _recomputed(
                 self._pass_end, ctx,
                 [self.body[-1], self.norm, self.head, self.gate], u,
                 ctx.targets)
@@ -911,21 +1093,13 @@ _top_k.defvjp(_top_k_fwd, _top_k_bwd)
 # grouped products computed (the groups padded to whole tiles), the fullest
 # held expert's rows (the per-layer maxima summed), pairs that found no row.
 MOE_COUNTERS = ("moe_rows", "moe_rows_padded", "moe_load_max", "moe_dropped")
-
-
-@functools.lru_cache(maxsize=None)
-def _log_moe_plan(**plan) -> None:
-    """Once per distinct plan of the process."""
-    log.info("moe plan: %s", " ".join(f"{k}={v}" for k, v in plan.items()))
-
-
-def _record_moe_plan(**plan) -> None:
-    """As ``penroz/flash_plan``: an INFO line per distinct plan and, each
-    time a program traces a dropless layer, a ``penroz/moe_plan`` span under
-    whatever span is compiling."""
-    _log_moe_plan(**plan)
-    with tracing.span("penroz/moe_plan", **plan):
-        pass
+# What a call's modules report as a largest value, the largest over the
+# layers of a model and the micro-steps of an epoch (the same three places):
+# the largest |column sum - 1| of a multi-stream residual's mixing matrix
+# after its last Sinkhorn iteration (:class:`HyperConnected`), and the
+# largest magnitude of a router's selection bias
+# (:class:`MixtureOfExperts` with ``selection_bias``).
+MAX_COUNTERS = ("hc_sinkhorn_err", "moe_bias_absmax")
 
 
 class _DroplessConfig(NamedTuple):
@@ -1193,7 +1367,33 @@ class MixtureOfExperts(Module):
                  dispatch: str = "dense", capacity_factor: float = 1.25,
                  norm_topk: bool = True, shared_expert_size: int = 0,
                  experts_held: Optional[int] = None, first_expert: int = 0,
-                 routed_scale: float = 1.0, shared_expert_gate: bool = True):
+                 routed_scale: float = 1.0, shared_expert_gate: bool = True,
+                 scoring: str = "softmax", selection_bias: bool = False,
+                 selection_bias_init: Optional[Sequence[float]] = None,
+                 bias_update_rate: float = 0.001):
+        if scoring not in ("softmax", "sigmoid"):
+            raise ValueError(f"scoring must be 'softmax' or 'sigmoid', "
+                             f"got {scoring!r}")
+        # ``scoring="sigmoid"``: an expert's score is sigmoid(logit), each
+        # on its own (DeepSeek-V3's router).  ``selection_bias``: a buffer
+        # b (num_experts,) is added to the scores for the *choice* of the
+        # top-k alone; the weights are the scores of the chosen, without
+        # it.  No gradient reaches b: once an optimizer step it moves by
+        # ``bias_update_rate`` towards balance, b += rate · sign(mean load
+        # - load) over the step's tokens (loss-free balancing,
+        # arXiv:2408.15664).  ``selection_bias_init``: its first value
+        # (default zeros).
+        self.scoring = scoring
+        self.selection_bias = bool(selection_bias)
+        self.bias_update_rate = float(bias_update_rate)
+        if selection_bias_init is not None and (
+                not selection_bias
+                or len(selection_bias_init) != num_experts):
+            raise ValueError("selection_bias_init gives one value an expert "
+                             "of a router with selection_bias")
+        self.selection_bias_init = (
+            None if selection_bias_init is None
+            else tuple(float(b) for b in selection_bias_init))
         if top_k < 1 or top_k > num_experts:
             raise ValueError(f"top_k={top_k} outside [1, {num_experts}]")
         if bias:
@@ -1280,11 +1480,28 @@ class MixtureOfExperts(Module):
     def init_buffers(self):
         # Latest per-expert routing fraction (observability; updated each
         # training step like BatchNorm running stats).
-        return {self.key("router_fraction"):
-                jnp.zeros((self.num_experts,), jnp.float32)}
+        zeros = jnp.zeros((self.num_experts,), jnp.float32)
+        buffers = {self.key("router_fraction"): zeros}
+        if self.selection_bias:
+            # the bias, and the (token, choice) pairs each expert got in
+            # the optimizer step so far
+            buffers[self.key("selection_bias")] = (
+                zeros if self.selection_bias_init is None
+                else jnp.asarray(self.selection_bias_init, jnp.float32))
+            buffers[self.key("selection_load")] = zeros
+        return buffers
+
+    def end_step(self, buffers):
+        if not self.selection_bias:
+            return {}
+        load = buffers[self.key("selection_load")]
+        bias = buffers[self.key("selection_bias")]
+        return {self.key("selection_bias"): bias + self.bias_update_rate
+                * jnp.sign(jnp.mean(load) - load),
+                self.key("selection_load"): jnp.zeros_like(load)}
 
     def router_weights(self, x, ctx):
-        """(B, T, held) combine weights of the experts held: softmax over
+        """(B, T, held) combine weights of the experts held: scores over
         all → top-k → renormalize → scale."""
         top_vals, top_idx = self.route(x, ctx)
         one_hot = jax.nn.one_hot(top_idx, self.num_experts,
@@ -1294,9 +1511,11 @@ class MixtureOfExperts(Module):
         return weights[..., first:first + self.experts_held]
 
     def route(self, x, ctx):
-        """``(weights, experts)``, both (B, T, top_k): softmax over all
-        ``num_experts`` → top-k → renormalize (``norm_topk``) → times
-        ``routed_scale``.
+        """``(weights, experts)``, both (B, T, top_k): scores over all
+        ``num_experts`` (``scoring``: softmax, or a sigmoid an expert) →
+        top-k, of the scores or with ``selection_bias`` of scores + bias
+        (the weights stay the scores of the chosen) → renormalize
+        (``norm_topk``) → times ``routed_scale``.
 
         Routing runs entirely in fp32 — logits einsum included: bf16
         rounding before the (monotonic) softmax still flips expert choices
@@ -1304,8 +1523,22 @@ class MixtureOfExperts(Module):
         router = ctx.params[self.key("router.weight")]
         logits = jnp.einsum("btd,ed->bte", x.astype(jnp.float32),
                             router.astype(jnp.float32))
-        probs = jax.nn.softmax(logits, axis=-1)
-        top_vals, top_idx = _top_k(probs, self.top_k)
+        probs = (jax.nn.sigmoid(logits) if self.scoring == "sigmoid"
+                 else jax.nn.softmax(logits, axis=-1))
+        if self.selection_bias:
+            bias = ctx.buffers[self.key("selection_bias")]
+            ctx.note_max("moe_bias_absmax", jnp.max(jnp.abs(bias)))
+            _, top_idx = jax.lax.top_k(
+                jax.lax.stop_gradient(probs + bias.astype(jnp.float32)),
+                self.top_k)
+            # the chosen experts' scores by a compare and a sum, forward
+            # and backward (:func:`_top_k`'s reason: no scatter)
+            hit = top_idx[..., None] == jnp.arange(self.num_experts,
+                                                   dtype=top_idx.dtype)
+            top_vals = jnp.sum(jnp.where(hit, probs[..., None, :], 0.0),
+                               axis=-1)
+        else:
+            top_vals, top_idx = _top_k(probs, self.top_k)
         if self.norm_topk:
             top_vals = top_vals / jnp.sum(top_vals, axis=-1, keepdims=True)
         if ctx.training:
@@ -1318,6 +1551,10 @@ class MixtureOfExperts(Module):
             mean_probs = jnp.mean(probs, axis=(0, 1))
             ctx.buffer_updates[self.key("router_fraction")] = \
                 fractions / self.top_k
+            if self.selection_bias:
+                load = self.key("selection_load")
+                ctx.buffer_updates[load] = ctx.buffers[load] + \
+                    fractions * (top_idx.shape[0] * top_idx.shape[1])
             if self.aux_loss_coef > 0.0:
                 aux = self.num_experts * jnp.sum(
                     (fractions / self.top_k) * mean_probs)
@@ -1438,7 +1675,7 @@ class MixtureOfExperts(Module):
         tokens, held = B * T, self.experts_held
         on_tpu = attn_ops._tpu_platform(x, ctx.platform)
         plan = self.dropless_plan(tokens, on_tpu)
-        _record_moe_plan(**plan)
+        _record_plan("moe", **plan)
         hit, chosen, weight = _held_choices(
             top_vals.reshape(tokens, self.top_k),
             top_idx.reshape(tokens, self.top_k), self.first_expert, held)
@@ -1996,6 +2233,132 @@ class CausalSelfAttention(Module):
                                             softcap=self.logit_softcap)
 
         return out.transpose(0, 2, 1, 3).reshape(B, T, q_dim)
+
+
+class LatentAttention(Module):
+    """Multi-head latent attention (DeepSeek-V2, arXiv:2405.04434 §2.1) on a
+    normed ``(B, T, in_features)`` input, with its own projections::
+
+        c_q = RMSNorm(x W_qa)  (q_rank);   [q_nope | q_rope] = c_q W_qb
+        [c_kv | k_rope] = x W_kva  (kv_rank + d_rope);  c_kv = RMSNorm(c_kv)
+        [k_nope | v] = c_kv W_kvb;   k_rope ONE vector a token, all heads'
+        RoPE (rotate-half) on q_rope and k_rope over ``d_rope`` dims
+        o = causal softmax([q_nope | q_rope] · [k_nope | k_rope] · scale) v
+        out = concat(o) W_o
+
+    a head ``d_nope + d_rope`` wide for the scores and ``d_v`` for the
+    values; W_qb's rows a head [nope | rope], W_kvb's a head [nope | v],
+    heads contiguous; no bias.  ``rope_scaling`` of type ``yarn`` blends the
+    frequencies (``ops/attention.py::_yarn_inv_freq``) and carries its
+    factor on the scores: ``scale = (d_nope + d_rope)^-½ ·
+    m(mscale_all_dim)²`` with ``m(s) = 0.1 · s · ln(factor) + 1``, cos and
+    sin times ``m(mscale) / m(mscale_all_dim)``.
+
+    This is the *expanded* form, what training and an uncached forward
+    take: every head's keys and values are made from the latent vector and
+    go through ``ops/attention.py::causal_attention`` (the flash kernels at
+    unlike score and value widths on a TPU).  The absorbed form, scores
+    against ``c_kv`` itself, is the cache's and is not written: a KV cache
+    refuses this module (``CompiledArch.refuse_latent``)."""
+
+    def __init__(self, in_features: int, num_heads: int, q_rank: int,
+                 kv_rank: int, d_nope: int, d_rope: int, d_v: int,
+                 rope_theta: float = 10000.0,
+                 rope_scaling: Optional[dict] = None, eps: float = 1e-6,
+                 init_std: float = 0.02,
+                 out_init_std: Optional[float] = None):
+        self.in_features, self.num_heads = int(in_features), int(num_heads)
+        self.q_rank, self.kv_rank = int(q_rank), int(kv_rank)
+        self.d_nope, self.d_rope, self.d_v = int(d_nope), int(d_rope), int(d_v)
+        if self.d_rope % 2:
+            raise ValueError(f"d_rope must be even, got {d_rope}")
+        self.rope_theta = float(rope_theta)
+        # the two norms of the bottlenecks: ``q_a_norm``, ``kv_a_norm``
+        self.q_a_norm = RMSNorm(self.q_rank, eps)
+        self.kv_a_norm = RMSNorm(self.kv_rank, eps)
+        self.init_std = float(init_std)
+        self.out_init_std = float(init_std if out_init_std is None
+                                  else out_init_std)
+        self.softmax_scale = (self.d_nope + self.d_rope) ** -0.5
+        self.rope_scaling = None
+        if rope_scaling:
+            kind = rope_scaling.get("rope_type") or rope_scaling.get("type")
+            if kind != "yarn":
+                raise ValueError(f"latentattention takes rope_scaling of "
+                                 f"type 'yarn' or none, got {kind!r}")
+            factor = float(rope_scaling["factor"])
+
+            def m(s):
+                return 0.1 * float(s) * math.log(factor) + 1.0 \
+                    if factor > 1 else 1.0
+
+            all_dim = m(rope_scaling.get("mscale_all_dim", 0))
+            self.softmax_scale *= all_dim * all_dim
+            self.rope_scaling = attn_ops.yarn_scaling({
+                **{k: rope_scaling[k] for k in (
+                    "factor", "original_max_position_embeddings",
+                    "beta_fast", "beta_slow") if k in rope_scaling},
+                "attention_factor": m(rope_scaling.get("mscale", 1))
+                / all_dim})
+
+    def children(self):
+        return [("q_a_norm", self.q_a_norm), ("kv_a_norm", self.kv_a_norm)]
+
+    def param_shapes(self):
+        d, H = self.in_features, self.num_heads
+        return {
+            "q_a_proj.weight": (self.q_rank, d),
+            "q_b_proj.weight": (H * (self.d_nope + self.d_rope), self.q_rank),
+            "kv_a_proj.weight": (self.kv_rank + self.d_rope, d),
+            "kv_b_proj.weight": (H * (self.d_nope + self.d_v), self.kv_rank),
+            "o_proj.weight": (d, H * self.d_v)}
+
+    def init(self, rng):
+        shapes = self.param_shapes()
+        keys = jax.random.split(rng, len(shapes))
+        out = {}
+        for k, (name, shape) in zip(keys, shapes.items()):
+            std = (self.out_init_std if name == "o_proj.weight"
+                   else self.init_std)
+            out[self.key(name)] = std * jax.random.normal(k, shape,
+                                                          jnp.float32)
+        return out
+
+    def apply(self, x, ctx):
+        if ctx.kv is not None:
+            raise ValueError("latent attention has no KV-cache path (the "
+                             "absorbed form is not written)")
+        B, T, _ = x.shape
+        H, dn, dr, dv = self.num_heads, self.d_nope, self.d_rope, self.d_v
+        _record_plan("latent", heads=H, q_rank=self.q_rank,
+                     kv_rank=self.kv_rank, d_nope=dn, d_rope=dr, d_v=dv,
+                     softmax_scale=round(self.softmax_scale, 6), T=T,
+                     path="expanded")
+        c_q = self.q_a_norm.apply(
+            jnp.matmul(x, self._p(ctx, "q_a_proj.weight").T), ctx)
+        q = jnp.matmul(c_q, self._p(ctx, "q_b_proj.weight").T)
+        latent = jnp.matmul(x, self._p(ctx, "kv_a_proj.weight").T)
+        c_kv = self.kv_a_norm.apply(latent[..., :self.kv_rank], ctx)
+        kv = jnp.matmul(c_kv, self._p(ctx, "kv_b_proj.weight").T)
+        q = q.reshape(B, T, H, dn + dr)
+        kv = kv.reshape(B, T, H, dn + dv)
+        cos, sin = attn_ops.rope_cos_sin(dr, self.rope_theta, ctx.offset(),
+                                         T, x.dtype,
+                                         scaling=self.rope_scaling)
+        cos, sin = cos[None, :, None, :], sin[None, :, None, :]
+        turn = lambda t: t * cos + attn_ops._rotate_half(t) * sin
+        q_rope = turn(q[..., dn:])
+        k_rope = turn(latent[:, :, None, self.kv_rank:])    # (B, T, 1, dr)
+        heads_first = lambda t: t.transpose(0, 2, 1, 3)
+        q = heads_first(jnp.concatenate([q[..., :dn], q_rope], axis=-1))
+        k = heads_first(jnp.concatenate(
+            [kv[..., :dn], jnp.broadcast_to(k_rope, (B, T, H, dr))],
+            axis=-1))
+        v = heads_first(kv[..., dn:])
+        o = attn_ops.causal_attention(q, k, v, platform=ctx.platform,
+                                      scale=self.softmax_scale)
+        o = heads_first(o).reshape(B, T, H * dv)
+        return jnp.matmul(o, self._p(ctx, "o_proj.weight").T)
 
 
 class GatedSSM(Module):

@@ -424,21 +424,27 @@ def _padded(rows: int, D: int, itemsize: int) -> int:
     return rows * -(-D // _LANES) * _LANES * itemsize
 
 
-def _fwd_resident_bytes(q_rows, S, D, itemsize, heads, kv_heads, bq, bk):
-    blocks = (2 * heads * _padded(q_rows, D, itemsize)          # q, o
-              + 2 * kv_heads * _padded(S, D, itemsize)          # k, v
+def _fwd_resident_bytes(q_rows, S, D, itemsize, heads, kv_heads, bq, bk,
+                        Dv=None):
+    """``D``: the width of q and k, ``Dv``: of v and o (default ``D``)."""
+    Dv = D if Dv is None else Dv
+    both = lambda rows, size: _padded(rows, D, size) + _padded(rows, Dv, size)
+    blocks = (heads * both(q_rows, itemsize)                    # q, o
+              + kv_heads * both(S, itemsize)                    # k, v
               + heads * _padded(q_rows, 1, 4))                  # lse (…, 1)
-    scratch = 2 * bq * _LANES * 4 + _padded(bq, D, 4)
+    scratch = 2 * bq * _LANES * 4 + _padded(bq, Dv, 4)
     return 2 * blocks + scratch + 4 * bq * bk * 4
 
 
-def _bwd_fused_bytes(T, S, D, itemsize, heads, kv_heads, bq, bk):
-    blocks = (3 * heads * _padded(T, D, itemsize)               # q, dO, dq
-              + 2 * kv_heads * _padded(S, D, itemsize)          # k, v
-              + 2 * heads * _padded(S, D, itemsize)             # dk, dv
+def _bwd_fused_bytes(T, S, D, itemsize, heads, kv_heads, bq, bk, Dv=None):
+    Dv = D if Dv is None else Dv
+    both = lambda rows, size: _padded(rows, D, size) + _padded(rows, Dv, size)
+    blocks = (heads * (_padded(T, D, itemsize) + both(T, itemsize))  # q, dq, dO
+              + kv_heads * both(S, itemsize)                    # k, v
+              + heads * both(S, itemsize)                       # dk, dv
               + 2 * heads * 8 * T * 4)                          # lse, δ rows
     scratch = (_padded(T, D, 4) + _padded(T, D, itemsize)       # dq, scaled q
-               + 8 * T * 4 + 2 * _padded(bk, D, 4))     # lse and δ; dk, dv
+               + 8 * T * 4 + both(bk, 4))               # lse and δ; dk, dv
     return 2 * blocks + scratch + 6 * bq * bk * 4
 
 
@@ -473,10 +479,16 @@ def plan_flash(T: int, S: int, D: int, itemsize: int, causal: bool = True,
                window=None, *, heads: int = 1, group: int = 1,
                block_q: int | None = None, block_k: int | None = None,
                vmem_budget: int = VMEM_BUDGET, layout: str = "bhtd",
-               fused_qkv: bool = False) -> FlashPlan:
+               fused_qkv: bool = False, Dv: int | None = None) -> FlashPlan:
     """The plan for ``(T, S, D, itemsize, causal, window)`` under
     ``vmem_budget`` bytes; ``heads`` query heads in groups of ``group`` per
-    K/V head bound the heads a grid step may own.  ``block_q``/``block_k``
+    K/V head bound the heads a grid step may own.  ``Dv`` (default ``D``):
+    the width of v and o where it is not that of q and k (latent attention:
+    192-wide scores over 128-wide values).  Such a pair takes the ``bhtd``
+    layout and one product over the whole score width: a head 192 lanes
+    wide starts on a 128-lane boundary every other head only, so lane blocks
+    of ``(B, T, H·D)`` arrays cannot address it, and a ``(rows, 192)``
+    operand pads to the 256 lanes two passes of the MXU take either way.  ``block_q``/``block_k``
     override the tile sizes of both directions (tests).  ``layout="btd"``:
     a step owns one lane block — ``max(D, 128)`` lanes, ``heads_per_block``
     heads — whose VMEM is that of one head as wide as the block.
@@ -487,9 +499,14 @@ def plan_flash(T: int, S: int, D: int, itemsize: int, causal: bool = True,
     that narrows the budget below the default (tests, to reach the chunked
     and split kernels at small shapes) narrows that with it."""
     heads_per_block = 1
+    Dv = D if Dv is None else Dv
     if layout == "btd":
+        if Dv != D:
+            raise ValueError(f"the btd layout takes one head width, got "
+                             f"D={D}, Dv={Dv}")
         heads_per_block = _heads_per_block(D)
         D, heads, group = D * heads_per_block, 1, 1
+        Dv = D
     fq, fk = (block_q or _FWD_TILE[0]), (block_k or _FWD_TILE[1])
     gq, gk = (block_q or _BWD_TILE[0]), (block_k or _BWD_TILE[1])
     fq, gq = _largest_dividing_block(T, fq), _largest_dividing_block(T, gq)
@@ -508,11 +525,12 @@ def plan_flash(T: int, S: int, D: int, itemsize: int, causal: bool = True,
     def fwd_fits(hps, q_rows=T):
         return _fwd_resident_bytes(
             q_rows, S, D, itemsize, hps, _kv_heads_per_step(hps, group),
-            fq, fk) <= vmem_budget
+            fq, fk, Dv) <= vmem_budget
 
     def bwd_bytes(hps):
         return _bwd_fused_bytes(
-            T, S, D, itemsize, hps, _kv_heads_per_step(hps, group), gq, gk)
+            T, S, D, itemsize, hps, _kv_heads_per_step(hps, group), gq, gk,
+            Dv)
 
     fused_bwd = (_asked(bwd_bytes(1)) <= VMEM_LIMIT
                  if vmem_budget >= VMEM_BUDGET
@@ -557,18 +575,22 @@ def plan_flash(T: int, S: int, D: int, itemsize: int, causal: bool = True,
 
 
 @functools.lru_cache(maxsize=None)
-def _log_plan(T, S, D, plan: FlashPlan) -> None:
-    """Once per distinct (shape, plan) of the process."""
-    log.info("flash plan: T=%d S=%d D=%d %s", T, S, D, plan.describe())
+def _log_plan(T, S, D, Dv, plan: FlashPlan) -> None:
+    """Once per distinct (shape, plan) of the process; ``Dv`` is named where
+    it is not ``D``."""
+    widths = f"D={D}" if Dv == D else f"D={D} Dv={Dv}"
+    log.info("flash plan: T=%d S=%d %s %s", T, S, widths, plan.describe())
 
 
-def _record_plan(T, S, D, plan: FlashPlan) -> None:
+def _record_plan(T, S, D, plan: FlashPlan, Dv: int | None = None) -> None:
     """The counter that says which plan a traced program was built with:
     an INFO line per distinct (shape, plan) and, each time a program traces
     an attention layer, a ``penroz/flash_plan`` span under whatever span is
     compiling (a /train/ job's first epochs, beside ``penroz/compile``)."""
-    _log_plan(T, S, D, plan)
-    with tracing.span("penroz/flash_plan", T=T, S=S, D=D, **dataclasses.asdict(plan)):
+    Dv = D if Dv is None else Dv
+    _log_plan(T, S, D, Dv, plan)
+    with tracing.span("penroz/flash_plan", T=T, S=S, D=D, Dv=Dv,
+                      **dataclasses.asdict(plan)):
         pass
 
 
@@ -593,6 +615,7 @@ class _HeadMajor:
     D: int
     heads: int
     kv_heads: int
+    Dv: int | None = None   # width of v, o, dO and dv; None: D
 
     layout = "bhtd"
     hpb = 1
@@ -605,6 +628,11 @@ class _HeadMajor:
     @property
     def width(self) -> int:
         return self.D
+
+    @property
+    def vwidth(self) -> int:
+        """Lanes of a value-side block or scratch (v, o, dO, dv)."""
+        return self.D if self.Dv is None else self.Dv
 
     # -- inside a kernel ----------------------------------------------------
 
@@ -653,20 +681,27 @@ class _HeadMajor:
     # -- BlockSpecs ---------------------------------------------------------
 
     def spec(self, heads, rows, at, operand=None):
-        """A query-side operand's block (``operand``: see
-        :meth:`_LaneBlocks.spec`)."""
+        """A query-head array's block as wide as q (q, dq, the per-head dk;
+        ``operand``: see :meth:`_LaneBlocks.spec`)."""
         return pl.BlockSpec((1, heads, rows, self.D),
                             lambda *g: (*at(*g), 0))
 
+    def vspec(self, heads, rows, at):
+        """… as wide as v (o, dO, the per-head dv)."""
+        return pl.BlockSpec((1, heads, rows, self.vwidth),
+                            lambda *g: (*at(*g), 0))
+
     def kv_spec(self, heads, rows, at, operand):
-        """K or V: the K/V heads of the step's query heads."""
+        """K (``operand`` 1) or V (2): the K/V heads of the step's query
+        heads."""
         kvh = _kv_heads_per_step(heads, self.group)
 
         def index(*g):
             b, h, r = at(*g)
             return b, h * heads // (self.group * kvh), r, 0
 
-        return pl.BlockSpec((1, kvh, rows, self.D), index)
+        return pl.BlockSpec(
+            (1, kvh, rows, self.D if operand == 1 else self.vwidth), index)
 
     def stat_spec(self, heads, rows, at, as_rows: bool):
         if as_rows:
@@ -681,8 +716,13 @@ class _HeadMajor:
         return q.shape[0], q.shape[2], k.shape[2]
 
     def like_q(self, B, rows):
-        """Shape of an array with ``rows`` positions of every query head."""
+        """Shape of an array with ``rows`` positions of every query head, as
+        wide as q."""
         return B, self.heads, rows, self.D
+
+    def like_v(self, B, rows):
+        """… as wide as v."""
+        return B, self.heads, rows, self.vwidth
 
     def lse_shape(self, B, T):
         return B, self.heads, T, 1
@@ -733,6 +773,8 @@ class _LaneBlocks:
     @property
     def width(self) -> int:
         return self.D * self.hpb
+
+    vwidth = width          # one head width in this layout
 
     @property
     def offsets(self) -> tuple:
@@ -815,6 +857,9 @@ class _LaneBlocks:
         first = 0 if operand is None else self.offsets[operand]
         return self._lane_block(rows, at, first, lambda h: h)
 
+    def vspec(self, heads, rows, at):
+        return self.spec(heads, rows, at)
+
     def kv_spec(self, heads, rows, at, operand):
         """K (``operand`` 1) or V (2)."""
         return self._lane_block(
@@ -832,6 +877,8 @@ class _LaneBlocks:
 
     def like_q(self, B, rows):
         return B, rows, self.heads * self.D
+
+    like_v = like_q
 
     def lse_shape(self, B, T):
         return B, self.heads // self.hpb, self.hpb, T
@@ -1183,8 +1230,10 @@ def _clamped(ranges_fn, *args):
     return clamp
 
 
-def _bhtd(q, k) -> _HeadMajor:
-    return _HeadMajor(D=q.shape[-1], heads=q.shape[1], kv_heads=k.shape[1])
+def _bhtd(q, k, v) -> _HeadMajor:
+    Dv = v.shape[-1]
+    return _HeadMajor(D=q.shape[-1], heads=q.shape[1], kv_heads=k.shape[1],
+                      Dv=None if Dv == q.shape[-1] else Dv)
 
 
 def _flash_forward(q, k, v, causal: bool = True,
@@ -1195,14 +1244,15 @@ def _flash_forward(q, k, v, causal: bool = True,
                    plan: FlashPlan | None = None, ix=None):
     """The forward call.  ``ix`` None: q ``(B, Hq, T, D)``, k/v ``(B, Hkv,
     S, D)``; a ``btd`` indexer: ``(B, T, ·)`` arrays (module docstring)."""
-    ix = ix or _bhtd(q, k)
+    ix = ix or _bhtd(q, k, v)
     B, T, S = ix.dims(q, k)
     D, Hq = ix.D, ix.heads
+    Dv = ix.vwidth // ix.hpb
     if plan is None:
         plan = plan_flash(T, S, D, q.dtype.itemsize, causal, window,
                           heads=Hq, group=ix.group, block_q=block_q,
                           block_k=block_k,
-                          layout=ix.layout)
+                          layout=ix.layout, Dv=Dv)
     block_q, block_k = plan.block_q, plan.block_k
     sm_scale = float(scale) if scale is not None else 1.0 / (D ** 0.5)
     num_k = S // block_k
@@ -1213,7 +1263,7 @@ def _flash_forward(q, k, v, causal: bool = True,
                   dropout_rate=dropout_rate, window=window,
                   use_alibi=alibi is not None, grain=plan.diag_grain)
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
-    stats = [(block_q, _LANES), (block_q, _LANES), (block_q, ix.width)]
+    stats = [(block_q, _LANES), (block_q, _LANES), (block_q, ix.vwidth)]
     if plan.resident:
         kernel = functools.partial(_fwd_resident_kernel, **common)
         grid = (B, Hq // hps, T // plan.q_rows)
@@ -1241,18 +1291,18 @@ def _flash_forward(q, k, v, causal: bool = True,
         grid=grid,
         in_specs=[smem, smem, q_spec, ix.kv_spec(hps, kv_rows, kv_at, 1),
                   ix.kv_spec(hps, kv_rows, kv_at, 2)],
-        out_specs=[ix.spec(hps, q_rows, at),
+        out_specs=[ix.vspec(hps, q_rows, at),
                    ix.stat_spec(hps, q_rows, at, as_rows=False)],
         out_shape=[
-            jax.ShapeDtypeStruct(ix.like_q(B, T), q.dtype),
+            jax.ShapeDtypeStruct(ix.like_v(B, T), q.dtype),
             jax.ShapeDtypeStruct(ix.lse_shape(B, T), jnp.float32),
         ],
         scratch_shapes=scratch,
         compiler_params=pltpu.CompilerParams(dimension_semantics=semantics),
         cost_estimate=pl.CostEstimate(
-            flops=int(4 * B * Hq * T * S * D * _live_share(causal)),
-            bytes_accessed=int((2 * B * Hq * T + 2 * B * ix.kv_heads * S)
-                               * D * q.dtype.itemsize),
+            flops=int(2 * (D + Dv) * B * Hq * T * S * _live_share(causal)),
+            bytes_accessed=int((B * Hq * T + B * ix.kv_heads * S)
+                               * (D + Dv) * q.dtype.itemsize),
             transcendentals=int(B * Hq * T * S * _live_share(causal))),
         interpret=interpret,
         name="penroz_flash_fwd",
@@ -1511,9 +1561,10 @@ def _flash_backward(q, k, v, out, lse, g, causal: bool, plan: FlashPlan,
                     window=None, alibi=None, scale=None, ix=None):
     """``(dq, dk, dv)`` shaped like q, k, v (of their own arrays in the
     ``btd`` layout, whatever array the forward read them from)."""
-    ix = ix or _bhtd(q, k)
+    ix = ix or _bhtd(q, k, v)
     B, T, S = ix.dims(q, k)
     D, Hq, Hkv, group = ix.D, ix.heads, ix.kv_heads, ix.group
+    Dv = ix.vwidth // ix.hpb
     block_q, block_k = plan.bwd_block_q, plan.bwd_block_k
     sm_scale = float(scale) if scale is not None else 1.0 / (D ** 0.5)
     num_q, num_k = T // block_q, S // block_k
@@ -1529,35 +1580,36 @@ def _flash_backward(q, k, v, out, lse, g, causal: bool, plan: FlashPlan,
                   dropout_rate=dropout_rate, window=window,
                   use_alibi=alibi is not None)
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
-    flops = int(10 * B * Hq * T * S * D * _live_share(causal))
+    flops = int(2 * (3 * D + 2 * Dv) * B * Hq * T * S * _live_share(causal))
     exps = int(B * Hq * T * S * _live_share(causal))
-    q_bytes, kv_bytes = B * Hq * T * D * itemsize, B * Hkv * S * D * itemsize
-    dkv_bytes = B * Hq * S * D * itemsize
+    # q, dO and dq; k and v; the per-query-head dk and dv
+    q_bytes = B * Hq * T * (2 * D + Dv) * itemsize
+    kv_bytes = B * Hkv * S * (D + Dv) * itemsize
+    dkv_bytes = B * Hq * S * (D + Dv) * itemsize
     dq_shape = jax.ShapeDtypeStruct(ix.like_q(B, T), q.dtype)
     dkv_shape = [jax.ShapeDtypeStruct(ix.like_q(B, S), k.dtype),
-                 jax.ShapeDtypeStruct(ix.like_q(B, S), v.dtype)]
-    width = ix.width
+                 jax.ShapeDtypeStruct(ix.like_v(B, S), v.dtype)]
+    width, vwidth = ix.width, ix.vwidth
 
     if plan.fused_bwd:
         at = lambda b, h: (b, h, 0)
         q_spec = ix.spec(hps, T, at, 0)
         own_spec = ix.spec(hps, T, at)
         row_spec = ix.stat_spec(hps, T, at, as_rows=True)
-        dkv_spec = ix.spec(hps, S, at)
         dq, dk_ph, dv_ph = pl.pallas_call(
             functools.partial(_bwd_fused_kernel, grain=plan.bwd_diag_grain,
                               **common),
             grid=(B, Hq // hps),
             in_specs=[smem, smem, q_spec, ix.kv_spec(hps, S, at, 1),
                       ix.kv_spec(hps, S, at, 2), row_spec, row_spec,
-                      own_spec],
-            out_specs=[own_spec, dkv_spec, dkv_spec],
+                      ix.vspec(hps, T, at)],
+            out_specs=[own_spec, ix.spec(hps, S, at), ix.vspec(hps, S, at)],
             out_shape=[dq_shape] + dkv_shape,
             scratch_shapes=[pltpu.VMEM((T, width), q.dtype),
                             pltpu.VMEM((T, width), jnp.float32),
                             pltpu.VMEM((2, T), jnp.float32),
                             pltpu.VMEM((block_k, width), jnp.float32),
-                            pltpu.VMEM((block_k, width), jnp.float32)],
+                            pltpu.VMEM((block_k, vwidth), jnp.float32)],
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel"),
                 # a plan past the compiler's default share says so; one
@@ -1567,7 +1619,7 @@ def _flash_backward(q, k, v, out, lse, g, causal: bool, plan: FlashPlan,
                                   else None)),
             cost_estimate=pl.CostEstimate(
                 flops=flops, transcendentals=exps,
-                bytes_accessed=3 * q_bytes + 2 * kv_bytes + 2 * dkv_bytes),
+                bytes_accessed=q_bytes + kv_bytes + dkv_bytes),
             interpret=interpret,
             name="penroz_flash_bwd",
         )(seed, alibi_arr, q, k, v, lse, delta, g)
@@ -1586,7 +1638,7 @@ def _flash_backward(q, k, v, out, lse, g, causal: bool, plan: FlashPlan,
             in_specs=[smem, smem, ix.spec(hps, block_q, at, 0),
                       ix.kv_spec(hps, block_k, kv_at, 1),
                       ix.kv_spec(hps, block_k, kv_at, 2), stat_spec,
-                      stat_spec, own_spec],
+                      stat_spec, ix.vspec(hps, block_q, at)],
             out_specs=own_spec,
             out_shape=dq_shape,
             scratch_shapes=[pltpu.VMEM(_per_head(hps, (block_q, width)),
@@ -1596,7 +1648,7 @@ def _flash_backward(q, k, v, out, lse, g, causal: bool, plan: FlashPlan,
             compiler_params=semantics,
             cost_estimate=pl.CostEstimate(
                 flops=flops // 2, transcendentals=exps,
-                bytes_accessed=3 * q_bytes + 2 * kv_bytes),
+                bytes_accessed=q_bytes + kv_bytes),
             interpret=interpret,
             name="penroz_flash_bwd_dq",
         )(seed, alibi_arr, q, k, v, lse, delta, g)
@@ -1607,10 +1659,10 @@ def _flash_backward(q, k, v, out, lse, g, causal: bool, plan: FlashPlan,
                            causal, window)
         q_at = lambda b, h, j, i: (b, h, clamp_q(j, i))
         kv_at = lambda b, h, j, i: (b, h, j)
-        stream = ix.spec(hps, block_q, q_at)
+        stream = ix.vspec(hps, block_q, q_at)
         stat_stream = ix.stat_spec(hps, block_q, q_at, as_rows=False)
-        dkv_out = ix.spec(hps, block_k, kv_at)
-        dkv_scr = pltpu.VMEM(_per_head(hps, (block_k, width)), jnp.float32)
+        dkv_scr = [pltpu.VMEM(_per_head(hps, (block_k, w)), jnp.float32)
+                   for w in (width, vwidth)]
         dk_ph, dv_ph = pl.pallas_call(
             functools.partial(_dkv_kernel, num_q=num_q, **common),
             grid=(B, Hq // hps, num_k, num_q),
@@ -1618,13 +1670,14 @@ def _flash_backward(q, k, v, out, lse, g, causal: bool, plan: FlashPlan,
                       ix.kv_spec(hps, block_k, kv_at, 1),
                       ix.kv_spec(hps, block_k, kv_at, 2), stat_stream,
                       stat_stream, stream],
-            out_specs=[dkv_out, dkv_out],
+            out_specs=[ix.spec(hps, block_k, kv_at),
+                       ix.vspec(hps, block_k, kv_at)],
             out_shape=dkv_shape,
-            scratch_shapes=[dkv_scr, dkv_scr],
+            scratch_shapes=dkv_scr,
             compiler_params=semantics,
             cost_estimate=pl.CostEstimate(
                 flops=flops // 2, transcendentals=exps,
-                bytes_accessed=3 * q_bytes + 4 * dkv_bytes),
+                bytes_accessed=q_bytes + 2 * dkv_bytes),
             interpret=interpret,
             name="penroz_flash_bwd_dkv",
         )(seed, alibi_arr, q, k, v, lse, delta, g)
@@ -1708,7 +1761,10 @@ def flash_attention(q, k, v, causal: bool = True,
                     scale=None, vmem_budget: int = VMEM_BUDGET):
     """Flash attention with a fused flash backward.
 
-    q: (B, Hq, T, D); k, v: (B, Hkv, S, D) with Hq % Hkv == 0.
+    q: (B, Hq, T, D); k: (B, Hkv, S, D); v: (B, Hkv, S, Dv) with
+    Hq % Hkv == 0; the result is (B, Hq, T, Dv).  ``Dv`` is ``D`` in most
+    models and narrower under latent attention (192-wide scores over
+    128-wide values).
     ``dropout_rate`` > 0 applies post-softmax dropout inside the kernels
     (mask derived from ``seed`` — pass a fresh int32 scalar per step).
     ``window``: sliding-window width (causal only) — query t attends keys
@@ -1720,21 +1776,27 @@ def flash_attention(q, k, v, causal: bool = True,
     plan may count on (a small one forces the chunked and split kernels).
     """
     B, Hq, T, D = q.shape
-    Hkv, S = k.shape[1], k.shape[2]
+    Hkv, S, Dv = k.shape[1], k.shape[2], v.shape[-1]
     seed, window, alibi, scale = _static_args(seed, alibi, Hq, window, scale)
     plan = plan_flash(T, S, D, q.dtype.itemsize, bool(causal), window,
                       heads=Hq, group=Hq // Hkv,
                       block_q=int(block_q) if block_q else None,
                       block_k=int(block_k) if block_k else None,
-                      vmem_budget=int(vmem_budget))
-    _record_plan(T, S, D, plan)
-    return _flash((q, k, v), seed, _bhtd(q, k), bool(causal), plan,
+                      vmem_budget=int(vmem_budget), Dv=Dv)
+    _record_plan(T, S, D, plan, Dv)
+    return _flash((q, k, v), seed, _bhtd(q, k, v), bool(causal), plan,
                   float(dropout_rate), bool(interpret), window, alibi, scale)
 
 
-def btd_refusal(D: int, heads: int, kv_heads: int) -> str | None:
+def btd_refusal(D: int, heads: int, kv_heads: int,
+                Dv: int | None = None) -> str | None:
     """Why :func:`flash_attention_btd` cannot take ``heads`` query heads on
-    ``kv_heads`` K/V heads of size ``D``, or None if it can."""
+    ``kv_heads`` K/V heads of size ``D`` (values ``Dv`` wide, default the
+    same), or None if it can."""
+    if Dv not in (None, D):
+        return (f"scores {D} wide over values {Dv} wide, (D, Dv)=({D}, "
+                f"{Dv}): a head's lanes start off the 128-lane blocks, the "
+                f"(B, H, T, ·) kernels take the pair")
     if D not in (64, 128, 256):
         return f"head size {D} fills no whole 128-lane block"
     hpb = _heads_per_block(D)

@@ -42,7 +42,9 @@ gauges     engines, active_rows, queue_depth, batch_occupancy,
            train_pass_loss{pass}, train_exit_mass{pass} (a looped model's
            exits, newest /train/ epoch; utils/tracing.py),
            train_moe{counter} (the dropless expert layers' routing
-           counters, newest /train/ epoch; utils/tracing.py)
+           counters, newest /train/ epoch; utils/tracing.py),
+           train_hc{counter} (a multi-stream residual's Sinkhorn error and
+           a router's largest selection bias, newest /train/ epoch)
 histograms ttft_ms, itl_ms, queue_wait_ms, chunk_stall_ms, tick_ms
            (fixed LATENCY_BUCKETS_MS buckets; cumulative ``_bucket``
            series sum to ``_count`` — asserted by the strict-format
@@ -264,6 +266,7 @@ TRAIN_SPAN_MS = REGISTRY.register(tracing.TRAIN_SPAN_MS)
 TRAIN_PASS_LOSS = REGISTRY.register(tracing.TRAIN_PASS_LOSS)
 TRAIN_EXIT_MASS = REGISTRY.register(tracing.TRAIN_EXIT_MASS)
 TRAIN_MOE = REGISTRY.register(tracing.TRAIN_MOE)
+TRAIN_HC = REGISTRY.register(tracing.TRAIN_HC)
 
 # -- gauges (scrape-time reads of live state) -------------------------------
 

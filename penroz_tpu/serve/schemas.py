@@ -58,7 +58,11 @@ class TrainingRequest(ModelOnDeviceRequest, DatasetRequest):
     epochs: int = Field(..., description="Number of training epochs")
     batch_size: int = Field(..., description="Batch size sampled each epoch")
     block_size: int = Field(..., description="Sequence length per sample")
-    step_size: int = Field(..., description="Blocks per accumulation step")
+    step_size: int | float = Field(
+        ..., gt=0, description="batch_size / step_size micro-steps of "
+        "batch_size x block_size tokens accumulate into one optimizer step; "
+        "a fraction (0.25 with batch_size 1) asks for more micro-steps than "
+        "the batch has rows")
     adapter: Optional[AdapterTrainConfig] = Field(
         None, description="Train a LoRA adapter instead of the base "
         "weights (base frozen; adapter-only checkpoint)")

@@ -188,6 +188,29 @@ def routing_counters(routed: dict) -> dict:
     return routed
 
 
+# The newest epoch's largest values of a model with a multi-stream residual
+# or a router with a selection bias (``ops/modules.py::MAX_COUNTERS``):
+# counters of ``penroz/train_epoch`` under their own names, and
+# ``penroz_train_hc{counter}`` on GET /metrics.
+_TRAIN_PEAKS: dict = {}
+TRAIN_HC = metrics.Gauge(
+    "penroz_train_hc",
+    "Largest values of the newest /train/ epoch over layers and "
+    "micro-steps: hc_sinkhorn_err (|column sum - 1| of a multi-stream "
+    "residual's mixing matrix after its last Sinkhorn iteration), "
+    "moe_bias_absmax (a router's selection bias)",
+    fn=lambda: _TRAIN_PEAKS, labelnames=("counter",))
+
+
+def peak_counters(peaks: dict) -> dict:
+    """One epoch's largest values as span counters (as they are); the same
+    values become the gauge's newest reading."""
+    if peaks:
+        _TRAIN_PEAKS.clear()
+        _TRAIN_PEAKS.update(peaks)
+    return peaks
+
+
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 _request_id_var: contextvars.ContextVar = contextvars.ContextVar(

@@ -1991,3 +1991,94 @@ def test_head_shares_add_up_to_the_uncut_reference_attention_block(kind):
             out = _attention_block(group, 1, hd, **args)(normed @ fused_w[:, cols])
             total = total + out @ o_w[rank * group * hd:(rank + 1) * group * hd]
     np.testing.assert_allclose(total, want, atol=2e-5)
+
+
+# -- unlike score and value widths (latent attention) -------------------------
+
+@pytest.mark.parametrize("plan_name", ["resident128", "resident256",
+                                       "chunked128"])
+@pytest.mark.parametrize("widths", [(192, 128), (48, 32), (64, 64)],
+                         ids=["192x128", "48x32", "64x64"])
+def test_flash_takes_scores_and_values_of_unlike_widths(widths, plan_name):
+    """q, k ``D`` wide and v, o ``Dv`` wide (interpret): forward and
+    dq/dk/dv against the jnp path under the resident forward with the
+    one-pass backward and under the chunked kernels with the split one, with
+    a scale of the caller's; beside the same at ``D == Dv``."""
+    from penroz_tpu.ops.pallas import flash_attention as FA
+    D, Dv = widths
+    block_q, block_k, budget = _FLASH_PLANS[plan_name]
+    rng = np.random.default_rng(D + len(plan_name))
+    draw = lambda *shape: jnp.asarray(
+        rng.normal(size=shape).astype(np.float32))
+    q, k, v = draw(1, 4, 256, D), draw(1, 2, 256, D), draw(1, 2, 256, Dv)
+    extra = {} if budget is None else {"vmem_budget": budget}
+    plan = FA.plan_flash(256, 256, D, 4, heads=4, group=2, block_q=block_q,
+                         block_k=block_k, Dv=Dv, **extra)
+    assert plan.layout == "bhtd"
+    assert (plan.resident, plan.fused_bwd) == ((budget is None,) * 2)
+
+    def attend(q, k, v):
+        return FA.flash_attention(q, k, v, block_q=block_q, block_k=block_k,
+                                  interpret=True, scale=0.11, **extra)
+
+    oracle = lambda q, k, v: A.causal_attention_reference(q, k, v,
+                                                          scale=0.11)
+    out = attend(q, k, v)
+    assert out.shape == (1, 4, 256, Dv)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(oracle(q, k, v)),
+                               atol=2e-5)
+    w = draw(*out.shape)
+    gf = jax.grad(lambda q, k, v: (attend(q, k, v) * w).sum(),
+                  (0, 1, 2))(q, k, v)
+    gr = jax.grad(lambda q, k, v: (oracle(q, k, v) * w).sum(),
+                  (0, 1, 2))(q, k, v)
+    assert [g.shape for g in gf] == [q.shape, k.shape, v.shape]
+    _grad_close(gf, gr)
+
+
+def test_flash_plan_at_equal_widths_is_what_it_was_and_names_unlike_ones(
+        caplog):
+    """``Dv`` left out, or equal to ``D``, changes no plan; the plan line
+    names ``Dv`` only where it differs; the ``btd`` layout refuses the
+    pair and says which."""
+    import logging
+    from penroz_tpu.ops.pallas import flash_attention as FA
+    for args, kw in (((1024, 1024, 64, 2), dict(heads=12, layout="btd",
+                                                fused_qkv=True)),
+                     ((4096, 4096, 128, 2), dict(heads=16, layout="btd")),
+                     ((8192, 8192, 128, 2), dict(heads=6, group=6))):
+        assert FA.plan_flash(*args, **kw) == FA.plan_flash(
+            *args, Dv=args[2], **kw)
+    plan = FA.plan_flash(4096, 4096, 192, 2, heads=32, Dv=128)
+    assert plan.fused_bwd and plan.layout == "bhtd"
+    assert plan.bwd_vmem_bytes < FA.plan_flash(4096, 4096, 192, 2,
+                                               heads=32).bwd_vmem_bytes
+    FA._log_plan.cache_clear()
+    with caplog.at_level(logging.INFO, logger=FA.log.name):
+        FA._record_plan(4096, 4096, 192, plan, 128)
+        FA._record_plan(4096, 4096, 128, plan)
+    lines = [r.getMessage() for r in caplog.records]
+    assert lines[0].startswith("flash plan: T=4096 S=4096 D=192 Dv=128 bq=")
+    assert lines[1].startswith("flash plan: T=4096 S=4096 D=128 bq=")
+    assert "(D, Dv)=(192, 128)" in FA.btd_refusal(192, 32, 32, 128)
+    with pytest.raises(ValueError, match="one head width"):
+        FA.plan_flash(4096, 4096, 192, 2, heads=32, Dv=128, layout="btd")
+
+
+def test_a_shape_off_the_flash_kernels_says_so_once(monkeypatch, caplog):
+    """On a TPU, a training shape the kernels do not take goes to the jnp
+    path with one log line that names the refused pair (D, Dv)."""
+    import logging
+    monkeypatch.setattr(A, "_WARNED_ONCE", set())
+    q = jnp.zeros((1, 2, 128, 96))
+    v = jnp.zeros((1, 2, 128, 64))
+    assert A._flash_shapes(4096, 192, 32, 32, 128)
+    assert A._flash_shapes(1024, 64, 12, 12)
+    assert not A._flash_shapes(4096, 192, 32, 32)       # 192-wide values
+    with caplog.at_level(logging.WARNING, logger=A.log.name):
+        assert not A._use_flash(q, q, "tpu", v)
+        assert not A._use_flash(q, q, "tpu", v)
+    said = [r.getMessage() for r in caplog.records
+            if "leaves the flash kernels" in r.getMessage()]
+    assert len(said) == 1 and "(D, Dv)=(96, 64)" in said[0]
+    assert not A._use_flash(q, q, "cpu", v)             # no TPU: no line

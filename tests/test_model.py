@@ -963,3 +963,60 @@ def test_generate_alibi_paged_matches_contiguous(workdir, monkeypatch):
     got = model.generate_tokens([[1, 2, 3]], block_size=256,
                                 max_new_tokens=6, temperature=0.0)
     assert got == want
+
+
+@pytest.mark.parametrize("batch, step, world, want", [
+    (12, 1, 1, 12),         # the GPT-2 cell: a micro-step a row
+    (2, 1, 1, 2),
+    (5, 2, 1, 2),           # floored, as the reference floors
+    (8, 2, 2, 2),           # two hosts halve it
+    (1, 1, 1, 1),
+    (2, 3, 1, 1),           # never under one
+    (1, 0.25, 1, 4),        # more micro-steps than the batch has rows
+    (2, 0.5, 1, 4),
+    (1, 0.1, 1, 10),        # 1 // 0.1 is 9.0
+])
+def test_accumulation_steps(batch, step, world, want):
+    from penroz_tpu.models.model import accumulation_steps
+    got = accumulation_steps(batch, step, world)
+    assert got == want and isinstance(got, int)
+
+
+def test_accumulation_steps_refuses_a_step_size_of_zero():
+    from penroz_tpu.models.model import accumulation_steps
+    from penroz_tpu.serve.schemas import TrainingRequest
+    with pytest.raises(ValueError, match="step_size must be positive"):
+        accumulation_steps(1, 0)
+    body = {"model_id": "m", "device": "cpu", "dataset_id": "toy",
+            "shard": 0, "epochs": 1, "batch_size": 1, "block_size": 16}
+    assert TrainingRequest(**body, step_size=0.25).step_size == 0.25
+    assert TrainingRequest(**body, step_size=2).step_size == 2
+    with pytest.raises(ValueError):
+        TrainingRequest(**body, step_size=0)
+
+
+def test_fractional_step_size_accumulates_more_micro_steps_than_rows(
+        workdir, toy_gpt_layers, toy_shards, monkeypatch):
+    """``batch_size`` 1 with ``step_size`` 0.25: an optimizer step is four
+    micro-steps of one row, each its own buffer from the loader."""
+    from penroz_tpu.models.model import CompiledArch
+    seen = []
+    make = CompiledArch.train_epoch_fn
+
+    def spying(arch, optimizer_config, num_steps, *args, **kwargs):
+        fn = make(arch, optimizer_config, num_steps, *args, **kwargs)
+
+        def epoch(params, opt_state, buffers, xs, ys, rng):
+            seen.append((num_steps, np.asarray(xs).copy()))
+            return fn(params, opt_state, buffers, xs, ys, rng)
+        return epoch
+
+    monkeypatch.setattr(CompiledArch, "train_epoch_fn", spying)
+    model = NeuralNetworkModel("acc", Mapper(toy_gpt_layers, ADAMW))
+    model.train_model("toy", shard=0, epochs=2, batch_size=1, block_size=16,
+                      step_size=0.25)
+    assert model.status["code"] == "Trained"
+    assert [(n, xs.shape) for n, xs in seen] == [(4, (4, 1, 16))] * 2
+    rows = np.concatenate([xs.reshape(4, 16) for _, xs in seen])
+    shard = np.load(workdir / "data" / "toy_000000.npy")
+    np.testing.assert_array_equal(rows.reshape(-1), shard[:8 * 16])

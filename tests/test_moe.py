@@ -700,13 +700,18 @@ def test_moe_dropped_counts_rows_the_rounds_really_handed_to_the_products():
         sizes=layout.sizes.at[1].add(-5)), 4) == 123     # padding rows
 
 
-def test_moe_expert_shares_add_up_to_the_uncut_reference_block(tile_of_4):
-    """Four ranks of four experts each, the program's dropless layer told
-    its share (``experts_held``, ``first_expert``): their routed parts, with
-    the shared expert counted once, are the uncut block of
-    ``benchmark/reference/laguna.py`` (all 16 experts held)."""
-    from benchmark.reference import laguna
-    d, h, experts, ranks, k = 16, 24, 16, 4, 5
+@pytest.mark.parametrize("router,ranks", [("softmax", 4), ("sigmoid", 8)])
+def test_moe_expert_shares_add_up_to_the_uncut_reference_block(
+        tile_of_4, router, ranks):
+    """Every rank of an expert-parallel layer, the program's dropless layer
+    told its share (``experts_held``, ``first_expert``): their routed parts,
+    with the shared expert counted once, are the uncut block of the plain
+    reference (all 16 experts held).  Four ranks of four under the softmax
+    router of ``benchmark/reference/laguna.py``; eight of two under the
+    sigmoid router with a selection bias of ``benchmark/reference/xing.py``."""
+    from benchmark.reference import laguna, xing
+    d, h, experts = 16, 24, 16
+    k, scale = (5, 2.5) if router == "softmax" else (4, 2.0)
     keys = jax.random.split(jax.random.key(1), 8)
     normal = lambda i, *shape: 0.3 * jax.random.normal(keys[i], shape)
     ref = {"router": normal(0, d, experts), "e_gate": normal(1, experts, d, h),
@@ -714,9 +719,17 @@ def test_moe_expert_shares_add_up_to_the_uncut_reference_block(tile_of_4):
            "s_gate": normal(4, d, 8), "s_up": normal(5, d, 8),
            "s_down": normal(6, 8, d)}
     x = jax.random.normal(keys[7], (2, 9, d))
+    bias = xing.router_bias(experts, 1)
     with jax.default_matmul_precision("highest"):
-        want = laguna._sparse(ref, x, first=0, top_k=k, scale=2.5,
-                              norm_topk=True, mm=jnp.matmul)
+        if router == "softmax":
+            want = laguna._sparse(ref, x, first=0, top_k=k, scale=scale,
+                                  norm_topk=True, mm=jnp.matmul)
+            how = {}
+        else:
+            want = xing._sparse(ref, x, tuple(bias), first=0, top_k=k,
+                                scale=scale, norm_topk=True, mm=jnp.matmul)
+            how = {"scoring": "sigmoid", "selection_bias": True,
+                   "selection_bias_init": [float(b) for b in bias]}
         shared = laguna._swiglu(x, ref["s_gate"], ref["s_up"], ref["s_down"],
                                 jnp.matmul)
         total = shared
@@ -724,8 +737,8 @@ def test_moe_expert_shares_add_up_to_the_uncut_reference_block(tile_of_4):
             held = experts // ranks
             mod = M.MixtureOfExperts(
                 in_features=d, intermediate_size=h, num_experts=experts,
-                top_k=k, dispatch="dropless", routed_scale=2.5,
-                experts_held=held, first_expert=rank * held)
+                top_k=k, dispatch="dropless", routed_scale=scale,
+                experts_held=held, first_expert=rank * held, **how)
             mod.bind("moe")
             mine = slice(rank * held, (rank + 1) * held)
             swap = lambda a: jnp.swapaxes(a[mine], 1, 2)
@@ -733,7 +746,8 @@ def test_moe_expert_shares_add_up_to_the_uncut_reference_block(tile_of_4):
                 "moe.router.weight": ref["router"].T,
                 "moe.experts.gate_proj.weight": swap(ref["e_gate"]),
                 "moe.experts.up_proj.weight": swap(ref["e_up"]),
-                "moe.experts.down_proj.weight": swap(ref["e_down"])}))
+                "moe.experts.down_proj.weight": swap(ref["e_down"])},
+                mod.init_buffers()))
     np.testing.assert_allclose(total, want, atol=1e-5)
 
 

@@ -779,3 +779,78 @@ def test_laguna_block_compiles_for_v5e_at_the_cells_shapes(chip, kind, heads):
         scatters
     # attention never leaves (B, T, H·D)
     assert f"bf16[1,{heads},8192,128]" not in hlo
+
+
+# -- unlike score and value widths: latent attention's flash calls ------------
+
+def _mla_shapes(heads):
+    return (1, heads, 4096, 192), (1, heads, 4096, 128)     # q and k; v
+
+
+def _flash_mla(backward, heads):
+    from penroz_tpu.ops.pallas import flash_attention as fa
+    qk, v = _mla_shapes(heads)
+    shapes = [(qk, jnp.bfloat16), (qk, jnp.bfloat16), (v, jnp.bfloat16)]
+    attend = lambda q, k, v: fa.flash_attention(q, k, v, scale=0.14468)
+    loss = lambda q, k, v: attend(q, k, v).astype(jnp.float32).sum()
+    return (jax.grad(loss, argnums=(0, 1, 2)) if backward else attend), shapes
+
+
+@pytest.mark.parametrize("heads", [8, 32], ids=["held8", "published32"])
+@pytest.mark.parametrize("backward", [False, True], ids=["fwd", "bwd"])
+def test_flash_compiles_for_v5e_at_192_wide_scores_over_128_wide_values(
+        chip, backward, heads):
+    """The new cell's attention call (``xing-train-4k-ep8share``: 1 x 8 held
+    heads x 4096, q and k 192 wide, v and o 128 wide, bf16; and the
+    published 32 heads), unpadded: Mosaic takes the 192-lane blocks as they
+    are, the forward with K/V resident, the backward in one pass under the
+    limit its plan asks for, under the names the benchmark reads; the
+    results are as wide as their operands."""
+    fn, shapes = _flash_mla(backward, heads)
+    qk_shape, v_shape = _mla_shapes(heads)
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+            for shape, dtype in shapes]
+    compiled = jax.jit(fn).lower(*args).compile()
+    calls = _custom_calls(compiled.as_text())
+    count = lambda needle: sum(needle in name for name, _ in calls)
+    assert count("penroz_flash_fwd") == 1, calls
+    if not backward:
+        assert len(calls) == 1, calls
+        assert jax.eval_shape(fn, *args).shape == v_shape
+        return
+    # one pass, and no kernel for δ in this layout (an XLA reduction)
+    assert count("penroz_flash_bwd") == 1 and len(calls) == 2, calls
+    assert not count("penroz_flash_bwd_dq"), calls
+    got = jax.eval_shape(fn, *args)
+    assert [g.shape for g in got] == [qk_shape, qk_shape, v_shape]
+
+
+def test_accepted_cells_flash_plans_read_as_they_did():
+    """The plan lines of the three accepted cells' attention (GPT-2's fused
+    projection at D = 64, the looped cell's one pass at D = 128, the share
+    cell's grouped queries at T = 8192 with and without a window), letter
+    for letter as before the kernels learnt a value width: PERF.md §3 quotes
+    them and an operator compares a job's log with it."""
+    from penroz_tpu.ops.pallas import flash_attention as fa
+    same = ("bq=512 bk=512 bwd_bq=512 bwd_bk=512 diag_grain=256 "
+            "bwd_diag_grain=128 ")
+    assert fa.plan_flash(1024, 1024, 64, 2, heads=12, layout="btd",
+                         fused_qkv=True).describe() == same + (
+        "computed_over_live=1.250 bwd_computed_over_live=1.125 resident "
+        "q_rows=1024 fused_bwd bwd_vmem_mib=10.9 heads_per_step=2 "
+        "layout=btd heads_per_block=2 fused_qkv")
+    assert fa.plan_flash(4096, 4096, 128, 2, heads=16,
+                         layout="btd").describe() == same + (
+        "computed_over_live=1.062 bwd_computed_over_live=1.031 resident "
+        "q_rows=1024 fused_bwd bwd_vmem_mib=24.1 heads_per_step=1 "
+        "layout=btd heads_per_block=1")
+    assert fa.plan_flash(8192, 8192, 128, 2, heads=6, group=6,
+                         layout="btd").describe() == same + (
+        "computed_over_live=1.031 bwd_computed_over_live=1.016 chunked "
+        "q_rows=512 fused_bwd bwd_vmem_mib=41.8 heads_per_step=1 "
+        "layout=btd heads_per_block=1")
+    assert fa.plan_flash(8192, 8192, 128, 2, window=512, heads=9, group=9,
+                         layout="btd").describe() == same + (
+        "computed_over_live=1.688 bwd_computed_over_live=1.562 chunked "
+        "q_rows=512 fused_bwd bwd_vmem_mib=41.8 heads_per_step=1 "
+        "layout=btd heads_per_block=1")

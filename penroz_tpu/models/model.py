@@ -443,7 +443,8 @@ class CompiledArch:
                     ragged_descs=ragged_descs, ragged_rows=ragged_rows,
                     targets=targets if self.looped is not None else None)
         if self.hyper:
-            M.record_hc_plan(self.hyper, math.prod(x.shape[:2]), training)
+            M.record_hc_plan(self.hyper, x, training, platform, jnp.dtype(
+                compute_dtype or jnp.float32).itemsize)
         acts = []
         h = x
         logits = None

@@ -742,17 +742,31 @@ class TransformerBlock(Module):
         return out
 
 
-def record_hc_plan(sub_blocks: Sequence["HyperConnected"], tokens: int,
-                   training: bool):
-    """One ``hc`` plan (:func:`_record_plan`) a traced program of a model
-    with a multi-stream residual, for all of its sub-blocks;
-    ``recomputed_sub_blocks``: those whose inside the backward runs again
-    (all of them in training, :func:`_recomputed`)."""
-    first = sub_blocks[0]
+def record_hc_plan(sub_blocks: Sequence["HyperConnected"], x, training: bool,
+                   platform=None, itemsize: int = 4):
+    """The plans (:func:`_record_plan`) of a traced program of a model with a
+    multi-stream residual, for all of its sub-blocks on tokens ``x`` ``(B,
+    T)``.  ``hc``: ``recomputed_sub_blocks`` are those whose inside the
+    backward runs again (all of them in training, :func:`_recomputed`).
+    ``hc_mix``: the path the mixing's passes over the state take
+    (:meth:`HyperConnected.mix_plan`)."""
+    first, (batch, seq) = sub_blocks[0], x.shape[:2]
     _record_plan("hc", streams=first.streams,
                  sinkhorn_iters=first.sinkhorn_iters,
-                 sub_blocks=len(sub_blocks), tokens=int(tokens),
+                 sub_blocks=len(sub_blocks), tokens=batch * seq,
                  recomputed_sub_blocks=len(sub_blocks) if training else 0)
+    _record_plan("hc_mix", **first.mix_plan(
+        batch, seq, _one_tpu(x, platform), itemsize))
+
+
+def _one_tpu(x, platform) -> bool:
+    """Whether ``x``'s program runs on a TPU and on one device: under a mesh
+    a Mosaic call would have to be mapped over the shards
+    (``ops/attention.py::_on_shards``) and the mixing's weight gradient
+    summed over them, where the same passes in ``jnp`` partition like any
+    XLA code."""
+    return (getattr(platform, "mesh", None) is None
+            and attn_ops._tpu_platform(x, platform))
 
 
 def sinkhorn(logits, iters: int, eps: float):
@@ -764,6 +778,213 @@ def sinkhorn(logits, iters: int, eps: float):
         m = m / (jnp.sum(m, axis=0, keepdims=True) + eps)
         m = m / (jnp.sum(m, axis=1, keepdims=True) + eps)
     return m
+
+
+class _HcMaps(NamedTuple):
+    """What a stream mixing is made with: the three maps' numbers, and the
+    ``path`` its passes over the state take (``fused``: :func:`_token_mix`,
+    XLA's; ``kernel``: ``ops/pallas/hc_mix.py``; ``interpret``: that kernel
+    interpreted, the tests')."""
+    streams: int
+    features: int
+    iters: int
+    eps: float
+    hc_eps: float
+    clamp: tuple
+    path: str = "fused"
+
+
+def _token_mix(groups, plan, coef=None, rows=None, w=None):
+    """``ops/pallas/hc_mix.py::token_mix`` in ``jax.numpy``: the same pass
+    over the same stream-major groups ``(B, g, T, d)``, what is a few
+    numbers a token ``(B, ·, T)`` with the tokens on the minor axis, for XLA
+    to fuse."""
+    d, out_dtype = groups[0].shape[3], groups[0].dtype
+    slabs = [g[:, j] for g in groups for j in range(g.shape[1])]
+    wide = {}
+
+    def f32(s):
+        if s not in wide:
+            wide[s] = slabs[s].astype(jnp.float32)
+        return wide[s]
+
+    of = lambda j: w[:, j * d:(j + 1) * d]
+    back = iter(plan.back)
+    results = []
+    for group in plan.outs:
+        made = []
+        for terms in group:
+            acc = sum(f32(s) if c is None else coef[:, c, :, None] * f32(s)
+                      for c, s in terms)
+            stream = next(back, None)
+            if stream is not None:
+                acc = acc + jnp.einsum("bmt,md->btd", rows,
+                                       of(stream).astype(jnp.float32))
+            made.append(acc)
+        results.append(jnp.stack(made, axis=1).astype(out_dtype))
+    if plan.dots:
+        results.append(jnp.stack([jnp.sum(f32(a) * f32(b), axis=-1)
+                                  for a, b in plan.dots], axis=1))
+    if plan.proj:
+        results.append(sum(
+            jnp.einsum("btd,md->bmt", slabs[s], of(j),
+                       preferred_element_type=jnp.float32)
+            for j, s in enumerate(plan.proj)))
+    if plan.wgrad:
+        results.append(jnp.concatenate([
+            jnp.einsum("bmt,btd->md", rows, slabs[s],
+                       preferred_element_type=jnp.float32)
+            for s in plan.wgrad], axis=1))
+    return results
+
+
+def _hc_pass(path: str, groups, coef=None, rows=None, w=None, **plan):
+    """One pass over the state, ``plan`` the fields of
+    ``ops/pallas/hc_mix.py::Pass``: the kernel's or XLA's."""
+    from penroz_tpu.ops.pallas import hc_mix
+    plan = hc_mix.Pass(**plan)
+    if path == "fused":
+        return _token_mix(groups, plan, coef, rows, w)
+    return hc_mix.token_mix(groups, plan, coef=coef, rows=rows, w=w,
+                            interpret=path == "interpret")
+
+
+def _hc_rows(t, like):
+    """Maps ``(k, tokens)`` as the passes' coefficients ``(B, k, T)`` beside
+    ``like`` ``(B, T, ...)``: the tokens stay on the minor axis."""
+    return jnp.moveaxis(t.reshape(t.shape[:1] + like.shape[:2]), 0, 1)
+
+
+def _hc_lanes(t):
+    """A pass's per-token results ``(B, k, T)`` as ``(k, tokens)``."""
+    return jnp.moveaxis(t, 1, 0).reshape(t.shape[1], -1)
+
+
+def _hc_stats(path: str, Xs, phi):
+    """A token's statistics of the state ``Xs`` ``(B, n, T, d)`` in float32,
+    one pass: its sum of squares ``(tokens,)`` and ``Phi vec(X)`` ``(maps,
+    tokens)`` as ``n`` products of a stream ``(T, d)`` with its ``d``
+    columns of ``Phi`` summed: ``X`` is never laid out as ``(B·T, n·d)``."""
+    n = Xs.shape[1]
+    squares, P = _hc_pass(path, [Xs], w=phi, proj=tuple(range(n)),
+                          dots=tuple((j, j) for j in range(n)))
+    return jnp.sum(squares, axis=1).reshape(-1), _hc_lanes(P)
+
+
+def _hc_maps(cfg: _HcMaps, s, P, alpha, bias):
+    """``(H_pre (n, tokens), H_post (n, tokens), H_res (n, n, tokens))`` of
+    a token's statistics (:func:`_hc_stats`): 24 numbers a token at n = 4,
+    the tokens on the minor axis."""
+    n = cfg.streams
+    # the token's 1/rms scales the product: x̃ itself is never written
+    u = P * jax.lax.rsqrt(s / (n * cfg.features) + cfg.eps)
+    bias = bias[:, None]
+    pre = jax.nn.sigmoid(alpha[0] * u[:n] + bias[:n])
+    post = 2.0 * jax.nn.sigmoid(alpha[1] * u[n:2 * n] + bias[n:2 * n])
+    res = jnp.clip(alpha[2] * u[2 * n:] + bias[2 * n:], *cfg.clamp)
+    # (n·n, tokens) to (n, n, tokens) by rows and not by a reshape: the
+    # kernel's results lie in tiles of 8 rows, and XLA carried that layout
+    # through a reshape into Sinkhorn, where every iteration then paid a
+    # broadcast and a relayout of their own (2 000 instructions a program)
+    return pre, post, sinkhorn(jnp.stack(jnp.split(res, n)), cfg.iters,
+                               cfg.hc_eps)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _hc_read(cfg: _HcMaps, X, phi, alpha, bias):
+    """The reading half of a stream mixing: ``(x_in, H_post, H_res, X)`` of
+    the state ``X`` ``(B, T, n, d)``, ``x_in = Σ_i H_pre[i] X_i`` in ``X``'s
+    type.  Two passes over ``X``: the statistics; ``x_in``.  ``X`` is handed
+    on as it came, for :func:`_hc_write`: what that finds for it in the
+    backward arrives here and is added in the pass that writes ``dX``
+    (:func:`_hc_read_bwd`)."""
+    return _hc_read_fwd(cfg, X, phi, alpha, bias)[0]
+
+
+def _hc_read_fwd(cfg, X, phi, alpha, bias):
+    n, Xs = cfg.streams, jnp.swapaxes(X, 1, 2)
+    (pre, post, res), pull = jax.vjp(
+        functools.partial(_hc_maps, cfg), *_hc_stats(cfg.path, Xs, phi),
+        alpha, bias)
+    x_in, = _hc_pass(cfg.path, [Xs], coef=_hc_rows(pre, X),
+                     outs=((tuple((i, i) for i in range(n)),),))
+    return (x_in[:, 0], post, res, X), (X, phi, pre, pull)
+
+
+def _hc_read_bwd(cfg, kept, cotangents):
+    """Two passes over ``X``: ``dH_pre[i] = <dx_in, X_i>``; then, with the
+    maps' cotangent taken through every Sinkhorn iteration (24 numbers a
+    token, XLA's: ``pull``, the pull-back the forward made), ``dX_j =
+    H_pre[j] dx_in + 2 ds X_j + dP Phi_j + dX_j of the writing half``,
+    summed in float32 and written once, with ``dPhi_j = dP X_j`` summed
+    over the tokens in the same pass."""
+    X, phi, pre, pull = kept
+    dx_in, dpost, dres, dX_write = cotangents
+    n, Xs, g = cfg.streams, jnp.swapaxes(X, 1, 2), dx_in[:, None]
+    dots, = _hc_pass(cfg.path, [g, Xs],
+                     dots=tuple((0, 1 + i) for i in range(n)))
+    ds, dP, dalpha, dbias = pull((_hc_lanes(dots), dpost, dres))
+    dXs, dphi = _hc_pass(
+        cfg.path, [g, Xs, jnp.swapaxes(dX_write, 1, 2)],
+        coef=_hc_rows(jnp.concatenate([pre, 2.0 * ds[None]]), X),
+        rows=_hc_rows(dP, X), w=phi,
+        outs=(tuple(((j, 0), (n, 1 + j), (None, 1 + n + j))
+                    for j in range(n)),),
+        back=tuple(range(n)), wgrad=tuple(1 + j for j in range(n)))
+    return jnp.swapaxes(dXs, 1, 2), dphi.astype(phi.dtype), dalpha, dbias
+
+
+_hc_read.defvjp(_hc_read_fwd, _hc_read_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _hc_write(path: str, reduce: bool, X, y, post, res):
+    """The writing half: ``X'_i = Σ_j H_res[i, j] X_j + H_post[i] y`` in
+    ``X``'s type (``reduce``: their sum over ``i``, ``(B, T, d)``), one pass
+    that reads ``X`` and ``y``; the backward (:func:`_hc_write_bwd`) one
+    more."""
+    return _hc_write_fwd(path, reduce, X, y, post, res)[0]
+
+
+def _hc_write_coef(X, post, res):
+    """Row ``i·n + j``: ``H_res[i, j]``; row ``n² + i``: ``H_post[i]``."""
+    return _hc_rows(jnp.concatenate([*res, post]), X)
+
+
+def _hc_write_fwd(path, reduce, X, y, post, res):
+    n = X.shape[2]
+    new = [tuple((i * n + j, j) for j in range(n)) + ((n * n + i, n),)
+           for i in range(n)]
+    out, = _hc_pass(path, [jnp.swapaxes(X, 1, 2), y[:, None]],
+                    coef=_hc_write_coef(X, post, res),
+                    outs=((sum(new, ()),) if reduce else tuple(new),))
+    return (out[:, 0] if reduce else jnp.swapaxes(out, 1, 2)), (X, y, post,
+                                                                res)
+
+
+def _hc_write_bwd(path, reduce, kept, dout):
+    """One pass over ``dX'``, ``X`` and ``y``: ``dy = Σ_i H_post[i] dX'_i``,
+    ``dH_post[i] = <dX'_i, y>``, ``dH_res[i, j] = <dX'_i, X_j>``, ``dX_j =
+    Σ_i H_res[i, j] dX'_i``."""
+    X, y, post, res = kept
+    n = X.shape[2]
+    G = dout[:, None] if reduce else jnp.swapaxes(dout, 1, 2)
+    m = G.shape[1]
+    g = lambda i: 0 if reduce else i        # dX'_i's slab; X_j's is m + j
+    dXs, dy, dots = _hc_pass(
+        path, [G, jnp.swapaxes(X, 1, 2), y[:, None]],
+        coef=_hc_write_coef(X, post, res),
+        outs=(tuple(tuple((i * n + j, g(i)) for i in range(n))
+                    for j in range(n)),
+              (tuple((n * n + i, g(i)) for i in range(n)),)),
+        dots=tuple((g(i), m + n) for i in range(n))
+        + tuple((g(i), m + j) for i in range(n) for j in range(n)))
+    dots = _hc_lanes(dots)
+    return (jnp.swapaxes(dXs, 1, 2), dy[:, 0], dots[:n],
+            dots[n:].reshape(n, n, -1))
+
+
+_hc_write.defvjp(_hc_write_fwd, _hc_write_bwd)
 
 
 class HyperConnected(Module):
@@ -789,18 +1010,53 @@ class HyperConnected(Module):
 
     The statistics (rms, u's scaling, the three maps, Sinkhorn) are float32
     whatever the compute dtype, with the tokens on the minor axis: an
-    ``(n, n)`` matrix a token would pad 64-fold in the TPU's tiles.  The
-    mixing itself is ``n²`` multiply-adds of ``(B, T, d)`` slices, which XLA
-    fuses; written as a contraction over ``n`` it would be a batch of
-    4 × 4 matmuls.
+    ``(n, n)`` matrix a token would pad 64-fold in the TPU's tiles.
+
+    **How the state is held and moved.**  ``X`` is ``(B, T, n, d)`` to its
+    callers and stream-major in memory: every pass takes it as
+    ``jnp.swapaxes(X, 1, 2)``, ``(B, n, T, d)``, a stream one ``(T, d)`` slab
+    like any activation of the model, and XLA folds the transposes into the
+    layout it keeps ``X`` in from sub-block to sub-block (``{3,1,2,0}``: no
+    copy, pinned by ``tests/test_tpu_compile.py``).  The mixing is two
+    operations with hand-written backwards round the body, each a few
+    *passes* over the state (``ops/pallas/hc_mix.py::Pass``: per-token
+    multiply-adds of slabs, per-token inner products, the ``Phi`` product),
+    run by the kernel ``penroz_hc_mix`` on a TPU where its tiles admit ``(T,
+    d)`` and by the same formulation in ``jnp`` anywhere else
+    (:func:`_token_mix`; :meth:`mix_plan` says which, the ``hc_mix plan:``
+    line).  A pass reads the streams in their own type, widens them in
+    registers, sums in float32 and writes the model's type: no float32
+    array of the state's size exists, and ``X`` is never laid out as ``(B·T,
+    n·d)`` (the ``Phi`` product is ``n`` products of a slab with its ``d``
+    columns of ``Phi``, float32 accumulation).  In units of one stream's
+    ``(T, d)``, reads + writes:
+
+    - :func:`_hc_read`: the statistics (sum of squares and ``Phi`` product;
+      n + 0), then ``x_in`` (n + 1);
+    - :func:`_hc_write`: ``X'`` (n + 1 reads, n writes);
+    - the backward of :func:`_hc_write`: one pass over ``dX'``, ``X`` and
+      ``y`` (2n + 1 reads) gives ``dy``, ``Σ_i H_res[i, j] dX'_i`` (n + 1
+      writes) and the inner products ``<dX'_i, y>``, ``<dX'_i, X_j>``;
+    - the backward of :func:`_hc_read`: ``<dx_in, X_i>`` (n + 1 reads); the
+      maps' own backward through every Sinkhorn iteration, on 24 numbers a
+      token, which stays XLA's as :func:`_hc_maps` is (the pull-back the
+      forward made); then ``dX`` whole, summed in float32 and rounded once
+      (2n + 1 reads, n writes: ``dx_in``, ``X``, the writing half's part,
+      which reaches it as the cotangent of the ``X`` that :func:`_hc_read`
+      hands on, and ``dP Phi_j`` on the MXU), with ``dPhi_j = dP X_j``
+      summed over the tokens in the same pass.
 
     In training a sub-block runs under ``jax.checkpoint``
     (:func:`_recomputed`, as :class:`Looped`'s applications do): ``n``
-    streams hold ``n`` times a plain stack's residual, and the maps, the
-    mixed input, ``y`` and the body's inside beside them for every
-    sub-block; the backward keeps a sub-block's input ``X`` and what the
-    kernels wrote and name, and runs the rest again.  A property of the
-    container, not an option."""
+    streams hold ``n`` times a plain stack's residual; the backward keeps a
+    sub-block's input ``X`` and what the kernels wrote and name, and runs
+    the rest again.  A property of the container, not an option.  What that
+    recomputes of the mixing: the sub-block's forward runs again but for the
+    ``X'`` pass (its result is not needed: the operations keep their inputs,
+    ``H_pre`` and the maps' pull-back), so the maps are made twice a
+    sub-block and the state is passed over 3 + 2 + 3 times, where the
+    formulas written out plainly and left to autodiff moved twelve states'
+    worth of bytes forward alone (PERF.md §6, PR 48)."""
 
     def __init__(self, features: int, body: Module, streams: int = 4,
                  sinkhorn_iters: int = 20, hc_eps: float = 1e-6,
@@ -839,41 +1095,49 @@ class HyperConnected(Module):
                 self.key("alpha"): jnp.full((3,), 0.01, jnp.float32),
                 self.key("bias"): bias}
 
+    def mix_plan(self, batch: int, seq: int, on_tpu: bool,
+                 itemsize: int) -> dict:
+        """How a call's passes over the state run: ``path`` ``kernel``
+        (``ops/pallas/hc_mix.py``, on one TPU (:func:`_one_tpu`) where its
+        tiles admit ``(seq, features)``) or ``fused`` (the same passes for
+        XLA to fuse and, under a mesh, to partition), and the
+        ``bytes`` a forward call of a sub-block moves of streams, mixed
+        input and ``y``: (4 · streams + 2) token vectors."""
+        from penroz_tpu.ops.pallas import hc_mix
+        kernel = on_tpu and hc_mix.fits(seq, self.features, self.streams,
+                                        itemsize)
+        tokens = batch * seq
+        return {"path": "kernel" if kernel else "fused",
+                "streams": self.streams, "features": self.features,
+                "tokens": tokens, "bytes": (4 * self.streams + 2) * tokens
+                * self.features * itemsize}
+
+    def _cfg(self, X, ctx) -> _HcMaps:
+        plan = self.mix_plan(*X.shape[:2], _one_tpu(X, ctx.platform),
+                             X.dtype.itemsize)
+        return _HcMaps(self.streams, self.features, self.sinkhorn_iters,
+                       self.eps, self.hc_eps, self.res_clamp, plan["path"])
+
+    def _own(self, ctx):
+        return (self._p(ctx, "phi.weight"),
+                ctx.params[self.key("alpha")].astype(jnp.float32),
+                ctx.params[self.key("bias")].astype(jnp.float32))
+
     def maps_of(self, X, ctx):
         """``(H_pre (n, tokens), H_post (n, tokens), H_res (n, n, tokens))``
         in float32 of ``X`` ``(B, T, n, d)``."""
-        B, T, n, d = X.shape
-        flat = X.reshape(B * T, n * d)
-        # the token's 1/rms scales the product: x̃ itself is never written
-        r = jax.lax.rsqrt(jnp.mean(jnp.square(flat.astype(jnp.float32)),
-                                   axis=-1) + self.eps)
-        u = jnp.matmul(flat, self._p(ctx, "phi.weight").T,
-                       preferred_element_type=jnp.float32)
-        u = (u * r[:, None]).T                              # (maps, tokens)
-        alpha = ctx.params[self.key("alpha")].astype(jnp.float32)
-        bias = ctx.params[self.key("bias")].astype(jnp.float32)[:, None]
-        pre = jax.nn.sigmoid(alpha[0] * u[:n] + bias[:n])
-        post = 2.0 * jax.nn.sigmoid(alpha[1] * u[n:2 * n] + bias[n:2 * n])
-        res = jnp.clip(alpha[2] * u[2 * n:] + bias[2 * n:], *self.res_clamp)
-        res = sinkhorn(res.reshape(n, n, B * T), self.sinkhorn_iters,
-                       self.hc_eps)
-        return pre, post, res
+        cfg = self._cfg(X, ctx)
+        phi, alpha, bias = self._own(ctx)
+        return _hc_maps(cfg, *_hc_stats(cfg.path, jnp.swapaxes(X, 1, 2), phi),
+                        alpha, bias)
 
     def _mix(self, X, ctx):
-        B, T, n, d = X.shape
-        pre, post, res = self.maps_of(X, ctx)
+        cfg = self._cfg(X, ctx)
+        x_in, post, res, X = _hc_read(cfg, X, *self._own(ctx))
         ctx.note_max("hc_sinkhorn_err",
                      jnp.max(jnp.abs(jnp.sum(res, axis=0) - 1.0)))
-        # a token's scalar against its d lanes: (B, T, 1) columns
-        col = lambda t: t.reshape(B, T, 1)
-        streams = [X[:, :, j, :].astype(jnp.float32) for j in range(n)]
-        x_in = sum(col(pre[i]) * streams[i] for i in range(n))
-        y = self.body.apply(x_in.astype(X.dtype), ctx).astype(jnp.float32)
-        new = [sum(col(res[i, j]) * streams[j] for j in range(n))
-               + col(post[i]) * y for i in range(n)]
-        if self.reduce:
-            return sum(new).astype(X.dtype)
-        return jnp.stack(new, axis=2).astype(X.dtype)
+        return _hc_write(cfg.path, self.reduce, X, self.body.apply(x_in, ctx),
+                         post, res)
 
     def _apply(self, x, ctx):
         n = self.streams

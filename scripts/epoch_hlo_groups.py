@@ -1,7 +1,7 @@
 """What the op groups of a training trace are, read without a chip.
 
     JAX_PLATFORMS=cpu python scripts/epoch_hlo_groups.py \\
-        [benchmark/configs/gpt2-124m-nanogpt.json]
+        [benchmark/configs/gpt2-124m-nanogpt.json] [--touching 4096,14336]
 
 The ledger's ``breakdown`` of a training cell groups device time by HLO
 instruction name with the serial number stripped (``fusion``,
@@ -9,8 +9,9 @@ instruction name with the serial number stripped (``fusion``,
 op_group``).  The TPU compiler is installed in the sandbox and compiles for a
 chip that is described, not attached, so the same names can be had from the
 compiled program: this compiles the fast epoch program (``with_ratios=
-False``, bfloat16 compute, AdamW or whatever the file names) of a GPT-2
-configuration file for one chip of a described ``v5e:2x2`` and prints, per
+False``, bfloat16 compute, AdamW or whatever the file names) of a benchmark
+configuration file (any whose reference names its preset: ``PRESET`` /
+``preset_args``) for one chip of a described ``v5e:2x2`` and prints, per
 group, how many instructions it holds, how often they run an optimizer step
 (``while`` trip counts multiplied in), the FLOPs of the convolutions they
 contain (a TPU's matmuls are convolutions), the bytes they read and write
@@ -20,10 +21,19 @@ peak or bytes ÷ bandwidth, whichever is larger, per instruction, summed.
 Set beside the measured seconds of a group, that says whether the group is
 at its bound or hides something (PERF.md §5 has the reading of PR 30).
 
+``--touching <dims>`` (repeatable) keeps the instructions one of whose
+operands or results has that shape, whatever its type: what a program spends
+on one array, as PR 48 counted the stream state of ``xing4.0-29b-a4b-ep8-5l``
+(``--touching 4096,14336 --touching 512,8,4,3584 --touching 1,4096,4,3584``:
+the state as the matmul, the compiler's tiles and the model hold it).
+``--compiled <file>`` counts a compiled text kept by ``--hlo`` instead of
+compiling (minutes for the larger configurations).
+
 Nothing runs and nothing is timed: every number is a count from shapes.
 Pallas kernels are ``custom-call``s: their bytes are counted, their FLOPs are
-not (the compiler does not know them).  Run by no benchmark cell and importing
-nothing of ``benchmark/``: the grouping rule and the peaks are restated here.
+not (the compiler does not know them).  Run by no benchmark cell; of
+``benchmark/`` it imports the configuration's reference for its preset, and
+restates the grouping rule and the peaks.
 """
 
 import argparse
@@ -179,16 +189,24 @@ def trip_count(comps: dict, while_attrs: str) -> int:
     return bounds[0] if len(bounds) == 1 else 1
 
 
-def walk(comps: dict, types: dict, name: str, times: int, rows: list):
+def walk(comps: dict, types: dict, name: str, times: int, rows: list,
+         touching=()):
     """Append ``(group, runs, flops, bytes)`` for each instruction of
     computation ``name`` that does work, descending into ``while`` bodies
-    with their known trip counts multiplied in."""
+    with their known trip counts multiplied in.  ``touching``: lists of
+    dimensions; given any, only instructions with an operand or a result of
+    one of those shapes are counted."""
     for line in comps.get(name, ()):
         iname, opcode, result, operands, attrs = _instruction(line)
         if opcode == "while":
             body = re.search(r"body=%?([\w.\-]+)", attrs).group(1)
-            walk(comps, types, body, times * trip_count(comps, attrs), rows)
+            walk(comps, types, body, times * trip_count(comps, attrs), rows,
+                 touching)
         if opcode in _FREE or opcode.endswith("-start"):
+            continue
+        if touching and not any(
+                dims in touching for _, dims in
+                _shapes(result) + _operand_shapes(operands, types)):
             continue
         flops = 0.0
         if opcode == "convolution":
@@ -209,13 +227,23 @@ def walk(comps: dict, types: dict, name: str, times: int, rows: list):
         rows.append((op_group(iname), times, flops, nbytes))
 
 
+def preset_layers(cfg: dict) -> list:
+    """The layer DSL of a benchmark configuration: its reference names the
+    preset that builds it (``PRESET``) and with what (``preset_args``)."""
+    import importlib
+    from penroz_tpu.models import presets
+    reference = importlib.import_module(
+        "benchmark.reference." + cfg["reference"])
+    return getattr(presets, reference.PRESET)(**reference.preset_args(cfg))
+
+
 def compile_epoch(cfg: dict) -> str:
     """The compiled fast epoch program's HLO text, one described v5e chip."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
-    from penroz_tpu.models import dsl, presets
+    from penroz_tpu.models import dsl
     from penroz_tpu.models.dsl import Mapper
     from penroz_tpu.models.model import CompiledArch
 
@@ -225,8 +253,7 @@ def compile_epoch(cfg: dict) -> str:
     chip = SingleDeviceSharding(topo.devices[0])
     train = cfg["train"]
     steps = train["gradient_accumulation_steps"]
-    layers = presets.gpt2_custom(cfg["n_embd"], cfg["n_head"], cfg["n_layer"],
-                                 cfg["vocab_size"], cfg["n_positions"])
+    layers = preset_layers(cfg)
     mapper = Mapper(layers, cfg["optimizer"])
     arch = CompiledArch.get(mapper.layers)
     params, buffers = jax.eval_shape(
@@ -251,16 +278,27 @@ def main():
     ap.add_argument("config", nargs="?",
                     default="benchmark/configs/gpt2-124m-nanogpt.json")
     ap.add_argument("--hlo", help="also write the compiled HLO text here")
+    ap.add_argument("--compiled", help="count this compiled HLO text (one "
+                    "kept by --hlo) instead of compiling")
+    ap.add_argument("--touching", action="append", default=[],
+                    metavar="DIMS", help="count only instructions with an "
+                    "operand or result of this shape, e.g. 4096,14336 "
+                    "(repeatable)")
     args = ap.parse_args()
     with open(args.config) as fh:
         cfg = json.load(fh)
-    hlo = compile_epoch(cfg)
+    if args.compiled:
+        with open(args.compiled) as fh:
+            hlo = fh.read()
+    else:
+        hlo = compile_epoch(cfg)
     if args.hlo:
         with open(args.hlo, "w") as fh:
             fh.write(hlo)
+    touching = [[int(x) for x in dims.split(",")] for dims in args.touching]
     rows = []
     comps = parse_computations(hlo)
-    walk(comps, result_types(comps), "__entry__", 1, rows)
+    walk(comps, result_types(comps), "__entry__", 1, rows, touching)
     groups = collections.defaultdict(lambda: [0, 0, 0.0, 0.0, 0.0])
     for group, runs, flops, nbytes in rows:
         g = groups[group]
@@ -270,6 +308,8 @@ def main():
         g[3] += runs * nbytes
         g[4] += runs * max(flops / PEAK_FLOPS, nbytes / PEAK_BYTES_PER_S)
     steps = cfg["train"]["gradient_accumulation_steps"]
+    if touching:
+        print("instructions touching " + " ".join(args.touching) + " only")
     print(f"{cfg['name']}: one optimizer step = {steps} micro-steps; "
           f"least time at {PEAK_FLOPS / 1e12:.0f} TFLOP/s, "
           f"{PEAK_BYTES_PER_S / 1e9:.0f} GB/s")

@@ -80,3 +80,39 @@ def test_walk_counts_flops_bytes_and_trip_counts(groups):
     assert "copy-start" not in by_group and "while" not in by_group
     assert by_group["copy"] == (1, 0.0, 2 * 2 * 1024 * 768)
     assert set(by_group) == {"convolution_add_fusion", "copy-done", "copy"}
+
+
+@pytest.mark.parametrize("touching,want", [
+    ([[1024, 3072]], {"convolution_add_fusion"}),
+    ([[768, 3072], [4, 4]], {"convolution_add_fusion"}),
+    ([[1024, 768]], {"convolution_add_fusion", "copy-done", "copy"}),
+    ([[768, 1024]], set())],
+    ids=["a_result", "an_operand_of_several", "every_instruction", "none"])
+def test_walk_keeps_the_instructions_that_touch_a_shape(groups, touching,
+                                                        want):
+    """``--touching``: an instruction counts when an operand or a result has
+    one of the shapes, whatever its type; trip counts still multiply in."""
+    comps = groups.parse_computations(HLO)
+    rows = []
+    groups.walk(comps, groups.result_types(comps), "__entry__", 1, rows,
+                touching)
+    assert {g for g, *_ in rows} == want
+    assert all(runs == (1 if g == "copy" else 12) for g, runs, *_ in rows)
+
+
+@pytest.mark.parametrize("name", [
+    "gpt2-124m-nanogpt", "ouro-2.6b-loop4-6l", "laguna-s-2.1-ep32-5l",
+    "xing4.0-29b-a4b-ep8-5l"])
+def test_any_training_configuration_names_its_preset(groups, name):
+    """The script builds the model of whichever benchmark configuration it
+    is given from the reference's ``PRESET`` / ``preset_args``."""
+    import json
+    from penroz_tpu.models import presets
+    with open(os.path.join(ROOT, "benchmark", "configs", name + ".json"),
+              encoding="utf-8") as fh:
+        cfg = json.load(fh)
+    layers = groups.preset_layers(cfg)
+    assert "embedding" in json.dumps(layers[0]) and "train" in cfg
+    assert "softmaxlast" in layers[-1]
+    if "parameters_held" in cfg:
+        assert presets.param_count(layers) == cfg["parameters_held"]

@@ -854,3 +854,78 @@ def test_accepted_cells_flash_plans_read_as_they_did():
         "computed_over_live=1.688 bwd_computed_over_live=1.562 chunked "
         "q_rows=512 fused_bwd bwd_vmem_mib=41.8 heads_per_step=1 "
         "layout=btd heads_per_block=1")
+
+
+# -- the stream mixing's passes over its state ---------------------------------
+
+HC_STATE = ([1, 4096, 4, 3584], [1, 4, 4096, 3584], [4096, 14336])
+
+
+def test_stream_mixing_moves_its_state_in_kernel_passes_alone(chip):
+    """Three sub-blocks of ``xing4.0-29b-a4b-ep8-5l``'s residual (4 streams
+    of 3584 over 1 x 4096 tokens, bf16: one that expands, the dense SwiGLU
+    of 9216 between whole states, one that reduces), value and gradient in
+    training, so each under ``_recomputed``, compiled for a v5e.  The state
+    is read and written by ``penroz_hc_mix`` calls alone (a sub-block: the
+    statistics, ``x_in`` and ``X'`` forward, the first two again in the
+    backward's recomputation, three backward passes, ``dPhi`` inside the
+    last), the first sub-block's broadcast and its sum apart: no ``copy`` of
+    its shape, no float32 array of its size anywhere in the program, and by
+    ``scripts/epoch_hlo_groups.py``'s rule (operands + results of every
+    instruction that touches it) under 6.2 GB for the three (5.59 read),
+    where the formulas written out plainly moved 4.2 GB a sub-block
+    (PERF.md §6, PR 48).  The plan line an operator reads beside ``hc
+    plan:`` is pinned with it."""
+    import importlib.util
+    import re
+    from penroz_tpu.ops import modules as M
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    spec = importlib.util.spec_from_file_location(
+        "epoch_hlo_groups", os.path.join(root, "scripts",
+                                         "epoch_hlo_groups.py"))
+    groups = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(groups)
+    d, n, T = 3584, 4, 4096
+    linear = lambda: M.Sequential(M.RMSNorm(d), M.Linear(d, d, bias=False))
+    subs = [M.HyperConnected(d, linear(), streams=n, expand=True),
+            M.HyperConnected(d, M.Sequential(
+                M.RMSNorm(d), M.GatedMLP(d, 9216, activation="silu")),
+                streams=n),
+            M.HyperConnected(d, linear(), streams=n, reduce=True)]
+    for i, sub in enumerate(subs):
+        sub.bind(f"h{i}")
+    plan = subs[1].mix_plan(1, T, True, 2)
+    assert " ".join(f"{k}={v}" for k, v in plan.items()) == (
+        "path=kernel streams=4 features=3584 tokens=4096 bytes=528482304")
+    assert subs[1].mix_plan(1, T, False, 2)["path"] == "fused"
+    assert subs[1].mix_plan(1, T - 8, True, 2)["path"] == "fused"
+    from penroz_tpu.models import dsl
+    shapes, _ = jax.eval_shape(lambda: dsl.init_module_params(subs, seed=0))
+    params = {k: jax.ShapeDtypeStruct(v.shape, jnp.bfloat16, sharding=chip)
+              for k, v in shapes.items()}
+    x = jax.ShapeDtypeStruct((1, T, d), jnp.bfloat16, sharding=chip)
+
+    def loss(params, x):
+        ctx = M.Ctx(params, training=True, rng=jax.random.key(0),
+                    compute_dtype=jnp.bfloat16, platform="tpu")
+        for sub in subs:
+            x = sub.apply(x, ctx)
+        return x.astype(jnp.float32).sum()
+
+    hlo = jax.jit(jax.value_and_grad(loss, (0, 1))).lower(
+        params, x).compile().as_text()
+    calls = [name for name, _ in _custom_calls(hlo)
+             if "penroz_hc_mix" in name]
+    assert len(calls) == 3 * 8, calls
+    state = "|".join(",".join(map(str, dims)) for dims in HC_STATE)
+    assert not re.findall(rf" = bf16\[({state})\]\S* copy\(", hlo)
+    assert not re.findall(rf"f32\[({state}|4,4096,3584|4096,4,3584)\]", hlo)
+    comps = groups.parse_computations(hlo)
+    rows = []
+    groups.walk(comps, groups.result_types(comps), "__entry__", 1, rows,
+                [list(dims) for dims in HC_STATE])
+    assert {g for g, *_ in rows} <= {
+        "penroz_hc_mix", "jvp_penroz_hc_mix_", "transpose_jvp_penroz_hc_mix__",
+        "broadcast", "reduce_sum"}, sorted({g for g, *_ in rows})
+    moved = sum(runs * nbytes for _, runs, _, nbytes in rows)
+    assert 4.5e9 < moved < 6.2e9, moved

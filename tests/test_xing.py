@@ -236,6 +236,142 @@ def test_hyperconnected_expands_copies_and_reduces_to_their_sum():
         _hc_module(n, d).apply(x, M.Ctx(params, platform="cpu"))
 
 
+def _plain_mix(mod, X, ctx):
+    """The mixing's formulas written out plainly (the module's own until
+    PR 48): float32 copies of the streams, the Phi product on ``X`` laid out
+    ``(B·T, n·d)``, every map and multiply-add left to autodiff."""
+    B, T, n, d = X.shape
+    flat = X.reshape(B * T, n * d)
+    r = jax.lax.rsqrt(jnp.mean(jnp.square(flat.astype(jnp.float32)),
+                               axis=-1) + mod.eps)
+    u = jnp.matmul(flat, mod._p(ctx, "phi.weight").T,
+                   preferred_element_type=jnp.float32)
+    u = (u * r[:, None]).T
+    alpha = ctx.params[mod.key("alpha")].astype(jnp.float32)
+    bias = ctx.params[mod.key("bias")].astype(jnp.float32)[:, None]
+    pre = jax.nn.sigmoid(alpha[0] * u[:n] + bias[:n])
+    post = 2.0 * jax.nn.sigmoid(alpha[1] * u[n:2 * n] + bias[n:2 * n])
+    res = jnp.clip(alpha[2] * u[2 * n:] + bias[2 * n:], *mod.res_clamp)
+    res = M.sinkhorn(res.reshape(n, n, B * T), mod.sinkhorn_iters,
+                     mod.hc_eps)
+    col = lambda t: t.reshape(B, T, 1)
+    streams = [X[:, :, j, :].astype(jnp.float32) for j in range(n)]
+    x_in = sum(col(pre[i]) * streams[i] for i in range(n))
+    y = mod.body.apply(x_in.astype(X.dtype), ctx).astype(jnp.float32)
+    new = [sum(col(res[i, j]) * streams[j] for j in range(n))
+           + col(post[i]) * y for i in range(n)]
+    if mod.reduce:
+        return sum(new).astype(X.dtype)
+    return jnp.stack(new, axis=2).astype(X.dtype)
+
+
+@pytest.mark.parametrize("path", ["fused", "interpret"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("n,ends", [
+    (4, {}), (2, {}), (4, {"expand": True}), (4, {"reduce": True}),
+    (2, {"expand": True, "reduce": True})],
+    ids=["n4", "n2", "n4_expand", "n4_reduce", "n2_expand_reduce"])
+def test_hyperconnected_passes_match_the_formulas_written_out(
+        monkeypatch, n, ends, dtype, path):
+    """The mixing as passes over the state with a hand-written backward
+    (``_hc_read`` / ``_hc_write``) against the formulas written out plainly
+    in float32: forward and every gradient (the state, the body's weight
+    through ``y``, Phi, alpha, the biases).  ``fused``: the passes as XLA
+    gets them off the TPU; ``interpret``: the TPU's kernel
+    (``ops/pallas/hc_mix.py``) interpreted, at a shape its tiles admit.
+    Float32 to 1e-5; bfloat16 state and weights against the float32 formulas
+    to the limit the configuration's gradient is held to."""
+    from penroz_tpu.ops.pallas import hc_mix
+    d, T = (128, hc_mix.TOKEN_TILE) if path == "interpret" else (24, 16)
+    assert hc_mix.fits(T, d, n, 4) == (path == "interpret")
+    keys = jax.random.split(jax.random.key(9), 4)
+    hc = _mixing(keys[0], n, d)
+    body_w = 0.3 * jax.random.normal(keys[1], (d, d))
+    shape = (2, T, d) if ends.get("expand") else (2, T, n, d)
+    X = jax.random.normal(keys[2], shape)
+    mod = _hc_module(n, d, **ends)
+    plan = mod._cfg
+    monkeypatch.setattr(M.HyperConnected, "_cfg", lambda self, X, ctx: plan(
+        X, ctx)._replace(path=path))
+    w = jax.random.normal(keys[3], mod.apply(
+        X, M.Ctx(_hc_params(hc, body_w, d), platform="cpu")).shape)
+
+    def loss(mix, compute, hc, body_w, X):
+        ctx = M.Ctx(_hc_params(hc, body_w, d), platform="cpu",
+                    compute_dtype=compute)
+        X = X.astype(compute)
+        if mix is _plain_mix and mod.expand:
+            X = jnp.broadcast_to(X[:, :, None, :], (2, T, n, d))
+        out = (mod.apply(X, ctx) if mix is None
+               else mix(mod, X, ctx)).astype(jnp.float32)
+        return (out * w).sum(), out
+
+    grad = lambda mix, compute: jax.jit(jax.value_and_grad(
+        lambda *a: loss(mix, compute, *a), (0, 1, 2), has_aux=True))(
+            hc, body_w, X)
+    ((_, got), got_grads) = grad(None, dtype)
+    ((_, want), want_grads) = grad(_plain_mix, jnp.float32)
+    if dtype == jnp.float32:
+        _close(got, want, 1e-5)
+        for g, r in zip(*map(jax.tree.leaves, (got_grads, want_grads))):
+            _close(g, r, 1e-5 * max(1.0, float(jnp.abs(r).max())))
+        return
+    with open(CONFIG, encoding="utf-8") as f:
+        limit = json.load(f)["correct"]["grad_rel_err"]
+    flat = lambda tree: dict(enumerate(jax.tree.leaves(tree)))
+    assert xing.tree_rel_error(flat(got), flat(want)) < limit
+    for name, g, r in zip(("hc", "body", "X"), got_grads, want_grads):
+        assert xing.tree_rel_error(flat(g), flat(r)) < limit, name
+
+
+def test_hc_mix_plan_is_logged_once_and_spanned_per_trace(caplog,
+                                                          monkeypatch):
+    """Which path a compile's mixing took, beside ``hc plan:``: one INFO line
+    a distinct plan and a ``penroz/hc_mix_plan`` span under whatever span of
+    a job's trace is compiling (``GET /trace/{id}``): the kernel on one TPU
+    where its tiles admit the shape, the fused passes anywhere else (off the
+    TPU, under a mesh, at a toy width)."""
+    import logging
+    from jax.sharding import Mesh
+    from penroz_tpu.ops import attention as attn_ops
+    from penroz_tpu.utils import tracing
+    meshed = attn_ops.Placement("tpu", Mesh(np.array(jax.devices()[:2]),
+                                            ("data",)))
+    toy, cell = [_hc_module(4, 24)], [_hc_module(4, 3584)]
+    tokens = jax.ShapeDtypeStruct((1, 4096), jnp.int32)
+    # the server's log_config.json, once a test of this worker has loaded
+    # it, keeps the package's records from the root logger caplog hears
+    monkeypatch.setattr(logging.getLogger("penroz_tpu"), "propagate", True)
+    M._log_plan.cache_clear()
+    tracing.reset()
+    trace = tracing.maybe_trace("hc-mix-plan-job", job=True, route="/train/")
+    with caplog.at_level(logging.INFO, logger=M.__name__), \
+            tracing.use(trace), tracing.span("penroz/train_dispatch"):
+        M.record_hc_plan(cell, tokens, True, "tpu", 2)
+        M.record_hc_plan(cell, tokens, True, "tpu", 2)
+        M.record_hc_plan(cell, tokens, True, "cpu", 2)
+        M.record_hc_plan(cell, tokens, True, meshed, 2)
+        M.record_hc_plan(toy, tokens, True, "tpu", 4)
+    lines = [r.getMessage() for r in caplog.records
+             if r.getMessage().startswith("hc_mix plan:")]
+    assert lines == [
+        "hc_mix plan: path=kernel streams=4 features=3584 tokens=4096 "
+        "bytes=528482304",
+        "hc_mix plan: path=fused streams=4 features=3584 tokens=4096 "
+        "bytes=528482304",
+        "hc_mix plan: path=fused streams=4 features=24 tokens=4096 "
+        "bytes=7077888"]
+    dispatch = trace.to_dict()["root"]["children"][0]
+    spans = [c["meta"] for c in dispatch["children"]
+             if c["name"] == "penroz/hc_mix_plan"]
+    assert [m["path"] for m in spans] == ["kernel", "kernel", "fused",
+                                          "fused", "fused"]
+    assert sum(c["name"] == "penroz/hc_plan"
+               for c in dispatch["children"]) == 5
+    trace.finish("completed")
+
+
 # -- the router ---------------------------------------------------------------
 
 def _router(bias, experts=16, k=4, **kw):

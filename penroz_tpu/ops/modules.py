@@ -2291,38 +2291,85 @@ class CausalSelfAttention(Module):
             k_flat = self._head_rmsnorm(k_flat, self._p(ctx, "k_norm.weight"))
         return q_flat, k_flat
 
-    def _head_norm_and_rope(self, q, k, ctx, offset, seq_axis: int):
-        """Per-head qk-norm, then RoPE from ``offset``, on head-split q, k
-        whose sequence is axis ``seq_axis``: both are elementwise per head,
-        so ``(B, H, T, D)`` and ``(B, T, H, D)`` serve alike."""
+    def rope_plan(self, batch: int, seq: int, head_dim: int, in_place: bool,
+                  itemsize: int) -> dict:
+        """How a call's rotation runs: ``path`` ``kernel``
+        (``ops/pallas/rope.py``: q and k turned where they lie in one pass;
+        ``in_place``: attention stays in ``(B, T, H·D)``
+        (:meth:`_apply_in_model_layout`) on one TPU (:func:`_one_tpu`);
+        and the kernel's tiles admit the shapes: whole heads of a multiple
+        of 128 dims, all of them rotated) or ``xla``
+        (``ops/attention.py::apply_rope`` on head-split views), and the
+        ``bytes`` a rotation has to move: q and k read once and written
+        once."""
+        from penroz_tpu.ops.pallas import rope
+        rotary = self._rotary_dim(head_dim)
+        kernel = in_place and rope.fits(seq, head_dim, rotary)
+        return {"path": "kernel" if kernel else "xla",
+                "heads": self.num_heads, "kv_heads": self.num_kv_heads,
+                "D": head_dim, "T": seq, "rotary_dim": rotary or head_dim,
+                "bytes": rope.moved_bytes(batch, seq, self.num_heads,
+                                          self.num_kv_heads, head_dim,
+                                          itemsize)}
+
+    def _head_norm_and_rope(self, q, k, ctx, offset, seq_axis: int,
+                            rope: bool = True):
+        """Per-head qk-norm, then (``rope``) RoPE from ``offset``, on
+        head-split q, k whose sequence is axis ``seq_axis``: both are
+        elementwise per head, so ``(B, H, T, D)`` and ``(B, T, H, D)`` serve
+        alike."""
         if self.qk_norm and self.qk_norm_scope == "head":
             q = self._head_rmsnorm(q, self._p(ctx, "q_norm.weight"))
             k = self._head_rmsnorm(k, self._p(ctx, "k_norm.weight"))
-        if self.rope_theta is not None:
+        if rope and self.rope_theta is not None:
             q, k = attn_ops.apply_rope(
                 q, k, self.rope_theta, offset, scaling=self.rope_scaling,
                 rotary_dim=self._rotary_dim(q.shape[-1]), seq_axis=seq_axis)
         return q, k
 
+    def _record_rope_plan(self, qkv, ctx, head_dim: int,
+                          in_model_layout: bool) -> bool:
+        """The rotation's plan of a traced layer on the record
+        (:func:`_record_plan`); whether the kernel turns q and k."""
+        B, T, _ = qkv.shape
+        plan = self.rope_plan(
+            B, T, head_dim, in_model_layout and _one_tpu(qkv, ctx.platform),
+            qkv.dtype.itemsize)
+        _record_plan("rope", **plan)
+        return plan["path"] == "kernel"
+
     def _apply_in_model_layout(self, qkv, ctx, head_dim: int):
         """No cache, no sequence parallelism, shapes the ``btd`` flash entry
         takes: q, k, v never leave ``(B, T, ·)``.  With nothing between the
         projection and the kernels (no qk-norm, no RoPE) they read the fused
-        array in place; the norms and the rotation are elementwise per head
-        and run on ``(B, T, H, D)`` views, no transpose."""
+        array in place, and so does the rotation's kernel where
+        :meth:`rope_plan` says ``kernel``; the norms, and the rotation where
+        it says ``xla``, are elementwise per head and run on ``(B, T, H,
+        D)`` views, no transpose."""
         B, T, _ = qkv.shape
         heads, kv_heads = self.num_heads, self.num_kv_heads
         q_dim, kv_dim = heads * head_dim, kv_heads * head_dim
-        arrays = (qkv,)
-        if self.qk_norm or self.rope_theta is not None:
+        turned_here = (self.rope_theta is not None
+                       and self._record_rope_plan(qkv, ctx, head_dim, True))
+        q = k = None        # the fused array's lanes serve
+        if self.qk_norm or (self.rope_theta is not None and not turned_here):
             q, k = self._flat_norm(qkv[..., :q_dim],
                                    qkv[..., q_dim:q_dim + kv_dim], ctx)
             q, k = self._head_norm_and_rope(
                 q.reshape(B, T, heads, head_dim),
                 k.reshape(B, T, kv_heads, head_dim), ctx, ctx.offset(),
-                seq_axis=1)
-            arrays = (q.reshape(B, T, q_dim), k.reshape(B, T, kv_dim),
-                      qkv[..., q_dim + kv_dim:])
+                seq_axis=1, rope=not turned_here)
+            q, k = q.reshape(B, T, q_dim), k.reshape(B, T, kv_dim)
+        arrays = (qkv,) if q is None else (q, k, qkv[..., q_dim + kv_dim:])
+        if turned_here:
+            from penroz_tpu.ops.pallas import rope
+            cos, sin = attn_ops.rope_cos_sin(
+                head_dim, self.rope_theta, ctx.offset(), T, jnp.float32,
+                scaling=self.rope_scaling)
+            # the fused projection comes back as q, k (turned) and v; q and
+            # k apart as the two of them, and v stays the slice it was
+            arrays = rope.rotate(qkv if q is None else q, k, cos, sin,
+                                 heads=heads, kv_heads=kv_heads) + arrays[2:]
         dropout_rate = self.dropout if ctx.training else 0.0
         return attn_ops.causal_attention_btd(
             *arrays, heads=heads, kv_heads=kv_heads,
@@ -2354,6 +2401,8 @@ class CausalSelfAttention(Module):
                     qkv, T, head_dim, self.num_heads, self.num_kv_heads,
                     ctx.platform, self.logit_softcap)):
             return self._apply_in_model_layout(qkv, ctx, head_dim)
+        if self.rope_theta is not None and ctx.kv is None:
+            self._record_rope_plan(qkv, ctx, head_dim, False)
 
         q_flat, k_flat = self._flat_norm(qkv[..., :q_dim],
                                          qkv[..., q_dim:q_dim + kv_dim], ctx)

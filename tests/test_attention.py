@@ -1672,9 +1672,14 @@ def test_flash_plan_names_its_layout(caplog):
 
 
 def _interpreted_kernels(monkeypatch, calls):
-    """Both flash entries in interpret mode, each call noted by layout: what
-    ``platform="tpu"`` dispatches to on a CPU."""
+    """Both flash entries in interpret mode, each call noted by layout, and
+    the rotation's kernel with them: what ``platform="tpu"`` dispatches to
+    on a CPU."""
     from penroz_tpu.ops.pallas import flash_attention as FA
+    from penroz_tpu.ops.pallas import rope
+    rotate = rope.rotate
+    monkeypatch.setattr(rope, "rotate", lambda *args, **kwargs: rotate(
+        *args, **kwargs, path="interpret"))
     real = {name: getattr(FA, name)
             for name in ("flash_attention", "flash_attention_btd")}
 
@@ -1694,7 +1699,9 @@ _MODULE_CASES = {
     # GPT-2: nothing between the projection and the kernels
     "fused": (dict(num_heads=4), 64),
     "alibi_window": (dict(num_heads=4, alibi=True, sliding_window=100), 64),
-    # RoPE and qk-norm run on (B, T, H, D) views, then three arrays
+    # RoPE at whole heads of 128: the kernel turns the fused projection's q
+    # and k where they lie (after a per-head qk-norm, the norm's two arrays);
+    # partial RoPE and the norms run on (B, T, H, D) views; three arrays
     "rope_gqa": (dict(num_heads=4, num_kv_heads=2, rope_theta=1e4), 128),
     "partial_rope": (dict(num_heads=2, rope_theta=1e4, rope_pct=0.5), 128),
     "qk_norm_head": (dict(num_heads=2, qk_norm=True, head_dim=128,

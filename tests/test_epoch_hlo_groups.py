@@ -100,6 +100,41 @@ def test_walk_keeps_the_instructions_that_touch_a_shape(groups, touching,
     assert all(runs == (1 if g == "copy" else 12) for g, runs, *_ in rows)
 
 
+@pytest.mark.parametrize("shapes,want", [
+    (["1024,3072", "768,3072"], {"convolution_add_fusion": (1, 12)}),
+    (["1024,768", "768,1024", "4,4"],
+     {"convolution_add_fusion": (1, 12), "copy-done": (1, 12),
+      "copy": (1, 1)}),
+    (["768,1024", "3072,1024"], {})],
+    ids=["two_shapes_of_one_instruction", "every_instruction", "none"])
+def test_a_kept_text_is_counted_touching_several_shapes(groups, tmp_path,
+                                                        monkeypatch, capsys,
+                                                        shapes, want):
+    """The command as PR 49 used it on the looped cell's program:
+    ``--compiled <kept text> --touching a --touching b …`` compiles nothing,
+    says which shapes it kept to, lists the groups that touch any of them
+    (instructions, runs with the trip counts in) and a total that is 0 where
+    nothing does."""
+    kept = tmp_path / "epoch.hlo"
+    kept.write_text(HLO)
+    argv = ["epoch_hlo_groups.py",
+            os.path.join(ROOT, "benchmark", "configs",
+                         "gpt2-124m-nanogpt.json"), "--compiled", str(kept)]
+    for dims in shapes:
+        argv += ["--touching", dims]
+    monkeypatch.setattr("sys.argv", argv)
+    monkeypatch.setattr(groups, "compile_epoch", lambda cfg: pytest.fail(
+        "a kept text is counted, not compiled"))
+    groups.main()
+    lines = capsys.readouterr().out.splitlines()
+    assert lines[0] == "instructions touching " + " ".join(shapes) + " only"
+    rows = {line.split()[0]: tuple(int(n) for n in line.split()[1:3])
+            for line in lines[3:]}
+    total = rows.pop("total")
+    assert rows == want
+    assert total == tuple(sum(r[i] for r in want.values()) for i in (0, 1))
+
+
 @pytest.mark.parametrize("name", [
     "gpt2-124m-nanogpt", "ouro-2.6b-loop4-6l", "laguna-s-2.1-ep32-5l",
     "xing4.0-29b-a4b-ep8-5l"])

@@ -161,6 +161,30 @@ def _ssm_scan():
                                                jnp.float32)]
 
 
+def _rope(backward, shapes, heads, kv_heads, dtype=jnp.bfloat16,
+          per_row=False):
+    """``ops/pallas/rope.py::rotate`` on a fused projection (one shape) or
+    on q and k apart (two), forward or value and gradient; ``per_row``: a
+    table a row, ``(B, T, D)``."""
+    from penroz_tpu.ops import attention as attn_ops
+    from penroz_tpu.ops.pallas import rope
+    B, T, width = shapes[0]
+    D = width // (heads + 2 * kv_heads if len(shapes) == 1 else heads)
+
+    def turn(*arrays):
+        offset = jnp.arange(B) * 3 if per_row else 0
+        cos, sin = attn_ops.rope_cos_sin(D, 1e4, offset, T, jnp.float32)
+        return rope.rotate(*arrays, *[None] * (2 - len(arrays)), cos, sin,
+                           heads=heads, kv_heads=kv_heads)
+
+    def loss(*arrays):
+        return sum((o.astype(jnp.float32) ** 2).sum() for o in turn(*arrays))
+
+    fn = jax.grad(loss, argnums=tuple(range(len(shapes)))) if backward \
+        else turn
+    return fn, [(shape, dtype) for shape in shapes]
+
+
 CASES = {
     "flash_fwd": _flash_fwd,
     "flash_bwd": _flash_bwd,
@@ -225,6 +249,20 @@ CASES = {
     "flash_btd_bwd_chunked": lambda: _flash_btd(
         True, (ROWS, BLOCK, 3 * HEADS * HEAD_DIM), heads=HEADS, window=700,
         vmem_budget=2 ** 20),
+    # the rotation where q and k lie (PR 49).  The looped cell's fused
+    # projection, 16 + 16 + 16 heads of 128
+    "rope_fwd_loop4k": lambda: _rope(False, [(2, 4096, 6144)], 16, 16),
+    "rope_bwd_loop4k": lambda: _rope(True, [(2, 4096, 6144)], 16, 16),
+    # Laguna's sliding layers: 9 query heads on one K/V head, T = 8192
+    "rope_bwd_9on1_t8192": lambda: _rope(True, [(1, 8192, 11 * 128)], 9, 1),
+    # after a qk-norm: q and k apart; a head two registers wide; float32,
+    # a table a row
+    "rope_bwd_apart": lambda: _rope(
+        True, [(2, 1024, 8 * 128), (2, 1024, 2 * 128)], 8, 2),
+    "rope_bwd_d256": lambda: _rope(True, [(2, 1024, 8 * 256)], 4, 2),
+    "rope_bwd_f32_per_row": lambda: _rope(
+        True, [(2, 1024, 3 * HEADS * 128)], HEADS, HEADS, jnp.float32,
+        per_row=True),
     "decode_bf16": lambda: _decode(False),
     "decode_int8": lambda: _decode(True),
     "paged_bf16_page128": lambda: _paged(128, False),
@@ -415,6 +453,18 @@ def test_embedding_backward_under_a_mesh_gathers_rows_not_tables(chips):
     assert "all-reduce" not in hlo and "reduce-scatter" not in hlo
 
 
+def _epoch_hlo_groups():
+    """``scripts/epoch_hlo_groups.py``, loaded from its file."""
+    import importlib.util
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    spec = importlib.util.spec_from_file_location(
+        "epoch_hlo_groups", os.path.join(root, "scripts",
+                                         "epoch_hlo_groups.py"))
+    groups = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(groups)
+    return groups
+
+
 def _custom_calls(hlo: str) -> list:
     """(instruction name, result types) of every Mosaic custom call."""
     calls = []
@@ -576,6 +626,7 @@ def test_looped_stack_compiles_and_its_kernels_are_where_the_benchmark_looks(
     and the benchmark's two readers, loaded from their files, find them in a
     trace made of this program's instructions."""
     import importlib.util
+    import re
     import sys
     from penroz_tpu.models import dsl, presets
     from penroz_tpu.models.model import CompiledArch
@@ -607,9 +658,15 @@ def test_looped_stack_compiles_and_its_kernels_are_where_the_benchmark_looks(
         not count("penroz_flash_bwd_dkv"), calls
     assert count("penroz_ce_fwd") == steps, calls
     assert count("penroz_ce_bwd") == steps, calls
-    assert len(calls) == 5 * steps, calls
-    # attention stays in the model's layout at D = 128 after RoPE
+    # the rotation: forward, again in the backward's recomputation, and
+    # turned back over the cotangents (PR 49)
+    assert count("penroz_rope") == 3 * steps, calls
+    assert len(calls) == 8 * steps, calls
+    # attention stays in the model's layout at D = 128, RoPE included: no
+    # head-major array, no head-split view of q, k or their halves
     assert "bf16[2,16,4096,128]" not in hlo
+    assert not re.findall(r"\[[\d,]*,16,(?:128|64)\]", hlo)
+    assert "pad_maximum_fusion" not in hlo
 
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     sys.path.insert(0, root)
@@ -642,6 +699,55 @@ def test_looped_stack_compiles_and_its_kernels_are_where_the_benchmark_looks(
     assert readers["penroz_ce_roofline"].read(art) == pytest.approx(
         100.0 * steps * (least(ce["fwd"]) + least(ce["bwd"]))
         / (2 * steps * 1e-3))
+
+
+ROPE_VIEWS = ([2, 4096, 16, 128], [2, 4096, 16, 64], [1024, 8, 16, 128],
+              [4096, 16, 128], [512, 8, 16, 128])
+
+
+def test_looped_cells_attention_turns_q_and_k_where_they_lie(chip):
+    """The looped cell's attention (``ouro-train-4k-loop4``: the fused
+    ``(2, 4096, 6144)`` projection of 16 + 16 + 16 heads of 128, bf16, RoPE
+    at θ = 1e6), value and gradient, compiled for a v5e.  The rotation is
+    two ``penroz_rope`` calls (q, k turned and v carried across to the flash
+    entry's three arrays; their cotangents turned back into the
+    projection's), and nothing of XLA's rotation is left: no array in a
+    head-split shape (``(…, 16, 128)``, the halves' ``(…, 16, 64)``, the
+    compiler's tiles of them), no ``pad_maximum_fusion`` (``rotate_half``'s
+    concatenate), and by ``scripts/epoch_hlo_groups.py --touching``'s rule
+    no instruction touches one, where the parent's epoch program moved 88.9
+    GB an optimizer step through them (PERF.md §6, PR 49).  The plan line an
+    operator reads is pinned with it."""
+    import re
+    from penroz_tpu.ops import modules as M
+    groups = _epoch_hlo_groups()
+    attn = M.CausalSelfAttention(num_heads=16, rope_theta=1e6)
+    attn.bind("attn")
+    plan = attn.rope_plan(2, 4096, 128, True, 2)
+    assert " ".join(f"{k}={v}" for k, v in plan.items()) == (
+        "path=kernel heads=16 kv_heads=16 D=128 T=4096 rotary_dim=128 "
+        "bytes=134217728")
+    assert attn.rope_plan(2, 4096, 128, False, 2)["path"] == "xla"
+    qkv = jax.ShapeDtypeStruct((2, 4096, 6144), jnp.bfloat16, sharding=chip)
+
+    def loss(qkv):
+        out = attn.apply(qkv, M.Ctx({}, platform="tpu"))
+        return out.astype(jnp.float32).sum()
+
+    hlo = jax.jit(jax.value_and_grad(loss)).lower(qkv).compile().as_text()
+    calls = _custom_calls(hlo)
+    turned = [types for name, types in calls if "penroz_rope" in name]
+    assert len(turned) == 2 and len(calls) == 5, calls
+    # forward: q, k, v apart; backward: the fused projection's cotangent
+    assert len(re.findall(r"bf16\[2,4096,2048\]", turned[0])) == 3, calls
+    assert re.match(r"bf16\[2,4096,6144\]", turned[1]), calls
+    assert not re.findall(r"\[[\d,]*,16,(?:128|64)\]", hlo)
+    assert "pad_maximum_fusion" not in hlo and " transpose(" not in hlo
+    comps = groups.parse_computations(hlo)
+    rows = []
+    groups.walk(comps, groups.result_types(comps), "__entry__", 1, rows,
+                [list(dims) for dims in ROPE_VIEWS])
+    assert rows == []
 
 
 def test_a_bare_checkpoint_still_runs_the_flash_forward_twice(chip):
@@ -769,6 +875,10 @@ def test_laguna_block_compiles_for_v5e_at_the_cells_shapes(chip, kind, heads):
     assert count("penroz_moe_gmm_bwd_dx") == 2 * 3, calls
     assert count("penroz_moe_gmm_bwd_dw") == 2 * 3, calls
     assert count("penroz_moe_combine") == 3, calls
+    # the sliding layers rotate whole heads: q and k turned where they lie,
+    # and turned back over the cotangents; the full layers rotate half a
+    # head and keep apply_rope (PR 49)
+    assert count("penroz_rope") == (2 if kind == "sliding_attention" else 0)
     # no scatter of rows of the model's width but the embedding's own, and
     # none as long as the (token, choice) pairs or the rows' bound
     scatters = [tuple(int(n) for n in dims.split(","))
@@ -876,15 +986,9 @@ def test_stream_mixing_moves_its_state_in_kernel_passes_alone(chip):
     where the formulas written out plainly moved 4.2 GB a sub-block
     (PERF.md §6, PR 48).  The plan line an operator reads beside ``hc
     plan:`` is pinned with it."""
-    import importlib.util
     import re
     from penroz_tpu.ops import modules as M
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    spec = importlib.util.spec_from_file_location(
-        "epoch_hlo_groups", os.path.join(root, "scripts",
-                                         "epoch_hlo_groups.py"))
-    groups = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(groups)
+    groups = _epoch_hlo_groups()
     d, n, T = 3584, 4, 4096
     linear = lambda: M.Sequential(M.RMSNorm(d), M.Linear(d, d, bias=False))
     subs = [M.HyperConnected(d, linear(), streams=n, expand=True),

@@ -292,29 +292,26 @@ class CompiledArch:
             raise ValueError("a model holds at most one looped stack, as a "
                              "top-level layer")
         self.looped: Optional[M.Looped] = looped[0] if looped else None
-        # Dropless expert layers count what they route
-        # (ops/modules.py::MOE_COUNTERS); a training epoch sums the counts
-        # and returns them after its other results.
-        self.counts_routing = any(
-            isinstance(m, M.MixtureOfExperts) and m.dispatch == "dropless"
-            for top in self.mods for m in top.walk())
-        # A multi-stream residual's sub-blocks and latent-attention layers
-        # (ops/modules.py::HyperConnected, LatentAttention); what they and
-        # a router's selection bias report as a largest value
-        # (ops/modules.py::MAX_COUNTERS) leaves the epoch with the counts.
+        # a multi-stream residual's sub-blocks, the latent-attention layers
         walked = [m for top in self.mods for m in top.walk()]
         self.hyper = [m for m in walked if isinstance(m, M.HyperConnected)]
         self.latent = [m for m in walked if isinstance(m, M.LatentAttention)]
-        self.max_counters = tuple(name for name, present in (
-            ("hc_sinkhorn_err", bool(self.hyper)),
-            ("moe_bias_absmax", any(
-                isinstance(m, M.MixtureOfExperts) and m.selection_bias
-                for m in walked))) if present)
         self.param_order: list[str] = []
-        for mod in self.mods:
-            for sub in mod.walk():
-                for name in sub.param_shapes():
-                    self.param_order.append(sub.key(name))
+        # What the modules declare to report of a training call, by name
+        # (ops/modules.py::Stat): a training epoch folds it over its
+        # micro-steps and returns it after its other results.
+        self.step_stats: dict[str, M.Stat] = {}
+        for sub in walked:
+            for name in sub.param_shapes():
+                self.param_order.append(sub.key(name))
+            for stat in sub.stats():
+                if (self.step_stats.setdefault(stat.name, stat) != stat
+                        or stat.reduce not in ("mean", "sum", "max")
+                        or stat.family not in tracing.TRAIN_FAMILIES):
+                    raise ValueError(
+                        f"{type(sub).__name__} declares {stat}: a mean, sum "
+                        f"or max of a family of utils/tracing.py, and one "
+                        f"declaration a name ({self.step_stats[stat.name]})")
         self.attn_layers: list[M.CausalSelfAttention] = []
         self.ssm_layers: list[M.GatedSSM] = []
         self._index_attention()
@@ -499,8 +496,8 @@ class CompiledArch:
         updates: ``(activations, cost, ctx, new_kv)``.  The cost of a model
         with several exits (a looped stack) is the expected loss over each
         token's exit distribution (``ops/losses.py::expected_exit_loss``),
-        and ``ctx.exit_stats`` then holds each pass's mean loss and the
-        mean exit distribution."""
+        which also gives what the stack declares to report: each pass's
+        mean loss and the mean exit distribution."""
         acts, logits, ctx = self._apply(
             params, buffers, tokens, training=training, rng=rng, kv=kv,
             pos_offset=pos_offset, skip_softmax=skip_softmax,
@@ -511,8 +508,10 @@ class CompiledArch:
         if targets is None:
             cost = None
         elif ctx.exits is not None:
-            cost, ctx.exit_stats = losses.expected_exit_loss(
+            cost, exits = losses.expected_exit_loss(
                 *ctx.exits, self.looped.entropy_weight)
+            for stat in self.looped.stats():
+                ctx.report(stat, exits[stat.name])
         else:
             cost = self._cost_from_logits(logits, targets, platform=platform)
         if cost is not None and ctx.aux_losses:
@@ -531,18 +530,19 @@ class CompiledArch:
     def _train_loss_fn(self, compute_dtype, sp_mesh, platform, sp_mode,
                        ep_mesh):
         """``fn(params, buffers, x, y, rng) -> (cost, (buffer updates,
-        stats))`` of one training micro-step; the stats are ``None`` but
-        for a model with several exits (each pass's loss and exit mass) or
-        with dropless expert layers (their routing counters)."""
+        stats))`` of one training micro-step; the stats are what the
+        modules reported of it, under the names they declare."""
         def loss_fn(params, buffers, x, y, rng):
             _, cost, ctx, _ = self._forward(
                 params, buffers, x, y, training=True, rng=rng,
                 skip_softmax=True, compute_dtype=compute_dtype,
                 sp_mesh=sp_mesh, platform=platform, sp_mode=sp_mode,
                 ep_mesh=ep_mesh)
-            stats = {**(ctx.exit_stats or {}), **(ctx.moe_stats or {}),
-                     **ctx.max_stats}
-            return cost, (ctx.buffer_updates, stats or None)
+            stats, declared = ctx.reported(), sorted(self.step_stats)
+            if sorted(stats) != declared:
+                raise ValueError(f"the modules declare {declared} and "
+                                 f"reported {sorted(stats)}")
+            return cost, (ctx.buffer_updates, stats)
         return loss_fn
 
     def jit_forward(self, params, buffers, tokens, targets=None, *,
@@ -611,14 +611,10 @@ class CompiledArch:
 
         Returns ``fn(params, opt_state, buffers, xs, ys, rng) ->
         (params, opt_state, buffers, cost, weight_update_ratios)`` where
-        ``xs``/``ys`` are ``(num_steps, B, T)`` token batches.  A model with
-        several exits (a looped stack) returns a sixth result after those
-        five, ``{"pass_loss": (steps,), "exit_mass": (steps,)}``: each
-        pass's mean cross-entropy and the mean exit distribution of the
-        epoch.  A model with dropless expert layers returns that sixth
-        result too, holding the epoch's routing counters
-        (``ops/modules.py::MOE_COUNTERS``, summed over layers and
-        micro-steps).
+        ``xs``/``ys`` are ``(num_steps, B, T)`` token batches.  A model
+        whose modules declare statistics (``ops/modules.py::Stat``) returns
+        a sixth result: the declared statistics, one dict by name, each
+        folded over the epoch's micro-steps by its declared rule.
 
         ``with_ratios=False`` compiles a variant that skips the per-weight
         update-ratio stds (two full passes over the parameters) — the
@@ -643,16 +639,11 @@ class CompiledArch:
         # the exact residency the OOM lever exists to avoid.  Stacked, the
         # blocks run once more (fwd, outer replay, per-block replay) in
         # exchange for the bound holding everywhere.
-        shard_key = None
-        if out_shardings is not None:
-            shard_key = (tuple(sorted(out_shardings[0].items())),
-                         tuple(jax.tree.leaves(out_shardings[1])))
-        key = ("epoch", json.dumps(optimizer_config, sort_keys=True),
-               int(num_steps), bool(remat), str(compute_dtype), sp_mesh,
-               platform, bool(with_ratios), shard_key, sp_mode,
-               (pipe_cfg[0], pipe_cfg[1], pipe_cfg[2], pipe_cfg[3])
-               if pipe_cfg else None,
-               pipe_remat if pipe_cfg is not None else None, ep_mesh)
+        key = ("epoch", *self._train_key(
+            optimizer_config, num_steps, remat, compute_dtype, sp_mesh,
+            platform, with_ratios, out_shardings, sp_mode, ep_mesh),
+            tuple(pipe_cfg[:4]) if pipe_cfg else None,
+            pipe_remat if pipe_cfg is not None else None)
         fn = self._jit_cache.get(key)
         if fn is not None:
             return fn
@@ -669,35 +660,20 @@ class CompiledArch:
 
             def loss_fn(*args):
                 cost, buf_upd = piped(*args)
-                return cost, (buf_upd, None)
+                return cost, (buf_upd, {})
 
-        if remat:
-            loss_fn = jax.checkpoint(loss_fn)
-        grad_fn = jax.value_and_grad(loss_fn, has_aux=True)
+        cast, step = self._micro_step_fns(loss_fn, remat, compute_dtype)
 
         def epoch(params, opt_state, buffers, xs, ys, rng):
-            # Cast params to the compute dtype ONCE per epoch, outside the
-            # micro-step scan — the cast's VJP is an upcast of the incoming
-            # (bf16) gradients, so accumulating them in fp32 below yields
-            # bit-identical grads to casting inside every micro-step while
-            # saving num_steps-1 full passes over the parameters.
-            if compute_dtype is not None:
-                params_c = {
-                    k: v.astype(compute_dtype)
-                    if jnp.issubdtype(v.dtype, jnp.floating) else v
-                    for k, v in params.items()}
-            else:
-                params_c = params
+            # ONCE per epoch, outside the micro-step scan: accumulating the
+            # cast's upcast gradients in fp32 yields bit-identical grads to
+            # casting inside every micro-step, num_steps-1 passes fewer.
+            params_c = cast(params)
 
             def micro(carry, batch):
                 grads_acc, bufs, cost_acc, i = carry
-                x, y = batch
-                (cost, (upd, exits)), grads = grad_fn(
-                    params_c, bufs, x, y, jax.random.fold_in(rng, i))
-                bufs = {**bufs, **upd}
-                grads_acc = jax.tree.map(
-                    lambda a, g: a + g.astype(jnp.float32), grads_acc, grads)
-                cost_acc = self.add_costs(cost_acc, cost, exits)
+                bufs, grads_acc, cost_acc = step(
+                    params_c, bufs, grads_acc, cost_acc, *batch, rng, i)
                 return (grads_acc, bufs, cost_acc, i + 1), None
 
             zeros = jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32),
@@ -714,6 +690,48 @@ class CompiledArch:
         self._jit_cache[key] = fn
         return fn
 
+    @staticmethod
+    def _train_key(optimizer_config, num_steps, remat, compute_dtype,
+                   sp_mesh, platform, with_ratios, out_shardings, sp_mode,
+                   ep_mesh) -> tuple:
+        """What the cache keys of the fused epoch program and of the
+        micro-stepped pair share."""
+        shard_key = None
+        if out_shardings is not None:
+            shard_key = (tuple(sorted(out_shardings[0].items())),
+                         tuple(jax.tree.leaves(out_shardings[1])))
+        return (json.dumps(optimizer_config, sort_keys=True), int(num_steps),
+                bool(remat), str(compute_dtype), sp_mesh, platform,
+                bool(with_ratios), shard_key, sp_mode, ep_mesh)
+
+    def _micro_step_fns(self, loss_fn, remat: bool, compute_dtype):
+        """A training micro-step, written once for the fused epoch's scan
+        and the dispatched micro program: ``(cast, step)``.  ``cast(params)``
+        is the parameters in the compute dtype (its VJP upcasts the incoming
+        gradients); ``step`` takes the loss and its gradient at
+        ``fold_in(rng, i)``, merges the buffer updates, adds the gradient in
+        float32 and folds the cost and the statistics."""
+        if remat:
+            loss_fn = jax.checkpoint(loss_fn)
+        grad_fn = jax.value_and_grad(loss_fn, has_aux=True)
+
+        def cast(params):
+            if compute_dtype is None:
+                return params
+            return {k: v.astype(compute_dtype)
+                    if jnp.issubdtype(v.dtype, jnp.floating) else v
+                    for k, v in params.items()}
+
+        def step(params_c, bufs, grads_acc, cost_acc, x, y, rng, i):
+            (cost, (upd, stats)), grads = grad_fn(
+                params_c, bufs, x, y, jax.random.fold_in(rng, i))
+            bufs = {**bufs, **upd}
+            grads_acc = jax.tree.map(
+                lambda a, g: a + g.astype(jnp.float32), grads_acc, grads)
+            return bufs, grads_acc, self.add_costs(cost_acc, cost, stats)
+
+        return cast, step
+
     def _finalize_update_fn(self, optimizer, num_steps: int, out_shardings,
                             with_ratios: bool, pipe_cfg):
         """Pure epoch tail shared by the fused epoch program and the
@@ -723,13 +741,13 @@ class CompiledArch:
 
         def finalize(params, opt_state, grads, new_buffers, cost_sum):
             inv = 1.0 / num_steps
-            # means over the micro-steps; the routing counters stay sums
-            # and what is a largest value stays one
-            exits = {k: c if k in M.MOE_COUNTERS + M.MAX_COUNTERS
-                     else c * inv for k, c in cost_sum.items()}
+            # a mean is its sum over the micro-steps ÷ their number
+            cost = cost_sum["cost"] * inv
+            stats = {name: cost_sum[name] * inv if stat.reduce == "mean"
+                     else cost_sum[name]
+                     for name, stat in self.step_stats.items()}
             new_buffers = self.end_step(new_buffers)
-            cost = exits.pop("cost")
-            exits = (exits,) if exits else ()   # looped or dropless only
+            stats = (stats,) if stats else ()   # only where one is declared
             grads = jax.tree.map(
                 lambda g, p: (g * inv).astype(p.dtype), grads, params)
             updates, new_opt_state = optimizer.update(grads, opt_state, params)
@@ -741,7 +759,7 @@ class CompiledArch:
                     new_opt_state, out_shardings[1])
             if not with_ratios:
                 return (new_params, new_opt_state, new_buffers, cost, None,
-                        *exits)
+                        *stats)
             # per-weight update ratio std(Δw)/std(w) (reference :686-700)
 
             def ratio(dw_src, w_src, stacked=False):
@@ -773,34 +791,24 @@ class CompiledArch:
             ratios = (jnp.stack([ratio_map[k] for k in self.param_order])
                       if self.param_order else jnp.zeros((0,)))
             return (new_params, new_opt_state, new_buffers, cost, ratios,
-                    *exits)
+                    *stats)
 
         return finalize
 
     def zero_cost_sum(self) -> dict:
         """What a training epoch accumulates over its micro-steps beside the
-        gradient: the cost and, for a model with several exits (a looped
-        stack), each pass's mean loss and the mean exit distribution, and
-        for one with dropless expert layers their routing counters, which
-        then leave the epoch program after its five results."""
-        zeros = lambda *shape: jnp.zeros(shape, jnp.float32)
+        gradient: the cost and what the modules declare to report, which
+        then leaves the epoch program after its five results."""
         # an array each: the micro-stepped path donates the accumulator
-        sums = {"cost": zeros()}
-        if self.looped is not None:
-            sums.update(pass_loss=zeros(self.looped.steps),
-                        exit_mass=zeros(self.looped.steps))
-        if self.counts_routing:
-            sums.update({name: zeros() for name in M.MOE_COUNTERS})
-        sums.update({name: zeros() for name in self.max_counters})
-        return sums
+        return {"cost": jnp.zeros((), jnp.float32), **{
+            name: stat.empty() for name, stat in self.step_stats.items()}}
 
-    @staticmethod
-    def add_costs(cost_acc: dict, cost, stats) -> dict:
+    def add_costs(self, cost_acc: dict, cost, stats: dict) -> dict:
         """:meth:`zero_cost_sum`'s accumulator after one more micro-step:
-        sums, but for what is a largest value (``M.MAX_COUNTERS``)."""
-        new = {"cost": cost, **(stats or {})}
-        return {k: jnp.maximum(v, new[k]) if k in M.MAX_COUNTERS
-                else v + new[k] for k, v in cost_acc.items()}
+        the cost summed, each statistic folded by its declared rule."""
+        return {"cost": cost_acc["cost"] + cost,
+                **{name: stat.fold(cost_acc[name], stats[name])
+                   for name, stat in self.step_stats.items()}}
 
     def train_micro_fns(self, optimizer_config: dict, num_steps: int,
                         remat: bool = False, compute_dtype=None,
@@ -823,49 +831,30 @@ class CompiledArch:
         - ``finalize_fn(params, opt_state, grads, buffers, cost)`` → the
           epoch fn's results.
 
-        Numerics match the fused epoch to fp tolerance: same
-        ``fold_in(rng, i)`` stream, same fp32 accumulation order, the
-        identical finalize body (``_finalize_update_fn``) — bitwise
-        equality is NOT guaranteed (the standalone micro program fuses
-        differently than the scanned epoch body).  The params'
-        compute-dtype cast runs
-        once per micro dispatch instead of once per epoch — identical
-        values, ``num_steps-1`` extra cast passes, the price of
-        preemptibility.  Pipelined (``pipe_cfg``) training keeps the
-        fused path: its schedule is one shard_map program by design.
+        Numerics match the fused epoch to fp tolerance: the same micro-step
+        body (``_micro_step_fns``) and finalize body
+        (``_finalize_update_fn``) — bitwise equality is NOT guaranteed (the
+        standalone micro program fuses differently than the scanned epoch
+        body).  The params' compute-dtype cast runs once per micro dispatch
+        instead of once per epoch — identical values, ``num_steps-1`` extra
+        cast passes, the price of preemptibility.  Pipelined (``pipe_cfg``)
+        training keeps the fused path: one shard_map program by design.
         """
-        key = ("microstep", json.dumps(optimizer_config, sort_keys=True),
-               int(num_steps), bool(remat), str(compute_dtype), sp_mesh,
-               platform, bool(with_ratios),
-               (tuple(sorted(out_shardings[0].items())),
-                tuple(jax.tree.leaves(out_shardings[1])))
-               if out_shardings is not None else None, sp_mode, ep_mesh)
+        key = ("microstep", *self._train_key(
+            optimizer_config, num_steps, remat, compute_dtype, sp_mesh,
+            platform, with_ratios, out_shardings, sp_mode, ep_mesh))
         cached = self._jit_cache.get(key)
         if cached is not None:
             return cached
 
         optimizer = dsl.build_optimizer(optimizer_config)
 
-        loss_fn = self._train_loss_fn(compute_dtype, sp_mesh, platform,
-                                      sp_mode, ep_mesh)
-        if remat:
-            loss_fn = jax.checkpoint(loss_fn)
-        grad_fn = jax.value_and_grad(loss_fn, has_aux=True)
+        cast, step = self._micro_step_fns(
+            self._train_loss_fn(compute_dtype, sp_mesh, platform, sp_mode,
+                                ep_mesh), remat, compute_dtype)
 
         def micro(params, bufs, grads_acc, cost_acc, x, y, rng, i):
-            if compute_dtype is not None:
-                params_c = {
-                    k: v.astype(compute_dtype)
-                    if jnp.issubdtype(v.dtype, jnp.floating) else v
-                    for k, v in params.items()}
-            else:
-                params_c = params
-            (cost, (upd, exits)), grads = grad_fn(
-                params_c, bufs, x, y, jax.random.fold_in(rng, i))
-            bufs = {**bufs, **upd}
-            grads_acc = jax.tree.map(
-                lambda a, g: a + g.astype(jnp.float32), grads_acc, grads)
-            return bufs, grads_acc, self.add_costs(cost_acc, cost, exits)
+            return step(cast(params), bufs, grads_acc, cost_acc, x, y, rng, i)
 
         finalize = self._finalize_update_fn(optimizer, num_steps,
                                             out_shardings, with_ratios,
@@ -1696,21 +1685,13 @@ class NeuralNetworkModel:
                          cost, ratios) = out[:5]
                     with tracing.span("penroz/train_wait"):
                         cost = float(cost)
-                        # a looped stack's exits: each pass's mean loss and
-                        # the mean exit distribution, one host read
-                        exits = ({k: np.asarray(v, np.float64).tolist()
+                        # what the modules declare to report, one host read
+                        declared = self.arch.step_stats
+                        stats = ({k: declared[k].on_host(v)
                                   for k, v in out[5].items()}
                                  if len(out) > 5 else {})
-                        # dropless expert layers: what the epoch routed
-                        routed = {k: int(exits.pop(k))
-                                  for k in M.MOE_COUNTERS if k in exits}
-                        # a multi-stream residual's Sinkhorn error, a
-                        # router's largest selection bias
-                        peaks = {k: float(exits.pop(k))
-                                 for k in M.MAX_COUNTERS if k in exits}
-                    epoch_span.set(**tracing.exit_counters(exits),
-                                   **tracing.routing_counters(routed),
-                                   **tracing.peak_counters(peaks))
+                    epoch_span.set(**tracing.train_stat_counters(
+                        stats, {k: declared[k].family for k in stats}))
                 duration = time.monotonic() - t0
                 if master:
                     if epoch % sample_every == 0:
@@ -1721,7 +1702,7 @@ class NeuralNetworkModel:
                             "speedPerSec": buffer_size / max(duration, 1e-9),
                             "weight_upd_ratio":
                                 np.asarray(ratios, np.float64).tolist(),
-                            **exits, **routed, **peaks,
+                            **stats,
                         })
                     log.info("Epoch %d: cost=%.4f %.0f tokens/sec",
                              epoch + 1, cost,

@@ -42,6 +42,37 @@ from penroz_tpu.utils import tracing
 log = logging.getLogger(__name__)
 
 
+class Stat(NamedTuple):
+    """What a module reports of a training call: declared beside the module
+    (:meth:`Module.stats`), reported through :meth:`Ctx.report`.  By these
+    fields alone the epoch program accumulates it and returns it under its
+    name, and the job's ``penroz/train_epoch`` span, its ``/progress/`` rows
+    and ``GET /metrics`` show it."""
+
+    name: str
+    reduce: str          # "mean", "sum" or "max": how reports fold, over
+    #                      the modules of a call and an epoch's micro-steps
+    family: str          # on /metrics: ``penroz_train_<family>``
+    #                      (utils/tracing.py::TRAIN_FAMILIES)
+    shape: tuple = ()    # () a scalar; (n,) one value a pass
+    host: type = float   # what it leaves the epoch as (``int``: a count)
+
+    def fold(self, seen, value):
+        """``seen`` after one more report: the larger for ``max``, the sum
+        for ``sum`` and for ``mean``, which is the sum ÷ the reports."""
+        return (jnp.maximum if self.reduce == "max" else jnp.add)(seen, value)
+
+    def empty(self):
+        """What folds to nothing: where an epoch's accumulator starts."""
+        return jnp.full(self.shape, -jnp.inf if self.reduce == "max" else 0.0,
+                        jnp.float32)
+
+    def on_host(self, value):
+        """An epoch's ``value`` on the host: ``host``, or a list a pass."""
+        value = np.asarray(value, np.float64)
+        return list(map(self.host, value)) if self.shape else self.host(value)
+
+
 class Ctx:
     """Per-call context threaded through module application.
 
@@ -102,14 +133,9 @@ class Ctx:
         # a pass holds.
         self.targets = targets
         self.exits = None
-        self.exit_stats = None  # the model's, from ``exits``: for counters
-        # What the dropless expert layers routed in this call, summed over
-        # them (:data:`MOE_COUNTERS`); ``None`` in a model with none.
-        self.moe_stats = None
-        # What a call's modules report as a largest value, over them
-        # (:data:`MAX_COUNTERS`); empty in a model with none.
-        self.max_stats = {}
         self.layer_offset = 0
+        # by name, what the modules reported: (Stat, folded value, reports)
+        self.stats = {}
         self.buffer_updates = {}
         self.aux_losses = []  # auxiliary training losses (e.g. MoE balance)
         self._rng_counter = 0
@@ -120,12 +146,20 @@ class Ctx:
         self._rng_counter += 1
         return jax.random.fold_in(self.rng, self._rng_counter)
 
-    def note_max(self, name: str, value):
-        """Keep the larger of ``value`` and what ``name`` read so far."""
+    def report(self, stat: Stat, value, reports: int = 1):
+        """Fold ``value`` into what the call reports as ``stat`` by its rule;
+        float32, and no gradient flows through it.  ``reports``: how many
+        ``value`` folds already (:func:`_recomputed`'s hand-over)."""
         value = jax.lax.stop_gradient(value.astype(jnp.float32))
-        seen = self.max_stats.get(name)
-        self.max_stats[name] = (value if seen is None
-                                else jnp.maximum(seen, value))
+        if stat.name in self.stats:
+            _, seen, n = self.stats[stat.name]
+            value, reports = stat.fold(seen, value), n + reports
+        self.stats[stat.name] = (stat, value, reports)
+
+    def reported(self) -> dict:
+        """``{name: value}`` of what the call reported."""
+        return {name: value / n if stat.reduce == "mean" else value
+                for name, (stat, value, n) in self.stats.items()}
 
     def offset(self):
         """Current sequence position offset (0 when no cache attached)."""
@@ -177,6 +211,11 @@ class Module:
         last micro-step (``CompiledArch.end_step``), from the buffers as the
         micro-steps left them."""
         return {}
+
+    def stats(self) -> Sequence[Stat]:
+        """What this module reports of every training call
+        (:meth:`Ctx.report`), its children's apart."""
+        return ()
 
     # -- application --------------------------------------------------------
     def apply(self, x, ctx: Ctx):
@@ -1003,7 +1042,7 @@ class HyperConnected(Module):
     ``body`` brings its own pre-norm.  Sinkhorn is ``sinkhorn_iters`` times
     columns then rows of ``exp(·)`` divided by their sums + ``hc_eps``, so
     H_res's rows sum to 1 and its columns nearly (``hc_sinkhorn_err``, the
-    largest |column sum − 1| of a call, :data:`MAX_COUNTERS`); the gradient
+    largest |column sum − 1| of a call, :attr:`SINKHORN_ERR`); the gradient
     goes through every iteration.  ``expand``: the input is ``(B, T, d)``
     and every stream starts as a copy of it (a model's first sub-block);
     ``reduce``: the result is Σ_i X'[i] ``(B, T, d)`` (its last).
@@ -1075,6 +1114,12 @@ class HyperConnected(Module):
     def children(self):
         return [("body", self.body)]
 
+    # |column sum - 1| of the mixing matrix after its last iteration
+    SINKHORN_ERR = Stat("hc_sinkhorn_err", "max", "hc")
+
+    def stats(self):
+        return (self.SINKHORN_ERR,)
+
     @property
     def maps(self) -> int:
         return 2 * self.streams + self.streams ** 2
@@ -1134,8 +1179,8 @@ class HyperConnected(Module):
     def _mix(self, X, ctx):
         cfg = self._cfg(X, ctx)
         x_in, post, res, X = _hc_read(cfg, X, *self._own(ctx))
-        ctx.note_max("hc_sinkhorn_err",
-                     jnp.max(jnp.abs(jnp.sum(res, axis=0) - 1.0)))
+        ctx.report(self.SINKHORN_ERR,
+                   jnp.max(jnp.abs(jnp.sum(res, axis=0) - 1.0)))
         return _hc_write(cfg.path, self.reduce, X, self.body.apply(x_in, ctx),
                          post, res)
 
@@ -1168,35 +1213,32 @@ def _recomputed(fn, ctx, mods, *args):
     function of ``mods``' own parameters and ``args``: the backward keeps
     ``args`` and what the kernels' callers name (:func:`_kept_names`) and
     runs the rest of the inside again.  What the inside leaves on its
-    context (auxiliary losses, buffer updates, the routing counters, the
-    largest values, the dropout counter) is handed on to ``ctx``."""
+    context (auxiliary losses, buffer updates, what its modules reported,
+    the dropout counter) is handed on to ``ctx``."""
     if not ctx.training:
         return fn(ctx, *args)
     prefixes = tuple(m.prefix + "." for m in mods)
     own = {k: v for k, v in ctx.params.items() if k.startswith(prefixes)}
-    counter = [ctx._rng_counter]
+    left = {}   # what the inside leaves that is no array
 
     def pure(params, rng, *inputs):
         inner = copy.copy(ctx)
         inner.params, inner.rng = params, rng
-        inner.aux_losses, inner.buffer_updates = [], {}
-        inner.moe_stats, inner.max_stats = None, {}
+        inner.aux_losses, inner.buffer_updates, inner.stats = [], {}, {}
         out = fn(inner, *inputs)
-        counter[0] = inner._rng_counter
+        left.update(rng_counter=inner._rng_counter, stats=inner.stats)
         return (out, inner.aux_losses, inner.buffer_updates,
-                inner.moe_stats or {}, inner.max_stats)
+                {name: value for name, (_, value, _) in inner.stats.items()})
 
     keep = jax.checkpoint_policies.save_only_these_names(*_kept_names())
-    out, aux, updates, routed, largest = jax.checkpoint(pure, policy=keep)(
+    out, aux, updates, reported = jax.checkpoint(pure, policy=keep)(
         own, ctx.rng, *args)
-    ctx._rng_counter = counter[0]
+    ctx._rng_counter = left["rng_counter"]
     ctx.aux_losses.extend(aux)
     ctx.buffer_updates.update(updates)
-    if routed:
-        ctx.moe_stats = {name: value + (ctx.moe_stats or {}).get(name, 0.0)
-                         for name, value in routed.items()}
-    for name, value in largest.items():
-        ctx.note_max(name, value)
+    for name, value in reported.items():
+        stat, _, reports = left["stats"][name]
+        ctx.report(stat, value, reports)
     return out
 
 
@@ -1251,6 +1293,13 @@ class Looped(Module):
         return ([(f"body.{i}", b) for i, b in enumerate(self.body)]
                 + [("norm", self.norm), ("head", self.head),
                    ("gate", self.gate)])
+
+    def stats(self):
+        """Each pass's mean cross-entropy and the mean exit distribution:
+        the model works them out with its loss over the exits
+        (``ops/losses.py::expected_exit_loss``) and reports them."""
+        return tuple(Stat(name, "mean", name, shape=(self.steps,))
+                     for name in ("pass_loss", "exit_mass"))
 
     def plan(self, training: bool) -> dict:
         """The loop's counters.  ``kept_outputs``: the names the
@@ -1349,21 +1398,6 @@ def _top_k_bwd(k, kept, cotangents):
 
 
 _top_k.defvjp(_top_k_fwd, _top_k_bwd)
-
-
-# What a dropless expert layer counts of a call, summed over the layers of a
-# model and the micro-steps of an epoch (``penroz/train_epoch`` counters,
-# ``/progress/`` rows, ``/metrics``): pairs routed to held experts, rows the
-# grouped products computed (the groups padded to whole tiles), the fullest
-# held expert's rows (the per-layer maxima summed), pairs that found no row.
-MOE_COUNTERS = ("moe_rows", "moe_rows_padded", "moe_load_max", "moe_dropped")
-# What a call's modules report as a largest value, the largest over the
-# layers of a model and the micro-steps of an epoch (the same three places):
-# the largest |column sum - 1| of a multi-stream residual's mixing matrix
-# after its last Sinkhorn iteration (:class:`HyperConnected`), and the
-# largest magnitude of a router's selection bias
-# (:class:`MixtureOfExperts` with ``selection_bias``).
-MAX_COUNTERS = ("hc_sinkhorn_err", "moe_bias_absmax")
 
 
 class _DroplessConfig(NamedTuple):
@@ -1764,6 +1798,18 @@ class MixtureOfExperts(Module):
                 * jnp.sign(jnp.mean(load) - load),
                 self.key("selection_load"): jnp.zeros_like(load)}
 
+    # What a dropless layer counts of a call: pairs routed to held experts,
+    # rows the grouped products computed (the groups padded to whole tiles),
+    # the fullest held expert's rows (the per-layer maxima summed), pairs
+    # that found no row.
+    ROUTED = tuple(Stat(name, "sum", "moe", host=int) for name in (
+        "moe_rows", "moe_rows_padded", "moe_load_max", "moe_dropped"))
+    BIAS_ABSMAX = Stat("moe_bias_absmax", "max", "hc")   # selection bias
+
+    def stats(self):
+        return ((self.ROUTED if self.dispatch == "dropless" else ())
+                + ((self.BIAS_ABSMAX,) if self.selection_bias else ()))
+
     def router_weights(self, x, ctx):
         """(B, T, held) combine weights of the experts held: scores over
         all → top-k → renormalize → scale."""
@@ -1791,7 +1837,7 @@ class MixtureOfExperts(Module):
                  else jax.nn.softmax(logits, axis=-1))
         if self.selection_bias:
             bias = ctx.buffers[self.key("selection_bias")]
-            ctx.note_max("moe_bias_absmax", jnp.max(jnp.abs(bias)))
+            ctx.report(self.BIAS_ABSMAX, jnp.max(jnp.abs(bias)))
             _, top_idx = jax.lax.top_k(
                 jax.lax.stop_gradient(probs + bias.astype(jnp.float32)),
                 self.top_k)
@@ -1953,13 +1999,10 @@ class MixtureOfExperts(Module):
         y, placed = _dropless_rows(
             cfg, x.reshape(tokens, d), weight, w_gate, w_up, w_down, layout,
             -(-layout.padded // plan["rows"]))
-        stats = {"moe_rows": routed_rows, "moe_rows_padded": layout.padded,
-                 "moe_load_max": jnp.max(layout.sizes),
-                 "moe_dropped": routed_rows - placed}
-        ctx.moe_stats = {
-            name: jax.lax.stop_gradient(value.astype(jnp.float32))
-            + (ctx.moe_stats or {}).get(name, 0.0)
-            for name, value in stats.items()}
+        for stat, value in zip(self.ROUTED, (
+                routed_rows, layout.padded, jnp.max(layout.sizes),
+                routed_rows - placed)):
+            ctx.report(stat, value)
         return y.reshape(B, T, d)
 
     # Tokens per dispatch group.  One-hot dispatch costs

@@ -39,12 +39,10 @@ gauges     engines, active_rows, queue_depth, batch_occupancy,
            source counters live in ops/kv_cache.py),
            jit_programs{function} (live compiled-program count per jit
            family — the ragged descriptor compile-churn guard),
-           train_pass_loss{pass}, train_exit_mass{pass} (a looped model's
-           exits, newest /train/ epoch; utils/tracing.py),
-           train_moe{counter} (the dropless expert layers' routing
-           counters, newest /train/ epoch; utils/tracing.py),
-           train_hc{counter} (a multi-stream residual's Sinkhorn error and
-           a router's largest selection bias, newest /train/ epoch)
+           train_pass_loss{pass}, train_exit_mass{pass},
+           train_moe{counter}, train_hc{counter} (what a model's modules
+           declare to report of a training epoch, newest /train/ epoch:
+           utils/tracing.py::TRAIN_FAMILIES)
 histograms ttft_ms, itl_ms, queue_wait_ms, chunk_stall_ms, tick_ms
            (fixed LATENCY_BUCKETS_MS buckets; cumulative ``_bucket``
            series sum to ``_count`` — asserted by the strict-format
@@ -263,10 +261,8 @@ SESSION_RESUME_TTFT_MS = REGISTRY.register(m.Histogram(
 # Every span of every /train/ job by name (train_epoch, ckpt_save and its
 # children, ...): the object lives with the code that observes it.
 TRAIN_SPAN_MS = REGISTRY.register(tracing.TRAIN_SPAN_MS)
-TRAIN_PASS_LOSS = REGISTRY.register(tracing.TRAIN_PASS_LOSS)
-TRAIN_EXIT_MASS = REGISTRY.register(tracing.TRAIN_EXIT_MASS)
-TRAIN_MOE = REGISTRY.register(tracing.TRAIN_MOE)
-TRAIN_HC = REGISTRY.register(tracing.TRAIN_HC)
+TRAIN_STATS = [REGISTRY.register(gauge)
+               for gauge in tracing.TRAIN_STAT_GAUGES]
 
 # -- gauges (scrape-time reads of live state) -------------------------------
 

@@ -139,76 +139,45 @@ TRAIN_SPAN_MS = metrics.Histogram(
     "(utils/tracing.py: train_epoch, ckpt_save and its children, ...), ms",
     labelnames=("span",))
 
-# The newest epoch's exits of a looped model's /train/ job, by pass (from 1):
-# each pass's mean cross-entropy and the mean exit distribution.  Counters
-# ``pass_loss_<t>`` / ``exit_mass_<t>`` of ``penroz/train_epoch``, and
-# ``penroz_train_pass_loss{pass}`` / ``penroz_train_exit_mass{pass}`` on
-# GET /metrics (registered by serve/metrics.py).
-_TRAIN_EXITS: dict = {"pass_loss": {}, "exit_mass": {}}
-TRAIN_PASS_LOSS = metrics.Gauge(
-    "penroz_train_pass_loss",
-    "Mean cross-entropy of each exit of a looped model, newest /train/ "
-    "epoch", fn=lambda: _TRAIN_EXITS["pass_loss"], labelnames=("pass",))
-TRAIN_EXIT_MASS = metrics.Gauge(
-    "penroz_train_exit_mass",
-    "Mean exit distribution of a looped model over its passes, newest "
-    "/train/ epoch", fn=lambda: _TRAIN_EXITS["exit_mass"],
-    labelnames=("pass",))
+# What the modules of a model report of a training epoch, by the ``family``
+# each statistic's declaration names: ``penroz_train_<family>{<label>}`` on
+# GET /metrics (registered by serve/metrics.py), the newest epoch's reading.
+# A statistic with one value a pass has a family of its own, labelled by the
+# pass (from 1); scalars share a family, labelled by their names.
+TRAIN_FAMILIES = {
+    "pass_loss": ("pass", "Mean cross-entropy of each exit of a looped "
+                  "model, newest /train/ epoch"),
+    "exit_mass": ("pass", "Mean exit distribution of a looped model over "
+                  "its passes, newest /train/ epoch"),
+    "moe": ("counter", "Counts of the newest /train/ epoch, summed over "
+            "layers and micro-steps (the dropless expert layers' routing)"),
+    "hc": ("counter", "Largest values of the newest /train/ epoch over "
+           "layers and micro-steps (a multi-stream residual's Sinkhorn "
+           "error, a router's selection bias)"),
+}
+_TRAIN_STATS: dict = {}     # family -> {label value: newest reading}
+TRAIN_STAT_GAUGES = [
+    metrics.Gauge(f"penroz_train_{family}", text,
+                  fn=lambda family=family: _TRAIN_STATS.get(family, {}),
+                  labelnames=(label,))
+    for family, (label, text) in TRAIN_FAMILIES.items()]
 
 
-def exit_counters(exits: dict) -> dict:
-    """``{"pass_loss": [...], "exit_mass": [...]}`` of one epoch as span
-    counters ``{"pass_loss_1": ..., "exit_mass_1": ..., ...}``; the same
-    values become the gauges' newest reading."""
-    for name, values in exits.items():
-        _TRAIN_EXITS[name] = {str(t + 1): v for t, v in enumerate(values)}
-    return {f"{name}_{t + 1}": v for name, values in exits.items()
-            for t, v in enumerate(values)}
-
-
-# The newest epoch's routing counters of a model with dropless expert layers
-# (``ops/modules.py::MOE_COUNTERS``): counters of ``penroz/train_epoch`` under
-# their own names, and ``penroz_train_moe{counter}`` on GET /metrics.
-_TRAIN_ROUTING: dict = {}
-TRAIN_MOE = metrics.Gauge(
-    "penroz_train_moe",
-    "Routing counters of the dropless expert layers, newest /train/ epoch, "
-    "summed over layers and micro-steps: moe_rows (pairs routed to held "
-    "experts), moe_rows_padded (rows the grouped products computed), "
-    "moe_load_max (the fullest held expert's rows), moe_dropped",
-    fn=lambda: _TRAIN_ROUTING, labelnames=("counter",))
-
-
-def routing_counters(routed: dict) -> dict:
-    """One epoch's routing counters as span counters (as they are); the
-    same values become the gauge's newest reading."""
-    if routed:
-        _TRAIN_ROUTING.clear()
-        _TRAIN_ROUTING.update(routed)
-    return routed
-
-
-# The newest epoch's largest values of a model with a multi-stream residual
-# or a router with a selection bias (``ops/modules.py::MAX_COUNTERS``):
-# counters of ``penroz/train_epoch`` under their own names, and
-# ``penroz_train_hc{counter}`` on GET /metrics.
-_TRAIN_PEAKS: dict = {}
-TRAIN_HC = metrics.Gauge(
-    "penroz_train_hc",
-    "Largest values of the newest /train/ epoch over layers and "
-    "micro-steps: hc_sinkhorn_err (|column sum - 1| of a multi-stream "
-    "residual's mixing matrix after its last Sinkhorn iteration), "
-    "moe_bias_absmax (a router's selection bias)",
-    fn=lambda: _TRAIN_PEAKS, labelnames=("counter",))
-
-
-def peak_counters(peaks: dict) -> dict:
-    """One epoch's largest values as span counters (as they are); the same
-    values become the gauge's newest reading."""
-    if peaks:
-        _TRAIN_PEAKS.clear()
-        _TRAIN_PEAKS.update(peaks)
-    return peaks
+def train_stat_counters(stats: dict, families: dict) -> dict:
+    """One epoch's statistics ``{name: value}`` as counters of its
+    ``penroz/train_epoch`` span: a list, one value a pass, as
+    ``<name>_<t>`` from 1, a scalar under its own name.  The same values
+    become the newest reading of ``families[name]``; a family this epoch
+    brings nothing for keeps its last."""
+    counters, fresh = {}, {}
+    for name, value in stats.items():
+        rows = ([(str(t), f"{name}_{t}", v) for t, v in enumerate(value, 1)]
+                if isinstance(value, list) else [(name, name, value)])
+        for label, counter, v in rows:
+            fresh.setdefault(families[name], {})[label] = v
+            counters[counter] = v
+    _TRAIN_STATS.update(fresh)
+    return counters
 
 
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"

@@ -56,7 +56,7 @@ def weights():
 def _program_loss(arch, params, x, y, training=True):
     _, cost, ctx, _ = arch._forward(params, {}, x, y, training=training,
                                     skip_softmax=True)
-    return cost, ctx.exit_stats
+    return cost, ctx.reported()
 
 
 def test_loss_gradient_and_exits_match_the_reference(arch, weights):

@@ -501,7 +501,7 @@ def test_moe_dropless_equals_dense_and_a_loop_under_a_skewed_router(
     assert plan["combine"] == "take"                # the kernel is the TPU's
     ctx = M.Ctx(params)
     dropless.apply(x, ctx)
-    assert -(-float(ctx.moe_stats["moe_rows_padded"]) // plan["rows"]) == took
+    assert -(-float(ctx.reported()["moe_rows_padded"]) // plan["rows"]) == took
     weight = jnp.cos(jnp.arange(x.size, dtype=jnp.float32)).reshape(x.shape)
 
     def loss(apply):
@@ -588,7 +588,7 @@ def test_moe_dropless_layout_is_a_stable_counting_sort(share, tile_of_4):
     assert -(-ends[-1] // cfg.rows) == took
     ctx = M.Ctx(params)
     mod.apply(x, ctx)
-    assert {k: float(v) for k, v in ctx.moe_stats.items()} == {
+    assert {k: float(v) for k, v in ctx.reported().items()} == {
         "moe_rows": sizes.sum(), "moe_rows_padded": ends[-1],
         "moe_load_max": sizes.max(), "moe_dropped": 0}
 
@@ -631,8 +631,8 @@ def test_moe_dropless_counts_what_the_router_chose_and_drops_nothing(
         chosen = np.asarray(mod.route(x, M.Ctx(params))[1]).ravel() - first
         loads = np.bincount(chosen[(chosen >= 0) & (chosen < held)],
                             minlength=held)
-        stats = {k: float(v) for k, v in ctx.moe_stats.items()}
-        assert set(stats) == set(M.MOE_COUNTERS)
+        stats = {k: float(v) for k, v in ctx.reported().items()}
+        assert set(stats) == {stat.name for stat in mod.stats()}
         assert stats["moe_rows"] == loads.sum() > 0
         assert stats["moe_rows_padded"] == (-(-loads // 4) * 4).sum()
         assert stats["moe_load_max"] == loads.max()

@@ -145,7 +145,7 @@ def test_hyperconnected_matches_the_reference_through_sinkhorn():
 
     def program(hc, body_w, X):
         ctx = M.Ctx(_hc_params(hc, body_w, d), platform="cpu")
-        return mod.apply(X, ctx), ctx.max_stats["hc_sinkhorn_err"]
+        return mod.apply(X, ctx), ctx.reported()["hc_sinkhorn_err"]
 
     def reference(hc, body_w, X):
         f = lambda u: jnp.matmul(xing._rmsnorm(u, jnp.ones((d,)), 1e-6),
@@ -178,7 +178,7 @@ def test_hyperconnected_recomputes_its_sub_block_in_training():
     def program(hc, body_w, X, training):
         ctx = M.Ctx(_hc_params(hc, body_w, d), platform="cpu",
                     training=training, rng=jax.random.key(0))
-        return mod.apply(X, ctx), ctx.max_stats["hc_sinkhorn_err"]
+        return mod.apply(X, ctx), ctx.reported()["hc_sinkhorn_err"]
 
     (got, got_err), (want, want_err) = (program(hc, body_w, X, True),
                                         program(hc, body_w, X, False))
@@ -401,7 +401,7 @@ def test_sigmoid_router_bias_moves_the_choice_and_not_the_weight():
         order = lambda w, e: jnp.take_along_axis(w, jnp.argsort(e, -1), -1)
         np.testing.assert_array_equal(np.sort(e, -1), np.sort(want_e, -1))
         _close(order(w, e), order(want_w, want_e))
-        assert float(ctx.max_stats["moe_bias_absmax"]) == pytest.approx(
+        assert float(ctx.reported()["moe_bias_absmax"]) == pytest.approx(
             float(np.abs(bias).max()))
         # the weights are sigma-proportional: the scores of the chosen,
         # without the bias, renormalised, times 2

@@ -59,6 +59,7 @@ _LEAF_ALGOS = {
     "attention": M.CausalSelfAttention,
     "latentattention": M.LatentAttention,
     "ssm": M.GatedSSM,
+    "mamba2": M.Mamba2Mixer,
     "gatedmlp": M.GatedMLP,
     "moe": M.MixtureOfExperts,
     "clamp": M.Clamp,
@@ -121,6 +122,13 @@ def to_layer(entry: dict) -> M.Module:
         mod = M.HyperConnected(
             body=to_layer(args["body"]),
             **{k: v for k, v in args.items() if k != "body"})
+    elif algo == "mixerblock":
+        # one mixer a layer, x + mixer(norm(x)): ops/modules.MixerBlock
+        if set(args) != {"norm", "mixer"}:
+            raise ValueError("mixerblock takes norm and mixer (one layer "
+                             f"entry each); got {sorted(args)}")
+        mod = M.MixerBlock(norm=to_layer(args["norm"]),
+                           mixer=to_layer(args["mixer"]))
     elif algo in _LEAF_ALGOS:
         mod = _LEAF_ALGOS[algo](**args)
     else:

@@ -296,6 +296,7 @@ class CompiledArch:
         walked = [m for top in self.mods for m in top.walk()]
         self.hyper = [m for m in walked if isinstance(m, M.HyperConnected)]
         self.latent = [m for m in walked if isinstance(m, M.LatentAttention)]
+        self.mixers = [m for m in walked if isinstance(m, M.Mamba2Mixer)]
         self.param_order: list[str] = []
         # What the modules declare to report of a training call, by name
         # (ops/modules.py::Stat): a training epoch folds it over its
@@ -372,6 +373,8 @@ class CompiledArch:
             self.refuse_looped("the paged KV pool (PAGED_KV_CACHE=1)")
         self.refuse_latent("a KV cache (/generate/, the paged pool, the "
                            "decode scheduler)")
+        self.refuse_mixer("a KV cache (/generate/, the paged pool, the "
+                          "decode scheduler)")
         specs = []
         for mod in self.attn_layers:
             if mod.head_dim is None:
@@ -397,6 +400,15 @@ class CompiledArch:
                 "only the expanded form is written (training, /evaluate/, "
                 "/output/); the latent cache and the absorbed decode path "
                 "are not")
+
+    def refuse_mixer(self, what: str):
+        """The one error for what a Mamba-2 mixer does not run."""
+        if self.mixers:
+            raise ValueError(
+                f"a model with a Mamba-2 mixer (mamba2) does not run with "
+                f"{what}: the mixer trains and runs uncached (/train/, "
+                "/evaluate/, /output/); its convolution and recurrent state "
+                "in a cache are not written")
 
     def end_step(self, buffers: dict) -> dict:
         """``buffers`` after what the modules do once an optimizer step

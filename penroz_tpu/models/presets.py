@@ -370,6 +370,104 @@ def xing_custom(d: int, heads: int, q_rank: int, kv_rank: int, d_nope: int,
             + [norm, linear(d, vocab), {"softmaxlast": {"dim": -1}}])
 
 
+def nemotron_h_custom(d: int, pattern: str, vocab: int, mamba_heads: int,
+                      mamba_head_dim: int, n_groups: int, state_size: int,
+                      conv_kernel: int, chunk_size: int, heads: int,
+                      kv_heads: int, head_dim: int, num_experts: int,
+                      top_k: int, moe_intermediate: int, latent: int,
+                      shared_intermediate: int, routed_scale: float = 1.0,
+                      norm_topk: bool = True,
+                      mamba_heads_held: int | None = None,
+                      first_mamba_head: int = 0,
+                      experts_held: int | None = None, first_expert: int = 0,
+                      bias_update_rate: float = 0.001,
+                      router_bias: list | None = None, eps: float = 1e-5,
+                      dt_min: float = 0.001, dt_max: float = 0.1,
+                      dt_floor: float = 1e-4,
+                      published_layers: int | None = None) -> list:
+    """Nemotron-H-shaped hybrid language model (NVIDIA ``nemotron_h``
+    ``config.json``) at arbitrary dimensions, whole or as one rank's share.
+
+    One mixer a layer (``mixerblock``: ``x + mixer(RMSNorm(x))``), a final
+    RMSNorm, an untied head.  ``pattern`` is the config's
+    ``hybrid_override_pattern``, one letter a layer:
+
+    - ``M`` a Mamba-2 mixer (``mamba2``): ``mamba_heads`` heads of
+      ``mamba_head_dim`` in ``n_groups`` groups, state ``state_size``, a
+      ``conv_kernel``-tap convolution, chunks of ``chunk_size``.
+    - ``E`` a LatentMoE (``moe``): a sigmoid router over ``num_experts``
+      with a selection bias, ``top_k`` a token, weights renormalised if
+      ``norm_topk`` times ``routed_scale``; experts ``relu(x W1)² W2`` of
+      ``moe_intermediate`` in a latent of ``latent`` the layer projects to
+      and from; beside one shared expert of ``shared_intermediate`` at the
+      full width; dropless.  ``router_bias``: one list of ``num_experts``
+      values an ``E`` layer, the selection bias's first value.
+    - ``*`` attention: ``heads`` query heads on ``kv_heads`` key/value
+      heads of ``head_dim``, causal, no rotary embedding, no bias.
+
+    The share: ``mamba_heads_held`` heads from ``first_mamba_head`` (whole
+    groups), ``experts_held`` experts from ``first_expert``, and the
+    caller's ``heads``, ``kv_heads`` and ``vocab`` already cut to what this
+    rank holds.
+
+    N(0, 0.02) initialisation of the linear layers, every projection onto
+    the residual path scaled by 1/sqrt(2 · ``published_layers``) (default:
+    the layers built)."""
+    unknown = sorted(set(pattern) - set("ME*"))
+    if unknown or not pattern:
+        raise ValueError(f"hybrid_override_pattern takes M, E and *, one a "
+                         f"layer; got {unknown or pattern!r}")
+    sparse = [i for i, kind in enumerate(pattern) if kind == "E"]
+    if router_bias is not None and len(router_bias) != len(sparse):
+        raise ValueError("router_bias names one list an E layer")
+    std = 0.02
+    proj_std = std / (2 * (published_layers or len(pattern))) ** 0.5
+    norm = {"rmsnorm": {"normalized_shape": d, "eps": eps}}
+
+    def linear(fan_in, fan_out, s=std):
+        return {"linear": {"in_features": fan_in, "out_features": fan_out,
+                           "bias": False},
+                "normal": {"mean": 0.0, "std": s}}
+
+    def mixer(i):
+        kind = pattern[i]
+        if kind == "M":
+            return {"mamba2": {
+                "in_features": d, "num_heads": mamba_heads,
+                "head_dim": mamba_head_dim, "state_size": state_size,
+                "n_groups": n_groups, "conv_kernel": conv_kernel,
+                "chunk_size": chunk_size,
+                "heads_held": mamba_heads_held or mamba_heads,
+                "first_head": first_mamba_head, "eps": eps,
+                "init_std": std, "out_init_std": proj_std,
+                "dt_min": dt_min, "dt_max": dt_max, "dt_floor": dt_floor}}
+        if kind == "*":
+            return {"sequential": [
+                linear(d, (heads + 2 * kv_heads) * head_dim),
+                {"attention": {"num_heads": heads, "num_kv_heads": kv_heads,
+                               "head_dim": head_dim}},
+                linear(heads * head_dim, d, proj_std)]}
+        args = {
+            "in_features": d, "intermediate_size": moe_intermediate,
+            "num_experts": num_experts, "top_k": top_k,
+            "activation": "relu2", "latent": latent, "norm_topk": norm_topk,
+            "routed_scale": routed_scale,
+            "shared_expert_size": shared_intermediate,
+            "shared_expert_gate": False, "dispatch": "dropless",
+            "experts_held": experts_held or num_experts,
+            "first_expert": first_expert, "scoring": "sigmoid",
+            "selection_bias": True, "bias_update_rate": bias_update_rate}
+        if router_bias is not None:
+            args["selection_bias_init"] = list(router_bias[sparse.index(i)])
+        return {"moe": args}
+
+    return ([{"embedding": {"num_embeddings": vocab, "embedding_dim": d},
+              "normal": {"mean": 0.0, "std": std}}]
+            + [{"mixerblock": {"norm": norm, "mixer": mixer(i)}}
+               for i in range(len(pattern))]
+            + [norm, linear(d, vocab), {"softmaxlast": {"dim": -1}}])
+
+
 def makemore_mlp(vocab: int = 27, d_embed: int = 10,
                  d_hidden: int = 200) -> list:
     """Char-level MLP in the makemore style (BASELINE.md CPU-parity config):

@@ -1377,6 +1377,19 @@ def _gated_activation(name: str, x):
     return jax.nn.gelu(x, approximate=(name == "gelu_pytorch_tanh"))
 
 
+# An expert that is not gated: ``relu(x W_up)² W_down``, two matrices and no
+# gate projection (``mlp_hidden_act: relu2``).
+UNGATED = "relu2"
+
+
+def _expert_hidden(activation: str, up, gate=None):
+    """An expert's hidden row from its projections: ``act(gate) · up``, or
+    ``relu(up)²`` for the expert that has no gate (:data:`UNGATED`)."""
+    if activation == UNGATED:
+        return jnp.square(jax.nn.relu(up))
+    return _gated_activation(activation, gate) * up
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
 def _top_k(probs, k: int):
     """``jax.lax.top_k`` whose gradient is a compare: ``dprobs[..., e] = Σ_j
@@ -1491,12 +1504,14 @@ def _round_rows(cfg, j, layout, weight) -> _RoundRows:
 
 
 def _round_out(cfg, xs, w_gate, w_up, w_down, tile_group):
-    """One round's rows through their experts: ``(rows, d)``."""
+    """One round's rows through their experts: ``(rows, d)``.  ``w_gate``
+    is ``None`` for experts that are not gated: two products, not three."""
     from penroz_tpu.ops.pallas import moe_gmm
     product = functools.partial(moe_gmm.grouped_matmul, tile_group=tile_group,
                                 row_tile=cfg.row_tile, on_tpu=cfg.on_tpu)
-    hidden = (_gated_activation(cfg.activation, product(xs, w_gate))
-              * product(xs, w_up))
+    hidden = _expert_hidden(
+        cfg.activation, product(xs, w_up),
+        None if w_gate is None else product(xs, w_gate))
     return product(hidden, w_down)
 
 
@@ -1579,14 +1594,17 @@ def _dropless_rows_fwd(cfg, x, weight, w_gate, w_up, w_down, layout, rounds):
 def _dropless_rows_bwd(cfg, kept, cotangents):
     x, weight, w_gate, w_up, w_down, layout, rounds = kept
     dy, _ = cotangents                   # the count has no derivative
-    stacks = (w_gate, w_up, w_down)
+    gated = w_gate is not None
+    stacks = (w_gate, w_up, w_down) if gated else (w_up, w_down)
+    whole = (lambda s: s) if gated else (lambda s: (None, *s))
 
     def round_grads(j, dx, dweight):
         """Round ``j`` recomputed and pulled back: its rows' gradients added
         into ``dx`` and ``dweight``, and the stacks' gradients it made."""
         rnd = _round_rows(cfg, j, layout, weight)
         _, pull = jax.vjp(
-            lambda xs, w, *s: (_round_out(cfg, xs, *s, rnd.groups).astype(
+            lambda xs, w, *s: (_round_out(cfg, xs, *whole(s),
+                                          rnd.groups).astype(
                 jnp.float32) * w[:, None]),
             x[rnd.tok], rnd.weight, *stacks)
         dxs, dw, *ds = pull(dy[rnd.tok].astype(jnp.float32))
@@ -1618,7 +1636,7 @@ def _dropless_rows_bwd(cfg, kept, cotangents):
     # no round at all (nothing routed here) takes the first branch too: its
     # one round finds padding rows and empty tiles only
     dx, dweight, dstacks = jax.lax.cond(rounds <= 1, one_round, many_rounds)
-    return (dx.astype(x.dtype), dweight, *dstacks, None, None)
+    return (dx.astype(x.dtype), dweight, *whole(dstacks), None, None)
 
 
 _dropless_rows.defvjp(_dropless_rows_fwd, _dropless_rows_bwd)
@@ -1668,7 +1686,20 @@ class MixtureOfExperts(Module):
                  routed_scale: float = 1.0, shared_expert_gate: bool = True,
                  scoring: str = "softmax", selection_bias: bool = False,
                  selection_bias_init: Optional[Sequence[float]] = None,
-                 bias_update_rate: float = 0.001):
+                 bias_update_rate: float = 0.001, latent: int = 0):
+        # ``latent`` (LatentMoE): the routed experts live at that width and
+        # the layer owns the way there and back, ``latent_down.weight``
+        # (latent, d) before them and ``latent_up.weight`` (d, latent)
+        # after their weighted sum; the router and the shared expert stay
+        # at the full width.  ``activation="relu2"``: experts (the shared
+        # one too) that are not gated, ``relu(x W_up)² W_down``: two stacks
+        # and no ``gate_proj``.
+        self.latent = int(latent)
+        if self.latent < 0:
+            raise ValueError(f"latent must be a width or 0, got {latent}")
+        if activation == UNGATED and dispatch == "capacity":
+            raise ValueError("dispatch 'capacity' computes gated experts; "
+                             "'relu2' takes 'dense' or 'dropless'")
         if scoring not in ("softmax", "sigmoid"):
             raise ValueError(f"scoring must be 'softmax' or 'sigmoid', "
                              f"got {scoring!r}")
@@ -1745,13 +1776,16 @@ class MixtureOfExperts(Module):
 
     def param_shapes(self):
         d, h, e = self.in_features, self.intermediate_size, self.num_experts
-        held = self.experts_held
+        held, w = self.experts_held, self.latent or self.in_features
         shapes = {
             "router.weight": (e, d),
-            "experts.gate_proj.weight": (held, h, d),
-            "experts.up_proj.weight": (held, h, d),
-            "experts.down_proj.weight": (held, d, h),
+            "experts.gate_proj.weight": (held, h, w),
+            "experts.up_proj.weight": (held, h, w),
+            "experts.down_proj.weight": (held, w, h),
         }
+        if self.latent:
+            shapes.update({"latent_down.weight": (w, d),
+                           "latent_up.weight": (d, w)})
         if self.shared_expert_size:
             hs = self.shared_expert_size
             shapes.update({
@@ -1761,6 +1795,9 @@ class MixtureOfExperts(Module):
             })
             if self.shared_expert_gate:
                 shapes["shared_expert_gate.weight"] = (1, d)
+        if self.activation == UNGATED:
+            shapes = {name: shape for name, shape in shapes.items()
+                      if "gate_proj" not in name}
         return shapes
 
     def init(self, rng):
@@ -1874,13 +1911,23 @@ class MixtureOfExperts(Module):
         return top_vals, top_idx
 
     def apply(self, x, ctx):
-        w_gate = self._p(ctx, "experts.gate_proj.weight")
+        if not self.latent:
+            return self._routed(x, x, ctx) + self._shared(x, ctx)
+        inner = jnp.matmul(x, self._p(ctx, "latent_down.weight").T)
+        routed = jnp.matmul(self._routed(x, inner, ctx),
+                            self._p(ctx, "latent_up.weight").T)
+        return routed + self._shared(x, ctx)
+
+    def _routed(self, x, inner, ctx):
+        """The weighted sum of the chosen held experts' outputs: routed by
+        ``x``, computed on ``inner`` (``x`` itself, or its latent)."""
+        gated = self.activation != UNGATED
+        w_gate = self._p(ctx, "experts.gate_proj.weight") if gated else None
         w_up = self._p(ctx, "experts.up_proj.weight")
         w_down = self._p(ctx, "experts.down_proj.weight")
         if self.dispatch == "dropless":
-            routed = self._apply_dropless(x, *self.route(x, ctx), w_gate,
-                                          w_up, w_down, ctx)
-            return routed + self._shared(x, ctx)
+            return self._apply_dropless(inner, *self.route(x, ctx), w_gate,
+                                        w_up, w_down, ctx)
         weights = self.router_weights(x, ctx).astype(x.dtype)
         if self.dispatch == "capacity":
             from penroz_tpu.parallel.mesh import EXPERT_AXIS
@@ -1890,17 +1937,17 @@ class MixtureOfExperts(Module):
                 ep = ep_mesh.shape.get(EXPERT_AXIS, 1)
                 if ep > 1 and self.num_experts % ep == 0:
                     routed = self._apply_capacity_ep(
-                        x, weights, w_gate, w_up, w_down, ep_mesh)
+                        inner, weights, w_gate, w_up, w_down, ep_mesh)
             if routed is None:
-                routed = self._apply_capacity(x, weights, w_gate, w_up,
+                routed = self._apply_capacity(inner, weights, w_gate, w_up,
                                               w_down)
         else:
-            g = jnp.einsum("btd,ehd->bteh", x, w_gate)
-            u = jnp.einsum("btd,ehd->bteh", x, w_up)
-            hidden = self._act(g) * u
+            u = jnp.einsum("btd,ehd->bteh", inner, w_up)
+            g = jnp.einsum("btd,ehd->bteh", inner, w_gate) if gated else None
+            hidden = _expert_hidden(self.activation, u, g)
             y = jnp.einsum("bteh,edh->bted", hidden, w_down)
             routed = jnp.einsum("bted,bte->btd", y, weights)
-        return routed + self._shared(x, ctx)
+        return routed
 
     def _shared(self, x, ctx):
         """The always-on shared expert (Qwen2-MoE): an ordinary gated MLP,
@@ -1908,12 +1955,12 @@ class MixtureOfExperts(Module):
         off; summed with the routed output.  0 without one."""
         if not self.shared_expert_size:
             return jnp.zeros((), x.dtype)
-        sg = jnp.einsum("btd,hd->bth", x,
-                        self._p(ctx, "shared_expert.gate_proj.weight"))
+        sg = (None if self.activation == UNGATED else jnp.einsum(
+            "btd,hd->bth", x, self._p(ctx, "shared_expert.gate_proj.weight")))
         su = jnp.einsum("btd,hd->bth", x,
                         self._p(ctx, "shared_expert.up_proj.weight"))
         shared = jnp.einsum(
-            "bth,dh->btd", self._act(sg) * su,
+            "bth,dh->btd", _expert_hidden(self.activation, su, sg),
             self._p(ctx, "shared_expert.down_proj.weight"))
         if not self.shared_expert_gate:
             return shared
@@ -1953,13 +2000,14 @@ class MixtureOfExperts(Module):
         rows = min(-(-tokens // tile) * tile, bound)
         rounds = -(-bound // rows)
         runs = on_tpu and moe_combine.fits(
-            rows=rows, tokens=tokens, width=self.in_features,
+            rows=rows, tokens=tokens, width=self.latent or self.in_features,
             groups=self.experts_held, places=places)
         return {"experts": self.num_experts, "held": self.experts_held,
                 "first": self.first_expert, "top_k": self.top_k,
                 "rows": rows, "row_tile": tile, "dispatch": self.dispatch,
                 "rows_bound": rounds * rows, "rounds_bound": rounds,
-                "places": places, "combine": "runs" if runs else "take"}
+                "places": places, "combine": "runs" if runs else "take",
+                "latent": self.latent, "activation": self.activation}
 
     def _apply_dropless(self, x, top_vals, top_idx, w_gate, w_up, w_down,
                         ctx):
@@ -2729,9 +2777,12 @@ class GatedSSM(Module):
     Cached serving rides ``ctx.kv.ssm`` (the fixed-size
     :class:`~penroz_tpu.ops.ssm.SSMState` child of any KV variant) through
     the same dense / packed-ragged dispatch as attention; without a cache
-    the full-sequence chunked form runs (Pallas kernel on TPU, scan oracle
-    elsewhere).  ``layer_idx`` indexes the model's *ssm* layers, assigned
-    by the model builder like attention's (models/model.py).
+    the full-sequence form runs: the chunked Pallas kernel on TPU
+    inference, and wherever a gradient is taken (and off the TPU) still the
+    sequential ``lax.scan`` over tokens (``ops/ssm.py::gla_full``): the
+    mixer that trains in chunks is :class:`Mamba2Mixer`.  ``layer_idx``
+    indexes the model's *ssm* layers, assigned by the model builder like
+    attention's (models/model.py).
     """
 
     def __init__(self, num_heads: int, head_dim: int,
@@ -2776,3 +2827,195 @@ class GatedSSM(Module):
             y = ssm_ops.gla_full(q, k, v, g, platform=ctx.platform,
                                  training=ctx.training)
         return y.reshape(B, T, H * dv).astype(x.dtype)
+
+
+class Mamba2Mixer(Module):
+    """A Mamba-2 mixer (SSD, arXiv:2405.21060) on a normed ``(B, T,
+    in_features)`` input, with its own projections, whole or as one rank's
+    share of its heads::
+
+        [z | xBC | dt] = u W_in        d_in | d_in + 2·G·N | H   (d_in = H·P)
+        xBC = silu(conv(xBC))          depthwise, causal, ``conv_kernel`` taps
+        [x | B | C] = xBC              d_in | G·N | G·N
+        Δ = softplus(dt + dt_bias);  A = −exp(A_log);  a = exp(Δ·A)
+        S_t = a_t S_{t−1} + Δ_t x_t ⊗ B_t;   y_t = S_t C_t + D x_t
+        y = GroupRMSNorm(y · silu(z))  G groups of d_in / G, gain d_in wide
+        out = y W_out
+
+    ``num_heads`` heads of ``head_dim`` (P) with a scalar decay each, B and
+    C (``state_size`` N wide) shared by the ``num_heads / n_groups`` heads
+    of a group; no projection bias, a convolution bias.  The recurrence runs
+    a chunk of ``chunk_size`` tokens at a time with its own backward
+    (``ops/ssm.py::ssd_chunked``); Δ, the decays and the state are float32.
+
+    The share: ``heads_held`` heads from ``first_head`` (default all), whole
+    groups of them, so the gated norm, which is a group's, needs nothing
+    from another rank; every parameter is cut to the heads and groups held.
+    What the absent heads would add to ``out`` is left out.
+
+    Trains and runs uncached; the convolution's and the recurrence's state
+    in a KV cache is not written (``CompiledArch.refuse_mixer``)."""
+
+    DT_MAX = Stat("ssd_dt_max", "max", "ssd")
+    LOG_DECAY_ABSMAX = Stat("ssd_log_decay_absmax", "max", "ssd")
+
+    def __init__(self, in_features: int, num_heads: int, head_dim: int,
+                 state_size: int, n_groups: int = 1, conv_kernel: int = 4,
+                 chunk_size: int = 128, heads_held: Optional[int] = None,
+                 first_head: int = 0, eps: float = 1e-5,
+                 init_std: float = 0.02,
+                 out_init_std: Optional[float] = None,
+                 dt_min: float = 0.001, dt_max: float = 0.1,
+                 dt_floor: float = 1e-4):
+        self.in_features, self.num_heads = int(in_features), int(num_heads)
+        self.head_dim, self.state_size = int(head_dim), int(state_size)
+        self.n_groups, self.conv_kernel = int(n_groups), int(conv_kernel)
+        self.chunk_size, self.eps = int(chunk_size), float(eps)
+        if self.num_heads % self.n_groups:
+            raise ValueError(f"num_heads={num_heads} is no multiple of "
+                             f"n_groups={n_groups}")
+        per_group = self.num_heads // self.n_groups
+        held = self.num_heads if heads_held is None else int(heads_held)
+        first = int(first_head)
+        if (held < 1 or held % per_group or first % per_group
+                or not 0 <= first <= self.num_heads - held):
+            raise ValueError(
+                f"heads_held={heads_held} from first_head={first_head} is "
+                f"not whole groups of {per_group} heads within "
+                f"{self.num_heads}")
+        self.heads_held, self.first_head = held, first
+        self.groups_held = held // per_group
+        self.d_inner = held * self.head_dim
+        self.bc_dim = self.groups_held * self.state_size
+        self.init_std = float(init_std)
+        self.out_init_std = float(init_std if out_init_std is None
+                                  else out_init_std)
+        self.dt_range = (float(dt_min), float(dt_max), float(dt_floor))
+
+    def stats(self):
+        return (self.DT_MAX, self.LOG_DECAY_ABSMAX)
+
+    def param_shapes(self):
+        d, conv = self.in_features, self.d_inner + 2 * self.bc_dim
+        return {"in_proj.weight": (self.d_inner + conv + self.heads_held, d),
+                "conv1d.weight": (conv, self.conv_kernel),
+                "conv1d.bias": (conv,),
+                "dt_bias": (self.heads_held,),
+                "A_log": (self.heads_held,),
+                "D": (self.heads_held,),
+                "norm.weight": (self.d_inner,),
+                "out_proj.weight": (d, self.d_inner)}
+
+    def init(self, rng):
+        """Projections N(0, ``init_std``) (the output's ``out_init_std``),
+        the convolution as a torch ``Conv1d`` of its fan-in, ``A_log = log
+        U(1, 16)``, ``dt_bias`` the inverse softplus of a log-uniform draw
+        in [``dt_min``, ``dt_max``] floored at ``dt_floor``, D and the gain
+        1 (the published modelling code's)."""
+        shapes = self.param_shapes()
+        k = dict(zip(shapes, jax.random.split(rng, len(shapes))))
+        lo, hi, floor = self.dt_range
+        dt = jnp.maximum(jnp.exp(jax.random.uniform(
+            k["dt_bias"], shapes["dt_bias"], jnp.float32,
+            math.log(lo), math.log(hi))), floor)
+        bound = self.conv_kernel ** -0.5
+        own = {
+            "in_proj.weight": self.init_std * jax.random.normal(
+                k["in_proj.weight"], shapes["in_proj.weight"], jnp.float32),
+            "out_proj.weight": self.out_init_std * jax.random.normal(
+                k["out_proj.weight"], shapes["out_proj.weight"], jnp.float32),
+            "conv1d.weight": _uniform(k["conv1d.weight"],
+                                      shapes["conv1d.weight"], bound),
+            "conv1d.bias": _uniform(k["conv1d.bias"], shapes["conv1d.bias"],
+                                    bound),
+            "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+            "A_log": jnp.log(jax.random.uniform(
+                k["A_log"], shapes["A_log"], jnp.float32, 1.0, 16.0)),
+            "D": jnp.ones(shapes["D"], jnp.float32),
+            "norm.weight": jnp.ones(shapes["norm.weight"], jnp.float32)}
+        return {self.key(name): value for name, value in own.items()}
+
+    def plan(self, batch: int, seq: int) -> dict:
+        """The mixer's counters for ``(batch, seq)`` tokens: its sizes, the
+        share, and the scan's (``ops/ssm.py::ssd_plan``)."""
+        from penroz_tpu.ops import ssm as ssm_ops
+        scan = ssm_ops.ssd_plan(seq, self.heads_held, self.groups_held,
+                                self.head_dim, self.state_size,
+                                self.chunk_size, batch)
+        return {"heads": self.num_heads, "held": self.heads_held,
+                "groups": self.groups_held, "state": self.state_size,
+                "head_dim": self.head_dim, "chunk": self.chunk_size,
+                "conv_kernel": self.conv_kernel, "T": seq,
+                "path": scan["path"],
+                "boundary_bytes": scan["boundary_bytes"]}
+
+    def _conv(self, x, ctx):
+        """Depthwise causal convolution a channel and its bias, float32:
+        ``y_t = b + Σ_k w_k x_{t − (K − 1) + k}``, zeros before the
+        sequence."""
+        w = ctx.params[self.key("conv1d.weight")].astype(jnp.float32)
+        bias = ctx.params[self.key("conv1d.bias")].astype(jnp.float32)
+        K, T = self.conv_kernel, x.shape[1]
+        padded = jnp.pad(x.astype(jnp.float32), ((0, 0), (K - 1, 0), (0, 0)))
+        return bias + sum(padded[:, k:k + T] * w[:, k] for k in range(K))
+
+    def apply(self, x, ctx):
+        from penroz_tpu.ops import ssm as ssm_ops
+        if ctx.kv is not None:
+            raise ValueError("a Mamba-2 mixer has no KV-cache path (its "
+                             "convolution and recurrent state are not "
+                             "written into the cache)")
+        B, T, _ = x.shape
+        H, P, G, N = (self.heads_held, self.head_dim, self.groups_held,
+                      self.state_size)
+        _record_plan("ssd", **self.plan(B, T))
+        f32 = lambda name: ctx.params[self.key(name)].astype(jnp.float32)
+        proj = jnp.matmul(x, self._p(ctx, "in_proj.weight").T)
+        z = proj[..., :self.d_inner]
+        xbc = jax.nn.silu(self._conv(proj[..., self.d_inner:-H],
+                                     ctx)).astype(x.dtype)
+        dt = jax.nn.softplus(proj[..., -H:].astype(jnp.float32)
+                             + f32("dt_bias"))
+        A = -jnp.exp(f32("A_log"))
+        xs = xbc[..., :self.d_inner].reshape(B, T, H, P)
+        Bm = xbc[..., self.d_inner:self.d_inner + G * N].reshape(B, T, G, N)
+        Cm = xbc[..., self.d_inner + G * N:].reshape(B, T, G, N)
+        if ctx.training:
+            # how near a chunk's whole decay exp(Σ Δ·A) comes to underflow
+            pad = -T % self.chunk_size
+            whole = jnp.pad(dt * A, ((0, 0), (0, pad), (0, 0))).reshape(
+                B, -1, self.chunk_size, H)
+            ctx.report(self.LOG_DECAY_ABSMAX,
+                       jnp.max(jnp.abs(jnp.sum(whole, axis=2))))
+            ctx.report(self.DT_MAX, jnp.max(dt))
+        y = ssm_ops.ssd_chunked(xs, dt, A, Bm, Cm, self.chunk_size)
+        y = y + f32("D")[:, None] * xs.astype(jnp.float32)
+        y = y.reshape(B, T, self.d_inner) * jax.nn.silu(
+            z.astype(jnp.float32))
+        grouped = y.reshape(B, T, G, -1)
+        grouped = grouped * jax.lax.rsqrt(
+            jnp.mean(jnp.square(grouped), axis=-1, keepdims=True) + self.eps)
+        y = (grouped.reshape(B, T, self.d_inner)
+             * f32("norm.weight")).astype(x.dtype)
+        return jnp.matmul(y, self._p(ctx, "out_proj.weight").T)
+
+
+class MixerBlock(Module):
+    """One mixer a layer: ``x + mixer(norm(x))``, the layer of a stack whose
+    layers are each a state-space mixer, an expert layer or attention
+    (``hybrid_override_pattern``) and not attention-then-MLP.  In training
+    the layer runs under :func:`_recomputed`: the backward keeps its input
+    (and what the kernels name) and runs its inside again.  That is a
+    property of the container, as :class:`Looped`'s."""
+
+    def __init__(self, norm: Module, mixer: Module):
+        self.norm, self.mixer = norm, mixer
+
+    def children(self):
+        return [("norm", self.norm), ("mixer", self.mixer)]
+
+    def _apply(self, ctx, x):
+        return x + self.mixer.apply(self.norm.apply(x, ctx), ctx)
+
+    def apply(self, x, ctx):
+        return _recomputed(self._apply, ctx, [self], x)

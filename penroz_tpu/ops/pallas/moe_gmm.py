@@ -58,8 +58,14 @@ def _dot(a, b, contract):
 
 
 def _tile(n: int, want: int) -> int:
-    """``want`` where it divides ``n`` (lane-aligned shapes), else all."""
-    return want if n % want == 0 else n
+    """``want`` where it divides ``n``; else the largest multiple of the 128
+    lanes under it that does (2688 = 21 · 128 takes 384: the whole width as
+    one block is past the core's VMEM beside a 1024-long contraction); all
+    of ``n`` where none does (shapes off the lanes: the tests')."""
+    if n % want == 0:
+        return want
+    return next((t for t in range(want - want % 128, 0, -128) if n % t == 0),
+                n)
 
 
 def _gmm_kernel(group_ref, lhs_ref, rhs_ref, out_ref, *, groups: int,

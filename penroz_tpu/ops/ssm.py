@@ -18,8 +18,14 @@ Three execution forms, all bit-identical in greedy decoding because every
 - ``update_packed`` — the unified ragged path: scan over the Tp packed slots
   of a ``build_descriptors`` block layout, read-modify-write per valid slot
   (mirrors ``PagedKVState.append_packed`` addressing).
-- ``gla_full``      — no-cache training/eval: jnp sequential oracle on CPU,
-  chunked Pallas kernel (ops/pallas/ssm_scan.py) on TPU inference.
+- ``gla_full``      — no-cache training/eval of :class:`GatedSSM`: the
+  sequential ``lax.scan`` over tokens wherever a gradient is taken and on
+  the CPU (the serving kernel defines no VJP), the chunked Pallas kernel
+  (ops/pallas/ssm_scan.py) on TPU inference.
+- ``ssd_chunked``   — the same recurrence as a Mamba-2 mixer trains it
+  (arXiv:2405.21060): a scalar decay a head, B and C shared by a group of
+  heads, computed a chunk at a time with its own backward that keeps the
+  chunk-boundary states and walks chunks, never tokens.
 
 Checkpoint ring (exact spec-decode rollback): every token write also stores
 the post-token state in a ring of ``ckpt_slots`` slots keyed by the *length
@@ -315,3 +321,161 @@ def gla_full(q, k, v, g, platform=None, training: bool = False):
                                    ("b.h.", "b.h.", "b.h.", "b.h"), "b.h.",
                                    q, k, v, g)
     return gla_full_reference(q, k, v, g)
+
+
+# ---------------------------------------------------------------------------
+# Mamba-2's form (SSD, arXiv:2405.21060), trained: a scalar decay a head,
+# B and C shared by a group of heads, a chunk at a time
+# ---------------------------------------------------------------------------
+
+def ssd_plan(T: int, heads: int, groups: int, head_dim: int, state: int,
+             chunk: int, batch: int = 1) -> dict:
+    """The static sizes of :func:`ssd_chunked` for ``T`` tokens: ``chunks``
+    of ``chunk`` tokens (``T`` padded up to whole chunks with steps that
+    change nothing), and the bytes of the float32 chunk-boundary states, the
+    one thing the backward keeps beside what it was given.  ``path``:
+    ``chunked`` is this file's ``jnp`` form, the only one written."""
+    chunks = -(-T // chunk)
+    return {"chunks": chunks, "padded": chunks * chunk - T, "path": "chunked",
+            "boundary_bytes": 4 * batch * chunks * heads * head_dim * state}
+
+
+def _ssd_log_decay(dt, A):
+    """``(B, c, L, H)`` float32: the running sum inside each chunk of
+    ``dt · A`` (inclusive), always <= 0."""
+    return jnp.cumsum(dt * A, axis=2)
+
+
+def _by_group(t, groups: int):
+    """``(B, c, L, H, …)`` with its heads split as ``(groups, heads a
+    group)``."""
+    return t.reshape(t.shape[:3] + (groups, -1) + t.shape[4:])
+
+
+def _ssd_chunk_states(x, dt, A, Bm):
+    """What each chunk adds to the state by its end, from a zero state,
+    ``(B, c, H, P, N)`` float32, and the decay over the whole chunk ``(B, c,
+    H)``: ``Σ_s exp(l_end − l_s) dt_s x_s (x) B_s`` and ``exp(l_end)``."""
+    G = Bm.shape[3]
+    la = _ssd_log_decay(dt, A)
+    w = jnp.exp(la[:, :, -1:, :] - la) * dt             # (B, c, L, H) f32
+    xw = (x.astype(jnp.float32) * w[..., None]).astype(x.dtype)
+    states = jnp.einsum("bcsgjp,bcsgn->bcgjpn", _by_group(xw, G), Bm,
+                        preferred_element_type=jnp.float32)
+    return (states.reshape(states.shape[:2] + (-1,) + states.shape[4:]),
+            jnp.exp(la[:, :, -1, :]))
+
+
+def _ssd_outputs(x, dt, A, Bm, Cm, s_in):
+    """``y (B, c, L, H, P)`` float32 given each chunk's entering state
+    ``s_in (B, c, H, P, N)``: inside a chunk the masked product with the
+    decay differences ``exp(l_t − l_s)``, ``s <= t``, and the entering state
+    read through ``C`` and decayed to ``t``."""
+    G = Bm.shape[3]
+    L = x.shape[2]
+    la = _by_group(_ssd_log_decay(dt, A), G)            # (B, c, L, G, J)
+    scores = jnp.einsum("bclgn,bcsgn->bcgls", Cm, Bm,
+                        preferred_element_type=jnp.float32)
+    below = jnp.tril(jnp.ones((L, L), bool), -1)
+    # (B, c, G, J, L, S): exponents <= 0 where kept, so nothing overflows;
+    # a token's own place is exp(0) by construction, not l_t − l_t, so that
+    # the largest terms of the product take no part in the log-decays'
+    # gradient, where they would cancel to rounding error
+    diff = (la.transpose(0, 1, 3, 4, 2)[..., :, None]
+            - la.transpose(0, 1, 3, 4, 2)[..., None, :])
+    decay = jnp.exp(jnp.where(below, diff, jnp.where(jnp.eye(L, dtype=bool),
+                                                      0.0, -jnp.inf)))
+    mix = (scores[:, :, :, None] * decay
+           * _by_group(dt, G).transpose(0, 1, 3, 4, 2)[..., None, :])
+    xg = _by_group(x, G)
+    y = jnp.einsum("bcgjls,bcsgjp->bclgjp", mix.astype(x.dtype), xg,
+                   preferred_element_type=jnp.float32)
+    sg = s_in.reshape(s_in.shape[:2] + (G, -1) + s_in.shape[3:])
+    carried = jnp.einsum("bclgn,bcgjpn->bclgjp", Cm, sg.astype(Cm.dtype),
+                         preferred_element_type=jnp.float32)
+    y = y + carried * jnp.exp(la)[..., None]
+    return y.reshape(x.shape)
+
+
+def _ssd_entering_states(states, decay):
+    """The state each chunk starts from, ``(B, c, H, P, N)``: zero, then
+    ``S ← decay_c · S + states_c`` a chunk — ``c`` steps, not ``T``."""
+    def step(S, part):
+        added, d = part
+        return d[..., None, None] * S + added, S
+
+    _, entering = jax.lax.scan(
+        step, jnp.zeros_like(states[:, 0]),
+        (states.swapaxes(0, 1), decay.swapaxes(0, 1)))
+    return entering.swapaxes(0, 1)
+
+
+@jax.custom_vjp
+def _ssd_core(x, dt, A, Bm, Cm):
+    return _ssd_core_fwd(x, dt, A, Bm, Cm)[0]
+
+
+def _ssd_core_fwd(x, dt, A, Bm, Cm):
+    states, decay = _ssd_chunk_states(x, dt, A, Bm)
+    s_in = _ssd_entering_states(states, decay)
+    # kept: what the call was given and the chunk-boundary states
+    return _ssd_outputs(x, dt, A, Bm, Cm, s_in), (x, dt, A, Bm, Cm, s_in)
+
+
+def _ssd_core_bwd(kept, dy):
+    """Chunks again, backwards: each chunk's inside is computed again from
+    what was given and its entering state, and the cotangent of the state
+    walks the ``c`` boundaries in reverse."""
+    x, dt, A, Bm, Cm, s_in = kept
+    _, pull_out = jax.vjp(_ssd_outputs, x, dt, A, Bm, Cm, s_in)
+    dx, ddt, dA, dB, dC, ds_in = pull_out(dy)
+    (_, decay), pull_states = jax.vjp(_ssd_chunk_states, x, dt, A, Bm)
+
+    def step(g_next, part):
+        # g_next: the cotangent of the state that leaves this chunk
+        ds, d = part
+        return ds + d[..., None, None] * g_next, g_next
+
+    _, g_out = jax.lax.scan(
+        step, jnp.zeros_like(ds_in[:, 0]),
+        (ds_in.swapaxes(0, 1), decay.swapaxes(0, 1)), reverse=True)
+    g_out = g_out.swapaxes(0, 1)
+    ddecay = jnp.sum(g_out * s_in, axis=(-2, -1))
+    dx2, ddt2, dA2, dB2 = pull_states((g_out, ddecay))
+    return dx + dx2, ddt + ddt2, dA + dA2, dB + dB2, dC
+
+
+_ssd_core.defvjp(_ssd_core_fwd, _ssd_core_bwd)
+
+
+def ssd_chunked(x, dt, A, Bm, Cm, chunk: int = 128):
+    """``y_t = S_t C_t`` of the recurrence ``S_t = exp(dt_t A) S_{t-1} +
+    dt_t x_t ⊗ B_t``, float32, a chunk of ``chunk`` tokens at a time (the
+    state-space duality of arXiv:2405.21060): inside a chunk matmuls,
+    between chunks the ``(P, N)`` state carried.  ``x (B, T, H, P)``, ``dt
+    (B, T, H)``, ``A (H,)`` negative, ``Bm``/``Cm (B, T, G, N)``, head
+    ``h`` reading group ``h // (H/G)``; the token-by-token form is the
+    benchmark's reference's (``reference/nemotron_h.py::ssd_recurrence``),
+    which tier-1 holds this to, values and gradients.  Its derivative is
+    its own (:func:`_ssd_core_bwd`): it keeps the chunk-boundary states
+    (:func:`ssd_plan`'s ``boundary_bytes``) and neither a state a token nor
+    ``T`` sequential steps.  The decay statistics (``dt``, the log-decays,
+    their running sums, the state) are float32 whatever ``x``'s type; the
+    products take ``x``'s, ``Bm``'s and ``Cm``'s type and accumulate in
+    float32.  ``T`` is padded to whole chunks with steps of ``dt = 0``,
+    which neither decay nor add."""
+    B, T, H, P = x.shape
+    G = Bm.shape[2]
+    if H % G or Bm.shape != Cm.shape or dt.shape != (B, T, H):
+        raise ValueError(f"ssd: x {x.shape}, dt {dt.shape}, B {Bm.shape}, "
+                         f"C {Cm.shape} do not fit (heads a multiple of "
+                         f"groups, dt a value a token and head)")
+    pad = -T % chunk
+    dt = dt.astype(jnp.float32)
+    if pad:
+        x, dt, Bm, Cm = (jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),)
+                                 * (t.ndim - 2)) for t in (x, dt, Bm, Cm))
+    chunks = lambda t: t.reshape((B, -1, chunk) + t.shape[2:])
+    y = _ssd_core(chunks(x), chunks(dt), A.astype(jnp.float32), chunks(Bm),
+                  chunks(Cm))
+    return y.reshape(B, T + pad, H, P)[:, :T]

@@ -40,7 +40,8 @@ gauges     engines, active_rows, queue_depth, batch_occupancy,
            jit_programs{function} (live compiled-program count per jit
            family — the ragged descriptor compile-churn guard),
            train_pass_loss{pass}, train_exit_mass{pass},
-           train_moe{counter}, train_hc{counter} (what a model's modules
+           train_moe{counter}, train_hc{counter}, train_ssd{counter}
+           (what a model's modules
            declare to report of a training epoch, newest /train/ epoch:
            utils/tracing.py::TRAIN_FAMILIES)
 histograms ttft_ms, itl_ms, queue_wait_ms, chunk_stall_ms, tick_ms

@@ -154,6 +154,9 @@ TRAIN_FAMILIES = {
     "hc": ("counter", "Largest values of the newest /train/ epoch over "
            "layers and micro-steps (a multi-stream residual's Sinkhorn "
            "error, a router's selection bias)"),
+    "ssd": ("counter", "Largest values of the newest /train/ epoch over "
+            "layers and micro-steps (a Mamba-2 mixer's step size and the "
+            "log-decay summed over a chunk)"),
 }
 _TRAIN_STATS: dict = {}     # family -> {label value: newest reading}
 TRAIN_STAT_GAUGES = [

@@ -697,7 +697,8 @@ def test_a_laguna_share_trains_on_the_normal_path_and_counts_its_routing(app):
     assert plans and all(p == {
         "experts": 16, "held": 4, "first": 4, "top_k": top_k, "rows": 128,
         "row_tile": 128, "dispatch": "dropless", "rows_bound": 640,
-        "rounds_bound": 5, "places": 4, "combine": "take"}
+        "rounds_bound": 5, "places": 4, "combine": "take", "latent": 0,
+        "activation": "silu"}
         for p in plans), plans[0]
     # an epoch's pairs, held or not: ``batch`` micro-steps of batch x BLOCK
     pairs = batch * batch * BLOCK * top_k * sparse
